@@ -842,7 +842,7 @@ class Accelerator:
         disk_store = info.get("disk_store")
 
         def base_fn(p):
-            from .utils.jax_compat import Space
+            from jax.memory import Space
 
             # host-resident source params (init_params_on_host) stream in;
             # the unused opt_state computation is dead code XLA eliminates
@@ -865,7 +865,7 @@ class Accelerator:
             orig_pos = {j: k for k, j in enumerate(orig_ids)}
 
             def chunk_init(chunk_leaves, group=group, masked=masked, orig_pos=orig_pos):
-                from .utils.jax_compat import Space
+                from jax.memory import Space
 
                 from .utils.chunked_update import fill_view
 
@@ -983,6 +983,15 @@ class Accelerator:
             return replicated
 
         return jax.tree_util.tree_map_with_path(rule, abstract_state)
+
+    def _pin_state_placement(self, state: TrainState) -> TrainState:
+        """Constrain a traced state to the placement :meth:`create_train_state`
+        gives it.  Left free, XLA's sharding propagation returns leaves the
+        policy keeps replicated (norm scales under ``min_weight_size``) sharded
+        over ``fsdp``: the state then comes back from its first step placed
+        differently from how it went in, and the second step compiles again."""
+        shardings = self._train_state_shardings(jax.eval_shape(lambda s: s, state))
+        return jax.lax.with_sharding_constraint(state, shardings)
 
     def _shard_train_state(self, state: TrainState) -> TrainState:
         abstract = jax.eval_shape(lambda s: s, state)
@@ -1377,7 +1386,7 @@ class Accelerator:
             micro programs — the sync program emits ``avg`` (aliased into the
             donated accumulation buffer) and no ``grad_accum``, the micro
             program the reverse, saving a params-sized buffer each."""
-            from .utils.jax_compat import Space
+            from jax.memory import Space
 
             # Host-offloaded params stream to HBM for the step and back after
             # (ZeRO-offload; reference DeepSpeedPlugin.offload_*_device).  The
@@ -1522,6 +1531,8 @@ class Accelerator:
 
             if offload_params:
                 new_state = new_state.replace(params=jax.device_put(new_state.params, Space.Host))
+            elif not (offload_opt or chunked):
+                new_state = self._pin_state_placement(new_state)
 
             return new_state, metrics
 
@@ -1839,7 +1850,7 @@ class Accelerator:
         def _step(state_or_params, batch):
             params = state_or_params.params if isinstance(state_or_params, TrainState) else state_or_params
             if offload_params:
-                from .utils.jax_compat import Space
+                from jax.memory import Space
 
                 params = jax.device_put(params, Space.Device)
             batch = self._constrain_batch(batch)
@@ -1913,7 +1924,7 @@ class Accelerator:
 
             def _grad(state, batch):
                 if offload_params:
-                    from .utils.jax_compat import Space
+                    from jax.memory import Space
 
                     state = state.replace(params=jax.device_put(state.params, Space.Device))
                 if state.rng is not None:
@@ -1969,7 +1980,7 @@ class Accelerator:
                 if offloading:
                     # Stream host-offloaded leaves to HBM for the update and back
                     # (same round-trip the compiled step does on sync steps).
-                    from .utils.jax_compat import Space
+                    from jax.memory import Space
 
                     if offload_params:
                         state = state.replace(params=jax.device_put(state.params, Space.Device))
@@ -1996,7 +2007,7 @@ class Accelerator:
                 if state.rng is not None:
                     new = new.replace(rng=jax.random.split(state.rng)[0])
                 if offloading:
-                    from .utils.jax_compat import Space
+                    from jax.memory import Space
 
                     if offload_params:
                         new = new.replace(params=jax.device_put(new.params, Space.Host))

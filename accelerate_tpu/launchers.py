@@ -115,10 +115,8 @@ def notebook_launcher(
         os.environ["ACCELERATE_NUM_PROCESSES"] = str(num_nodes)
         os.environ["ACCELERATE_PROCESS_ID"] = str(node_rank)
         return function(*args)
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:
-        platform = "cpu"
+    # a backend that fails to start raises here — it is not reported as "cpu"
+    platform = jax.devices()[0].platform
     if platform == "cpu" and num_processes and num_processes > 1:
         return debug_launcher(function, args, num_processes)
     if num_processes and num_processes > 1:

@@ -187,9 +187,6 @@ class TransformerConfig:
     # runtime shape).  None adds parameters, so one set of params serves
     # Transformers differing only in these fields.
     paged_kernel: str = "xla"
-    # pallas interpret-mode override for the paged kernel; None = auto
-    # (interpret off TPU — the CPU-testing discipline)
-    paged_interpret: Optional[bool] = None
 
     @property
     def resolved_head_dim(self) -> int:
@@ -326,7 +323,7 @@ class PagedKVCache(struct.PyTreeNode):
     """Paged KV cache: the serving page pool threaded *through* the model.
 
     Where :class:`KVCache` owns a contiguous per-lane slab, this carries the
-    shared refcounted page pool (``[L, num_pages, page, Hkv, D]``) plus each
+    shared refcounted page pool (``[L, num_pages, Hkv, page, D]``) plus each
     lane's block table — attention reads pages in place
     (:mod:`accelerate_tpu.ops.paged_attention`), selected by
     ``TransformerConfig.paged_kernel``.  Scales are ALWAYS present (ones for
@@ -339,7 +336,7 @@ class PagedKVCache(struct.PyTreeNode):
     the engine surfaces it as ``serve/kv_quant_error``.
     """
 
-    pages_k: jax.Array      # [L, num_pages, page, n_kv_heads, head_dim]
+    pages_k: jax.Array      # [L, num_pages, n_kv_heads, page, head_dim]
     pages_v: jax.Array
     k_scales: jax.Array     # [L, num_pages, n_kv_heads] f32 dequant scales
     v_scales: jax.Array
@@ -350,7 +347,7 @@ class PagedKVCache(struct.PyTreeNode):
 
     @property
     def max_len(self) -> int:
-        return self.tables.shape[1] * self.pages_k.shape[2]
+        return self.tables.shape[1] * self.pages_k.shape[3]
 
 
 def cached_attention(q, k, v, q_positions, window=None, alibi=False,
@@ -608,8 +605,7 @@ class Attention(nn.Module):
             if cfg.paged_kernel == "pallas":
                 out = paged_attention(
                     q, pages_k, pages_v, tables, index,
-                    k_scales=sk, v_scales=sv, interpret=cfg.paged_interpret,
-                    tree_mask=tree_mask,
+                    k_scales=sk, v_scales=sv, tree_mask=tree_mask,
                 )
             elif cfg.paged_kernel == "flash_prefill":
                 if tree_mask is not None:
@@ -619,7 +615,7 @@ class Attention(nn.Module):
                     )
                 out = paged_flash_prefill(
                     q, pages_k, pages_v, tables, index,
-                    k_scales=sk, v_scales=sv, interpret=cfg.paged_interpret,
+                    k_scales=sk, v_scales=sv,
                 )
             else:
                 out = paged_attention_reference(

@@ -11,8 +11,8 @@ kernels, ``utils/megatron_lm.py``); here the implementations are:
     Halves attention matmul FLOPs *and* the S^2 logits bandwidth vs ``"xla"``
     (which materializes the full square), keeps GQA KV heads unexpanded, and
     needs no custom kernel: on a v5e at seq 2048 / GQA 32:4 / head-dim 64 it
-    out-ran XLA's path, the in-tree pallas flash, and splash attention (see
-    BENCH_NOTES.md round-4 sweep).
+    out-ran XLA's path, the in-tree pallas flash, and splash attention (an
+    earlier round's sweep; not measured on today's code).
   - ``"pallas"``: hand-written flash attention kernel (``ops/flash_attention.py``).
   - ``"ring"``: sequence-parallel ring attention over an ``sp`` mesh axis
     (``parallel/ring_attention.py``) — net-new capability vs the reference

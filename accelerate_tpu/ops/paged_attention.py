@@ -1,9 +1,11 @@
 """Paged decode attention: a Pallas kernel that reads KV straight from the page pool.
 
 The serving engine's paged windows (:mod:`accelerate_tpu.serving.pool`) keep
-every lane's KV in a shared refcounted page pool ``[num_pages, page, Hkv, D]``
-addressed through per-lane block tables.  PR 6 ran attention by *gathering*
-each lane's pages into a contiguous slab-width view — bitwise-identical logits,
+every lane's KV in a shared refcounted page pool ``[num_pages, Hkv, page, D]``
+addressed through per-lane block tables.  The kv-head axis sits OUTSIDE the
+page: Mosaic tiles the last two axes of a block, so a ``(page, D)`` tile per
+(page, kv-head) is the block the TPU compiler accepts.  PR 6 ran attention by
+*gathering* each lane's pages into a contiguous slab-width view — bitwise-identical logits,
 but every decode step moves ``pages_per_lane * page`` KV rows per lane through
 HBM even when the lane holds three tokens.  This module removes the gather:
 
@@ -93,25 +95,42 @@ def kv_qmax(dtype) -> Optional[float]:
 
 def resolve_paged_kernel(kernel: str, mesh=None, tp_axis: str = "tp",
                          role: str = "decode") -> str:
-    """Shard-aware kernel dispatch: under a tensor-parallel mesh the Pallas
-    grid would read whole ``(kv-head, page)`` tiles of a head-sharded pool, so
-    ``"pallas"`` falls back to the pure-XLA reference — the einsum partitions
-    head-parallel under GSPMD for free.  tp=1 meshes (and no mesh at all) keep
-    the requested kernel.
+    """Shard-aware kernel check: the Pallas grid reads whole ``(kv-head,
+    page)`` tiles of an unsharded pool, so under a tensor-parallel mesh
+    (tp > 1) a requested ``"pallas"`` cannot be honoured and RAISES — it is
+    never swapped for the XLA reference behind the caller's back.  tp=1
+    meshes (and no mesh at all) keep the requested kernel; ``"xla"`` (whose
+    einsum partitions head-parallel under GSPMD) passes everywhere.
 
     ``role`` names which pool program is being resolved — ``"decode"``
     (:func:`paged_attention`), ``"prefill"`` (:func:`paged_flash_prefill`) or
     ``"tree_verify"`` (the decode kernel carrying a token-tree ancestor mask
     for speculative tree verification).  All walk the same head-sharded page
-    pool through the same scalar-prefetched block tables, so the fallback
-    condition is identical; the arms exist so no caller can route any of them
-    around the sharding check."""
+    pool through the same scalar-prefetched block tables, so the condition is
+    identical; the arms exist so no caller can route any of them around the
+    sharding check."""
     if role not in ("decode", "prefill", "tree_verify"):
         raise ValueError(f"unknown paged-kernel role {role!r}")
-    if kernel != "pallas" or mesh is None:
-        return kernel
-    tp = mesh.shape[tp_axis] if tp_axis in mesh.axis_names else 1
-    return "xla" if tp > 1 else kernel
+    if kernel == "pallas" and mesh is not None:
+        tp = mesh.shape[tp_axis] if tp_axis in mesh.axis_names else 1
+        if tp > 1:
+            raise ValueError(
+                f"{role} kernel 'pallas' is single-chip: it addresses an "
+                f"unsharded page pool and cannot run under {tp_axis}={tp}; "
+                f"ask for the 'xla' kernel on a tensor-parallel mesh"
+            )
+    return kernel
+
+
+def _lane_scales(tables, k_scales, v_scales, quantized: bool):
+    """The kernels' scale operands: each ``[NP, Hkv]`` table gathered through
+    the block tables into per-lane rows ``[N, Hkv, 1, P]``, so one VMEM block
+    per (lane, kv-head) carries every page's scale.  Native-dtype pools pass
+    no scale operands at all."""
+    if not quantized:
+        return ()
+    return tuple(sc[tables].transpose(0, 2, 1)[:, :, None, :]
+                 for sc in (k_scales, v_scales))
 
 
 def _live_pages(lengths: jax.Array, s: int, page: int) -> jax.Array:
@@ -122,20 +141,21 @@ def _live_pages(lengths: jax.Array, s: int, page: int) -> jax.Array:
 
 # ------------------------------------------------------------------- writes
 def paged_insert(pages, new, tables, index, active):
-    """Scatter ``new [N, S, H, D]`` into ``pages [NP, page, H, D]`` at
+    """Scatter ``new [N, S, H, D]`` into ``pages [NP, H, page, D]`` at
     positions ``index[n] .. index[n] + S - 1`` through lane ``n``'s block
     table.  Inactive lanes are rerouted to the null page — a lane mid-prefill
     has real (possibly shared) pages mapped and a stale index that must never
     trample them.  Values are cast to the page dtype exactly as the slab pool
     casts into its cache, so native-dtype storage stays bitwise identical."""
     n, s, h, d = new.shape
-    page = pages.shape[1]
+    page = pages.shape[2]
     p_max = tables.shape[1] - 1
     pos = index[:, None] + jnp.arange(s)[None, :]                    # [N, S]
     pid = jnp.take_along_axis(tables, jnp.clip(pos // page, 0, p_max), axis=1)
     pid = jnp.where(active[:, None], pid, NULL_PAGE)
     off = pos % page
-    return pages.at[pid.reshape(-1), off.reshape(-1)].set(
+    # advanced indices split by a slice: the indexed rows lead, [N*S, H, D]
+    return pages.at[pid.reshape(-1), :, off.reshape(-1)].set(
         new.astype(pages.dtype).reshape(n * s, h, d)
     )
 
@@ -144,7 +164,7 @@ def paged_quantized_insert(pages, scales, new, tables, index, active,
                            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Quantized scatter: requantize every page the ``S`` new positions touch.
 
-    ``pages [NP, page, H, D]`` (int8 / fp8-e4m3), ``scales [NP, H]`` f32 with
+    ``pages [NP, H, page, D]`` (int8 / fp8-e4m3), ``scales [NP, H]`` f32 with
     ``dequant = pages * scales``.  Returns ``(pages, scales, max_abs_err)``
     where the error is the largest round-trip quantization error over the
     newly written values — the measurable upper bound the engine exposes as
@@ -161,7 +181,7 @@ def paged_quantized_insert(pages, scales, new, tables, index, active,
     if qmax is None:
         raise ValueError(f"pages dtype {pages.dtype} is not a quantized KV format")
     n, s, h, d = new.shape
-    page = pages.shape[1]
+    page = pages.shape[2]
     p_max = tables.shape[1] - 1
     t = (s + page - 2) // page + 1              # max pages a span of S can touch
     p0 = index // page
@@ -171,34 +191,32 @@ def paged_quantized_insert(pages, scales, new, tables, index, active,
     pid = jnp.take_along_axis(tables, jnp.clip(pt, 0, p_max), axis=1)
     pid = jnp.where(touched, pid, NULL_PAGE)                         # [N, T]
 
-    old = pages[pid].astype(jnp.float32) * scales[pid][:, :, None, :, None]
+    old = pages[pid].astype(jnp.float32) * scales[pid][..., None, None]
     g = pt[:, :, None] * page + jnp.arange(page)[None, None, :]      # [N, T, page]
     i_new = g - index[:, None, None]
-    use_new = (i_new >= 0) & (i_new < s)
+    use_new = ((i_new >= 0) & (i_new < s))[:, :, None, :, None]
     gathered = jnp.take_along_axis(
         new.astype(jnp.float32), jnp.clip(i_new, 0, s - 1).reshape(n, t * page)[:, :, None, None],
         axis=1,
-    ).reshape(n, t, page, h, d)
-    keep_old = g < index[:, None, None]          # valid history, strictly pre-frontier
-    content = jnp.where(
-        use_new[..., None, None], gathered,
-        jnp.where(keep_old[..., None, None], old, 0.0),
-    )
-    amax = jnp.max(jnp.abs(content), axis=(2, 4))                    # [N, T, H]
+    ).reshape(n, t, page, h, d).transpose(0, 1, 3, 2, 4)        # [N, T, H, page, D]
+    # valid history, strictly pre-frontier
+    keep_old = (g < index[:, None, None])[:, :, None, :, None]
+    content = jnp.where(use_new, gathered, jnp.where(keep_old, old, 0.0))
+    amax = jnp.max(jnp.abs(content), axis=(3, 4))                    # [N, T, H]
     new_scales = jnp.maximum(amax, 1e-8) / qmax
-    q = content / new_scales[:, :, None, :, None]
+    q = content / new_scales[..., None, None]
     if jnp.dtype(pages.dtype) == jnp.dtype(jnp.int8):
         q = jnp.clip(jnp.round(q), -qmax, qmax)
     q = q.astype(pages.dtype)
     err = jnp.max(
         jnp.where(
-            use_new[..., None, None],
-            jnp.abs(q.astype(jnp.float32) * new_scales[:, :, None, :, None] - content),
+            use_new,
+            jnp.abs(q.astype(jnp.float32) * new_scales[..., None, None] - content),
             0.0,
         )
     )
     flat = pid.reshape(-1)
-    pages = pages.at[flat].set(q.reshape(n * t, page, h, d))
+    pages = pages.at[flat].set(q.reshape(n * t, h, page, d))
     scales = scales.at[flat].set(new_scales.reshape(n * t, h))
     return pages, scales, err
 
@@ -209,7 +227,7 @@ def paged_attention_reference(q, pages_k, pages_v, tables, lengths,
                               alibi: bool = False, tree_mask=None):
     """Pure-XLA oracle/fallback: live-masked gather + the slab attention math.
 
-    ``q [N, S, Hq, D]`` against pages ``[NP, page, Hkv, D]`` through
+    ``q [N, S, Hq, D]`` against pages ``[NP, Hkv, page, D]`` through
     ``tables [N, P]``; query ``i`` of lane ``n`` sits at position
     ``lengths[n] + i`` and sees keys ``j <= lengths[n] + i`` (the new
     positions' KV must already be inserted).  Table slots past each lane's
@@ -229,28 +247,35 @@ def paged_attention_reference(q, pages_k, pages_v, tables, lengths,
 
     n, s, _, d = q.shape
     num_p = tables.shape[1]
-    page = pages_k.shape[1]
-    hkv = pages_k.shape[2]
+    hkv, page = pages_k.shape[1:3]
     live = _live_pages(lengths, s, page)
     t = jnp.where(jnp.arange(num_p)[None, :] < live[:, None], tables, NULL_PAGE)
-    k = pages_k[t]                                    # [N, P, page, Hkv, D]
+    k = pages_k[t]                                    # [N, P, Hkv, page, D]
     v = pages_v[t]
     if k_scales is not None:
-        k = (k.astype(jnp.float32) * k_scales[t][:, :, None, :, None]).astype(q.dtype)
-        v = (v.astype(jnp.float32) * v_scales[t][:, :, None, :, None]).astype(q.dtype)
+        k = (k.astype(jnp.float32) * k_scales[t][..., None, None]).astype(q.dtype)
+        v = (v.astype(jnp.float32) * v_scales[t][..., None, None]).astype(q.dtype)
     else:
         k = k.astype(q.dtype)
         v = v.astype(q.dtype)
-    k = k.reshape(n, num_p * page, hkv, d)
-    v = v.reshape(n, num_p * page, hkv, d)
+    k = k.transpose(0, 1, 3, 2, 4).reshape(n, num_p * page, hkv, d)
+    v = v.transpose(0, 1, 3, 2, 4).reshape(n, num_p * page, hkv, d)
     q_positions = lengths[:, None] + jnp.arange(s)[None, :]
     return cached_attention(q, k, v, q_positions, window=window, alibi=alibi,
                             tree_mask=tree_mask)
 
 
 # --------------------------------------------------------------------- kernel
-def _paged_attn_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
-                       ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
+def _page_scale(scale_ref, p):
+    """Lane ``p`` of a ``(1, 1, 1, P)`` per-lane scale row as a ``(1, 1)``
+    array.  The row sits in VMEM (Mosaic refuses ``(1, 1)`` SMEM blocks over a
+    2-D table), where a dynamic lane index is a select-and-reduce."""
+    row = scale_ref[0, 0]                                      # [1, P]
+    at_p = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1) == p
+    return jnp.sum(jnp.where(at_p, row, 0.0), axis=1, keepdims=True)
+
+
+def _paged_attn_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
                        page: int, s_len: int, scale: float, quantized: bool,
                        tree_words=None):
     """One (lane, kv-head, page) step of the online softmax.
@@ -270,6 +295,8 @@ def _paged_attn_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
     at most 32 selects, folded at compile time.  History slots
     (``j < length``) stay visible to every node — the page walk and online
     softmax are untouched, only the mask predicate changes."""
+    ks_ref, vs_ref = rest[:2] if quantized else (None, None)
+    o_ref, m_ref, l_ref, acc_ref = rest[-4:]
     lane, p = pl.program_id(0), pl.program_id(2)
     n_p = pl.num_programs(2)
     gs = acc_ref.shape[0]
@@ -286,11 +313,11 @@ def _paged_attn_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
 
     @pl.when(p < live)
     def _compute():
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
         if quantized:
-            k = k.astype(jnp.float32) * ks_ref[0, 0]
-            v = v.astype(jnp.float32) * vs_ref[0, 0]
+            k = k.astype(jnp.float32) * _page_scale(ks_ref, p)
+            v = v.astype(jnp.float32) * _page_scale(vs_ref, p)
         q = q_ref[0, 0].astype(jnp.float32) * scale
         s = jax.lax.dot_general(
             q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
@@ -360,7 +387,7 @@ def paged_attention(q, pages_k, pages_v, tables, lengths, k_scales=None,
     q: ``[N, S, Hq, D]`` queries for the ``S`` positions being written this
         call (decode: 1; speculative verify: K+1).  Query ``i`` of lane ``n``
         sits at position ``lengths[n] + i``.
-    pages_k, pages_v: the page pool ``[NP, page, Hkv, D]`` for ONE layer, with
+    pages_k, pages_v: the page pool ``[NP, Hkv, page, D]`` for ONE layer, with
         this call's new KV already inserted (:func:`paged_insert` /
         :func:`paged_quantized_insert`).
     tables: ``[N, P]`` int32 per-lane block tables; dead slots hold the null
@@ -385,7 +412,7 @@ def paged_attention(q, pages_k, pages_v, tables, lengths, k_scales=None,
     if interpret is None:
         interpret = _default_interpret()
     n, s, hq, d = q.shape
-    num_pages, page, hkv, _ = pages_k.shape
+    _, hkv, page, _ = pages_k.shape
     num_p = tables.shape[1]
     rep = hq // hkv
     gs = rep * s
@@ -407,10 +434,6 @@ def paged_attention(q, pages_k, pages_v, tables, lengths, k_scales=None,
     quantized = kv_qmax(pages_k.dtype) is not None
     if quantized and (k_scales is None or v_scales is None):
         raise ValueError("quantized pages need k_scales/v_scales")
-    if not quantized:
-        # native dtype: feed dummy scales so the kernel signature is uniform
-        k_scales = jnp.ones((num_pages, hkv), jnp.float32)
-        v_scales = k_scales
 
     # fold GQA groups into rows: row r = g * S + i  ->  head h*rep + g, query i
     qf = (
@@ -426,12 +449,10 @@ def paged_attention(q, pages_k, pages_v, tables, lengths, k_scales=None,
         grid=(n, hkv, num_p),
         in_specs=[
             pl.BlockSpec((1, 1, gs, d), lambda i, h, p, t, ln: (i, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, d), lambda i, h, p, t, ln: (t[i, p], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, d), lambda i, h, p, t, ln: (t[i, p], 0, h, 0)),
-            pl.BlockSpec((1, 1), lambda i, h, p, t, ln: (t[i, p], h),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, h, p, t, ln: (t[i, p], h),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, page, d), lambda i, h, p, t, ln: (t[i, p], h, 0, 0)),
+            pl.BlockSpec((1, 1, page, d), lambda i, h, p, t, ln: (t[i, p], h, 0, 0)),
+            *[pl.BlockSpec((1, 1, 1, num_p), lambda i, h, p, t, ln: (i, h, 0, 0))
+              ] * (2 if quantized else 0),
         ],
         out_specs=pl.BlockSpec((1, 1, gs, d), lambda i, h, p, t, ln: (i, h, 0, 0)),
         scratch_shapes=[
@@ -450,7 +471,8 @@ def paged_attention(q, pages_k, pages_v, tables, lengths, k_scales=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, hkv, gs, d), q.dtype),
         interpret=interpret,
-    )(tables, lengths, qf, pages_k, pages_v, k_scales, v_scales)
+    )(tables, lengths, qf, pages_k, pages_v,
+      *_lane_scales(tables, k_scales, v_scales, quantized))
     return (
         out.reshape(n, hkv, rep, s, d)
         .reshape(n, hq, s, d)
@@ -468,16 +490,15 @@ def paged_flash_prefill_reference(q, pages_k, pages_v, tables, lengths,
     ``j <= lengths[n] + i``, which covers both the attention over prior pages
     and the in-chunk causal triangle (the chunk's own KV is inserted before
     the call, exactly like decode) — so this is a documented delegation, not
-    a reimplementation.  It is also the tp>1 fallback
-    (:func:`resolve_paged_kernel` with ``role="prefill"``)."""
+    a reimplementation.  It is also the program tp>1 engines run
+    (``prefill_kernel="xla"``)."""
     return paged_attention_reference(
         q, pages_k, pages_v, tables, lengths,
         k_scales=k_scales, v_scales=v_scales, window=window, alibi=alibi,
     )
 
 
-def _paged_prefill_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
-                          ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
+def _paged_prefill_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
                           page: int, block_q: int, rep: int, scale: float,
                           quantized: bool):
     """One (lane, kv-head, q-block, page) step of the prefill online softmax.
@@ -491,6 +512,8 @@ def _paged_prefill_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
     lies past the block's last query position are skipped outright — that
     bound subsumes the dead-page check (a dead slot's index degenerates to the
     null page, fetched at most once and never past any lane's frontier)."""
+    ks_ref, vs_ref = rest[:2] if quantized else (None, None)
+    o_ref, m_ref, l_ref, acc_ref = rest[-4:]
     lane, iq, p = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     n_p = pl.num_programs(3)
     rows = acc_ref.shape[0]
@@ -506,11 +529,11 @@ def _paged_prefill_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
 
     @pl.when(p * page <= length + (iq + 1) * block_q - 1)
     def _compute():
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
         if quantized:
-            k = k.astype(jnp.float32) * ks_ref[0, 0]
-            v = v.astype(jnp.float32) * vs_ref[0, 0]
+            k = k.astype(jnp.float32) * _page_scale(ks_ref, p)
+            v = v.astype(jnp.float32) * _page_scale(vs_ref, p)
         q = q_ref[0, 0].astype(jnp.float32) * scale
         s = jax.lax.dot_general(
             q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
@@ -562,7 +585,7 @@ def paged_flash_prefill(q, pages_k, pages_v, tables, lengths, k_scales=None,
     ----------
     q: ``[N, S, Hq, D]`` — the chunk's queries; query ``i`` of lane ``n``
         sits at position ``lengths[n] + i``.
-    pages_k, pages_v: the page pool ``[NP, page, Hkv, D]`` for ONE layer.
+    pages_k, pages_v: the page pool ``[NP, Hkv, page, D]`` for ONE layer.
     tables: ``[N, P]`` int32 per-lane block tables; dead slots hold the null
         page.
     lengths: ``[N]`` int32 — each lane's valid length before this chunk (the
@@ -578,15 +601,12 @@ def paged_flash_prefill(q, pages_k, pages_v, tables, lengths, k_scales=None,
     if interpret is None:
         interpret = _default_interpret()
     n, s, hq, d = q.shape
-    num_pages, page, hkv, _ = pages_k.shape
+    _, hkv, page, _ = pages_k.shape
     num_p = tables.shape[1]
     rep = hq // hkv
     quantized = kv_qmax(pages_k.dtype) is not None
     if quantized and (k_scales is None or v_scales is None):
         raise ValueError("quantized pages need k_scales/v_scales")
-    if not quantized:
-        k_scales = jnp.ones((num_pages, hkv), jnp.float32)
-        v_scales = k_scales
 
     block_q = pick_block_divisor(s)
     n_qb = s // block_q
@@ -608,14 +628,13 @@ def paged_flash_prefill(q, pages_k, pages_v, tables, lengths, k_scales=None,
         grid=(n, hkv, n_qb, num_p),
         in_specs=[
             pl.BlockSpec((1, 1, rows, d), lambda i, h, b, p, t, ln: (i, h, b, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda i, h, b, p, t, ln: (t[i, p], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda i, h, b, p, t, ln: (t[i, p], 0, h, 0)),
-            pl.BlockSpec((1, 1), lambda i, h, b, p, t, ln: (t[i, p], h),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, h, b, p, t, ln: (t[i, p], h),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda i, h, b, p, t, ln: (t[i, p], h, 0, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda i, h, b, p, t, ln: (t[i, p], h, 0, 0)),
+            *[pl.BlockSpec((1, 1, 1, num_p),
+                           lambda i, h, b, p, t, ln: (i, h, 0, 0))
+              ] * (2 if quantized else 0),
         ],
         out_specs=pl.BlockSpec((1, 1, rows, d),
                                lambda i, h, b, p, t, ln: (i, h, b, 0)),
@@ -635,7 +654,8 @@ def paged_flash_prefill(q, pages_k, pages_v, tables, lengths, k_scales=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, hkv, s * rep, d), q.dtype),
         interpret=interpret,
-    )(tables, lengths, qf, pages_k, pages_v, k_scales, v_scales)
+    )(tables, lengths, qf, pages_k, pages_v,
+      *_lane_scales(tables, k_scales, v_scales, quantized))
     return (
         out.reshape(n, hkv, s, rep, d)
         .transpose(0, 2, 1, 3, 4)

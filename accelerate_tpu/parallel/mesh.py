@@ -154,37 +154,12 @@ def replica_meshes(
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = True,
               axis_names=None):
-    """Version-portable ``shard_map`` (use this, not ``jax.shard_map``).
-
-    jax >= 0.6 exposes ``jax.shard_map(check_vma=..., axis_names=...)``; the
-    0.4.x line only has ``jax.experimental.shard_map.shard_map`` with the
-    older spellings — ``check_rep`` for the replication check and
-    ``auto=<complement of axis_names>`` for partial-manual meshes.  This
-    wrapper translates so every call site works on both.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map`` with ``axis_names`` passed only when given (``None``
+    means fully manual, which ``jax.shard_map`` spells by omission)."""
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma)
     if axis_names is not None:
-        auto = frozenset(a for a in mesh.axis_names
-                         if a not in axis_names and mesh.shape[a] > 1)
-        if auto:
-            # 0.4.x XLA's SPMD partitioner hard-crashes (Check failed:
-            # IsManualSubgroup) on manual-subgroup programs; refuse at trace
-            # time instead of aborting the process mid-compile
-            raise NotImplementedError(
-                f"partial-auto shard_map over manual axes {sorted(axis_names)} "
-                f"with auto axes {sorted(auto)} requires jax >= 0.6 "
-                f"(this build: {jax.__version__}); run this path on a "
-                f"{sorted(axis_names)}-only mesh or upgrade jax"
-            )
-        kw["auto"] = frozenset()
-    return _shard_map(f, **kw)
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, **kw)
 
 
 def present_data_axes(mesh: Mesh) -> tuple:

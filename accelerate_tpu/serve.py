@@ -32,7 +32,7 @@ import sys
 import time
 from typing import Optional
 
-__all__ = ["build_service", "main"]
+__all__ = ["build_service", "main", "parse_args"]
 
 
 def _build_params(model, cfg, seed: int, checkpoint: Optional[str]):
@@ -49,15 +49,26 @@ def _build_params(model, cfg, seed: int, checkpoint: Optional[str]):
 
 
 def build_service(args):
-    """Construct (router, frontdoor, server) from parsed CLI args.  Split
-    from :func:`main` so tests and benches can assemble the exact service
-    the CLI would, minus the blocking serve loop."""
+    """Construct (router, frontdoor, server) from parsed CLI args
+    (:func:`parse_args`).  Split from :func:`main` so tests, benches and
+    ``chip_smoke.py`` can assemble the exact service the CLI would, minus the
+    blocking serve loop.
+
+    Where more than one device is present, replica ``i`` is built on the mesh
+    of chip ``i`` (:func:`~accelerate_tpu.parallel.mesh.replica_meshes`):
+    its params and KV pool live there and nowhere else.  On a single device
+    every replica shares it, and the params, as before."""
+    import jax
+    import jax.numpy as jnp
+
     from .models.transformer import Transformer, TransformerConfig
+    from .parallel.mesh import replica_meshes
     from .serving import ReplicaRouter, ServingEngine
     from .serving.api import ApiServer, FrontDoor
 
     presets = {
         "tiny": TransformerConfig.tiny,
+        "gpt2": TransformerConfig.gpt2,
         "gpt2-xl": TransformerConfig.gpt2_xl_equiv,
         "small": lambda **kw: TransformerConfig(
             vocab_size=32000, hidden_size=1024, intermediate_size=4096,
@@ -69,9 +80,19 @@ def build_service(args):
         raise SystemExit(
             f"unknown --preset {args.preset!r}; choose from {sorted(presets)}"
         )
-    cfg = presets[args.preset](max_seq_len=args.max_len)
+    overrides = {"max_seq_len": args.max_len}
+    if args.param_dtype is not None:
+        overrides["param_dtype"] = jnp.dtype(args.param_dtype)
+    cfg = presets[args.preset](**overrides)
     model = Transformer(cfg)
     params = _build_params(model, cfg, args.seed, args.checkpoint)
+    if len(jax.devices()) > 1:
+        meshes = replica_meshes(args.replicas)
+        # each replica places its own copy on its own chip: stage the weights
+        # on the host once, so the default device never holds two
+        params = jax.device_get(params)
+    else:
+        meshes = [None] * args.replicas
 
     engines = [
         ServingEngine(
@@ -84,6 +105,7 @@ def build_service(args):
             max_queue=args.max_queue,
             weights_version=args.weights_version,
             rng_seed=args.seed + i,
+            mesh=meshes[i],
         )
         for i in range(args.replicas)
     ]
@@ -105,7 +127,7 @@ def _parser() -> argparse.ArgumentParser:
         description="OpenAI-compatible serving front door for accelerate_tpu",
     )
     p.add_argument("--preset", default="tiny",
-                   help="model geometry: tiny | small | gpt2-xl")
+                   help="model geometry: tiny | small | gpt2 | gpt2-xl")
     p.add_argument("--checkpoint", default=None,
                    help="safetensors directory (save_model export); random "
                         "init when omitted")
@@ -132,13 +154,26 @@ def _parser() -> argparse.ArgumentParser:
     p.add_argument("--unhealthy-after-s", type=float, default=60.0)
     p.add_argument("--request-timeout-s", type=float, default=600.0)
     p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--param-dtype", default=None,
+                   choices=("float32", "bfloat16"),
+                   help="dtype the weights are held in (default: the preset's; "
+                        "gpt2-xl's float32 weights alone are 8.5 GB)")
     return p
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
+    """The CLI's arguments as :func:`build_service` takes them."""
     args = _parser().parse_args(argv)
     if args.max_queue == 0:
         args.max_queue = None
+    return args
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    from .utils.environment import enable_compile_cache
+
+    enable_compile_cache()
     router, frontdoor, server = build_service(args)
     print(f"serving {args.model_name} ({args.preset}, "
           f"{args.replicas} replica(s), version {args.weights_version}) "

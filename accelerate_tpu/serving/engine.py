@@ -254,8 +254,8 @@ class ServingEngine:
         pages in place with a q-blocked flash online softmax and writes the
         chunk's K/V straight into the page pool (scatter-time quantization
         included) — no gather temporary, no scatter round-trip.  ``"xla"``
-        forces the gather/scatter reference path (the tp>1 fallback, and the
-        bisection knob when a prefill divergence is suspected).  Same
+        forces the gather/scatter reference path (the only arm under tp>1,
+        and the bisection knob when a prefill divergence is suspected).  Same
         compiled-shape budget either way (the kernel replaces the per-bucket
         prefill executables' attention, it adds none).  Requires
         ``paged=True``; full-causal rope/learned models only.
@@ -294,8 +294,9 @@ class ServingEngine:
         combination and the compiled-executable budget is unchanged; both
         are pinned by ``tests/test_serving_mesh.py`` and
         ``bench_inference.py --task serve --tp-ab``.  ``decode_kernel=
-        "pallas"`` falls back to the XLA reference under tp > 1 (the Pallas
-        grid reads whole head tiles; the einsum partitions head-parallel).
+        "pallas"`` is refused at construction under tp > 1 (the Pallas grid
+        reads whole head tiles of an unsharded pool; the XLA einsum
+        partitions head-parallel) — it never silently becomes ``"xla"``.
         Head counts must divide the tp degree.
     tp_axis: mesh axis name the KV heads and weight matrices shard over
         (default ``"tp"``); axes absent from the mesh count as size 1.
@@ -465,14 +466,14 @@ class ServingEngine:
             resolve_paged_kernel,
         )
 
-        # shard-aware kernel dispatch: under a tp>1 mesh the Pallas grid would
-        # read whole (kv-head, page) tiles of a head-sharded pool, so "pallas"
-        # resolves to the XLA reference (head-parallel under GSPMD for free)
+        # shard-aware kernel check: under a tp>1 mesh the Pallas grid would
+        # read whole (kv-head, page) tiles of a head-sharded pool, so asking
+        # for "pallas" there raises — the engine runs the kernel it was asked
+        # for or does not start
         decode_kernel = resolve_paged_kernel(decode_kernel, mesh, tp_axis)
         self.decode_kernel = decode_kernel
-        # prefill follows the resolved decode kernel unless forced: a pool
-        # decoding through Pallas prefills through its chunk-wide twin, and
-        # the tp>1 fallback applies to both independently
+        # prefill follows the decode kernel unless forced: a pool decoding
+        # through Pallas prefills through its chunk-wide twin
         if prefill_kernel is None:
             prefill_kernel = decode_kernel if self.paged else "xla"
         self.prefill_kernel = resolve_paged_kernel(
@@ -627,7 +628,7 @@ class ServingEngine:
         if self.debug_server is not None:
             self.debug_server.add_collector(self.analyze_costs)
         # Window models: the direct paged windows run a Transformer whose
-        # config selects the attention kernel (and interpret default).  The
+        # config selects the attention kernel.  The
         # fields carry no parameters, so the engine's params serve every
         # variant.  The prefill model picks its own kernel: the chunk-wide
         # flash kernel under prefill_kernel="pallas", the XLA reference
@@ -1149,7 +1150,11 @@ class ServingEngine:
             x = x.copy()
         if self._shardings is None:
             return jnp.asarray(x)
-        return jax.device_put(jnp.asarray(x), self._shardings.replicated)
+        # straight from the host to the mesh's own devices: staging through
+        # jnp.asarray would land every operand on the default device first
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x)
+        return jax.device_put(x, self._shardings.replicated)
 
     # ------------------------------------------------------------- submission
     def submit(
@@ -1710,7 +1715,7 @@ class ServingEngine:
         (head-axis sharded under a mesh, so the promote install's donated
         in-place aliasing holds per shard)."""
         if self._shardings is not None:
-            return jax.device_put(np.ascontiguousarray(x), self._shardings.kv)
+            return jax.device_put(np.ascontiguousarray(x), self._shardings.pages)
         return jnp.asarray(x)
 
     def _put_scale_chunk(self, x: np.ndarray):

@@ -15,7 +15,7 @@ while all allocation, refcounting, and copy-on-write stay host-side numpy:
   (their block-table rows are reset to null), so no compiled program ever
   needs a "has pages?" branch.
 * :class:`PagedKVPool` — the device-resident page arrays
-  ``[L, num_pages, page_size, Hkv, Dh]`` plus per-lane block tables
+  ``[L, num_pages, Hkv, page_size, Dh]`` plus per-lane block tables
   (host ``[num_slots, pages_per_lane]`` int32, uploaded per cycle — a few KB).
   ``pages_per_lane * page_size == max_len`` exactly: the gathered per-lane
   view has the *same* width as the legacy slab, so paged decode runs the
@@ -180,26 +180,25 @@ class PagedKVPool:
                 f"num_kv_heads {cfg.num_kv_heads} must divide evenly over "
                 f"tp={self.tp_degree} to shard the page pool on the head axis"
             )
-        shape = (cfg.num_layers, self.num_pages, self.page_size,
-                 cfg.num_kv_heads, cfg.resolved_head_dim)
+        # kv-head axis OUTSIDE the page: a (page, Dh) tile per (page, head)
+        # is the block shape the TPU kernels in ops/paged_attention.py need
+        shape = (cfg.num_layers, self.num_pages, cfg.num_kv_heads,
+                 self.page_size, cfg.resolved_head_dim)
         scale_shape = (cfg.num_layers, self.num_pages, cfg.num_kv_heads)
         if mesh is not None:
             # head-axis NamedSharding: each device holds Hkv/tp heads of every
             # page.  Block tables / refcounts stay host-side and whole.
-            import jax
             from jax.sharding import NamedSharding, PartitionSpec
 
             ax = tp_axis if self.tp_degree > 1 else None
-            kv_sh = NamedSharding(mesh, PartitionSpec(None, None, None, ax, None))
+            kv_sh = NamedSharding(mesh, PartitionSpec(None, None, ax, None, None))
             sc_sh = NamedSharding(mesh, PartitionSpec(None, None, ax))
-            self.pages_k = jax.device_put(
-                jnp.zeros(shape, self.storage_dtype), kv_sh
-            )
-            self.pages_v = jax.device_put(
-                jnp.zeros(shape, self.storage_dtype), kv_sh
-            )
-            self.k_scales = jax.device_put(jnp.ones(scale_shape, jnp.float32), sc_sh)
-            self.v_scales = jax.device_put(jnp.ones(scale_shape, jnp.float32), sc_sh)
+            # allocated in place on the mesh's own devices: a replica on chip
+            # i must never stage its pool through the default device
+            self.pages_k = jnp.zeros(shape, self.storage_dtype, device=kv_sh)
+            self.pages_v = jnp.zeros(shape, self.storage_dtype, device=kv_sh)
+            self.k_scales = jnp.ones(scale_shape, jnp.float32, device=sc_sh)
+            self.v_scales = jnp.ones(scale_shape, jnp.float32, device=sc_sh)
         else:
             self.pages_k = jnp.zeros(shape, self.storage_dtype)
             self.pages_v = jnp.zeros(shape, self.storage_dtype)

@@ -66,12 +66,12 @@ from .paging import NULL_PAGE
 class ServeShardings:
     """The engine's placement vocabulary under a tensor-parallel mesh.
 
-    Every serving executable moves arrays from exactly three families: KV
-    slabs/pools ``[L, *, *, Hkv, D]`` (sharded on the kv-head axis — dim 3 in
-    both the slab ``[L, N, max_len, H, D]`` and page ``[L, NP, page, H, D]``
-    layouts), per-page quantization scales ``[L, NP, Hkv]`` (head axis last),
-    and host-side control state (tokens, tables, indices, sampling knobs —
-    replicated).  Params carry the :data:`~accelerate_tpu.parallel
+    Every serving executable moves arrays from exactly four families: KV
+    slabs ``[L, N, max_len, Hkv, D]`` (``kv``: sharded on the kv-head axis,
+    dim 3), page pools and page chunks ``[L, NP, Hkv, page, D]`` (``pages``:
+    kv-head axis at dim 2), per-page quantization scales ``[L, NP, Hkv]``
+    (head axis last), and host-side control state (tokens, tables, indices,
+    sampling knobs — replicated).  Params carry the :data:`~accelerate_tpu.parallel
     .tensor_parallel.DEFAULT_TP_RULES` placement computed by the engine.
 
     Factories take ``shardings=None`` (single-chip, plain ``jax.jit``) or an
@@ -87,6 +87,7 @@ class ServeShardings:
         ax = tp_axis if self.tp_degree > 1 else None
         self.replicated = NamedSharding(mesh, PartitionSpec())
         self.kv = NamedSharding(mesh, PartitionSpec(None, None, None, ax, None))
+        self.pages = NamedSharding(mesh, PartitionSpec(None, None, ax, None, None))
         self.scales = NamedSharding(mesh, PartitionSpec(None, None, ax))
         self.params = params
 
@@ -667,7 +668,7 @@ def make_copy_chunk(chunk_len: int,
 
 # --------------------------------------------------------------------- paged
 # Block-table variants (ServingEngine(paged=True), :mod:`.paging`): KV lives
-# in a shared page pool ``[L, num_pages, page, Hkv, Dh]`` and each executable
+# in a shared page pool ``[L, num_pages, Hkv, page, Dh]`` and each executable
 # gathers a lane's pages into a contiguous view, runs the *same* traced
 # decode/verify/prefill body as the slab path, then scatters only the
 # newly-written positions back.  The view width equals the slab width
@@ -680,11 +681,13 @@ def make_copy_chunk(chunk_len: int,
 
 
 def _gather_view(pages, tables):
-    """``pages [L, NP, page, H, D]`` gathered through ``tables [N, P]`` into a
-    contiguous per-lane view ``[L, N, P * page, H, D]``."""
-    L, _, page, H, D = pages.shape
+    """``pages [L, NP, H, page, D]`` gathered through ``tables [N, P]`` into a
+    contiguous per-lane slab view ``[L, N, P * page, H, D]``."""
+    L, _, H, page, D = pages.shape
     N, P = tables.shape
-    return pages[:, tables].reshape(L, N, P * page, H, D)
+    return (pages[:, tables]                             # [L, N, P, H, page, D]
+            .transpose(0, 1, 2, 4, 3, 5)
+            .reshape(L, N, P * page, H, D))
 
 
 def _live_tables(tables, live):
@@ -709,7 +712,7 @@ def _scatter_span(pages, view, tables, start, width: int, active):
     frozen lane's row may be vacant (all-null already), but a lane mid-prefill
     has REAL pages mapped — possibly shared with the prefix cache — and its
     stale write index must never trample them."""
-    L, _, page, H, D = pages.shape
+    L, _, H, page, D = pages.shape
     N = tables.shape[0]
     written = jax.vmap(
         lambda kv, i: jax.lax.dynamic_slice(kv, (0, i, 0, 0), (L, width, H, D)),
@@ -719,8 +722,9 @@ def _scatter_span(pages, view, tables, start, width: int, active):
     pid = jnp.take_along_axis(tables, pos // page, axis=1)
     pid = jnp.where(active[:, None], pid, NULL_PAGE)
     off = pos % page
-    return pages.at[:, pid.reshape(-1), off.reshape(-1)].set(
-        written.reshape(L, N * width, H, D)
+    # advanced indices split by a slice: the indexed rows lead, [N*width, L, H, D]
+    return pages.at[:, pid.reshape(-1), :, off.reshape(-1)].set(
+        written.reshape(L, N * width, H, D).swapaxes(0, 1)
     )
 
 
@@ -772,16 +776,16 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
             direct_prefill_chunk,
             donate_argnums=(2, 3, 4, 5),
             in_shardings=None if s is None else (
-                s.params, s.replicated, s.kv, s.kv, s.scales, s.scales,
+                s.params, s.replicated, s.pages, s.pages, s.scales, s.scales,
                 *s.rep(2),
             ),
             out_shardings=None if s is None else (
-                s.kv, s.kv, s.scales, s.scales, s.replicated,
+                s.pages, s.pages, s.scales, s.scales, s.replicated,
             ),
         )
 
     def paged_prefill_chunk(params, tokens, pages_k, pages_v, table, base):
-        L, _, page, H, D = pages_k.shape
+        L, _, H, page, D = pages_k.shape
         live = (base + chunk_len - 1) // page_size + 1
         gt = _live_tables(table, live)
         cache = KVCache(
@@ -793,17 +797,18 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
         ids = jax.lax.dynamic_slice(table, (base // page_size,), (npg,))
         wk = jax.lax.dynamic_slice(cache.k, (0, 0, base, 0, 0), (L, 1, chunk_len, H, D))
         wv = jax.lax.dynamic_slice(cache.v, (0, 0, base, 0, 0), (L, 1, chunk_len, H, D))
-        pages_k = pages_k.at[:, ids].set(wk.reshape(L, npg, page, H, D))
-        pages_v = pages_v.at[:, ids].set(wv.reshape(L, npg, page, H, D))
+        to_pages = lambda w: w.reshape(L, npg, page, H, D).swapaxes(2, 3)
+        pages_k = pages_k.at[:, ids].set(to_pages(wk))
+        pages_v = pages_v.at[:, ids].set(to_pages(wv))
         return pages_k, pages_v
 
     return _serve_jit(
         paged_prefill_chunk,
         donate_argnums=(2, 3),
         in_shardings=None if s is None else (
-            s.params, s.replicated, s.kv, s.kv, *s.rep(2),
+            s.params, s.replicated, s.pages, s.pages, *s.rep(2),
         ),
-        out_shardings=None if s is None else (s.kv, s.kv),
+        out_shardings=None if s is None else (s.pages, s.pages),
     )
 
 
@@ -853,17 +858,17 @@ def make_paged_decode_window(model: Transformer, window: int,
             direct_decode_window,
             donate_argnums=(1, 2, 3, 4),
             in_shardings=None if s is None else (
-                s.params, s.kv, s.kv, s.scales, s.scales, *s.rep(11),
+                s.params, s.pages, s.pages, s.scales, s.scales, *s.rep(11),
             ),
             out_shardings=None if s is None else (
-                s.kv, s.kv, s.scales, s.scales, *s.rep(4),
+                s.pages, s.pages, s.scales, s.scales, *s.rep(4),
             ),
         )
 
     def paged_decode_window(params, pages_k, pages_v, tables, index, tokens,
                             active, eos, do_sample, temperature, top_k, top_p,
                             pad, rngs):
-        page = pages_k.shape[2]
+        page = pages_k.shape[3]
         gt = _live_tables(tables, (index + window - 1) // page + 1)
         cache = KVCache(
             k=_gather_view(pages_k, gt),
@@ -881,8 +886,8 @@ def make_paged_decode_window(model: Transformer, window: int,
     return _serve_jit(
         paged_decode_window,
         donate_argnums=(1, 2),
-        in_shardings=None if s is None else (s.params, s.kv, s.kv, *s.rep(11)),
-        out_shardings=None if s is None else (s.kv, s.kv, *s.rep(3)),
+        in_shardings=None if s is None else (s.params, s.pages, s.pages, *s.rep(11)),
+        out_shardings=None if s is None else (s.pages, s.pages, *s.rep(3)),
     )
 
 
@@ -924,17 +929,17 @@ def make_paged_verify_window(model: Transformer, k: int, direct: bool = False,
             direct_verify_window,
             donate_argnums=(1, 2, 3, 4),
             in_shardings=None if s is None else (
-                s.params, s.kv, s.kv, s.scales, s.scales, *s.rep(11),
+                s.params, s.pages, s.pages, s.scales, s.scales, *s.rep(11),
             ),
             out_shardings=None if s is None else (
-                s.kv, s.kv, s.scales, s.scales, *s.rep(5),
+                s.pages, s.pages, s.scales, s.scales, *s.rep(5),
             ),
         )
 
     def paged_verify_window(params, pages_k, pages_v, tables, index, tokens,
                             active, eos, do_sample, temperature, top_k, top_p,
                             pad, rngs):
-        page = pages_k.shape[2]
+        page = pages_k.shape[3]
         gt = _live_tables(tables, (index + kp1 - 1) // page + 1)
         cache = KVCache(
             k=_gather_view(pages_k, gt),
@@ -952,8 +957,8 @@ def make_paged_verify_window(model: Transformer, k: int, direct: bool = False,
     return _serve_jit(
         paged_verify_window,
         donate_argnums=(1, 2),
-        in_shardings=None if s is None else (s.params, s.kv, s.kv, *s.rep(11)),
-        out_shardings=None if s is None else (s.kv, s.kv, *s.rep(4)),
+        in_shardings=None if s is None else (s.params, s.pages, s.pages, *s.rep(11)),
+        out_shardings=None if s is None else (s.pages, s.pages, *s.rep(4)),
     )
 
 
@@ -974,7 +979,7 @@ def _tree_commit_paged(cache: PagedKVCache, prev_index, path):
         paged_quantized_insert,
     )
 
-    page = cache.pages_k.shape[2]
+    page = cache.pages_k.shape[3]
     p_max = cache.tables.shape[1] - 1
     pos = prev_index[:, None] + path                     # [N, D+1]
     pid = jnp.take_along_axis(
@@ -984,7 +989,8 @@ def _tree_commit_paged(cache: PagedKVCache, prev_index, path):
     quantized = kv_qmax(cache.pages_k.dtype) is not None
 
     def _rows(pages, scales):
-        rows = pages[:, pid, off]                        # [L, N, D+1, H, Dh]
+        # advanced indices split by a slice lead the result: [N, D+1, L, H, Dh]
+        rows = jnp.moveaxis(pages[:, pid, :, off], 2, 0)  # [L, N, D+1, H, Dh]
         if quantized:
             rows = rows.astype(jnp.float32) * scales[:, pid][..., None]
         return rows
@@ -1056,17 +1062,17 @@ def make_paged_tree_verify_window(model: Transformer, tree,
             direct_tree_verify_window,
             donate_argnums=(1, 2, 3, 4),
             in_shardings=None if s is None else (
-                s.params, s.kv, s.kv, s.scales, s.scales, *s.rep(11),
+                s.params, s.pages, s.pages, s.scales, s.scales, *s.rep(11),
             ),
             out_shardings=None if s is None else (
-                s.kv, s.kv, s.scales, s.scales, *s.rep(5),
+                s.pages, s.pages, s.scales, s.scales, *s.rep(5),
             ),
         )
 
     def paged_tree_verify_window(params, pages_k, pages_v, tables, index,
                                  tokens, active, eos, do_sample, temperature,
                                  top_k, top_p, pad, rngs):
-        page = pages_k.shape[2]
+        page = pages_k.shape[3]
         gt = _live_tables(tables, (index + s_nodes - 1) // page + 1)
         cache = KVCache(
             k=_gather_view(pages_k, gt),
@@ -1084,8 +1090,8 @@ def make_paged_tree_verify_window(model: Transformer, tree,
     return _serve_jit(
         paged_tree_verify_window,
         donate_argnums=(1, 2),
-        in_shardings=None if s is None else (s.params, s.kv, s.kv, *s.rep(11)),
-        out_shardings=None if s is None else (s.kv, s.kv, *s.rep(4)),
+        in_shardings=None if s is None else (s.params, s.pages, s.pages, *s.rep(11)),
+        out_shardings=None if s is None else (s.pages, s.pages, *s.rep(4)),
     )
 
 
@@ -1111,16 +1117,16 @@ def make_copy_page(shardings: Optional[ServeShardings] = None):
         copy_page,
         donate_argnums=(0, 1, 2, 3),
         in_shardings=None if s is None else (
-            s.kv, s.kv, s.scales, s.scales, *s.rep(2),
+            s.pages, s.pages, s.scales, s.scales, *s.rep(2),
         ),
-        out_shardings=None if s is None else (s.kv, s.kv, s.scales, s.scales),
+        out_shardings=None if s is None else (s.pages, s.pages, s.scales, s.scales),
     )
 
 
 def make_spill_extract(npages: int, shardings: Optional[ServeShardings] = None):
     """Jitted D2H-side gather for the hierarchical prefix cache's spill path:
     ``(pages_k, pages_v, k_scales, v_scales, ids [npages]) -> (chunk_k
-    [L, npages, page, Hkv, Dh], chunk_v, chunk_k_scales [L, npages, Hkv],
+    [L, npages, Hkv, page, Dh], chunk_v, chunk_k_scales [L, npages, Hkv],
     chunk_v_scales)`` packs one evicted chunk's pages (quant scales ride
     along, so int8/fp8 chunks spill at their quantized density) into dense
     per-chunk arrays the engine fetches at its drain point — the gather is
@@ -1144,9 +1150,9 @@ def make_spill_extract(npages: int, shardings: Optional[ServeShardings] = None):
     return _serve_jit(
         spill_extract,
         in_shardings=None if s is None else (
-            s.kv, s.kv, s.scales, s.scales, s.replicated,
+            s.pages, s.pages, s.scales, s.scales, s.replicated,
         ),
-        out_shardings=None if s is None else (s.kv, s.kv, s.scales, s.scales),
+        out_shardings=None if s is None else (s.pages, s.pages, s.scales, s.scales),
     )
 
 
@@ -1179,10 +1185,10 @@ def make_promote_install(npages: int, shardings: Optional[ServeShardings] = None
         promote_install,
         donate_argnums=(0, 1, 2, 3),
         in_shardings=None if s is None else (
-            s.kv, s.kv, s.scales, s.scales,
-            s.kv, s.kv, s.scales, s.scales, s.replicated,
+            s.pages, s.pages, s.scales, s.scales,
+            s.pages, s.pages, s.scales, s.scales, s.replicated,
         ),
-        out_shardings=None if s is None else (s.kv, s.kv, s.scales, s.scales),
+        out_shardings=None if s is None else (s.pages, s.pages, s.scales, s.scales),
     )
 
 

@@ -402,8 +402,8 @@ class PageMigrator:
                     # same platform, different mesh handles: re-lay the
                     # gathered chunk onto the destination's sharding —
                     # device-to-device, never through the host
-                    ck = jax.device_put(ck, dst._shardings.kv)
-                    cv = jax.device_put(cv, dst._shardings.kv)
+                    ck = jax.device_put(ck, dst._shardings.pages)
+                    cv = jax.device_put(cv, dst._shardings.pages)
                     cks = jax.device_put(cks, dst._shardings.scales)
                     cvs = jax.device_put(cvs, dst._shardings.scales)
             # the install donates the destination pool handles, which any

@@ -69,9 +69,9 @@ class DevicePeaks:
     source: str = "spec"
 
 
-# Matched by substring against ``device.device_kind.lower()``; first hit wins.
-# bf16 dense peaks mirror bench.py's CHIP_PEAK_TFLOPS; bandwidths are the
-# public per-chip HBM numbers.
+# THE peaks table (bench.py reads it too).  Matched by substring against
+# ``device.device_kind.lower()``; first hit wins.  bf16 dense peaks and
+# per-chip HBM bandwidths from the public Cloud TPU spec sheets.
 HARDWARE_PEAKS: Tuple[Tuple[str, DevicePeaks], ...] = (
     ("v6e", DevicePeaks("tpu-v6e", 918e12, 1.64e12)),
     ("v5p", DevicePeaks("tpu-v5p", 459e12, 2.765e12)),
@@ -80,30 +80,33 @@ HARDWARE_PEAKS: Tuple[Tuple[str, DevicePeaks], ...] = (
     ("v4", DevicePeaks("tpu-v4", 275e12, 1.228e12)),
 )
 
-# A deliberately round generic-CPU number so MFU stays finite (and honest:
-# source="fallback") on hosts where we cannot know the real peak. 2 TFLOP/s
-# is in the ballpark of a modern many-core AVX-512 server at fp32.
+# The CPU test rig's labelled stand-in (source="fallback"): a deliberately
+# round number so the MFU arithmetic the tests read stays finite.  CPU
+# devices only — an accelerator never gets it.
 CPU_FALLBACK_PEAKS = DevicePeaks("generic-cpu", 2e12, 0.1e12, source="fallback")
 
 
 def detect_device_peaks(device: Any = None) -> DevicePeaks:
     """Return peaks for ``device`` (default: ``jax.devices()[0]``).
 
-    Always returns *something*: unknown kinds get the CPU fallback entry so
-    MFU arithmetic never divides by ``None``.
+    A CPU device gets :data:`CPU_FALLBACK_PEAKS`; an accelerator whose
+    ``device_kind`` is not in :data:`HARDWARE_PEAKS` raises — a utilization
+    figure against made-up peaks is worse than none.
     """
     if device is None:
-        try:
-            import jax
+        import jax
 
-            device = jax.devices()[0]
-        except Exception:  # pragma: no cover - no backend at all
-            return CPU_FALLBACK_PEAKS
-    kind = str(getattr(device, "device_kind", "")).lower()
+        device = jax.devices()[0]
+    kind = str(getattr(device, "device_kind", ""))
     for needle, peaks in HARDWARE_PEAKS:
-        if needle in kind:
+        if needle in kind.lower():
             return peaks
-    return CPU_FALLBACK_PEAKS
+    if getattr(device, "platform", None) == "cpu":
+        return CPU_FALLBACK_PEAKS
+    raise ValueError(
+        f"no peak FLOP/s and bandwidth entry for accelerator {kind!r}: add "
+        f"it to telemetry.cost.HARDWARE_PEAKS with its source"
+    )
 
 
 def _abstractify(x: Any) -> Any:

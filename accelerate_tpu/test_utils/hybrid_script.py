@@ -3,7 +3,7 @@ spanning BOTH process (dcn) and local (ici) boundaries — the actual pod shape
 (reference approximates it with ``tpu_pod_launcher``,
 ``commands/launch.py:827-883``).
 
-Launched by ``__graft_entry__.dryrun_multichip`` (and usable standalone):
+Launch it through the CLI:
 
     accelerate-tpu launch --cpu --num_processes 2 --num_cpu_devices 4 \\
         --mesh dp=2,fsdp=4 --dcn_mesh dp=2 hybrid_script.py --out loss.json

@@ -32,6 +32,7 @@ from .imports import (
     is_tensorboard_available,
     is_torch_available,
     is_tpu_available,
+    is_tpu_platform,
     is_transformers_available,
     is_wandb_available,
 )

@@ -374,8 +374,8 @@ class FullyShardedDataParallelPlugin:
     # chunk N's host write-back with chunk N+1's host read at peak HBM =
     # overlap * chunk transients.  With the round-4 donation fixes in place,
     # overlap=2 at an EXPLICIT ~1 GB chunk size measured 11% faster than
-    # serialized on the 2.13B/16 GB-v5e config (13.2 vs 14.9 s/step,
-    # BENCH_NOTES.md round-5 A/B; the same cell was 2x SLOWER pre-fix).
+    # serialized on the 2.13B/16 GB-v5e config (13.2 vs 14.9 s/step in an
+    # earlier round's A/B; the same cell was 2x SLOWER pre-fix).
     # The default stays 1 because adaptive sizing (chunk_mb=-1) divides the
     # chunk budget by the window — halving every chunk — and the safe default
     # must not trade step time for peak-memory risk on unknown rigs; set

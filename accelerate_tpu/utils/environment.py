@@ -19,6 +19,34 @@ import re
 from typing import Dict, List, Optional
 
 
+#: the in-checkout compile cache used when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset — a FIXED path (the directory is part of the cache key, so one built
+#: from a temporary name, a pid or the time would never hit)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a directory that can be
+    placed from outside, and return the directory in use.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and no
+    directory is set in code; otherwise the cache lives at
+    :data:`DEFAULT_COMPILE_CACHE_DIR`.  Called by every entry point that
+    compiles (``PartialState``, ``python -m accelerate_tpu.serve``,
+    ``bench.py``, ``chip_smoke.py``) before its first compile.  Touches only
+    ``jax.config`` — it does not start the backend."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+    return DEFAULT_COMPILE_CACHE_DIR
+
+
 def get_cpu_count() -> int:
     """Number of CPUs usable by this process (cgroup/affinity aware)."""
     try:

@@ -1,59 +1,16 @@
-"""Version portability for jax APIs that moved between the 0.4.x and 0.6+
-lines.  The host-offload paths were written against ``jax.memory.Space``
-(0.6+); on 0.4.x the same in-jit placement is spelled
-``TransferToMemoryKind("<kind>")``.  Import :data:`Space` from here instead of
-``jax.memory`` — both spellings are accepted by ``jax.device_put`` *inside*
-``jax.jit``, which is the only place the offload code calls it.
+"""The one sanctioned reader of a private jax attribute.
 
-(The matching ``shard_map`` shim lives in ``parallel/mesh.py`` next to its
-call sites.)
-
-Also here: :func:`jit_cache_size`, the one sanctioned reader of the private
-pjit compiled-executable counter (``f._cache_size()``) that the serving
-compiled-shape assertions and the telemetry recompile watchdog rely on — the
-attribute is internal and has no stability promise, so every consumer goes
-through this probe instead of touching it directly.
+:func:`jit_cache_size` reads the pjit compiled-executable counter
+(``f._cache_size()``) that the serving compiled-shape assertions and the
+telemetry recompile watchdog rely on — the attribute is internal and has no
+stability promise, so every consumer goes through this probe instead of
+touching it directly.  (Checked against the pinned jax line in
+``pyproject.toml``: the probe answers there.)
 """
 
 from __future__ import annotations
 
 from typing import Optional
-
-try:  # jax >= 0.6
-    from jax.memory import Space  # type: ignore[import-not-found]
-except ImportError:  # jax 0.4.x
-    import jax as _jax
-    from jax._src.sharding_impls import TransferToMemoryKind as _Transfer
-
-    def _has_host_memory() -> bool:
-        # single-memory backends (the forced-CPU test rig) can't compile
-        # annotate_device_placement custom calls; degrade transfers to no-ops
-        # (device_put(x, None)) so offload paths run un-offloaded instead of
-        # hitting an XLA RET_CHECK
-        try:
-            return len(_jax.devices()[0].addressable_memories()) > 1
-        except Exception:
-            return False
-
-    class _SpaceMeta(type):
-        # Resolving the attributes needs jax.devices(), which initializes the
-        # runtime backend — fatal for anyone importing this module before
-        # jax.distributed.initialize() (the debug_launcher workers).  Defer
-        # the probe to first attribute access instead of class creation.
-        _kinds = {"Device": "device", "Host": "pinned_host"}
-
-        def __getattr__(cls, name):
-            try:
-                kind = cls._kinds[name]
-            except KeyError:
-                raise AttributeError(name) from None
-            value = _Transfer(kind) if _has_host_memory() else None
-            setattr(cls, name, value)
-            return value
-
-    class Space(metaclass=_SpaceMeta):  # type: ignore[no-redef]
-        """0.4.x stand-in: attributes are in-jit ``device_put`` destinations."""
-
 
 # pjit-internal spellings of the compiled-executable counter, newest first.
 _CACHE_SIZE_ATTRS = ("_cache_size",)
@@ -62,7 +19,7 @@ _CACHE_SIZE_ATTRS = ("_cache_size",)
 def jit_cache_size(fn) -> Optional[int]:
     """Compiled-executable count of a jitted callable, or ``None`` if unknown.
 
-    jax 0.4-0.7 expose the per-function executable-cache size as the private
+    jax exposes the per-function executable-cache size as the private
     ``f._cache_size()`` (0 until the first call).  Wrappers that forward
     attribute access to a wrapped jitted fn (the telemetry
     ``RecompileWatchdog``) work transparently.  When no known probe exists —
@@ -89,4 +46,4 @@ def jit_cache_supported() -> bool:
     return jit_cache_size(jax.jit(lambda x: x)) is not None
 
 
-__all__ = ["Space", "jit_cache_size", "jit_cache_supported"]
+__all__ = ["jit_cache_size", "jit_cache_supported"]

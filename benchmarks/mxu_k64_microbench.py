@@ -1,7 +1,7 @@
 """Test the round-4 'half-MXU K=64 contraction' hypothesis directly.
 
-BENCH_NOTES round-4 named head_dim-64 contractions (K=64) as the FSDP
-attention bottleneck; VERDICT round-5 asks for a head-packed K=128 variant.
+An earlier round's notes named head_dim-64 contractions (K=64) as the FSDP
+attention bottleneck and asked for a head-packed K=128 variant.
 Mathematically, packing two heads' features into one K=128 score contraction
 computes the SUM of their score matrices — the only shape-true packing is
 block-diagonal K/V, which doubles the MACs.  So packing can only win if the

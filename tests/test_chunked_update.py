@@ -216,7 +216,7 @@ class TestAutoChunkBytes:
         # 2.13B-param bf16-working/bf16-grad config on a 16 GB chip (the zero3
         # bench shape): resident ~8.5 GB, margin 1.6 GB -> ~5.9 GB free over
         # a serialized window at the swept 6x budget => ~1 GB chunks (the
-        # measured-optimal size; BENCH_NOTES.md round 4).
+        # size an earlier round's sweep measured best).
         params = {"w": jax.ShapeDtypeStruct((2_130_000, 1000), jnp.float32)}
         chunk = auto_chunk_bytes(
             params,
@@ -254,11 +254,24 @@ class TestAutoChunkBytes:
         )
         assert chunk == 64 << 20
 
-    def test_detect_hbm_has_fallback(self):
+    def test_detect_hbm_cpu_standin_and_unknown_device_raises(self):
         from accelerate_tpu.utils.chunked_update import detect_hbm_bytes
 
-        # real runtimes report usable HBM slightly below the spec size
-        assert detect_hbm_bytes() >= 8 << 30
+        # the CPU rig reports no memory_stats: a labelled one-v5e stand-in
+        assert detect_hbm_bytes() == 16 << 30
+
+        class Reporting:
+            platform, device_kind = "tpu", "TPU v5 lite"
+            def memory_stats(self):
+                return {"bytes_limit": 15 << 30}
+
+        class Silent(Reporting):
+            def memory_stats(self):
+                return None
+
+        assert detect_hbm_bytes(Reporting()) == 15 << 30
+        with pytest.raises(ValueError, match="bytes_limit"):
+            detect_hbm_bytes(Silent())
 
     def test_accelerator_resolves_auto(self):
         from accelerate_tpu.state import AcceleratorState, GradientState

@@ -169,14 +169,6 @@ class TestPowerSGDTrainStep:
         # error feedback is per-replica: leading axis == dp
         assert state.comm_state["w"]["error"].shape[0] == 4
 
-    @pytest.mark.skipif(
-        not hasattr(jax, "shard_map"),
-        reason="powersgd x fsdp needs partial-auto shard_map (jax >= 0.6): "
-               "parallel/mesh.py deliberately refuses the manual-subgroup "
-               "program that hard-crashes the 0.4.x SPMD partitioner; the "
-               "refusal contract is pinned by "
-               "test_powersgd_fsdp_refused_on_legacy_jax",
-    )
     def test_powersgd_composes_with_fsdp(self):
         """HYBRID_SHARD composition (partial-auto shard_map): a dp2 x fsdp2
         run must train IDENTICALLY to a dp2-only run on the same global
@@ -210,33 +202,6 @@ class TestPowerSGDTrainStep:
             rtol=1e-4, atol=1e-5,
         )
         np.testing.assert_allclose(float(m_h["loss"]), float(m_dp["loss"]), rtol=1e-4)
-
-    @pytest.mark.skipif(
-        hasattr(jax, "shard_map"),
-        reason="jax >= 0.6 runs the hybrid path for real "
-               "(test_powersgd_composes_with_fsdp)",
-    )
-    def test_powersgd_fsdp_refused_on_legacy_jax(self):
-        """On the 0.4.x line the dp x fsdp powersgd composition must fail
-        with mesh.py's explicit NotImplementedError at trace time — never
-        reach the SPMD partitioner, which hard-crashes the process on
-        manual-subgroup programs (Check failed: IsManualSubgroup)."""
-        from accelerate_tpu import FullyShardedDataParallelPlugin
-        from accelerate_tpu.state import AcceleratorState, GradientState
-
-        GradientState._reset_state()
-        AcceleratorState._reset_state(reset_partial_state=True)
-        acc = Accelerator(
-            mesh={"dp": 2, "fsdp": 2},
-            fsdp_plugin=FullyShardedDataParallelPlugin(min_weight_size=0),
-            kwargs_handlers=[
-                CollectiveKwargs(comm_hook="powersgd", powersgd_rank=2,
-                                 comm_hook_min_size=1)
-            ],
-        )
-        with pytest.raises(NotImplementedError, match="requires jax >= 0.6"):
-            state, step, _ = _quadratic_setup(acc)
-            step(state, _batch())
 
     def test_powersgd_rejects_model_parallel_mesh(self):
         acc = Accelerator(

@@ -1,5 +1,5 @@
 """Questionnaire completeness: the interactive config alone must reproduce a
-FULL plugin surface with no launch flags (VERDICT r4 item 8; reference
+FULL plugin surface with no launch flags (reference
 ``get_cluster_input``, ``commands/config/cluster.py:49-520``).
 
 Flow under test: scripted answers -> get_cluster_input() -> YAML round-trip ->

@@ -300,10 +300,19 @@ class TestCostTable:
             set_enabled(True)
         assert not table.captured("mm")
 
-    def test_device_peaks_always_resolve(self):
+    def test_device_peaks_cpu_standin_and_unknown_accelerator_raises(self):
         peaks = detect_device_peaks()
         assert peaks.flops_per_s > 0 and peaks.hbm_bytes_per_s > 0
-        assert peaks.source in ("spec", "fallback")
+        assert peaks.source == "fallback"  # the CPU rig's labelled stand-in
+
+        class Dev:
+            def __init__(self, platform, kind):
+                self.platform, self.device_kind = platform, kind
+
+        v5e = detect_device_peaks(Dev("tpu", "TPU v5 lite"))
+        assert v5e.source == "spec" and v5e.flops_per_s == 197e12
+        with pytest.raises(ValueError, match="HARDWARE_PEAKS"):
+            detect_device_peaks(Dev("tpu", "TPU v9 imaginary"))
 
 
 # ---------------------------------------------------------------------------

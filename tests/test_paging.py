@@ -150,15 +150,17 @@ class TestPagedTokenIdentity:
         _, paged = self._serve(model, params, prompts, gen, paged=True)
         assert paged == legacy
 
-    def test_speculative_paged_matches_legacy(self):
+    def test_speculative_paged_matches_legacy(self, cycling_prompts):
         model, params = _tiny_model()
-        base = np.tile(np.array([5, 6, 7], np.int32), 8)
-        prompts = [base[:9], base[:12], base[:9]]
+        prompts = cycling_prompts(model, params, new_tokens=8, k=2)
         gen = GenerationConfig(max_new_tokens=8, do_sample=False, eos_token_id=None)
         _, legacy = self._serve(model, params, prompts, gen, paged=False, speculate_k=2)
         eng, paged = self._serve(model, params, prompts, gen, paged=True, speculate_k=2)
         assert paged == legacy
-        assert eng.stats["spec_accepted"] > 0  # the verify path actually ran
+        assert paged == [_expected(model, params, p, gen) for p in prompts]
+        # the prompts' continuations are ones the drafter provably predicts,
+        # so the verify path ran AND committed drafts
+        assert eng.stats["spec_accepted"] > 0
 
     def test_compiled_shape_budget(self):
         """Paged swaps insert + per-bucket copies for one copy_page: the whole

@@ -727,7 +727,7 @@ class TestSpeculative:
         model, params = _tiny_model()
         eng = _engine(model, params, decode_window=2, speculate_k=7)
         # max(window, k + 1) = 8: an 8-token prompt + 49 new > 64 capacity
-        with pytest.raises(ValueError, match="speculate_k"):
+        with pytest.raises(ValueError, match="speculation span"):
             eng.submit(np.ones(8, np.int32), max_new_tokens=49)
         eng.submit(np.ones(8, np.int32), max_new_tokens=48)
 

@@ -56,7 +56,7 @@ class TestShardedPoolGeometry:
         pool = PagedKVPool(cfg, num_slots=2, max_len=64, page_size=8,
                            num_pages=17, mesh=mesh)
         spec = pool.pages_k.sharding.spec
-        assert tuple(spec) == (None, None, None, "tp", None)
+        assert tuple(spec) == (None, None, "tp", None, None)
         assert pool.pages_v.sharding.spec == spec
         assert pool.tp_degree == 2
         assert pool.kv_bytes_per_device() == pool.kv_bytes() // 2
@@ -92,11 +92,15 @@ class TestShardedPoolGeometry:
         assert not any(v for k, v in sharded.items() if "o_proj" in k)
         assert not any(v for k, v in sharded.items() if "down_proj" in k)
 
-    def test_pallas_kernel_falls_back_under_tp(self):
+    def test_pallas_kernel_refused_under_tp(self):
         from accelerate_tpu.ops.paged_attention import resolve_paged_kernel
 
         mesh = _mesh_tp2()
-        assert resolve_paged_kernel("pallas", mesh) == "xla"
+        with pytest.raises(ValueError, match="single-chip"):
+            resolve_paged_kernel("pallas", mesh)
+        model, params = _tiny_model()
+        with pytest.raises(ValueError, match="single-chip"):
+            _engine(model, params, paged=True, mesh=mesh, decode_kernel="pallas")
         assert resolve_paged_kernel("pallas", None) == "pallas"
         assert resolve_paged_kernel("xla", mesh) == "xla"
         dp = build_mesh({"dp": 2}, devices=jax.devices()[:2])
@@ -146,15 +150,19 @@ class TestTokenIdentity:
         assert t1 == t2
         assert e2.kv_pool_bytes() * 2 == e1.kv_pool_bytes()
 
-    def test_interleaved_flash_prefill_falls_back_and_matches(self):
-        """prefill_kernel="pallas" under tp=2 resolves to the XLA prefill arm
-        (the flash kernel is single-chip) and the interleaved ordering stays
-        token-identical to the unsharded, non-interleaved engine."""
+    def test_interleaved_prefill_matches_and_flash_kernel_refused(self):
+        """prefill_kernel="pallas" under tp=2 is refused at construction (the
+        flash kernel is single-chip) — never swapped for the XLA arm — and
+        the interleaved ordering on the XLA arm stays token-identical to the
+        unsharded, non-interleaved engine."""
         model, params = _tiny_model()
         gen = GenerationConfig(max_new_tokens=12, do_sample=False)
+        with pytest.raises(ValueError, match="single-chip"):
+            _engine(model, params, mesh=_mesh_tp2(), paged=True,
+                    prefill_kernel="pallas", interleave_prefill=True)
         t1, _ = self._serve(model, params, gen, None, paged=True)
         t2, e2 = self._serve(model, params, gen, _mesh_tp2(), paged=True,
-                             prefill_kernel="pallas", interleave_prefill=True)
+                             interleave_prefill=True)
         assert t1 == t2
         assert e2.prefill_kernel == "xla"
 

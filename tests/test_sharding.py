@@ -76,6 +76,37 @@ class TestStrategies:
         assert str(state.params["tiny"].sharding.spec) == "PartitionSpec()"
 
 
+class TestStepKeepsPlacement:
+    def test_state_keeps_its_placement_and_the_step_compiles_once(self):
+        """The compiled step must hand the state back placed as it came in.
+        Left to XLA's sharding propagation a vector the policy keeps
+        replicated (under ``min_weight_size``) returns sharded over ``fsdp``
+        — and a placement spelled ``P("fsdp", None)`` returns as
+        ``P("fsdp")`` — so the second step saw "new" input shardings and
+        compiled again."""
+        from accelerate_tpu.utils.jax_compat import jit_cache_size
+
+        acc = Accelerator(
+            mesh={"fsdp": 4},
+            fsdp_plugin=FullyShardedDataParallelPlugin(min_weight_size=2**12),
+        )
+        params = {"w": jnp.ones((256, 64)) * 0.01, "scale": jnp.ones((64,))}
+        state = acc.create_train_state(params=params, tx=optax.adamw(1e-2))
+        placed = jax.tree_util.tree_map(lambda x: x.sharding, state)
+        assert "fsdp" in str(placed.params["w"].spec)
+        assert placed.params["scale"].spec == PartitionSpec()
+
+        def loss_fn(p, batch, rng=None):
+            return jnp.mean((batch["x"] @ p["w"] * p["scale"]) ** 2)
+
+        step = acc.compile_train_step(loss_fn)
+        batch = {"x": jnp.ones((8, 256))}
+        for _ in range(3):
+            state, _ = step(state, batch)
+        assert jax.tree_util.tree_map(lambda x: x.sharding, state) == placed
+        assert jit_cache_size(step._jitted) == 1
+
+
 class TestZeroMapping:
     @pytest.mark.parametrize(
         "stage,shards_params,shards_opt",
@@ -174,8 +205,7 @@ class TestHybridMesh:
             if hasattr(x, "sharding")
         }
         # fallback on the CPU backend: everything stays in the backend's
-        # default memory (reported as "device" on newer jax, "unpinned_host"
-        # on 0.4.x CPU)
+        # default memory
         assert kinds == {jax.devices()[0].default_memory().kind}
         with pytest.warns(UserWarning, match="TPU runtime"):
             acc.compile_train_step(lambda p, b: jnp.mean((b["x"] @ p["w"]) ** 2))

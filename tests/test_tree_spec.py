@@ -406,7 +406,7 @@ class TestTreeEngine:
               for r in _engine(model, params, **kw, **TREE_KW).serve(prompts, gen)]
         assert t1 == t0
 
-    def test_tp2_falls_back_and_matches(self):
+    def test_tp2_matches_and_refuses_the_pallas_kernel(self):
         mesh = build_mesh({"tp": 2}, devices=jax.devices()[:2])
         model, params = _tiny_model()
         prompts = self._workload(model, np.random.default_rng(44))
@@ -414,10 +414,12 @@ class TestTreeEngine:
         t1 = [r.tokens
               for r in _engine(model, params, paged=True, **TREE_KW)
               .serve(prompts, gen)]
-        e2 = _engine(model, params, paged=True, mesh=mesh,
-                     decode_kernel="pallas", **TREE_KW)
+        with pytest.raises(ValueError, match="single-chip"):
+            _engine(model, params, paged=True, mesh=mesh,
+                    decode_kernel="pallas", **TREE_KW)
+        e2 = _engine(model, params, paged=True, mesh=mesh, **TREE_KW)
         t2 = [r.tokens for r in e2.serve(prompts, gen)]
-        assert e2.decode_kernel == "xla"           # single-chip kernel fell back
+        assert e2.decode_kernel == "xla"
         assert t2 == t1
 
     @pytest.mark.parametrize("paged", [False, True])
