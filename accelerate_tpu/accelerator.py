@@ -1572,6 +1572,7 @@ class Accelerator:
             name=f"train_step/{getattr(loss_fn, '__name__', 'loss')}",
             budget=compile_budget,
             registry=self.telemetry,
+            span="train/dispatch",
         )
 
         # python mirror of the chunked path's micro-step counter (see above)
@@ -1717,38 +1718,41 @@ class Accelerator:
                 except Exception:
                     shapes = None
                 recorder.record("train/capture", name=cost_key, batch_shapes=shapes)
-            t0 = time.perf_counter()
+            # the span covers the whole wrapper: its self time (less
+            # train/dispatch) is this repo's Python per step, the watchdog's
+            # signature pass, the gauges and the heartbeat included
             with tracer.span("train/step"):
+                t0 = time.perf_counter()
                 new_state, metrics = step(state, batch)
-            dt = time.perf_counter() - t0
-            step_hist.observe(dt)
-            steps_total.inc()
-            ntok = _batch_token_count(batch)
-            if ntok:
-                tokens_total.inc(ntok)
-                tps_gauge.set(ntok / dt if dt > 0 else 0.0)
-            loss = None
-            if isinstance(metrics, dict):
-                if metrics.get("grad_norm") is not None:
-                    gnorm_gauge.set(metrics["grad_norm"])
-                if metrics.get("loss") is not None:
-                    loss = metrics["loss"]
-                    loss_gauge.set(loss)
-            # Cost-derived gauges: dict lookups only; None until someone ran
-            # analyze_costs() (bench, scrape collector, flight dump).
-            flops = cost_table.flops(cost_key)
-            if flops:
-                flops_gauge.set(flops)
-                if dt > 0:
-                    mfu_gauge.set(min(1.0, flops / dt / peak_flops))
-            hbm = cost_table.hbm_peak_bytes(cost_key)
-            if hbm:
-                hbm_gauge.set(hbm)
-            # Progress heartbeat: feeds the stall detector and /healthz; the
-            # loss stays a live device value until a dump coerces it.
-            recorder.heartbeat(
-                "train/step", step=steps_total.value, dt_s=dt, tokens=ntok, loss=loss
-            )
+                dt = time.perf_counter() - t0
+                step_hist.observe(dt)
+                steps_total.inc()
+                ntok = _batch_token_count(batch)
+                if ntok:
+                    tokens_total.inc(ntok)
+                    tps_gauge.set(ntok / dt if dt > 0 else 0.0)
+                loss = None
+                if isinstance(metrics, dict):
+                    if metrics.get("grad_norm") is not None:
+                        gnorm_gauge.set(metrics["grad_norm"])
+                    if metrics.get("loss") is not None:
+                        loss = metrics["loss"]
+                        loss_gauge.set(loss)
+                # Cost-derived gauges: dict lookups only; None until someone ran
+                # analyze_costs() (bench, scrape collector, flight dump).
+                flops = cost_table.flops(cost_key)
+                if flops:
+                    flops_gauge.set(flops)
+                    if dt > 0:
+                        mfu_gauge.set(min(1.0, flops / dt / peak_flops))
+                hbm = cost_table.hbm_peak_bytes(cost_key)
+                if hbm:
+                    hbm_gauge.set(hbm)
+                # Progress heartbeat: feeds the stall detector and /healthz; the
+                # loss stays a live device value until a dump coerces it.
+                recorder.heartbeat(
+                    "train/step", step=steps_total.value, dt_s=dt, tokens=ntok, loss=loss
+                )
             return new_state, metrics
 
         instrumented._jitted = jitted

@@ -35,7 +35,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ...logging import get_logger
 from ...models.generation import GenerationConfig
-from ...telemetry import get_flight_recorder, get_reqtrace, slo_tick
+from ...telemetry import get_flight_recorder, get_reqtrace, get_tracer, slo_tick
 from ..errors import AdmissionError, DeadlineExceeded
 from ..router import ReplicaRouter
 from ..scheduler import Request, RequestState
@@ -47,6 +47,20 @@ __all__ = ["FrontDoor", "TokenStream"]
 
 #: Sentinel queued into a TokenStream when the producer side closes.
 _CLOSED = object()
+
+
+def _name_os_thread(name: str) -> None:
+    """Give the calling thread an OS-level name (Linux; 15 characters).
+    ``threading.Thread(name=...)`` names it for Python alone; ``top -H`` and
+    the host lines of a ``jax.profiler`` trace show the OS name, which every
+    Python thread inherits as ``python``.  A reducer that keys a trace's host
+    lines by name then keeps one such line and loses the others, the driver's
+    spans with them: the driver's line gets a name of its own."""
+    try:
+        with open(f"/proc/self/task/{threading.get_native_id()}/comm", "w") as f:
+            f.write(name[:15])
+    except OSError:
+        pass  # not Linux, or /proc is not writable: the name is a convenience
 
 
 class TokenStream:
@@ -66,10 +80,13 @@ class TokenStream:
         self.final_tokens: List[int] = []
         self.final_state: Optional[RequestState] = None
         self.error: Optional[BaseException] = None
+        #: ``time.perf_counter()`` at which the engine emitted the token that
+        #: :meth:`get` returned last (where ``http/stream_write`` starts)
+        self.emitted_at = 0.0
 
     # ---- driver side -----------------------------------------------------
     def push(self, token: int) -> None:
-        self._q.put(int(token))
+        self._q.put((int(token), time.perf_counter()))
 
     def close(self, tokens: List[int], state: Optional[RequestState],
               error: Optional[BaseException] = None) -> None:
@@ -85,7 +102,10 @@ class TokenStream:
         tokens queued before the close first).  Raises ``queue.Empty`` on
         timeout."""
         item = self._q.get(timeout=timeout)
-        return None if item is _CLOSED else item
+        if item is _CLOSED:
+            return None
+        token, self.emitted_at = item
+        return token
 
     def wait_done(self, timeout: Optional[float] = None) -> bool:
         return self._done.wait(timeout)
@@ -140,6 +160,7 @@ class FrontDoor:
         self.heartbeat_interval_s = float(heartbeat_interval_s)
         self.ticket_timeout_s = float(ticket_timeout_s)
         self.recorder = get_flight_recorder().tagged(engine="frontdoor")
+        self.tracer = get_tracer()
         self._tickets: "queue.Queue[_Ticket]" = queue.Queue()
         # keyed by a front-door-minted id, NOT ``req.rid``: engine rids are
         # per-replica counters (and rewritten by failover adoption), so two
@@ -311,9 +332,9 @@ class FrontDoor:
         )
 
     # ------------------------------------------------------------- driver
-    def _reap(self) -> None:
-        """Close the streams of every finished/cancelled request.  Runs on
-        the driver thread only."""
+    def _reap(self) -> int:
+        """Close the streams of every finished/cancelled request; returns how
+        many.  Runs on the driver thread only."""
         finished = [
             rid for rid, (req, _) in self._outstanding.items()
             if req.state in (RequestState.DONE, RequestState.CANCELLED)
@@ -333,37 +354,50 @@ class FrontDoor:
                 )
             else:
                 stream.close(req.tokens, req.state)
+        return len(finished)
 
     def _process_tickets(self, skip_admin: bool = False) -> None:
+        if self._tickets.empty():
+            return  # no span for an empty inbox: an idle server would flood the ring
         deferred: List[_Ticket] = []
-        while True:
-            try:
-                t = self._tickets.get_nowait()
-            except queue.Empty:
-                break
-            if skip_admin and t.admin:
-                # an admin op is already in progress on this stack (we are
-                # inside its drain loop); run nested admin ops after it
-                deferred.append(t)
-                continue
-            try:
-                t.result = t.fn()
-            except BaseException as exc:  # propagate to the waiting thread
-                t.error = exc
-            finally:
-                t.event.set()
+        with self.tracer.span("door/tickets") as span:
+            ran = 0
+            while True:
+                try:
+                    t = self._tickets.get_nowait()
+                except queue.Empty:
+                    break
+                if skip_admin and t.admin:
+                    # an admin op is already in progress on this stack (we are
+                    # inside its drain loop); run nested admin ops after it
+                    deferred.append(t)
+                    continue
+                ran += 1
+                try:
+                    t.result = t.fn()
+                except BaseException as exc:  # propagate to the waiting thread
+                    t.error = exc
+                finally:
+                    t.event.set()
+            span["tickets"] = ran
         for t in deferred:
             self._tickets.put(t)
 
-    def _pump(self) -> None:
-        """One drive iteration: service the inbox (admin ops deferred —
-        this is also the hot-swap drain hook, which must keep accepting
-        submits without re-entering another rollout), step replicas with
-        work, resolve finished requests."""
-        self._process_tickets(skip_admin=True)
-        if self.router.has_work:
-            self.router.step()
-        self._reap()
+    def _pump(self, skip_admin: bool = True) -> bool:
+        """One drive iteration: service the inbox, step replicas with work,
+        resolve finished requests; returns whether the router stepped.  As
+        the hot-swap drain hook it defers admin ops (the default): the drain
+        must keep accepting submits without re-entering another rollout.
+        ``door/tickets``, ``router/step`` and ``door/reap`` tile this
+        thread's busy time; an idle iteration opens none of them."""
+        self._process_tickets(skip_admin=skip_admin)
+        if not self.router.has_work:
+            self._reap()
+            return False
+        self.router.step()
+        with self.tracer.span("door/reap") as span:
+            span["finished"] = self._reap()
+        return True
 
     def _fail_outstanding(self, exc: BaseException) -> None:
         """An engine step blew up: every in-flight request's stream is closed
@@ -378,14 +412,11 @@ class FrontDoor:
             self._outstanding.pop(rid, None)
 
     def _drive(self) -> None:
+        _name_os_thread("atpu-driver")
         while not self._stop.is_set():
             worked = False
             try:
-                self._process_tickets()
-                if self.router.has_work:
-                    self.router.step()
-                    worked = True
-                self._reap()
+                worked = self._pump(skip_admin=False)
             except Exception as exc:
                 self._fail_outstanding(exc)
             now = time.monotonic()

@@ -55,6 +55,7 @@ from ...telemetry import (
     TelemetryEndpoints,
     get_registry,
     get_reqtrace,
+    get_tracer,
 )
 from .. import faults
 from ..errors import AdmissionError, DeadlineExceeded
@@ -331,6 +332,7 @@ class _ApiHandler(BaseHTTPRequestHandler):
         # time into the trace (an overlay — it runs concurrently with engine
         # phases on another thread, so it never enters the TTFT tiling)
         trace = get_reqtrace().lookup(str(rid))
+        tracer = get_tracer()
         sse_t0 = time.perf_counter()
         try:
             while True:
@@ -352,8 +354,12 @@ class _ApiHandler(BaseHTTPRequestHandler):
                     decode=api.decode,
                 )).encode("utf-8"))
                 self.wfile.flush()
+                w1 = time.perf_counter()
+                # from the engine's emit of this token to its frame on the
+                # socket: queue, this thread's wake-up under the GIL, write
+                tracer.record("http/stream_write", stream.emitted_at, w1, req=rid)
                 if trace is not None:
-                    trace.add_sse_write(time.perf_counter() - w0)
+                    trace.add_sse_write(w1 - w0)
                 first = False
             cancelled = (stream.final_state is not None
                          and stream.final_state.name == "CANCELLED")

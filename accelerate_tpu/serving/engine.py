@@ -54,7 +54,7 @@ import dataclasses
 import itertools
 import math
 import time
-from typing import Any, Callable, List, Optional, Sequence
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1050,14 +1050,9 @@ class ServingEngine:
         # pipeline overlap accounting (async_depth=1): host_s accumulates the
         # dispatch->drain host-work time each window, wait_s the blocking tail
         # of each fetch; their ratio is the fraction of host work the device
-        # covered.  _t_pipeline_empty timestamps the moment the pipeline went
-        # empty so the next dispatch can charge the gap as device idle — under
-        # async_depth=0 that is every host gap (the honest baseline number),
-        # at steady depth-1 state it stays ~0.
+        # covered.
         self._overlap_host_s = 0.0
         self._overlap_wait_s = 0.0
-        self._device_idle_s = 0.0
-        self._t_pipeline_empty: Optional[float] = None
         # set when a lane is freed while its window is still in flight: the
         # active mask is host-authoritative, so the next dispatch refreshes
         # just that one device vector instead of a full (blocking) resync
@@ -1074,13 +1069,6 @@ class ServingEngine:
             help="fraction of serve-loop host work (emit/callbacks/admission) "
                  "hidden under device execution: host_s / (host_s + "
                  "readback_wait_s), cumulative; 0 under async_depth=0",
-        )
-        self._idle_gauge = self.metrics.gauge(
-            "serve/device_idle_ms",
-            help="cumulative ms the device sat with no window dispatched or "
-                 "in flight (pipeline-empty gaps between drain and the next "
-                 "dispatch); grows every step under async_depth=0, stays "
-                 "near-flat once the depth-1 pipeline fills",
         )
         # lane-migration gather/scatter pair, built lazily by
         # serving/transfer.py on this engine's first migration (most
@@ -1485,7 +1473,6 @@ class ServingEngine:
             self.prefix_cache.flush()
         self._lane_device = None
         self._mask_stale = False
-        self._t_pipeline_empty = None
         self._poisoned = None
         self.admission_paused = False
         self.recorder.record("serve/revive", step=self._step_count)
@@ -1556,6 +1543,13 @@ class ServingEngine:
         return None
 
     def _admit(self) -> None:
+        chunks, tokens = self.stats["prefill_chunks"], self.stats["prefill_tokens"]
+        with self.tracer.span("serve/admit") as span:
+            self._admit_impl()
+            span["chunks"] = self.stats["prefill_chunks"] - chunks
+            span["prefill_tokens"] = self.stats["prefill_tokens"] - tokens
+
+    def _admit_impl(self) -> None:
         # paused admission (drain / hot-swap): never START a prefill, but a
         # request already mid-prefill finishes — abandoning it would leak its
         # reserved slot and cache pins
@@ -2243,6 +2237,12 @@ class ServingEngine:
                 self._bump("prefreed_lanes")
 
     def _dispatch_decode(self) -> Optional["Readback"]:
+        with self.tracer.span("serve/dispatch") as span:
+            hd = self._dispatch_decode_impl()
+            span["occupied"] = int(self._active.sum())
+        return hd
+
+    def _dispatch_decode_impl(self) -> Optional["Readback"]:
         """Dispatch one decode phase over the pool — a speculative verify
         cycle when any lane has an n-gram draft, the plain decode window
         otherwise — and return the handle the caller must drain (the
@@ -2350,14 +2350,6 @@ class ServingEngine:
         if hd is not None:
             self._drain(hd)
 
-    def _note_dispatch(self) -> None:
-        """Charge the gap since the pipeline last went empty as device idle
-        time (the bubble the depth-1 pipeline exists to close)."""
-        if self._t_pipeline_empty is not None:
-            self._device_idle_s += time.perf_counter() - self._t_pipeline_empty
-            self._idle_gauge.set(self._device_idle_s * 1e3)
-            self._t_pipeline_empty = None
-
     def _drain(self, hd: Readback) -> None:
         """Land one window's deferred outputs: the ONE blocking readback per
         window, then all host-side bookkeeping against the window's
@@ -2366,7 +2358,8 @@ class ServingEngine:
         and its tokens are dropped — exactly what the sync loop would never
         have produced)."""
         try:
-            self._drain_impl(hd)
+            with self.tracer.span("serve/drain", kind=hd.kind):
+                self._drain_impl(hd)
         except BaseException:
             # a failed drain poisons this engine (step()'s wrapper) with the
             # handle already detached from ``_inflight`` — a pre-freed lane's
@@ -2460,14 +2453,15 @@ class ServingEngine:
                 accepted=accepted,
             )
         self._trace_drain(hd, counts, t0, t1)
-        self._emit(toks, counts, mask=hd.active, reqs=hd.reqs, eos=hd.eos,
-                   prefreed=hd.prefreed)
+        # one span a call: nothing is opened inside the per-token loop
+        with self.tracer.span("serve/emit") as span:
+            span["tokens"], span["lanes"] = self._emit(
+                toks, counts, mask=hd.active, reqs=hd.reqs, eos=hd.eos,
+                prefreed=hd.prefreed)
         if self.paged and hd.deferred_pages:
             # fetch() above proved the window retired: its masked writes to
             # detached lanes' pages have landed, so the pages can recycle
             hd.settle(self.kv.allocator)
-        if self._inflight is None:
-            self._t_pipeline_empty = time.perf_counter()
 
     def _trace_drain(self, hd: Readback, counts: np.ndarray,
                      t0: float, t1: float) -> None:
@@ -2499,7 +2493,6 @@ class ServingEngine:
         next dispatch donates the new handles, never a buffer the in-flight
         window still owns."""
         lanes = self._lane_arrays()
-        self._note_dispatch()
         qerr = None
         if self.paged and self._direct:
             kv = self.kv
@@ -2616,7 +2609,6 @@ class ServingEngine:
         and their commits never emit."""
         tree = self.tree
         lanes = self._lane_arrays()
-        self._note_dispatch()
         t0 = time.perf_counter()
         dw = self._draft_window
         ctx = self._put(dw.tokens)
@@ -2710,7 +2702,6 @@ class ServingEngine:
         speculative cycles drain the previous window before dispatching."""
         k = self.speculate_k
         lanes = self._lane_arrays()
-        self._note_dispatch()
         # the host pending mirror is always fresh here (a pending verify
         # handle was drained before drafting); only the [N, K+1] token block
         # uploads per verify cycle
@@ -2785,7 +2776,7 @@ class ServingEngine:
               mask: Optional[np.ndarray] = None,
               reqs: Optional[List[Optional[Request]]] = None,
               eos: Optional[np.ndarray] = None,
-              prefreed: Optional[set] = None) -> None:
+              prefreed: Optional[set] = None) -> Tuple[int, int]:
         """Land device-produced tokens on their requests. ``toks[s, :counts[s]]``
         is lane ``s``'s output this cycle (a full decode window, or a verify
         cycle's committed prefix).  Per-lane take counts — EOS cut plus the
@@ -2796,7 +2787,9 @@ class ServingEngine:
         ``mask``/``reqs``/``eos`` are the window's dispatch-time snapshots
         (:class:`Readback`): under the pipeline the live lane state may have
         moved on — a lane freed/cancelled/preempted since dispatch no longer
-        owns its slot, so the ownership check drops its tokens."""
+        owns its slot, so the ownership check drops its tokens.  Returns the
+        tokens landed and the lanes they landed on (the ``serve/emit`` span's
+        counts)."""
         if mask is None:
             mask = self._active
         if reqs is None:
@@ -2811,6 +2804,7 @@ class ServingEngine:
         first_eos = np.where(has_eos, is_eos.argmax(axis=1), width)
         n_take = np.minimum(valid.sum(axis=1), first_eos + 1)
         now = time.perf_counter()
+        landed = lanes = 0
         for s in np.nonzero(n_take > 0)[0]:
             req = reqs[s]
             if req is None:
@@ -2851,6 +2845,8 @@ class ServingEngine:
                 # keep the draft context's tail == the lane's pending token
                 # (the committed suffix ends with the next pending token)
                 self._draft_window.push(int(s), toks[s, :n])
+            landed += n
+            lanes += 1
             self._bump("tokens_generated", n)
             self._bump_tenant(req.tenant, "tokens_generated", n)
             # a cycle lands n tokens on this lane at once: each is charged its
@@ -2867,6 +2863,7 @@ class ServingEngine:
                     self._finish_request(int(s), req)
             elif owner:
                 self._pending_tok[s] = int(toks[s, n - 1])
+        return landed, lanes
 
     # ------------------------------------------------------------------ drive
     def step(self) -> None:
@@ -2881,7 +2878,11 @@ class ServingEngine:
         if self._poisoned is not None:
             raise self._poisoned
         try:
-            self._step_impl()
+            with self.tracer.span(
+                "serve/step", queue=self.scheduler.queue_depth
+            ) as span:
+                self._step_impl()
+                span["occupied"] = int(self._active.sum())
         except Exception as exc:
             self._poisoned = exc
             self.recorder.record(

@@ -72,6 +72,7 @@ from ..telemetry import (
     get_flight_recorder,
     get_registry,
     get_reqtrace,
+    get_tracer,
 )
 from . import faults
 from .engine import ServingEngine
@@ -703,6 +704,12 @@ class ReplicaRouter:
         that arrives already poisoned (:meth:`ServingEngine.kill`) — is
         ejected and its in-flight requests replay on survivors; ejected
         replicas re-admit through the half-open circuit breaker."""
+        with get_tracer().span("router/step") as span:
+            span["replicas"] = self._step_impl()
+
+    def _step_impl(self) -> int:
+        """The body of :meth:`step`; returns how many replicas had work."""
+        stepped = 0
         if (faults.ACTIVE is not None and len(self.engines) > 1
                 and faults.ACTIVE.fire("replica_kill")):
             # kill the busiest replica — the worst case for replay
@@ -716,6 +723,7 @@ class ReplicaRouter:
                 continue
             if not engine.has_work:
                 continue
+            stepped += 1
             try:
                 engine.step()
             except Exception as exc:
@@ -727,6 +735,7 @@ class ReplicaRouter:
             self._sweep_handoffs()
         self._reap_drained()
         self._probe_breaker()
+        return stepped
 
     def run(self, max_steps: Optional[int] = None) -> None:
         steps = 0

@@ -28,6 +28,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 from ..logging import get_logger
 from .metrics import MetricsRegistry, enabled, get_registry
+from .tracer import get_tracer
 
 logger = get_logger(__name__)
 
@@ -67,6 +68,10 @@ class RecompileWatchdog:
         count).  The warning fires once per budget crossing, not per call.
     registry: metrics registry for the ``<name>/compile_count`` gauge and
         ``<name>/compile_time_s`` counter (default: the process registry).
+    span: name of a tracer span opened around the call into ``fn`` alone,
+        the signature pass left outside it (the trainer's ``train/dispatch``);
+        None opens nothing (the serve watchdogs: their callers' spans are
+        already there).
     """
 
     def __init__(
@@ -75,8 +80,10 @@ class RecompileWatchdog:
         name: Optional[str] = None,
         budget: Optional[int] = None,
         registry: Optional[MetricsRegistry] = None,
+        span: Optional[str] = None,
     ):
         self._fn = fn
+        self._span = span
         self.name = name or getattr(fn, "__name__", type(fn).__name__)
         self.budget = budget
         self.signatures: Dict[Tuple, Dict[str, float]] = {}
@@ -97,15 +104,21 @@ class RecompileWatchdog:
     def over_budget(self) -> bool:
         return self.budget is not None and len(self.signatures) > self.budget
 
+    def _dispatch(self, args, kwargs):
+        if self._span is None:
+            return self._fn(*args, **kwargs)
+        with get_tracer().span(self._span):
+            return self._fn(*args, **kwargs)
+
     def __call__(self, *args, **kwargs):
         if not enabled():
             return self._fn(*args, **kwargs)
         sig = arg_signature(args, kwargs)
         known = sig in self.signatures
         if known:
-            return self._fn(*args, **kwargs)
+            return self._dispatch(args, kwargs)
         t0 = time.perf_counter()
-        out = self._fn(*args, **kwargs)
+        out = self._dispatch(args, kwargs)
         dt = time.perf_counter() - t0
         self.signatures[sig] = {"first_call_s": dt, "at": time.time()}
         self._count_gauge.set(len(self.signatures))

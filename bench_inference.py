@@ -969,8 +969,6 @@ def _async_ab_bench(args, model, cfg, params, preset):
         "overlap": {
             "host_overlap_ratio": round(overlap, 4),
             "host_overlap_ratio_sync": round(overlap_sync, 4),
-            "device_idle_ms": round(float(reg_a.get("serve/device_idle_ms").value), 2),
-            "device_idle_ms_sync": round(float(reg_s.get("serve/device_idle_ms").value), 2),
         },
         "compiled_executables": eng_a.compiled_executable_counts(),
     }

@@ -8,3 +8,10 @@ def register(registry):
         registry.counter(f"serve/{k}_total", help="dynamic family")
     for t in ("acme", "umbrella"):
         registry.gauge(f"serve/pages_tenant_{t}", help="documented family")
+
+
+def watch(fn, watchdog, tracer):
+    with tracer.span("train/step"):
+        # the name of a span handed on as a keyword is an emitter too: the
+        # index row below would be an orphan without it
+        return watchdog(fn, span="train/dispatch")

@@ -29,7 +29,7 @@ from accelerate_tpu.models.generation import GenerationConfig
 from accelerate_tpu.models.transformer import Transformer, TransformerConfig
 from accelerate_tpu.serving import ReplicaRouter, ServingEngine
 from accelerate_tpu.serving.api import ApiServer, FrontDoor
-from accelerate_tpu.telemetry import MetricsRegistry
+from accelerate_tpu.telemetry import MetricsRegistry, get_tracer
 
 NEW_TOKENS = 6
 ENGINE_KW = dict(num_slots=2, max_len=64, prefill_buckets=(4, 8),
@@ -154,6 +154,104 @@ def test_sse_streams_frame_tokens_before_done(svc):
     assert chunks[0]["choices"][0]["token_ids"], "first frame must carry a token"
     assert chunks[-1]["choices"][0]["finish_reason"] == "length"
     assert chunks[-1]["choices"][0]["token_ids"] == []
+
+
+def _stream(svc, prompt):
+    """One streamed completion; ``(request id header, token frames)``."""
+    conn = http.client.HTTPConnection(svc.host, svc.port, timeout=60.0)
+    try:
+        conn.request("POST", "/v1/completions", json.dumps({
+            "prompt": [int(t) for t in prompt],
+            "max_tokens": NEW_TOKENS, "temperature": 0, "stream": True,
+        }), {"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        assert resp.status == 200
+        frames = []
+        for raw in iter(resp.readline, b""):
+            line = raw.strip()
+            if line == b"data: [DONE]":
+                break
+            if line.startswith(b"data: "):
+                frames.append(json.loads(line[len(b"data: "):]))
+        return resp.getheader("X-Request-Id"), [f for f in frames if f["choices"][0]["token_ids"]]
+    finally:
+        conn.close()
+
+
+def test_driver_spans_nest_and_every_streamed_frame_is_recorded(svc):
+    """The driver thread's spans nest door -> router -> engine step -> admit /
+    dispatch / drain -> emit, on one thread; a handler thread records one
+    ``http/stream_write`` interval per token frame under the request's id."""
+    tracer = get_tracer()
+    last = max((e["id"] for e in tracer.events), default=0)
+    request_id, token_frames = _stream(svc, svc.prompts[1])
+    assert len(token_frames) == NEW_TOKENS
+    # the trailing steps (pipeline flush, reap) close after the last frame
+    assert _settle(lambda: not svc.router.has_work and not svc.frontdoor._outstanding)
+    events = [e for e in tracer.events if e["id"] > last]
+    by_id = {e["id"]: e for e in events}
+    named = lambda name: [e for e in events if e["name"] == name]
+
+    def inside(child, parent):
+        return (parent["ts"] <= child["ts"]
+                and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"] + 1.0)
+
+    steps, router_steps = named("serve/step"), named("router/step")
+    assert steps and len(router_steps) == len(steps)      # one replica: one each
+    for step in steps:
+        router_step = by_id[step["parent"]]
+        assert router_step["name"] == "router/step" and inside(step, router_step)
+        assert router_step["args"]["replicas"] == 1 and router_step["parent"] is None
+        assert {"queue", "occupied"} <= set(step["args"])
+        parts = [e for e in events if e["parent"] == step["id"]]
+        assert {e["name"] for e in parts} <= {"serve/admit", "serve/dispatch", "serve/drain"}
+        assert all(inside(e, step) for e in parts)
+        assert sum(e["dur"] for e in parts) <= step["dur"]
+    assert len(named("serve/admit")) == len(steps)
+    assert sum(e["args"]["prefill_tokens"] for e in named("serve/admit")) == len(svc.prompts[1])
+    # the windows and the blocking fetch stay children of dispatch and drain
+    assert all(by_id[e["parent"]]["name"] == "serve/dispatch" for e in named("serve/decode_window"))
+    assert all(by_id[e["parent"]]["name"] == "serve/drain" for e in named("serve/readback"))
+    # one emit per drain, and together they landed the request's tokens
+    emits, drains = named("serve/emit"), named("serve/drain")
+    assert len(emits) == len(drains) >= NEW_TOKENS // ENGINE_KW["decode_window"]
+    assert all(by_id[e["parent"]]["name"] == "serve/drain" for e in emits)
+    assert sum(e["args"]["tokens"] for e in emits) == NEW_TOKENS
+    assert all(e["args"]["lanes"] == (1 if e["args"]["tokens"] else 0) for e in emits)
+    # the front door: the submit ticket, and a reap on every stepped iteration
+    assert sum(e["args"]["tickets"] for e in named("door/tickets")) >= 1
+    assert len(named("door/reap")) == len(router_steps)
+    assert sum(e["args"]["finished"] for e in named("door/reap")) == 1
+    # every span above ran on the one driver thread; the frames did not
+    driver = {e["tid"] for e in events if e["name"] != "http/stream_write"}
+    assert len(driver) == 1
+    writes = named("http/stream_write")
+    assert len(writes) == len(token_frames)
+    assert {f"cmpl-{e['args']['req']}" for e in writes} == {request_id}
+    assert all(e["parent"] is None and e["dur"] > 0 and e["tid"] not in driver for e in writes)
+    # the emit stamp travels with the token: a write starts inside an emit
+    for write in writes:
+        assert any(e["ts"] <= write["ts"] <= e["ts"] + e["dur"] for e in emits)
+
+
+def test_an_idle_server_opens_no_driver_span(svc):
+    assert _settle(lambda: not svc.router.has_work)
+    tracer = get_tracer()
+    last = max((e["id"] for e in tracer.events), default=0)
+    time.sleep(0.2)                      # some two hundred idle iterations
+    assert [e["name"] for e in tracer.events if e["id"] > last] == []
+
+
+def test_driver_thread_has_an_os_name_of_its_own(svc):
+    """A device trace names a host thread's line by its OS name, ``python``
+    for every Python thread: the driver's spans need a line of their own."""
+    comm = f"/proc/self/task/{svc.frontdoor._thread.native_id}/comm"
+    try:
+        with open(comm) as f:
+            name = f.read().strip()
+    except OSError:
+        pytest.skip("no /proc/self/task here")
+    assert name == "atpu-driver"
 
 
 def test_queue_flood_answers_429_with_retry_after(svc):

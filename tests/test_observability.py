@@ -465,3 +465,30 @@ class TestTrainIntegration:
         kinds = [e["kind"] for e in acc.flight_recorder.tail()]
         assert acc.flight_recorder.events_total > before
         assert "train/step" in kinds
+
+    def test_train_dispatch_span_lies_inside_train_step(self):
+        """``train/step`` is the whole instrumented wrapper; ``train/dispatch``
+        is the call into the compiled program alone (the watchdog's signature
+        pass outside it), so the step's self time is the repo's own Python."""
+        stop_debug_server()
+        acc = fresh_accelerator()
+        state = acc.create_train_state(
+            params={"a": jnp.zeros((1,)), "b": jnp.zeros((1,))}, tx=optax.sgd(0.1)
+        )
+        step = acc.compile_train_step(regression_loss)
+        last = max((e["id"] for e in acc.tracer.events), default=0)
+        for _ in range(3):
+            state, _ = step(state, self._batch())
+        events = [e for e in acc.tracer.events if e["id"] > last]
+        steps = [e for e in events if e["name"] == "train/step"]
+        dispatches = [e for e in events if e["name"] == "train/dispatch"]
+        assert len(steps) == len(dispatches) == 3
+        for outer, inner in zip(steps, dispatches):
+            assert inner["parent"] == outer["id"] and outer["parent"] is None
+            assert outer["ts"] <= inner["ts"]
+            assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+        # an eval step's watchdog was given no span name and opens nothing
+        evaluate = acc.compile_eval_step(lambda params, batch, rng=None: params["a"] * batch["x"])
+        last = max(e["id"] for e in acc.tracer.events)
+        evaluate(state, self._batch())
+        assert [e["name"] for e in acc.tracer.events if e["id"] > last] == ["eval/step"]

@@ -10,12 +10,15 @@ kwargs).
 
 import json
 import logging
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from accelerate_tpu import telemetry
 from accelerate_tpu.logging import MultiProcessAdapter, get_logger
 from accelerate_tpu.telemetry import (
     Counter,
@@ -228,9 +231,116 @@ class TestTracer:
 
     def test_disabled_records_nothing(self):
         tr = Tracer(enabled=False)
-        with tr.span("a"):
-            pass
+        with tr.span("a") as args:
+            args["n"] = 1          # a caller's late count is taken and dropped
+        tr.record("b", 0.0, 1.0, req=3)
         assert tr.events == [] and tr.aggregate() == {}
+
+    def test_id_and_parent_link_nested_spans_per_thread(self):
+        tr = Tracer(enabled=True)
+        other = {}
+
+        def elsewhere():
+            with tr.span("other_outer"):
+                with tr.span("other_inner"):
+                    other["done"] = True
+
+        with tr.span("outer"):
+            with tr.span("first"):
+                pass
+            # a span open on another thread is no parent of this thread's
+            worker = threading.Thread(target=elsewhere)
+            worker.start()
+            worker.join(10.0)
+            with tr.span("second") as args:
+                args["tokens"] = 5     # a count known only at the span's end
+        assert other == {"done": True}
+        by = {e["name"]: e for e in tr.events}
+        assert len({e["id"] for e in tr.events}) == 5
+        assert by["outer"]["parent"] is None and by["other_outer"]["parent"] is None
+        assert by["first"]["parent"] == by["second"]["parent"] == by["outer"]["id"]
+        assert by["other_inner"]["parent"] == by["other_outer"]["id"]
+        assert by["second"]["args"] == {"tokens": 5, "depth": 1}
+        # self time: a parent's duration less the events that name it parent
+        children = sum(e["dur"] for e in tr.events if e["parent"] == by["outer"]["id"])
+        assert 0.0 <= children <= by["outer"]["dur"]
+
+    def test_record_takes_an_interval_stamped_elsewhere(self):
+        tr = Tracer(enabled=True)
+        t0 = time.perf_counter()
+        with tr.span("open"):
+            tr.record("http/stream_write", t0, t0 + 0.25, req=7)
+        event = next(e for e in tr.events if e["name"] == "http/stream_write")
+        assert event["dur"] == pytest.approx(0.25e6)
+        assert event["args"] == {"req": 7}
+        # not nested under whatever is open here: its start lies elsewhere
+        assert event["parent"] is None
+        assert tr.aggregate()["http/stream_write"]["count"] == 1
+
+    def test_record_enters_no_trace_annotation_during_a_capture(self, monkeypatch):
+        entered = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(self.name)
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        tr = telemetry.get_tracer()
+        telemetry.set_device_trace_active(True)
+        try:
+            with tr.span("test/mirrored"):
+                pass
+            now = time.perf_counter()
+            tr.record("test/recorded", now - 0.001, now, req=1)
+        finally:
+            telemetry.set_device_trace_active(False)
+        assert entered == ["test/mirrored"]
+        names = [e["name"] for e in tr.capture()["events"]]
+        assert "test/mirrored" in names and "test/recorded" in names
+
+    def test_capture_returns_the_events_that_overlap_the_last_flips(self):
+        tr = Tracer(enabled=True)
+        with tr.span("before_any_capture"):
+            pass
+        assert tr.capture() is None            # nothing before a first flip
+        tr.mark_capture(True)
+        tr.mark_capture(False)                 # an earlier capture, superseded
+        with tr.span("between"):
+            pass
+        with tr.span("straddles_start"):
+            tr.mark_capture(True)
+            with tr.span("inside"):
+                pass
+            during = tr.capture()              # still on: it ends now
+        with tr.span("straddles_end"):
+            tr.mark_capture(False)
+        with tr.span("after"):
+            pass
+        taken = tr.capture()
+        assert [e["name"] for e in during["events"]] == ["inside"]
+        assert [e["name"] for e in taken["events"]] == ["inside", "straddles_start", "straddles_end"]
+        assert taken["t0"] < taken["t1"]
+        inside = taken["events"][0]
+        assert taken["t0"] <= inside["ts"] and inside["ts"] + inside["dur"] <= taken["t1"]
+        tr.reset()
+        assert tr.capture() is None
+
+    def test_device_trace_flips_stamp_the_default_tracer(self):
+        tr = telemetry.get_tracer()
+        telemetry.set_device_trace_active(True)
+        with tr.span("test/in_capture"):
+            pass
+        telemetry.set_device_trace_active(False)
+        with tr.span("test/after_capture"):
+            pass
+        names = [e["name"] for e in tr.capture()["events"]]
+        assert "test/in_capture" in names and "test/after_capture" not in names
 
 
 class TestRecompileWatchdog:

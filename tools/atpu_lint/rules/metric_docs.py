@@ -8,7 +8,8 @@ in ``docs/usage/observability.md`` — the doc is the operator-facing contract
 for what a ``/metrics`` scrape can contain, and an undocumented gauge is
 invisible to whoever has to build the dashboard.  The same holds for
 namespaced span and flight-event names (``tracer.span("serve/...")``,
-``recorder.record("serve/...")``, ``recorder.heartbeat("serve/...")``): an
+``recorder.record("serve/...")``, ``recorder.heartbeat("serve/...")``, and a
+span's name handed on as a ``span="train/..."`` keyword): an
 undocumented event kind is noise to whoever reads a ``/debug/flight`` ring
 during an incident.
 
@@ -73,7 +74,18 @@ class MetricDocsRule(Rule):
 
     def visit(self, tree, src, ctx) -> List[Diagnostic]:
         for node in ast.walk(tree):
-            if not (isinstance(node, ast.Call) and node.args):
+            if not isinstance(node, ast.Call):
+                continue
+            for kw in node.keywords:
+                # a span's name handed to whoever opens it
+                # (``RecompileWatchdog(fn, span="train/dispatch")``)
+                if (kw.arg == "span" and isinstance(kw.value, ast.Constant)
+                        and isinstance(kw.value.value, str)
+                        and _CONCRETE.fullmatch(kw.value.value)):
+                    self._event_literals.append(
+                        (ctx.rel, node.lineno, "span", kw.value.value)
+                    )
+            if not node.args:
                 continue
             if isinstance(node.func, ast.Attribute):
                 attr = node.func.attr
