@@ -286,9 +286,16 @@ class KVCache(struct.PyTreeNode):
     (``/root/reference/benchmarks/big_model_inference.py:108-139``); its cache
     lives inside transformers' dynamic python objects.  TPU-first the cache is
     one pytree of fixed-shape arrays — ``[num_layers, batch, max_len, kv_heads,
-    head_dim]`` — written in place with ``lax.dynamic_update_slice`` at a
-    traced position index, so ONE decode executable serves every token and XLA
-    aliases the update when the cache is donated.
+    head_dim]`` — so ONE decode executable serves every token.  The unrolled
+    forward threads the STACKED arrays through the layers: layer ``i`` writes
+    only its new rows at ``[i, lane, index : index + S]`` (:func:`_write_rows`)
+    and attends over the static slice ``k[i]``.  No layer's slab is sliced
+    out, copied or stacked back, which is what lets XLA keep the write in
+    place — in a donated cache and in a ``lax.scan`` carry alike (a slice ->
+    update -> ``jnp.stack`` round trip compiles to several copies of the
+    whole cache per forward; ``tests/test_tpu_compile.py`` holds the compiled
+    decode window to that).  ``scan_layers=True`` instead lets ``nn.scan``
+    slice and restack per-layer slabs itself.
 
     ``index`` is either a scalar (the whole batch decodes in lockstep — the
     ``generate`` path) or a per-lane ``[B]`` vector (each lane sits at its own
@@ -326,14 +333,17 @@ class PagedKVCache(struct.PyTreeNode):
     shared refcounted page pool (``[L, num_pages, Hkv, page, D]``) plus each
     lane's block table — attention reads pages in place
     (:mod:`accelerate_tpu.ops.paged_attention`), selected by
-    ``TransformerConfig.paged_kernel``.  Scales are ALWAYS present (ones for
-    direct-store dtypes) so the pytree structure — and with it the compiled
-    window signature — does not fork on the KV dtype; quantized-ness is the
-    static page dtype.  ``active`` gates writes: frozen lanes' scatters are
-    rerouted to the null page exactly like the gather windows in
-    :mod:`accelerate_tpu.serving.pool`.  ``quant_err`` accumulates the max
-    abs KV round-trip error of values written this forward (0 when native) —
-    the engine surfaces it as ``serve/kv_quant_error``.
+    ``TransformerConfig.paged_kernel``.  Like :class:`KVCache` the stacked
+    pool goes through the unrolled layers whole: layer ``i`` inserts its new
+    rows at ``[i, page, :, offset]`` in place and reads ``pages_k[i]``.
+    Scales are ALWAYS present (ones for direct-store dtypes) so the pytree
+    structure — and with it the compiled window signature — does not fork on
+    the KV dtype; quantized-ness is the static page dtype.  ``active`` gates
+    writes: frozen lanes' scatters are rerouted to the null page exactly like
+    the gather windows in :mod:`accelerate_tpu.serving.pool`.  ``quant_err``
+    accumulates the max abs KV round-trip error of values written this
+    forward (0 when native) — the engine surfaces it as
+    ``serve/kv_quant_error``.
     """
 
     pages_k: jax.Array      # [L, num_pages, n_kv_heads, page, head_dim]
@@ -543,18 +553,61 @@ def make_norm(cfg: "TransformerConfig", name: Optional[str] = None):
     return RMSNorm(cfg.rms_norm_eps, cfg.param_dtype, cfg.norm_unit_offset, name=name)
 
 
+def _write_rows(buf, new, index, layer=None):
+    """Write ``new [B, S, H, D]`` into a KV buffer at rows ``index .. index +
+    S - 1`` of every lane, in place.
+
+    ``buf`` is one layer's slab ``[B, M, H, D]`` (``layer=None``: the callers
+    that hold per-layer arrays, ``ScanBody`` and ``big_modeling``'s streamed
+    decode) or the stacked cache ``[L, B, M, H, D]`` addressed at the static
+    ``layer``.  Scalar ``index`` (generate, a prefill chunk) is one
+    ``dynamic_update_slice``; a per-lane ``index [B]`` (decode, verify and
+    tree windows of the serving pool) is one scatter of the ``B * S`` new
+    rows.  The start is clamped so the span fits, as ``dynamic_update_slice``
+    clamps it: every write the engine admits is in range already (its
+    admission check), and a frozen lane's stale index can only land on that
+    lane's own dead rows — so the scatter may be told its indices are in
+    bounds and unique."""
+    new = new.astype(buf.dtype)
+    lead = () if layer is None else (layer,)
+    if jnp.ndim(index) == 0:
+        return jax.lax.dynamic_update_slice(
+            buf, new[(None,) * len(lead)], (*lead, 0, index, 0, 0)
+        )
+    b, s = new.shape[:2]
+    start = jnp.clip(index, 0, buf.shape[-3] - s)
+    rows = start[:, None] + jnp.arange(s)[None, :]                  # [B, S]
+    return buf.at[(*lead, jnp.arange(b)[:, None], rows)].set(
+        new, unique_indices=True, mode="promise_in_bounds"
+    )
+
+
 class Attention(nn.Module):
     config: TransformerConfig
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, cache=None,
-                 tree_mask=None):
-        """``cache`` is ``(k_cache [B,M,Hkv,D], v_cache, index)`` for this layer;
-        when given, new k/v are written at ``index`` (post-rope, so cached keys
-        never need re-rotation) and the call returns ``(out, (new_k_cache,
-        new_v_cache))``.  ``tree_mask`` (an ``[S, S]`` ancestor-or-self numpy
-        constant, ``S == x.shape[1]``) switches the cache-read mask to token-
-        tree visibility for speculative tree verification — cache required."""
+                 tree_mask=None, layer=None):
+        """With a ``cache`` the new k/v are written at its ``index`` (post-rope,
+        so cached keys never need re-rotation) and attention runs over the
+        cache.  The cache's type says who holds the arrays:
+
+        * a :class:`KVCache` / :class:`PagedKVCache` — the STACKED arrays of
+          every layer, ``layer`` this layer's static index.  The new rows go
+          into ``[layer, ...]`` in place and attention reads the static slice
+          ``[layer]``; returns ``(out, cache)`` with the written arrays (and a
+          paged cache's ``quant_err``) replaced, ``index`` as it was — the
+          unrolled :class:`Transformer` loop advances it once.
+        * a tuple — this layer's own arrays, for the callers that hold them
+          per layer: ``(k_cache [B,M,Hkv,D], v_cache, index)`` returns
+          ``(out, (k_cache, v_cache))``; the paged ``(pages_k, pages_v,
+          k_scales, v_scales, tables, index, active)`` returns ``(out,
+          (pages_k, pages_v, k_scales, v_scales, quant_err))``.
+
+        Both forms share everything but the address of the write.
+        ``tree_mask`` (an ``[S, S]`` ancestor-or-self numpy constant, ``S ==
+        x.shape[1]``) switches the cache-read mask to token-tree visibility
+        for speculative tree verification — cache required."""
         cfg = self.config
         hd = cfg.resolved_head_dim
         dense = functools_partial_dense(cfg, use_bias=cfg.attn_bias)
@@ -573,12 +626,24 @@ class Attention(nn.Module):
         if cfg.positional == "rope":
             q = _apply_rope(q, positions, cfg)
             k = _apply_rope(k, positions, cfg)
-        if cache is not None and len(cache) == 7:
-            # paged layer cache: (pages_k, pages_v, k_scales, v_scales,
-            # tables, index, active) — scatter the new KV through the block
-            # tables, then attend over pages in place.  ``index`` doubles as
-            # each lane's pre-write length (= first new position).
-            pages_k, pages_v, k_scales, v_scales, tables, index, active = cache
+        # the stacked cache of every layer, addressed at ``layer``; or this
+        # layer's own arrays in a tuple, addressed whole
+        stacked = isinstance(cache, (KVCache, PagedKVCache))
+        if stacked:
+            at = lambda a: a[layer]      # static slice of the leading axis
+            if isinstance(cache, PagedKVCache):
+                cache_arrays = (cache.pages_k, cache.pages_v, cache.k_scales,
+                                cache.v_scales, cache.tables, cache.index,
+                                cache.active)
+            else:
+                cache_arrays = (cache.k, cache.v, cache.index)
+        else:
+            at, layer, cache_arrays = (lambda a: a), None, cache
+        if cache is not None and len(cache_arrays) == 7:
+            # paged cache: scatter the new KV through the block tables, then
+            # attend over pages in place.  ``index`` doubles as each lane's
+            # pre-write length (= first new position).
+            pages_k, pages_v, k_scales, v_scales, tables, index, active = cache_arrays
             from ..ops.paged_attention import (
                 kv_qmax,
                 paged_attention,
@@ -590,21 +655,21 @@ class Attention(nn.Module):
 
             if kv_qmax(pages_k.dtype) is not None:
                 pages_k, k_scales, err_k = paged_quantized_insert(
-                    pages_k, k_scales, k, tables, index, active
+                    pages_k, k_scales, k, tables, index, active, layer=layer
                 )
                 pages_v, v_scales, err_v = paged_quantized_insert(
-                    pages_v, v_scales, v, tables, index, active
+                    pages_v, v_scales, v, tables, index, active, layer=layer
                 )
                 err = jnp.maximum(err_k, err_v)
-                sk, sv = k_scales, v_scales
+                sk, sv = at(k_scales), at(v_scales)
             else:
-                pages_k = paged_insert(pages_k, k, tables, index, active)
-                pages_v = paged_insert(pages_v, v, tables, index, active)
+                pages_k = paged_insert(pages_k, k, tables, index, active, layer=layer)
+                pages_v = paged_insert(pages_v, v, tables, index, active, layer=layer)
                 err = jnp.float32(0.0)
                 sk = sv = None
             if cfg.paged_kernel == "pallas":
                 out = paged_attention(
-                    q, pages_k, pages_v, tables, index,
+                    q, at(pages_k), at(pages_v), tables, index,
                     k_scales=sk, v_scales=sv, tree_mask=tree_mask,
                 )
             elif cfg.paged_kernel == "flash_prefill":
@@ -614,43 +679,37 @@ class Attention(nn.Module):
                         "paged_kernel='flash_prefill' cannot carry a tree_mask"
                     )
                 out = paged_flash_prefill(
-                    q, pages_k, pages_v, tables, index,
+                    q, at(pages_k), at(pages_v), tables, index,
                     k_scales=sk, v_scales=sv,
                 )
             else:
                 out = paged_attention_reference(
-                    q, pages_k, pages_v, tables, index,
+                    q, at(pages_k), at(pages_v), tables, index,
                     k_scales=sk, v_scales=sv, window=cfg.sliding_window,
                     alibi=cfg.positional == "alibi", tree_mask=tree_mask,
                 )
             out = out.reshape(b, s, cfg.num_heads * hd)
-            return dense("o_proj", cfg.hidden_size)(out), (
-                pages_k, pages_v, k_scales, v_scales, err,
-            )
+            out = dense("o_proj", cfg.hidden_size)(out)
+            if stacked:
+                return out, cache.replace(
+                    pages_k=pages_k, pages_v=pages_v,
+                    k_scales=k_scales, v_scales=v_scales,
+                    quant_err=jnp.maximum(cache.quant_err, err),
+                )
+            return out, (pages_k, pages_v, k_scales, v_scales, err)
         if cache is not None:
-            k_cache, v_cache, index = cache
-            if jnp.ndim(index) == 0:
-                k_cache = jax.lax.dynamic_update_slice(
-                    k_cache, k.astype(k_cache.dtype), (0, index, 0, 0)
-                )
-                v_cache = jax.lax.dynamic_update_slice(
-                    v_cache, v.astype(v_cache.dtype), (0, index, 0, 0)
-                )
-            else:
-                # per-lane index [B] (serving slot pool): every lane writes at
-                # its own position — vmap the slice update over the batch (XLA
-                # lowers it to a scatter; still a single executable)
-                def _write(c, u, i):
-                    return jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
-
-                k_cache = jax.vmap(_write)(k_cache, k.astype(k_cache.dtype), index)
-                v_cache = jax.vmap(_write)(v_cache, v.astype(v_cache.dtype), index)
-            out = cached_attention(q, k_cache, v_cache, positions,
+            k_cache, v_cache, index = cache_arrays
+            k_cache = _write_rows(k_cache, k, index, layer)
+            v_cache = _write_rows(v_cache, v, index, layer)
+            out = cached_attention(q, at(k_cache), at(v_cache), positions,
                                    window=cfg.sliding_window,
                                    alibi=cfg.positional == "alibi",
                                    tree_mask=tree_mask)
             out = out.reshape(b, s, cfg.num_heads * hd)
-            return dense("o_proj", cfg.hidden_size)(out), (k_cache, v_cache)
+            out = dense("o_proj", cfg.hidden_size)(out)
+            if stacked:
+                return out, cache.replace(k=k_cache, v=v_cache)
+            return out, (k_cache, v_cache)
         if tree_mask is not None:
             raise ValueError("tree_mask requires a KV cache (verify window)")
         bias = None
@@ -739,11 +798,11 @@ class DecoderLayer(nn.Module):
     config: TransformerConfig
 
     @nn.compact
-    def __call__(self, x, positions, cache=None, tree_mask=None):
+    def __call__(self, x, positions, cache=None, tree_mask=None, layer=None):
         cfg = self.config
         normed = make_norm(cfg, "input_norm")(x)
         attn_out = Attention(cfg, name="attn")(
-            normed, positions, cache=cache, tree_mask=tree_mask
+            normed, positions, cache=cache, tree_mask=tree_mask, layer=layer
         )
         new_kv = None
         if cache is not None:
@@ -769,9 +828,10 @@ class Transformer(nn.Module):
     """Decoder-only LM.  ``__call__(input_ids [B,S]) -> logits [B,S,V]``.
 
     With ``cache=``\\ :class:`KVCache` the call is an incremental forward:
-    positions default to ``cache.index + arange(S)``, each layer reads/writes
-    its cache slice, and the result is ``(logits, new_cache)`` — the substrate
-    for :mod:`accelerate_tpu.models.generation`.
+    positions default to ``cache.index + arange(S)``, each layer writes its
+    new rows into the stacked cache in place and reads its slice of it, and
+    the result is ``(logits, new_cache)`` — the substrate for
+    :mod:`accelerate_tpu.models.generation`.
     """
 
     config: TransformerConfig
@@ -866,47 +926,20 @@ class Transformer(nn.Module):
             layer_cls = DecoderLayer
             if cfg.remat and cache is None:
                 layer_cls = nn.remat(DecoderLayer, prevent_cse=False, policy=_remat_policy(cfg))
-            new_ks, new_vs, new_sks, new_svs, errs = [], [], [], [], []
-            paged = isinstance(cache, PagedKVCache)
+            # the cache goes through the layers whole: layer i writes its new
+            # rows into the stacked arrays at [i] and reads the slice [i]
+            new_cache = cache
             for i in range(cfg.num_layers):
                 if cache is None:
                     x = layer_cls(cfg, name=f"layers_{i}")(x, positions)
-                elif paged:
-                    x, (pk_i, pv_i, sk_i, sv_i, err_i) = layer_cls(
-                        cfg, name=f"layers_{i}"
-                    )(
-                        x, positions,
-                        cache=(cache.pages_k[i], cache.pages_v[i],
-                               cache.k_scales[i], cache.v_scales[i],
-                               cache.tables, cache.index, cache.active),
-                        tree_mask=tree_mask,
-                    )
-                    new_ks.append(pk_i)
-                    new_vs.append(pv_i)
-                    new_sks.append(sk_i)
-                    new_svs.append(sv_i)
-                    errs.append(err_i)
                 else:
-                    x, (k_i, v_i) = layer_cls(cfg, name=f"layers_{i}")(
-                        x, positions, cache=(cache.k[i], cache.v[i], cache.index),
-                        tree_mask=tree_mask,
+                    x, new_cache = layer_cls(cfg, name=f"layers_{i}")(
+                        x, positions, cache=new_cache, tree_mask=tree_mask,
+                        layer=i,
                     )
-                    new_ks.append(k_i)
-                    new_vs.append(v_i)
-            if paged:
-                new_cache = cache.replace(
-                    pages_k=jnp.stack(new_ks),
-                    pages_v=jnp.stack(new_vs),
-                    k_scales=jnp.stack(new_sks),
-                    v_scales=jnp.stack(new_svs),
-                    index=cache.index + input_ids.shape[1],
-                    quant_err=jnp.maximum(cache.quant_err, jnp.max(jnp.stack(errs))),
-                )
-            elif cache is not None:
-                new_cache = cache.replace(
-                    k=jnp.stack(new_ks),
-                    v=jnp.stack(new_vs),
-                    index=cache.index + input_ids.shape[1],
+            if cache is not None:
+                new_cache = new_cache.replace(
+                    index=cache.index + input_ids.shape[1]
                 )
 
         x = make_norm(cfg, "final_norm")(x)

@@ -38,7 +38,10 @@ HBM even when the lane holds three tokens.  This module removes the gather:
   garbage, which must not inflate the scale), and the page requantized against
   its own fresh amax.  When the page's amax is unchanged the old entries
   round-trip exactly (they are integer multiples of the unchanged scale), so
-  repeated touches do not accumulate drift.
+  repeated touches do not accumulate drift.  Both take one layer's pool, or
+  the whole stack ``[L, num_pages, Hkv, page, D]`` with a static ``layer``:
+  the model's unrolled forward carries the stack through its layers and each
+  insert writes at ``[layer, ...]`` in place.
 """
 
 from __future__ import annotations
@@ -140,28 +143,35 @@ def _live_pages(lengths: jax.Array, s: int, page: int) -> jax.Array:
 
 
 # ------------------------------------------------------------------- writes
-def paged_insert(pages, new, tables, index, active):
+def paged_insert(pages, new, tables, index, active, layer=None):
     """Scatter ``new [N, S, H, D]`` into ``pages [NP, H, page, D]`` at
     positions ``index[n] .. index[n] + S - 1`` through lane ``n``'s block
     table.  Inactive lanes are rerouted to the null page — a lane mid-prefill
     has real (possibly shared) pages mapped and a stale index that must never
     trample them.  Values are cast to the page dtype exactly as the slab pool
-    casts into its cache, so native-dtype storage stays bitwise identical."""
+    casts into its cache, so native-dtype storage stays bitwise identical.
+
+    With a static ``layer`` the pool is the whole stack ``[L, NP, H, page,
+    D]`` and the same ``N * S`` rows are written at ``[layer, ...]``: the
+    model's unrolled forward carries the stack through its layers and no
+    layer's pool is sliced out or stacked back (a slice -> insert -> stack
+    round trip compiles to copies of the whole pool on every forward)."""
     n, s, h, d = new.shape
-    page = pages.shape[2]
+    lead = () if layer is None else (layer,)
+    page = pages.shape[-2]
     p_max = tables.shape[1] - 1
     pos = index[:, None] + jnp.arange(s)[None, :]                    # [N, S]
     pid = jnp.take_along_axis(tables, jnp.clip(pos // page, 0, p_max), axis=1)
     pid = jnp.where(active[:, None], pid, NULL_PAGE)
     off = pos % page
     # advanced indices split by a slice: the indexed rows lead, [N*S, H, D]
-    return pages.at[pid.reshape(-1), :, off.reshape(-1)].set(
+    return pages.at[(*lead, pid.reshape(-1), slice(None), off.reshape(-1))].set(
         new.astype(pages.dtype).reshape(n * s, h, d)
     )
 
 
 def paged_quantized_insert(pages, scales, new, tables, index, active,
-                           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                           layer=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Quantized scatter: requantize every page the ``S`` new positions touch.
 
     ``pages [NP, H, page, D]`` (int8 / fp8-e4m3), ``scales [NP, H]`` f32 with
@@ -176,12 +186,17 @@ def paged_quantized_insert(pages, scales, new, tables, index, active,
     amax), recompute the per-head scale from the page's own amax, requantize.
     Writes for inactive lanes (and slots past each lane's touched span) are
     rerouted to the null page.
+
+    With a static ``layer``, ``pages`` and ``scales`` are the whole stacks
+    ``[L, NP, H, page, D]`` / ``[L, NP, H]``: the touched pages are read from
+    and written back to ``[layer, ...]`` in place (see :func:`paged_insert`).
     """
     qmax = kv_qmax(pages.dtype)
     if qmax is None:
         raise ValueError(f"pages dtype {pages.dtype} is not a quantized KV format")
     n, s, h, d = new.shape
-    page = pages.shape[2]
+    lead = () if layer is None else (layer,)
+    page = pages.shape[-2]
     p_max = tables.shape[1] - 1
     t = (s + page - 2) // page + 1              # max pages a span of S can touch
     p0 = index // page
@@ -191,7 +206,8 @@ def paged_quantized_insert(pages, scales, new, tables, index, active,
     pid = jnp.take_along_axis(tables, jnp.clip(pt, 0, p_max), axis=1)
     pid = jnp.where(touched, pid, NULL_PAGE)                         # [N, T]
 
-    old = pages[pid].astype(jnp.float32) * scales[pid][..., None, None]
+    old = (pages[(*lead, pid)].astype(jnp.float32)
+           * scales[(*lead, pid)][..., None, None])
     g = pt[:, :, None] * page + jnp.arange(page)[None, None, :]      # [N, T, page]
     i_new = g - index[:, None, None]
     use_new = ((i_new >= 0) & (i_new < s))[:, :, None, :, None]
@@ -216,8 +232,8 @@ def paged_quantized_insert(pages, scales, new, tables, index, active,
         )
     )
     flat = pid.reshape(-1)
-    pages = pages.at[flat].set(q.reshape(n * t, h, page, d))
-    scales = scales.at[flat].set(new_scales.reshape(n * t, h))
+    pages = pages.at[(*lead, flat)].set(q.reshape(n * t, h, page, d))
+    scales = scales.at[(*lead, flat)].set(new_scales.reshape(n * t, h))
     return pages, scales, err
 
 

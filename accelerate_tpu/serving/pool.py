@@ -668,16 +668,23 @@ def make_copy_chunk(chunk_len: int,
 
 # --------------------------------------------------------------------- paged
 # Block-table variants (ServingEngine(paged=True), :mod:`.paging`): KV lives
-# in a shared page pool ``[L, num_pages, Hkv, page, Dh]`` and each executable
-# gathers a lane's pages into a contiguous view, runs the *same* traced
-# decode/verify/prefill body as the slab path, then scatters only the
-# newly-written positions back.  The view width equals the slab width
+# in a shared page pool ``[L, num_pages, Hkv, page, Dh]``.  Each executable has
+# two arms.  The gathered arm (``direct=False``) gathers every lane's pages
+# into a contiguous view of the slab's width once a call, runs the *same*
+# traced decode/verify/prefill body as the slab path on it, then scatters only
+# the newly-written positions back.  The view width equals the slab width
 # (``pages_per_lane * page == max_len``), so the attention program — and with
-# it every greedy argmax — is bitwise identical to the legacy pool.  The
-# transient gathered view costs one slab-sized temporary per call; removing it
-# is exactly the ROADMAP's "Pallas paged decode kernel" item, which reads
-# pages in place.  Compiled-shape budget: one paged executable per legacy
-# shape plus ONE ``copy_page`` (copy-on-write), still bounded by bucket count.
+# it every greedy argmax — is bitwise identical to the legacy pool.  What the
+# view costs is once a CALL: the gather, the write-back and their layout
+# copies, and a view-sized temporary for K and for V.  Inside the call the
+# model writes the view in place — each layer its new rows, each scan step of
+# a decode window 2 x L small scatters into the carried view
+# (:class:`~accelerate_tpu.models.transformer.KVCache`); nothing of the view's
+# size is copied per step.  The in-place arm (``direct=True``) hands the model
+# the pool itself (:class:`~accelerate_tpu.models.transformer.PagedKVCache`):
+# no view, the same in-place write through the block tables, pages read where
+# they lie.  Compiled-shape budget: one paged executable per legacy shape plus
+# ONE ``copy_page`` (copy-on-write), still bounded by bucket count.
 
 
 def _gather_view(pages, tables):

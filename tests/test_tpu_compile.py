@@ -9,11 +9,23 @@ and 12x64 heads, page 16, a 128-wide prefill chunk) with ``interpret=False``
 and require ``tpu_custom_call`` in the compiled program.  Nothing runs; a
 compile that passes is not a chip run.
 
+The same facility holds the serving engine's paged programs to what the
+incremental forward promises: the stacked KV cache goes through the layers
+whole and each layer writes its new rows in place.  A slice -> update -> stack
+round trip per layer compiles (it did, until PR 26) to four passes over the
+whole cache on every decode step, which no CPU test can see: the values are
+the same.  So the compiled decode window and prefill chunk are read here, at
+gpt2-xl's widths and a few layers, and may hold nothing of the whole cache's
+shape under the model's name but the in-place write itself.
+
 The topology is described inside a module-scoped fixture (never at import:
 only one process may load the TPU library, and every xdist worker imports
 every test file), with the persistent compile cache off around it — such a
 compile can be written to the cache but not read back without a chip.
 """
+
+import re
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -122,3 +134,150 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, batch, seq, heads, d):
     text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
     # forward, dq and dk/dv are three kernels
     assert text.count("tpu_custom_call") >= 3
+
+
+# ------------------------------------------------- the cache write, compiled
+# gpt2-xl's widths (25 heads of 64, 1600 wide) at the serve cell's pool: 4
+# lanes of 8 pages of 128 and the null page, window 4
+XL = dict(vocab_size=50257, hidden_size=1600, intermediate_size=6400, num_layers=2,
+          num_heads=25, num_kv_heads=25, max_seq_len=1024, norm_type="layernorm",
+          use_bias=True, positional="learned", mlp_variant="gelu",
+          tie_word_embeddings=True, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+XL_LANES, XL_PAGES_PER_LANE, XL_PAGE, XL_WINDOW, XL_CHUNK = 4, 8, 128, 4, 128
+#: opcodes that hand a buffer on without writing it
+_PASS_THROUGH = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+#: the in-place writes: what ``_write_rows`` / ``paged_insert`` lower to
+_WRITES = {"scatter", "dynamic-update-slice"}
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->\s+.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?[\w.\-]+\s+=\s+(\w+\[[\d,]*\])\S*\s+([\w\-]+)\(")
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+
+
+class _Ins(NamedTuple):
+    """One HLO instruction: ``shape`` and ``opcode`` are None where the result
+    is a tuple (a while, a call), which still has computations it ``called``."""
+    is_root: bool
+    shape: Optional[str]
+    opcode: Optional[str]
+    op_name: str
+    called: list
+    line: str
+
+
+def _parse_hlo(text):
+    """``{computation: [_Ins]}`` and the entry computation's name."""
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+            if line.startswith("ENTRY"):
+                entry = head.group(1)
+            continue
+        ins, called = _INSTRUCTION.match(line), _CALLED.findall(line)
+        if cur is None or not (ins or called):
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        is_root, shape, opcode = ins.groups() if ins else (None, None, None)
+        cur.append(_Ins(bool(is_root), shape, opcode,
+                        op_name.group(1) if op_name else "", called, line[:300]))
+    return comps, entry
+
+
+def _reachable(comps, roots):
+    seen, todo = [], list(roots)
+    while todo:
+        name = todo.pop()
+        if name not in seen and name in comps:
+            seen.append(name)
+            todo.extend(c for ins in comps[name] for c in ins.called)
+    return seen
+
+
+def _check_cache_plumbing(text, whole_shape, n_writes, scope):
+    """No ``Transformer/squeeze`` or ``Transformer/concatenate`` (a layer cut
+    out of the stack, the stack put together again) in ``scope`` and what it
+    calls, and nothing in ``scope`` that outputs an array of the whole cache's
+    shape except the write: a scatter or dynamic-update-slice, alone or as the
+    root of a fusion.  ``scope`` is "while": the decode scan's body; or
+    "model": what the model's forward gave its name to in the entry
+    computation (the gather and the write-back round it are ``pool.py``'s)."""
+    comps, entry = _parse_hlo(text)
+    if scope == "while":
+        top = [body for body in set(re.findall(r"body=%?([\w.\-]+)", text))
+               if any(ins.shape == whole_shape for ins in comps[body])]
+        assert top, "no while body carries the cache"
+    else:
+        top = [entry]
+    writes = 0
+    for comp in _reachable(comps, top):
+        for ins in comps[comp]:
+            assert not ins.op_name.endswith(
+                ("Transformer/squeeze", "Transformer/concatenate")), ins.line
+            if (comp not in top or ins.shape != whole_shape
+                    or ins.opcode in _PASS_THROUGH
+                    or (scope == "model" and "/Transformer/" not in ins.op_name)):
+                continue
+            if ins.opcode == "fusion":
+                root = next(i for i in comps[ins.called[0]] if i.is_root)
+                assert root.opcode in _WRITES, ins.line
+            else:
+                assert ins.opcode in _WRITES, ins.line
+            writes += 1
+    # K and V, once a layer (the scan body is compiled once, run ``window`` times)
+    assert writes == n_writes, f"{writes} whole-cache writes, expected {n_writes}"
+
+
+def _xl_programs(one_chip, program, direct):
+    from accelerate_tpu.models.transformer import Transformer, TransformerConfig
+    from accelerate_tpu.serving import pool
+
+    model = Transformer(TransformerConfig(**XL))
+    L, n, p = XL["num_layers"], XL_LANES, XL_PAGES_PER_LANE
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
+    )
+    num_pages = n * p + 1
+    pages = spec((L, num_pages, 25, XL_PAGE, 64), jnp.bfloat16)
+    scales = (spec((L, num_pages, 25), jnp.float32),) * 2 if direct else ()
+    pool_shape = f"bf16[{L},{num_pages},25,{XL_PAGE},64]"
+    if program == "decode":
+        fn = pool.make_paged_decode_window(model, XL_WINDOW, direct=direct)
+        i32, f32 = (lambda *s: spec(s, jnp.int32)), (lambda *s: spec(s, jnp.float32))
+        flag = lambda *s: spec(s, jnp.bool_)
+        args = (params, pages, pages, *scales, i32(n, p), i32(n),
+                i32(n), flag(n), i32(n), flag(n), f32(n), i32(n), f32(n), i32(n),
+                spec((n, 2), jnp.uint32))
+        view_shape = f"bf16[{L},{n},{p * XL_PAGE},25,64]"
+    else:
+        fn = pool.make_paged_prefill_chunk(model, XL_CHUNK, XL_PAGE, direct=direct)
+        args = (params, spec((1, XL_CHUNK), jnp.int32), pages, pages, *scales,
+                spec((p,), jnp.int32), spec((), jnp.int32))
+        view_shape = f"bf16[{L},1,{p * XL_PAGE},25,64]"
+    return fn, args, pool_shape if direct else view_shape
+
+
+@pytest.mark.parametrize(
+    "program,direct",
+    [("decode", False), ("decode", True), ("prefill", False), ("prefill", True)],
+    ids=["decode-gathered-lane_index", "decode-direct-lane_index",
+         "prefill-gathered-scalar_index", "prefill-direct-lane_index"],
+)
+def test_cache_is_written_in_place(one_chip, program, direct):
+    """The gathered arm writes the slab view ``[L, N, M, H, D]`` (per-lane
+    index in the decode scan, the scalar chunk base in prefill), the direct arm
+    the page pool ``[L, NP, H, page, D]`` through the block tables (always per
+    lane), both with the XLA read."""
+    fn, args, whole_shape = _xl_programs(one_chip, program, direct)
+    text = fn.lower(*args).compile().as_text()
+    _check_cache_plumbing(
+        text, whole_shape, n_writes=2 * XL["num_layers"],
+        scope="while" if program == "decode" else "model",
+    )
