@@ -88,6 +88,81 @@ def _tag_proj(x, name: str = "proj_out"):
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (Peng et al. 2023) as DeepSeek's ``rope_scaling`` block
+    gives it: frequencies above ``beta_fast`` turns over the original context
+    stay, those below ``beta_slow`` are divided by ``factor``, a linear ramp
+    between; ``mscale_all_dim`` enters the softmax scale squared."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttentionSpec:
+    """Multi-head latent attention (DeepSeek-V2): queries through a low-rank
+    ``q_rank`` bottleneck, keys and values decompressed from one shared latent
+    ``c_kv`` of ``kv_rank`` plus one rope key of ``rope_dim`` a token — which
+    is all the cache holds (``models/latent_attention.py``)."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    yarn: Optional[YarnScaling] = None
+
+    def __post_init__(self):
+        if isinstance(self.yarn, dict):
+            object.__setattr__(self, "yarn", YarnScaling(**self.yarn))
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertSpec:
+    """Dropless routed experts with a shared expert (``parallel/moe.py``
+    :class:`~accelerate_tpu.parallel.moe.RoutedExperts`).  The router scores
+    all ``num_routed`` experts and picks ``top_k`` among them (from the best
+    ``topk_group`` of ``n_group`` groups); this device holds experts ``held =
+    [lo, hi)`` and computes their part of the result.  Gates are ``scaling *
+    score``, renormalised over the chosen instead where ``norm_topk``.  The
+    first ``dense_layers`` layers keep a dense MLP of ``dense_width``
+    (``intermediate_size`` when None)."""
+
+    num_routed: int
+    top_k: int
+    width: int
+    held: Optional[Tuple[int, int]] = None
+    n_group: int = 1
+    topk_group: int = 1
+    scaling: float = 1.0
+    norm_topk: bool = False
+    shared_width: int = 0
+    dense_layers: int = 0
+    dense_width: Optional[int] = None
+
+    def __post_init__(self):
+        held = (0, self.num_routed) if self.held is None else tuple(int(e) for e in self.held)
+        object.__setattr__(self, "held", held)
+        if not 0 <= held[0] < held[1] <= self.num_routed:
+            raise ValueError(f"held experts {held} must lie within [0, {self.num_routed})")
+        if self.num_routed % self.n_group or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(
+                f"n_group {self.n_group} must divide num_routed {self.num_routed} "
+                f"and topk_group {self.topk_group} lie within it"
+            )
+        if self.top_k > self.topk_group * (self.num_routed // self.n_group):
+            raise ValueError(f"top_k {self.top_k} exceeds the experts of {self.topk_group} groups")
+
+    @property
+    def num_held(self) -> int:
+        return self.held[1] - self.held[0]
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -178,6 +253,12 @@ class TransformerConfig:
     num_experts_per_tok: int = 2
     expert_capacity_factor: float = 2.0
     router_aux_loss_coef: float = 0.01
+    # Architectures beyond the Llama/GPT-2 block, as nested specs (a dict from
+    # a config file is accepted): latent attention replaces Attention and the
+    # K/V cache rows; experts replaces the MLP of every layer past its
+    # dense_layers.  Both None: the parameter tree is what it always was.
+    latent_attention: Optional[LatentAttentionSpec] = None
+    experts: Optional[ExpertSpec] = None
     # Attention program for PagedKVCache forwards (the serving engine's
     # in-model paged windows): "xla" is the live-masked-gather reference —
     # bitwise identical to the contiguous slab; "pallas" the in-place paged
@@ -192,6 +273,17 @@ class TransformerConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    @property
+    def cache_row_shapes(self) -> Tuple[Tuple[int, int], Tuple[int, int]]:
+        """``((heads, width), (heads, width))`` of the two arrays a token and
+        layer leave in the cache (``KVCache.k`` / ``.v``, the page pool's
+        ``pages_k`` / ``pages_v``): keys and values of every kv head, or, under
+        latent attention, the one latent ``c_kv`` and the one rope key."""
+        la = self.latent_attention
+        if la is not None:
+            return (1, la.kv_rank), (1, la.rope_dim)
+        return (self.num_kv_heads, self.resolved_head_dim), (self.num_kv_heads, self.resolved_head_dim)
+
     def resolved_expert_capacity(self, n_tokens: int) -> int:
         """Per-expert token buffer: factor * even-split share, rounded up to a
         multiple of 8 (TPU sublane tiling; keeps the dispatch einsum MXU-friendly)."""
@@ -200,6 +292,27 @@ class TransformerConfig:
         return max(8, -(-cap // 8) * 8)
 
     def __post_init__(self):
+        if isinstance(self.latent_attention, dict):
+            object.__setattr__(self, "latent_attention", LatentAttentionSpec(**self.latent_attention))
+        if isinstance(self.experts, dict):
+            object.__setattr__(self, "experts", ExpertSpec(**self.experts))
+        if self.latent_attention is not None and (
+            self.positional != "rope" or self.sliding_window is not None
+            or self.quantization is not None or self.use_fp8 or self.paged_kernel != "xla"
+        ):
+            raise ValueError(
+                "latent_attention is a full-causal rope attention in the model's "
+                "dtype: positional, sliding_window, quantization, use_fp8 and "
+                "paged_kernel must keep their defaults"
+            )
+        if self.experts is not None and (self.num_experts > 0 or self.quantization is not None
+                                         or self.use_fp8):
+            raise ValueError(
+                "experts (the dropless layer) excludes num_experts (the capacity "
+                "dispatch), quantization and use_fp8"
+            )
+        if self.experts is not None and self.experts.dense_layers and self.scan_layers:
+            raise ValueError("scan_layers needs one block repeated; experts.dense_layers mixes two")
         if self.remat_policy not in _REMAT_POLICIES:
             raise ValueError(
                 f"Unknown remat_policy {self.remat_policy!r}; "
@@ -307,17 +420,21 @@ class KVCache(struct.PyTreeNode):
     k: jax.Array            # [L, B, max_len, n_kv_heads, head_dim]
     v: jax.Array            # [L, B, max_len, n_kv_heads, head_dim]
     index: jax.Array        # int32 next write position: scalar, or [B] per lane
+    # The row shapes are the configuration's (``cache_row_shapes``), not
+    # derived from heads: under latent attention ``k`` holds the latent
+    # ``c_kv`` rows ``[.., 1, kv_rank]`` and ``v`` the shared rope key
+    # ``[.., 1, rope_dim]`` — every consumer below reads shapes off the arrays.
 
     @classmethod
     def create(cls, config: "TransformerConfig", batch_size: int, max_len: Optional[int] = None,
                dtype: Any = None, per_lane_index: bool = False) -> "KVCache":
         max_len = max_len if max_len is not None else config.max_seq_len
-        shape = (config.num_layers, batch_size, max_len,
-                 config.num_kv_heads, config.resolved_head_dim)
+        k_row, v_row = config.cache_row_shapes
+        lead = (config.num_layers, batch_size, max_len)
         dtype = dtype if dtype is not None else config.dtype
         return cls(
-            k=jnp.zeros(shape, dtype),
-            v=jnp.zeros(shape, dtype),
+            k=jnp.zeros(lead + k_row, dtype),
+            v=jnp.zeros(lead + v_row, dtype),
             index=jnp.zeros((batch_size,) if per_lane_index else (), jnp.int32),
         )
 
@@ -772,10 +889,12 @@ def functools_partial_dense(cfg: TransformerConfig, use_bias: Optional[bool] = N
 
 class MLP(nn.Module):
     config: TransformerConfig
+    width: Optional[int] = None        # None: ``config.intermediate_size``
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
+        width = self.width or cfg.intermediate_size
         dense = functools_partial_dense(cfg, use_bias=cfg.mlp_bias)
         if cfg.mlp_variant in ("gelu", "gelu_exact", "relu"):
             # GPT-2/GPT-J: gelu_new (tanh approximation, = flax approximate
@@ -785,10 +904,10 @@ class MLP(nn.Module):
                 "gelu": lambda z: nn.gelu(z, approximate=True),
                 "gelu_exact": lambda z: nn.gelu(z, approximate=False),
             }[cfg.mlp_variant]
-            up = _tag_proj(dense("up_proj", cfg.intermediate_size)(x), "proj_wide")
+            up = _tag_proj(dense("up_proj", width)(x), "proj_wide")
             return _tag_proj(dense("down_proj", cfg.hidden_size)(act(up)))
-        gate = _tag_proj(dense("gate_proj", cfg.intermediate_size)(x))
-        up = _tag_proj(dense("up_proj", cfg.intermediate_size)(x), "proj_wide")
+        gate = _tag_proj(dense("gate_proj", width)(x))
+        up = _tag_proj(dense("up_proj", width)(x), "proj_wide")
         # swiglu: silu gate (Llama); geglu: tanh-gelu gate (Gemma)
         gated = nn.gelu(gate, approximate=True) if cfg.mlp_variant == "geglu" else nn.silu(gate)
         return _tag_proj(dense("down_proj", cfg.hidden_size)(gated * up))
@@ -796,18 +915,32 @@ class MLP(nn.Module):
 
 class DecoderLayer(nn.Module):
     config: TransformerConfig
+    # one of ``config.experts.dense_layers`` leading layers: a dense MLP where
+    # the layers after it route (set by :class:`Transformer`'s loop)
+    leading_dense: bool = False
 
     @nn.compact
     def __call__(self, x, positions, cache=None, tree_mask=None, layer=None):
         cfg = self.config
         normed = make_norm(cfg, "input_norm")(x)
-        attn_out = Attention(cfg, name="attn")(
-            normed, positions, cache=cache, tree_mask=tree_mask, layer=layer
-        )
+        if cfg.latent_attention is not None:
+            from .latent_attention import LatentAttention
+
+            attn = LatentAttention(cfg, name="attn")
+        else:
+            attn = Attention(cfg, name="attn")
+        attn_out = attn(normed, positions, cache=cache, tree_mask=tree_mask, layer=layer)
         new_kv = None
         if cache is not None:
             attn_out, new_kv = attn_out
-        if cfg.num_experts > 0:
+        spec = cfg.experts
+        if spec is not None and self.leading_dense:
+            mlp = MLP(cfg, spec.dense_width, name="mlp")
+        elif spec is not None:
+            from ..parallel.moe import RoutedExperts
+
+            mlp = RoutedExperts(cfg, name="moe_mlp")
+        elif cfg.num_experts > 0:
             from ..parallel.moe import MoEMLP
 
             mlp = MoEMLP(cfg, name="moe_mlp")
@@ -930,10 +1063,14 @@ class Transformer(nn.Module):
             # rows into the stacked arrays at [i] and reads the slice [i]
             new_cache = cache
             for i in range(cfg.num_layers):
+                block = layer_cls(
+                    cfg, cfg.experts is not None and i < cfg.experts.dense_layers,
+                    name=f"layers_{i}",
+                )
                 if cache is None:
-                    x = layer_cls(cfg, name=f"layers_{i}")(x, positions)
+                    x = block(x, positions)
                 else:
-                    x, new_cache = layer_cls(cfg, name=f"layers_{i}")(
+                    x, new_cache = block(
                         x, positions, cache=new_cache, tree_mask=tree_mask,
                         layer=i,
                     )

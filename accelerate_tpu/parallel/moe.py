@@ -116,6 +116,125 @@ class MoEMLP(nn.Module):
         return y.reshape(b, s, h).astype(x.dtype)
 
 
+# ------------------------------------------------------------------- dropless
+# The layer the serving engine runs (``TransformerConfig.experts``): no
+# capacity, no dropped token, and a device that holds only ``[lo, hi)`` of the
+# experts.  Token-expert pairs are sorted by expert and the held experts'
+# products are ragged (grouped) matmuls over the sorted rows; pairs that fall on
+# experts held elsewhere sort last and contribute nothing.  The dense dispatch
+# above stays for the configurations trained with it on an ``ep`` mesh
+# (``num_experts``: its einsums are what GSPMD turns into the all-to-all).
+
+
+def route_top_k(scores: jax.Array, spec) -> Tuple[jax.Array, jax.Array]:
+    """``(experts [N, k] int32, gates [N, k] f32)`` from the router's softmax
+    ``scores [N, num_routed]``: group-limited greedy choice (the ``top_k``
+    largest among the experts of the ``topk_group`` groups whose best score is
+    largest; one group: plain top-k), gates ``scaling * score`` or, with
+    ``norm_topk``, renormalised over the chosen."""
+    n, e = scores.shape
+    masked = scores
+    if spec.n_group > 1:
+        best = jnp.max(scores.reshape(n, spec.n_group, e // spec.n_group), axis=-1)
+        _, kept = jax.lax.top_k(best, spec.topk_group)
+        allowed = jnp.any(jax.nn.one_hot(kept, spec.n_group, dtype=bool), axis=1)
+        masked = jnp.where(jnp.repeat(allowed, e // spec.n_group, axis=1), scores, 0.0)
+    gates, experts = jax.lax.top_k(masked, spec.top_k)
+    if spec.norm_topk and spec.top_k > 1:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    else:
+        gates = gates * spec.scaling
+    return experts.astype(jnp.int32), gates
+
+
+class _ExpertProjection(nn.Module):
+    """One projection of every held expert, ``kernel [held, in, out]``, applied
+    to rows sorted by expert: a ragged matmul."""
+
+    shape: Tuple[int, int, int]
+    dtype: Any
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self, rows, group_sizes):
+        kernel = self.param("kernel", nn.initializers.normal(0.02), self.shape, self.param_dtype)
+        return jax.lax.ragged_dot(rows, kernel.astype(self.dtype), group_sizes)
+
+
+class _HeldExperts(nn.Module):
+    config: Any
+
+    @nn.compact
+    def __call__(self, rows, group_sizes):
+        cfg, spec = self.config, self.config.experts
+        proj = lambda name, i, o: _ExpertProjection(
+            (spec.num_held, i, o), cfg.dtype, cfg.param_dtype, name=name)
+        gate = proj("gate_proj", cfg.hidden_size, spec.width)(rows, group_sizes)
+        up = proj("up_proj", cfg.hidden_size, spec.width)(rows, group_sizes)
+        return proj("down_proj", spec.width, cfg.hidden_size)(nn.silu(gate) * up, group_sizes)
+
+
+class RoutedExperts(nn.Module):
+    """Dropless routed experts plus a shared expert: ``y = sum_{e chosen, held
+    here} g_e E_e(x) + S(x)`` (``config.experts``, an
+    :class:`~accelerate_tpu.models.transformer.ExpertSpec`).
+
+    The router (float32, highest precision: a choice is a comparison of
+    nearly equal numbers) scores all ``num_routed`` experts and
+    :func:`route_top_k` chooses among all of them; of the ``N * top_k`` pairs
+    those on ``[lo, hi)`` are computed here, whatever their number: no
+    capacity, so a token's result does not depend on what shares its batch.
+    What experts held elsewhere would add is left out (on one device of an
+    expert-parallel group this is the local partial sum; the exchange that
+    completes it is not part of this layer).
+
+    Sows ``routed_here [B, S, top_k]`` (the chosen expert's index among the
+    held, ``-1`` where it is held elsewhere) into ``"intermediates"`` for the
+    serving programs' counters; nothing is sown unless that collection is
+    mutable."""
+
+    config: Any
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, spec = self.config, self.config.experts
+        b, s, h = x.shape
+        xf = x.reshape(b * s, h)
+        n, k = b * s, spec.top_k
+        lo, hi = spec.held
+        with jax.named_scope("moe/route"):
+            logits = nn.Dense(
+                spec.num_routed, use_bias=False, dtype=jnp.float32, param_dtype=cfg.param_dtype,
+                kernel_init=nn.initializers.normal(0.02), precision=jax.lax.Precision.HIGHEST,
+                name="router",
+            )(xf.astype(jnp.float32))
+            experts, gates = route_top_k(jax.nn.softmax(logits, axis=-1), spec)
+            here = (experts >= lo) & (experts < hi)
+            local = jnp.where(here, experts - lo, spec.num_held)          # elsewhere sorts last
+            self.sow("intermediates", "routed_here",
+                     jnp.where(here, local, -1).reshape(b, s, k))
+            order = jnp.argsort(local.reshape(-1), stable=True)           # pairs by held expert
+            token_of = order // k
+            group_sizes = jnp.sum(
+                jax.nn.one_hot(local.reshape(-1), spec.num_held, dtype=jnp.int32), axis=0)
+        with jax.named_scope("moe/experts"):
+            rows = _HeldExperts(cfg, name="experts")(xf[token_of].astype(cfg.dtype), group_sizes)
+            # rows past the groups (pairs held elsewhere) belong to no expert
+            # and a ragged matmul leaves them unspecified (the TPU's writes
+            # nothing there): select them away, a weight of 0 would keep a NaN
+            in_group = jnp.arange(n * k) < jnp.sum(group_sizes)
+            weight = gates.reshape(-1)[order]
+            routed = jnp.zeros((n, h), jnp.float32).at[token_of].add(
+                jnp.where(in_group[:, None], rows.astype(jnp.float32) * weight[:, None], 0.0))
+        with jax.named_scope("moe/shared"):
+            from ..models.transformer import MLP
+
+            y = routed.astype(x.dtype)
+            if spec.shared_width:
+                y = y + MLP(cfg, spec.shared_width, name="shared")(xf).astype(x.dtype)
+        return y.reshape(b, s, h)
+
+
 def shard_moe_params(params, mesh: Mesh, *, marker: str = "experts"):
     """Shard stacked expert weights over the mesh's ``ep`` axis (leading expert
     dim, composed with ``fsdp`` on the largest remaining dim); non-expert leaves

@@ -141,6 +141,39 @@ class _Stats(dict):
         return out
 
 
+def _refuse_unported(cfg, *, kv_dtype, speculate_k, draft_model, decode_kernel,
+                     prefill_kernel, prefix_host_mb, role, mesh, tp_axis) -> None:
+    """Refuse at construction, by name, each option that a latent cache
+    (``config.latent_attention``) or routed experts (``config.experts``) do
+    not have yet.  Everything else — chunked prefill, the prefix cache,
+    preemption, cancellation, the scheduler — is the same code for them."""
+    asked = {}
+    if cfg.latent_attention is not None:
+        # the latent and the rope key live in the gathered view's two arrays;
+        # everything below reads K and V per kv head from the pool in place,
+        # ships them between hosts, or verifies rows under a tree mask
+        asked.update({
+            "kv_dtype": kv_dtype is not None,
+            "speculate_k": bool(speculate_k),
+            "draft_model": draft_model is not None,
+            "decode_kernel": decode_kernel != "xla",
+            "prefill_kernel": prefill_kernel not in (None, "xla"),
+            "prefix_host_mb": bool(prefix_host_mb),
+            "role": role != "both",
+        })
+    if cfg.latent_attention is not None or cfg.experts is not None:
+        from ..parallel.mesh import mesh_axis_size
+
+        asked["mesh"] = mesh is not None and mesh_axis_size(mesh, tp_axis) > 1
+    for name, used in asked.items():
+        if used:
+            what = "a latent-attention cache" if cfg.latent_attention is not None else "routed experts"
+            raise ValueError(
+                f"{name} is not ported to {what} yet: serve this model with the "
+                f"default {name} (see ROADMAP.md Reach for what is missing)"
+            )
+
+
 class ServingEngine:
     """Serve many requests through one slot pool with in-flight admission.
 
@@ -376,6 +409,13 @@ class ServingEngine:
         self.model = model
         self.params = params
         self.config = cfg
+        _refuse_unported(cfg, kv_dtype=kv_dtype, speculate_k=speculate_k,
+                         draft_model=draft_model, decode_kernel=decode_kernel,
+                         prefill_kernel=prefill_kernel, prefix_host_mb=prefix_host_mb,
+                         role=role, mesh=mesh, tp_axis=tp_axis)
+        #: routed experts: decode windows and prefill chunks return the
+        #: ``moe_*`` counters, fetched with the window's tokens
+        self._routed = cfg.experts is not None
         self.num_slots = int(num_slots)
         self.max_len = int(max_len if max_len is not None else cfg.max_seq_len)
         self.max_prompt_len = int(
@@ -888,6 +928,11 @@ class ServingEngine:
             "deadline_shed": 0,
             "requests_replayed": 0,
         })
+        if self._routed:
+            # token-expert choices made, those that fell on experts held
+            # here, and (decode windows) held experts that got a row, summed
+            # over steps and layers: live lanes and valid prompt rows only
+            self.stats.update(moe_pairs_total=0, moe_pairs_here=0, moe_experts_hit=0)
         self.stats.engine = self
         self._counters = {
             k: self.metrics.counter(f"serve/{k}_total") for k in self.stats
@@ -1016,6 +1061,9 @@ class ServingEngine:
         # attach to the next dispatched window's Readback and are folded into
         # the quant-error gauge at drain (fetching here would sync the pipe)
         self._pending_prefill_qerr: List = []
+        # ``moe_*`` counter handles of this cycle's prefill chunks, attached
+        # and fetched the same way (with the window's tokens, in its one fetch)
+        self._pending_moe_counts: List = []
         # hierarchical prefix cache deferrals, same discipline: spill gathers
         # enqueued at eviction time (``(node, handles)``) land their payloads
         # at the next drain; promotion-install records are acknowledged there.
@@ -1446,6 +1494,7 @@ class ServingEngine:
                 hd.settle(self.kv.allocator)
         self._stale_handles.clear()
         self._pending_prefill_qerr.clear()
+        self._pending_moe_counts.clear()
         try:
             self._settle_spills(self._pending_spills)
         except Exception as exc:
@@ -1636,12 +1685,18 @@ class ServingEngine:
                 if self.paged:
                     self._paged_prefill_chunk(req, bucket, valid, chunk, start)
                 else:
+                    args = (self.params, chunk[None], self.scratch)
+                    if self._routed:
+                        args += (self._put(jnp.int32(valid)),)
                     self.cost_table.capture(
-                        f"serve/prefill_{bucket}", self._prefill[bucket],
-                        (self.params, chunk[None], self.scratch),
+                        f"serve/prefill_{bucket}", self._prefill[bucket], args,
                     )
                     with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid):
-                        self.scratch = self._prefill[bucket](self.params, chunk[None], self.scratch)
+                        out = self._prefill[bucket](*args)
+                    if self._routed:
+                        out, counts = out
+                        self._pending_moe_counts.append(counts)
+                    self.scratch = out
                 budget -= bucket
                 self._bump("prefill_chunks")
                 if self.interleave_prefill and self._cycle_decode_tokens:
@@ -1865,14 +1920,13 @@ class ServingEngine:
                 # gauge when the next window drains
                 self._pending_prefill_qerr.append(qerr)
             return
-        self.cost_table.capture(
-            f"serve/prefill_{bucket}", self._prefill[bucket],
-            (self.params, chunk[None], kv.pages_k, kv.pages_v, table, base),
-        )
+        args = (self.params, chunk[None], kv.pages_k, kv.pages_v, table, base)
+        if self._routed:
+            args += (self._put(jnp.int32(valid)),)
+        self.cost_table.capture(f"serve/prefill_{bucket}", self._prefill[bucket], args)
         with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid):
-            kv.pages_k, kv.pages_v = self._prefill[bucket](
-                self.params, chunk[None], kv.pages_k, kv.pages_v, table, base,
-            )
+            kv.pages_k, kv.pages_v, *counts = self._prefill[bucket](*args)
+        self._pending_moe_counts.extend(counts)
 
     def _reclaim_pages(self, need: int, allow_preempt: bool) -> bool:
         """Recover free pages until at least ``need`` are available.  The
@@ -2382,12 +2436,24 @@ class ServingEngine:
         t0 = time.perf_counter()
         with self.tracer.span("serve/readback", kind=hd.kind,
                               occupied=hd.n_occupied):
-            if hd.kind == "verify":
-                toks, counts = fetch(hd.toks, hd.counts)
-            else:
-                toks = fetch(hd.toks)
-                counts = np.full(self.num_slots, hd.width)
+            # the window's ONE fetch: its tokens, a verify's commit counts,
+            # and the moe counters parked on it (its own and its cycle's
+            # prefill chunks'), which retired no later than it did
+            commits = [hd.counts] if hd.kind == "verify" else []
+            got = fetch(hd.toks, *commits, *hd.moe_counts)
+            got = got if isinstance(got, tuple) else (got,)
+            toks = got[0]
+            counts = got[1] if commits else np.full(self.num_slots, hd.width)
+            moe = got[1 + len(commits):]
         t1 = time.perf_counter()
+        for total, here, hit in moe:
+            self._bump("moe_pairs_total", int(total))
+            self._bump("moe_pairs_here", int(here))
+        if moe and hd.kind == "decode":
+            # experts hit is a decode-step quantity (a chunk of hundreds of
+            # rows hits every expert): the window's own counter, parked first
+            self._bump("moe_experts_hit", int(moe[0][2]))
+        hd.moe_counts = []
         # overlap accounting: host work since dispatch ran under the device;
         # the blocking tail is what the pipeline failed to hide.  Under
         # async_depth=0 the drain follows dispatch immediately, so host ~ 0
@@ -2509,7 +2575,7 @@ class ServingEngine:
             with self.tracer.span("serve/decode_window", occupied=n_occupied):
                 with self.tracer.span("serve/paged_attn", kernel=self.decode_kernel):
                     (kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales, toks,
-                     pending, rngs, qerr) = self._decode(*args)
+                     pending, rngs, qerr, *moe) = self._decode(*args)
             self._lane_len[self._active] += self.window
         elif self.paged:
             kv = self.kv
@@ -2526,7 +2592,7 @@ class ServingEngine:
                     (self.params, kv.pages_k, kv.pages_v, tables, index, *lanes),
                 )
             with self.tracer.span("serve/decode_window", occupied=n_occupied):
-                kv.pages_k, kv.pages_v, toks, pending, rngs = self._decode(
+                kv.pages_k, kv.pages_v, toks, pending, rngs, *moe = self._decode(
                     self.params, kv.pages_k, kv.pages_v, tables, index, *lanes
                 )
             self._lane_len[self._active] += self.window
@@ -2538,7 +2604,7 @@ class ServingEngine:
                     "serve/decode_window", self._decode, (self.params, self.pool, *lanes)
                 )
             with self.tracer.span("serve/decode_window", occupied=n_occupied):
-                self.pool, toks, pending, rngs = self._decode(
+                self.pool, toks, pending, rngs, *moe = self._decode(
                     self.params, self.pool, *lanes
                 )
         # the carried pending token / rng live on into the next cycle without
@@ -2550,6 +2616,7 @@ class ServingEngine:
         self._stale_handles = []
         return Readback(
             kind="decode", toks=toks, width=self.window, qerr=qerr,
+            moe_counts=moe,
             active=self._active.copy(), reqs=list(self._slot_req),
             eos=self._eos.copy(), n_occupied=n_occupied, consumed=consumed,
         )
@@ -2943,6 +3010,9 @@ class ServingEngine:
             if tgt is not None:
                 tgt.prefill_qerrs.extend(self._pending_prefill_qerr)
                 self._pending_prefill_qerr.clear()
+        if self._pending_moe_counts and tgt is not None:
+            tgt.moe_counts.extend(self._pending_moe_counts)
+            self._pending_moe_counts.clear()
         if self._pending_spills or self._pending_promotions:
             # same discipline for hierarchical-cache traffic: spill payloads
             # land, and promotions are acknowledged, at the drain of a window

@@ -181,10 +181,13 @@ class PagedKVPool:
                 f"tp={self.tp_degree} to shard the page pool on the head axis"
             )
         # kv-head axis OUTSIDE the page: a (page, Dh) tile per (page, head)
-        # is the block shape the TPU kernels in ops/paged_attention.py need
-        shape = (cfg.num_layers, self.num_pages, cfg.num_kv_heads,
-                 self.page_size, cfg.resolved_head_dim)
-        scale_shape = (cfg.num_layers, self.num_pages, cfg.num_kv_heads)
+        # is the block shape the TPU kernels in ops/paged_attention.py need.
+        # The row shapes are the configuration's: K and V of every kv head,
+        # or a latent-attention model's one latent and one rope key a token.
+        (k_heads, k_width), (v_heads, v_width) = cfg.cache_row_shapes
+        lead = (cfg.num_layers, self.num_pages)
+        k_shape = lead + (k_heads, self.page_size, k_width)
+        v_shape = lead + (v_heads, self.page_size, v_width)
         if mesh is not None:
             # head-axis NamedSharding: each device holds Hkv/tp heads of every
             # page.  Block tables / refcounts stay host-side and whole.
@@ -195,24 +198,24 @@ class PagedKVPool:
             sc_sh = NamedSharding(mesh, PartitionSpec(None, None, ax))
             # allocated in place on the mesh's own devices: a replica on chip
             # i must never stage its pool through the default device
-            self.pages_k = jnp.zeros(shape, self.storage_dtype, device=kv_sh)
-            self.pages_v = jnp.zeros(shape, self.storage_dtype, device=kv_sh)
-            self.k_scales = jnp.ones(scale_shape, jnp.float32, device=sc_sh)
-            self.v_scales = jnp.ones(scale_shape, jnp.float32, device=sc_sh)
+            self.pages_k = jnp.zeros(k_shape, self.storage_dtype, device=kv_sh)
+            self.pages_v = jnp.zeros(v_shape, self.storage_dtype, device=kv_sh)
+            self.k_scales = jnp.ones(lead + (k_heads,), jnp.float32, device=sc_sh)
+            self.v_scales = jnp.ones(lead + (v_heads,), jnp.float32, device=sc_sh)
         else:
-            self.pages_k = jnp.zeros(shape, self.storage_dtype)
-            self.pages_v = jnp.zeros(shape, self.storage_dtype)
+            self.pages_k = jnp.zeros(k_shape, self.storage_dtype)
+            self.pages_v = jnp.zeros(v_shape, self.storage_dtype)
             # per-(layer, page, kv-head) dequantization scales; ones (a no-op
             # multiply the direct-store windows never read) when not quantized
-            self.k_scales = jnp.ones(scale_shape, jnp.float32)
-            self.v_scales = jnp.ones(scale_shape, jnp.float32)
+            self.k_scales = jnp.ones(lead + (k_heads,), jnp.float32)
+            self.v_scales = jnp.ones(lead + (v_heads,), jnp.float32)
         #: bytes of k+v one page holds, scales included — the sharing/HBM
         #: accounting unit
         itemsize = jnp.zeros((), self.storage_dtype).itemsize
-        self.page_kv_bytes = 2 * int(
-            np.prod(shape[2:]) * cfg.num_layers * itemsize
-            + cfg.num_layers * cfg.num_kv_heads * 4
-        )
+        self.page_kv_bytes = int(cfg.num_layers * (
+            (k_heads * k_width + v_heads * v_width) * self.page_size * itemsize
+            + (k_heads + v_heads) * 4
+        ))
         self.allocator = PageAllocator(self.num_pages)
         # host block tables: row s maps lane s's logical page slots to
         # physical ids; NULL_PAGE marks unmapped (garbage-sink) entries

@@ -135,14 +135,52 @@ def audit_donation(*trees) -> None:
                 )
 
 
+def _routed(model: Transformer) -> bool:
+    """Does the model have routed experts (``config.experts``)?  Their decode
+    windows and prefill chunks return the ``moe_*`` counters."""
+    return getattr(model.config, "experts", None) is not None
+
+
+def _forward(model: Transformer, params, tokens, cache, live):
+    """``model.apply`` on a cache: ``(logits, cache, counts)``.  For a model
+    with routed experts ``counts`` is ``int32 [3]``, computed here on the
+    device from what each expert layer sows (``routed_here``) over the rows
+    where ``live [B, S]`` holds (a frozen lane's and a chunk's padding rows run
+    through the static shapes but are no work): token-expert pairs chosen,
+    those that fell on experts held here, and held experts that got at least
+    one row, summed over the layers.  ``None`` for every other model, whose
+    program is what it was."""
+    if not _routed(model):
+        logits, cache = model.apply({"params": params}, tokens, cache=cache)
+        return logits, cache, None
+    (logits, cache), sown = model.apply(
+        {"params": params}, tokens, cache=cache, mutable=["intermediates"]
+    )
+    spec = model.config.experts
+    total = here = hit = jnp.int32(0)
+    for path, local in jax.tree_util.tree_leaves_with_path(sown["intermediates"]):
+        if not any(getattr(k, "key", None) == "routed_here" for k in path):
+            continue
+        held = (local >= 0) & live[..., None]                      # [B, S, k]
+        total += jnp.sum(live) * spec.top_k
+        here += jnp.sum(held)
+        got = jnp.zeros((spec.num_held + 1,), bool).at[
+            jnp.where(held, local, spec.num_held)].set(True)
+        hit += jnp.sum(got[:-1])
+    return logits, cache, jnp.stack([total, here, hit]).astype(jnp.int32)
+
+
 def _decode_scan(model: Transformer, window: int, params, cache, tokens, active,
                  eos, do_sample, temperature, top_k, top_p, pad, rngs):
     """The masked decode scan shared by the slab and paged decode windows —
     one traced program, so the paged path cannot drift from the legacy
-    numerics.  Returns ``(cache, out_tokens [N, window], pending, rngs)``."""
+    numerics.  Returns ``(cache, out_tokens [N, window], pending, rngs,
+    counts)``; ``counts`` is the window's ``moe_*`` counters (:func:`_forward`)
+    or ``None``."""
+    counted = _routed(model)
 
     def step(carry, _):
-        cache, tok, done, rngs = carry
+        cache, tok, done, rngs, counts = carry
         prev_index = cache.index
         if isinstance(cache, PagedKVCache):
             # direct paged cache: route frozen lanes' writes to the null page
@@ -151,7 +189,9 @@ def _decode_scan(model: Transformer, window: int, params, cache, tokens, active,
             # write REQUANTIZES the whole touched page — pad-token garbage
             # must not keep churning a page that still holds real history.
             cache = cache.replace(active=~done)
-        logits, cache = model.apply({"params": params}, tok[:, None], cache=cache)
+        logits, cache, seen = _forward(model, params, tok[:, None], cache, ~done[:, None])
+        if counted:
+            counts = counts + seen
         # model.apply advanced every lane; frozen lanes roll back
         cache = cache.replace(
             index=jnp.where(done, prev_index, prev_index + 1)
@@ -164,13 +204,28 @@ def _decode_scan(model: Transformer, window: int, params, cache, tokens, active,
         )
         nxt = jnp.where(done, pad, nxt)
         done = done | ((eos >= 0) & (nxt == eos))
-        return (cache, nxt, done, split[:, 1]), nxt
+        return (cache, nxt, done, split[:, 1], counts), nxt
 
     done0 = ~active
-    (cache, tok, _, rngs), toks = jax.lax.scan(
-        step, (cache, tokens, done0, rngs), None, length=window
+    counts0 = jnp.zeros((3,), jnp.int32) if counted else None
+    (cache, tok, _, rngs, counts), toks = jax.lax.scan(
+        step, (cache, tokens, done0, rngs, counts0), None, length=window
     )
-    return cache, toks.T, tok, rngs
+    return cache, toks.T, tok, rngs, counts
+
+
+def _with_counts(*outputs):
+    """A program's outputs with a trailing ``None`` (no ``moe_*`` counters)
+    dropped: models without routed experts keep their signatures."""
+    return outputs if outputs[-1] is not None else outputs[:-1]
+
+
+def _unrouted(model: Transformer, shardings):
+    """``shardings`` for a program whose output tuple is fixed: a model with
+    routed experts appends its counters, and has no placement rules yet."""
+    if shardings is not None and _routed(model):
+        raise ValueError("routed experts are served on one device: no mesh placement rules yet")
+    return shardings
 
 
 def make_decode_window(model: Transformer, window: int,
@@ -203,10 +258,11 @@ def make_decode_window(model: Transformer, window: int,
 
     def decode_window(params, cache, tokens, active, eos, do_sample, temperature,
                       top_k, top_p, pad, rngs):
-        return _decode_scan(model, window, params, cache, tokens, active, eos,
-                            do_sample, temperature, top_k, top_p, pad, rngs)
+        return _with_counts(*_decode_scan(
+            model, window, params, cache, tokens, active, eos, do_sample,
+            temperature, top_k, top_p, pad, rngs))
 
-    s = shardings
+    s = _unrouted(model, shardings)
     return _serve_jit(
         decode_window,
         donate_argnums=(1,),
@@ -553,11 +609,19 @@ def make_prefill_chunk(model: Transformer, chunk_len: int,
     prompt token, so prefill and decode share one sampling path.
     """
 
+    s = _unrouted(model, shardings)
+    if _routed(model):
+        def prefill_chunk(params, tokens, scratch, valid):
+            live = jnp.arange(chunk_len)[None, :] < valid
+            _, scratch, counts = _forward(model, params, tokens, scratch, live)
+            return scratch, counts
+
+        return _serve_jit(prefill_chunk, donate_argnums=(2,))
+
     def prefill_chunk(params, tokens, scratch):
         _, scratch = model.apply({"params": params}, tokens, cache=scratch)
         return scratch
 
-    s = shardings
     return _serve_jit(
         prefill_chunk,
         donate_argnums=(2,),
@@ -764,7 +828,8 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
             f"chunk bucket {chunk_len} must be a multiple of page_size {page_size}"
         )
     npg = chunk_len // page_size
-    s = shardings
+    s = _unrouted(model, shardings)
+    counted = _routed(model)
 
     if direct:
         def direct_prefill_chunk(params, tokens, pages_k, pages_v, k_scales,
@@ -791,8 +856,7 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
             ),
         )
 
-    def paged_prefill_chunk(params, tokens, pages_k, pages_v, table, base):
-        L, _, H, page, D = pages_k.shape
+    def chunk(params, tokens, pages_k, pages_v, table, base, valid):
         live = (base + chunk_len - 1) // page_size + 1
         gt = _live_tables(table, live)
         cache = KVCache(
@@ -800,14 +864,27 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
             v=_gather_view(pages_v, gt[None]),
             index=base,
         )
-        _, cache = model.apply({"params": params}, tokens, cache=cache)
+        rows = None if valid is None else jnp.arange(chunk_len)[None, :] < valid
+        _, cache, counts = _forward(model, params, tokens, cache, rows)
         ids = jax.lax.dynamic_slice(table, (base // page_size,), (npg,))
-        wk = jax.lax.dynamic_slice(cache.k, (0, 0, base, 0, 0), (L, 1, chunk_len, H, D))
-        wv = jax.lax.dynamic_slice(cache.v, (0, 0, base, 0, 0), (L, 1, chunk_len, H, D))
-        to_pages = lambda w: w.reshape(L, npg, page, H, D).swapaxes(2, 3)
-        pages_k = pages_k.at[:, ids].set(to_pages(wk))
-        pages_v = pages_v.at[:, ids].set(to_pages(wv))
-        return pages_k, pages_v
+
+        def write_back(pages, view):
+            L, _, H, page, D = pages.shape          # K's and V's rows may differ
+            w = jax.lax.dynamic_slice(view, (0, 0, base, 0, 0), (L, 1, chunk_len, H, D))
+            return pages.at[:, ids].set(w.reshape(L, npg, page, H, D).swapaxes(2, 3))
+
+        return _with_counts(write_back(pages_k, cache.k), write_back(pages_v, cache.v), counts)
+
+    if counted:
+        # a model with routed experts: a trailing ``valid`` (the chunk's real
+        # rows) in, the ``moe_*`` counters out (:func:`_forward`)
+        def paged_prefill_chunk(params, tokens, pages_k, pages_v, table, base, valid):
+            return chunk(params, tokens, pages_k, pages_v, table, base, valid)
+
+        return _serve_jit(paged_prefill_chunk, donate_argnums=(2, 3))
+
+    def paged_prefill_chunk(params, tokens, pages_k, pages_v, table, base):
+        return chunk(params, tokens, pages_k, pages_v, table, base, None)
 
     return _serve_jit(
         paged_prefill_chunk,
@@ -839,10 +916,11 @@ def make_paged_decode_window(model: Transformer, window: int,
     sampling/freeze/EOS semantics cannot drift.  Signature gains the scale
     arrays: ``(params, pages_k, pages_v, k_scales, v_scales, tables, index,
     tokens, ...) -> (pages_k, pages_v, k_scales, v_scales, out_tokens,
-    new_pending, new_rngs, quant_err)``.
+    new_pending, new_rngs, quant_err)``.  A model with routed experts appends
+    its window's ``moe_*`` counters (:func:`_forward`) to either tuple.
     """
 
-    s = shardings
+    s = _unrouted(model, shardings)
 
     if direct:
         def direct_decode_window(params, pages_k, pages_v, k_scales, v_scales,
@@ -854,12 +932,13 @@ def make_paged_decode_window(model: Transformer, window: int,
                 tables=tables, index=index, active=active,
                 quant_err=jnp.float32(0.0),
             )
-            cache, toks, tok, rngs = _decode_scan(
+            cache, toks, tok, rngs, counts = _decode_scan(
                 model, window, params, cache, tokens, active, eos, do_sample,
                 temperature, top_k, top_p, pad, rngs,
             )
-            return (cache.pages_k, cache.pages_v, cache.k_scales,
-                    cache.v_scales, toks, tok, rngs, cache.quant_err)
+            return _with_counts(cache.pages_k, cache.pages_v, cache.k_scales,
+                                cache.v_scales, toks, tok, rngs, cache.quant_err,
+                                counts)
 
         return _serve_jit(
             direct_decode_window,
@@ -882,13 +961,13 @@ def make_paged_decode_window(model: Transformer, window: int,
             v=_gather_view(pages_v, gt),
             index=index,
         )
-        cache, toks, tok, rngs = _decode_scan(
+        cache, toks, tok, rngs, counts = _decode_scan(
             model, window, params, cache, tokens, active, eos, do_sample,
             temperature, top_k, top_p, pad, rngs,
         )
         pages_k = _scatter_span(pages_k, cache.k, tables, index, window, active)
         pages_v = _scatter_span(pages_v, cache.v, tables, index, window, active)
-        return pages_k, pages_v, toks, tok, rngs
+        return _with_counts(pages_k, pages_v, toks, tok, rngs, counts)
 
     return _serve_jit(
         paged_decode_window,

@@ -59,6 +59,11 @@ class Readback:
     width: int                     # decode window width / speculate_k + 1
     counts: Any = None             # device [slots] n_commit (verify only)
     qerr: Any = None               # device KV quantization round-trip error
+    #: device ``int32 [3]`` counters of a routed-experts model (pairs chosen,
+    #: pairs on experts held here, held experts hit): this window's own first,
+    #: then those of the prefill chunks dispatched in its cycle — all fetched
+    #: with ``toks`` in the window's one blocking fetch
+    moe_counts: list = dataclasses.field(default_factory=list)
     active: Optional[np.ndarray] = None   # dispatch-time active mask (copy)
     reqs: Optional[list] = None           # dispatch-time _slot_req snapshot
     eos: Optional[np.ndarray] = None      # dispatch-time per-lane EOS ids

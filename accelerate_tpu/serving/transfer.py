@@ -288,6 +288,8 @@ class PageMigrator:
             return "source and destination are the same engine"
         if not (src.paged and dst.paged):
             return "both engines must run the paged KV pool"
+        if src.config.latent_attention is not None or dst.config.latent_attention is not None:
+            return "page migration is not ported to a latent-attention cache yet"
         if src.kv.page_size != dst.kv.page_size:
             return (f"page_size differs ({src.kv.page_size} vs "
                     f"{dst.kv.page_size})")

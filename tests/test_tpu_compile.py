@@ -281,3 +281,41 @@ def test_cache_is_written_in_place(one_chip, program, direct):
         text, whole_shape, n_writes=2 * XL["num_layers"],
         scope="while" if program == "decode" else "model",
     )
+
+
+def test_latent_decode_window_fits_at_published_widths(one_chip):
+    """DeepSeek-V2's widths (``bench/configs/deepseek-v2.json``) cut to two
+    layers (the dense one and one expert layer holding 4 experts), 16 lanes of
+    8192: the gathered decode window compiles for the chip, the held experts'
+    products are the compiler's own grouped matmul (``ragged-dot``), and the
+    latent view ``[L, N, M, 1, 512]`` is not padded out on its unit axis (16 x
+    the view would be 5 GB of temporaries where 1.06 GB is measured)."""
+    import json
+    from pathlib import Path
+
+    from accelerate_tpu.models.transformer import Transformer, TransformerConfig
+    from accelerate_tpu.serving.pool import make_paged_decode_window
+
+    fields = json.loads((Path(__file__).resolve().parents[1] / "bench" / "configs"
+                         / "deepseek-v2.json").read_text())["transformer"]
+    fields.update(num_layers=2, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    fields["experts"] = dict(fields["experts"], held=[0, 4])
+    model = Transformer(TransformerConfig(**fields))
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"],
+    )
+    n, page, pages_per_lane = 16, 128, 64
+    num_pages = n * pages_per_lane + 1
+    lanes = [spec((n,), t) for t in (jnp.int32, jnp.bool_, jnp.int32, jnp.bool_, jnp.float32, jnp.int32,
+                                     jnp.float32, jnp.int32)] + [spec((n, 2), jnp.uint32)]
+    compiled = make_paged_decode_window(model, 4).lower(
+        params, spec((2, num_pages, 1, page, 512), jnp.bfloat16), spec((2, num_pages, 1, page, 64), jnp.bfloat16),
+        spec((n, pages_per_lane), jnp.int32), spec((n,), jnp.int32), *lanes,
+    ).compile()
+    assert "ragged-dot" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.6e9, memory
+    # the pool itself is handed over unpadded: 576 values a token and layer
+    assert memory.alias_size_in_bytes == 2 * num_pages * page * (512 + 64) * 2
