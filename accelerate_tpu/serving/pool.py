@@ -735,14 +735,19 @@ def make_copy_chunk(chunk_len: int,
 # in a shared page pool ``[L, num_pages, Hkv, page, Dh]``.  Each executable has
 # two arms.  The gathered arm (``direct=False``) gathers every lane's pages
 # into a contiguous view of the slab's width once a call, runs the *same*
-# traced decode/verify/prefill body as the slab path on it, then scatters only
-# the newly-written positions back.  The view width equals the slab width
-# (``pages_per_lane * page == max_len``), so the attention program — and with
-# it every greedy argmax — is bitwise identical to the legacy pool.  What the
-# view costs is once a CALL: the gather, the write-back and their layout
-# copies, and a view-sized temporary for K and for V.  Inside the call the
-# model writes the view in place — each layer its new rows, each scan step of
-# a decode window 2 x L small scatters into the carried view
+# traced decode/verify/prefill body as the slab path on it, then stores the
+# pages the call wrote into back whole (:func:`_store_span_pages`; a prefill
+# chunk's span is page-aligned and is its pages).  The view width equals the
+# slab width (``pages_per_lane * page == max_len``), so the attention program —
+# and with it every greedy argmax — is bitwise identical to the legacy pool.
+# What the view costs is once a CALL: the gather and its one layout pass (the
+# pool keeps ``page`` minor on the chip, the view ``Dh``), a view-sized
+# temporary for K and for V, and a write-back of two pages a lane, scattered
+# in the pool's own layout.  Rows are never stored singly: with ``page`` minor
+# a row is a strided write, and the compiler would copy the whole pool into a
+# row-minor layout and back to serve it.  Inside the call the model writes the
+# view in place — each layer its new rows, each scan step of a decode window
+# 2 x L small scatters into the carried view
 # (:class:`~accelerate_tpu.models.transformer.KVCache`); nothing of the view's
 # size is copied per step.  The in-place arm (``direct=True``) hands the model
 # the pool itself (:class:`~accelerate_tpu.models.transformer.PagedKVCache`):
@@ -775,27 +780,37 @@ def _live_tables(tables, live):
     return jnp.where(jnp.arange(num_p)[None, :] < live[:, None], tables, NULL_PAGE)
 
 
-def _scatter_span(pages, view, tables, start, width: int, active):
+def _store_span_pages(pages, view, tables, start, width: int, active):
     """Write ``view[:, n, start[n] : start[n] + width]`` back through lane
-    ``n``'s block table, for every ACTIVE lane.  Positions are guaranteed
-    in-range by the engine's admission check (``prompt + max_new + span <=
-    max_len``).  Inactive lanes' writes are rerouted to the null page: a
-    frozen lane's row may be vacant (all-null already), but a lane mid-prefill
-    has REAL pages mapped — possibly shared with the prefix cache — and its
-    stale write index must never trample them."""
-    L, _, H, page, D = pages.shape
-    N = tables.shape[0]
-    written = jax.vmap(
-        lambda kv, i: jax.lax.dynamic_slice(kv, (0, i, 0, 0), (L, width, H, D)),
-        in_axes=(1, 0), out_axes=1,
-    )(view, start)                                       # [L, N, width, H, D]
-    pos = start[:, None] + jnp.arange(width)             # [N, width]
-    pid = jnp.take_along_axis(tables, pos // page, axis=1)
-    pid = jnp.where(active[:, None], pid, NULL_PAGE)
-    off = pos % page
-    # advanced indices split by a slice: the indexed rows lead, [N*width, L, H, D]
-    return pages.at[:, pid.reshape(-1), :, off.reshape(-1)].set(
-        written.reshape(L, N * width, H, D).swapaxes(0, 1)
+    ``n``'s block table, for every ACTIVE lane, by storing whole the pages the
+    span touches: a static ``(width + page - 2) // page + 1`` a lane.  The
+    pool's minor dimension on the chip is ``page``, so one row is a strided
+    write that the compiler serves by copying the whole pool into another
+    layout and back; a page is a contiguous block, scattered in place.  The
+    page blocks are cut out of the view by one gather over (lane, page slot);
+    the view itself is never transposed.
+
+    A touched page is stored over itself plus its new rows: it is live in
+    :func:`_live_tables`, so the view's copy of it was gathered from that same
+    pool page in this call, and the engine made a decoding lane's tail page
+    private to it (``_cow_tail_page``).  Positions are in range by the engine's
+    admission check (``prompt + max_new + span <= max_len``).  Stores are
+    rerouted to the null page for a slot the span does not reach (no page
+    boundary crossed; a slot past the table's end) and for every inactive lane:
+    a frozen lane's row may be vacant (all-null already), but a lane
+    mid-prefill has REAL pages mapped — possibly shared with the prefix cache —
+    and its stale write index must never trample them."""
+    L, _, H, page, D = pages.shape                       # K's and V's rows may differ
+    N, P = tables.shape
+    touched = (width + page - 2) // page + 1
+    slot = (start // page)[:, None] + jnp.arange(touched)            # [N, touched]
+    reached = (active[:, None] & (slot < P)
+               & (slot <= ((start + width - 1) // page)[:, None]))
+    slot = jnp.minimum(slot, P - 1)
+    ids = jnp.where(reached, jnp.take_along_axis(tables, slot, axis=1), NULL_PAGE)
+    blocks = view.reshape(L, N, P, page, H, D)[:, jnp.arange(N)[:, None], slot]
+    return pages.at[:, ids.reshape(-1)].set(
+        blocks.reshape(L, N * touched, page, H, D).swapaxes(2, 3)
     )
 
 
@@ -904,7 +919,8 @@ def make_paged_decode_window(model: Transformer, window: int,
     -> (pages_k, pages_v, out_tokens [N, window], new_pending, new_rngs)``.
 
     Gather view -> the shared :func:`_decode_scan` (bitwise the slab program)
-    -> scatter the ``window`` written positions per lane.  The engine tracks
+    -> store back whole the pages each active lane's ``window`` new positions
+    lie in (:func:`_store_span_pages`: two a lane).  The engine tracks
     each lane's index on the host (install/advance arithmetic is exact), so
     no index array needs to round-trip.
 
@@ -965,8 +981,8 @@ def make_paged_decode_window(model: Transformer, window: int,
             model, window, params, cache, tokens, active, eos, do_sample,
             temperature, top_k, top_p, pad, rngs,
         )
-        pages_k = _scatter_span(pages_k, cache.k, tables, index, window, active)
-        pages_v = _scatter_span(pages_v, cache.v, tables, index, window, active)
+        pages_k = _store_span_pages(pages_k, cache.k, tables, index, window, active)
+        pages_v = _store_span_pages(pages_v, cache.v, tables, index, window, active)
         return _with_counts(pages_k, pages_v, toks, tok, rngs, counts)
 
     return _serve_jit(
@@ -980,11 +996,12 @@ def make_paged_decode_window(model: Transformer, window: int,
 def make_paged_verify_window(model: Transformer, k: int, direct: bool = False,
                              shardings: Optional[ServeShardings] = None):
     """Paged speculative verify: the slab :func:`_verify_body` over a gathered
-    view, scattering all ``K+1`` written positions back (rejected positions'
-    KV is unreachable past the committed index and gets overwritten later,
-    exactly as in the slab path).  ``(params, pages_k, pages_v, tables, index,
-    tokens [N, K+1], ...) -> (pages_k, pages_v, out, n_commit, new_pending,
-    new_rngs)`` — the engine advances its host index mirror by ``n_commit``.
+    view, storing back the pages all ``K+1`` written positions lie in
+    (:func:`_store_span_pages`; rejected positions' KV is unreachable past the
+    committed index and gets overwritten later, exactly as in the slab path).
+    ``(params, pages_k, pages_v, tables, index, tokens [N, K+1], ...) ->
+    (pages_k, pages_v, out, n_commit, new_pending, new_rngs)`` — the engine
+    advances its host index mirror by ``n_commit``.
 
     ``direct=True``: in-model paged cache (see
     :func:`make_paged_decode_window`); signature gains the scale arrays and a
@@ -1036,8 +1053,8 @@ def make_paged_verify_window(model: Transformer, k: int, direct: bool = False,
             model, k, params, cache, tokens, active, eos, do_sample,
             temperature, top_k, top_p, pad, rngs,
         )
-        pages_k = _scatter_span(pages_k, cache.k, tables, index, kp1, active)
-        pages_v = _scatter_span(pages_v, cache.v, tables, index, kp1, active)
+        pages_k = _store_span_pages(pages_k, cache.k, tables, index, kp1, active)
+        pages_v = _store_span_pages(pages_v, cache.v, tables, index, kp1, active)
         return pages_k, pages_v, out, n_commit, new_pending, new_rngs
 
     return _serve_jit(
@@ -1114,9 +1131,10 @@ def make_paged_tree_verify_window(model: Transformer, tree,
     new_pending, new_rngs)``.
 
     ``direct=False`` runs the slab :func:`_tree_verify_body` (including its
-    slab compaction) over a gathered per-lane view and scatters all ``S``
-    written positions back — rows past the compacted frontier are unreachable
-    garbage, exactly like rejected positions in the linear paged verify.
+    slab compaction) over a gathered per-lane view and stores back the pages
+    all ``S`` written positions lie in (:func:`_store_span_pages`) — rows past
+    the compacted frontier are unreachable garbage, exactly like rejected
+    positions in the linear paged verify.
     ``direct=True`` threads the :class:`PagedKVCache` through the model (the
     quantized / pallas-kernel path); the winning path commits via
     :func:`_tree_commit_paged` and the signature gains the scale arrays and a
@@ -1169,8 +1187,8 @@ def make_paged_tree_verify_window(model: Transformer, tree,
             model, tree, params, cache, tokens, active, eos, do_sample,
             temperature, top_k, top_p, pad, rngs,
         )
-        pages_k = _scatter_span(pages_k, cache.k, tables, index, s_nodes, active)
-        pages_v = _scatter_span(pages_v, cache.v, tables, index, s_nodes, active)
+        pages_k = _store_span_pages(pages_k, cache.k, tables, index, s_nodes, active)
+        pages_v = _store_span_pages(pages_v, cache.v, tables, index, s_nodes, active)
         return pages_k, pages_v, out, n_commit, new_pending, new_rngs
 
     return _serve_jit(

@@ -331,3 +331,58 @@ class TestPagedPressure:
         assert "serve/kv_bytes_shared" in snap
         assert snap["serve/kv_pages_in_use"] + snap["serve/kv_pages_free"] \
             == eng.kv.num_pages - 1
+
+
+# ------------------------------------------------ the windows' write-back
+# ``pool._store_span_pages`` stores whole pages; the oracle stores the span's
+# rows one by one.  Each case: page size, the lanes' tables (page 0 is the null
+# page), their write indices, the span's width, who is active, K's and V's
+# row shapes ``[H, D]``.  Every page but the null one must come out the same,
+# bit for bit, so a page nobody wrote is a page nobody touched.
+_STORE_CASES = {
+    "inside_one_page": dict(page=8, tables=[[1, 2, 3], [4, 5, 6]], start=[9, 17], width=4),
+    "crosses_a_boundary": dict(page=8, tables=[[1, 2, 3], [4, 5, 6]], start=[6, 13], width=4),
+    # lane 0's span ends with the table: its second slot lies past the end
+    "last_page_clipped_slot": dict(page=8, tables=[[1, 2, 3], [4, 5, 6]], start=[20, 2], width=4),
+    # lane 1 is mid-prefill: pages 1 and 2 are lane 0's prefix (shared), its
+    # index is stale and points into page 2
+    "inactive_lane_shares_pages": dict(page=8, tables=[[1, 2, 3], [1, 2, 4]], start=[18, 11],
+                                       width=4, active=[True, False]),
+    "width_over_a_page": dict(page=16, tables=[[1, 2, 3, 4], [5, 6, 7, 8]], start=[13, 30], width=20),
+    "latent_rows_differ": dict(page=8, tables=[[1, 2, 3], [4, 5, 6]], start=[7, 12], width=4,
+                               rows=((1, 32), (1, 8))),
+}
+
+
+def _row_store(pages, view, tables, start, width, active):
+    out, page = pages.copy(), pages.shape[3]
+    for lane in np.flatnonzero(active):
+        for pos in range(start[lane], start[lane] + width):
+            out[:, tables[lane, pos // page], :, pos % page] = view[:, lane, pos]
+    return out
+
+
+@pytest.mark.parametrize("case", list(_STORE_CASES))
+def test_store_span_pages_matches_row_store(case):
+    from accelerate_tpu.serving import pool
+
+    c = _STORE_CASES[case]
+    page, width, layers = c["page"], c["width"], 2
+    tables = np.asarray(c["tables"], np.int32)
+    start = np.asarray(c["start"], np.int32)
+    active = np.asarray(c.get("active", [True] * len(start)))
+    rng = np.random.default_rng(29)
+    for heads, dim in c.get("rows", ((3, 4), (3, 4))):
+        pages = rng.standard_normal((layers, tables.max() + 1, heads, page, dim)).astype(np.float32)
+        # the view a window sees, then the rows its forward wrote (an inactive
+        # lane's are garbage that must never reach the pool)
+        live = pool._live_tables(jnp.asarray(tables), jnp.asarray((start + width - 1) // page + 1))
+        view = np.array(pool._gather_view(jnp.asarray(pages), live))
+        for lane, at in enumerate(start):
+            view[:, lane, at:at + width] = rng.standard_normal((layers, width, heads, dim))
+        got = jax.jit(pool._store_span_pages, static_argnums=4)(
+            jnp.asarray(pages), jnp.asarray(view), jnp.asarray(tables), jnp.asarray(start),
+            width, jnp.asarray(active))
+        want = _row_store(pages, view, tables, start, width, active)
+        np.testing.assert_array_equal(np.asarray(got)[:, 1:], want[:, 1:])
+        assert not np.array_equal(want[:, 1:], pages[:, 1:])     # the span was stored

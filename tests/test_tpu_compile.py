@@ -16,7 +16,9 @@ round trip per layer compiles (it did, until PR 26) to four passes over the
 whole cache on every decode step, which no CPU test can see: the values are
 the same.  So the compiled decode window and prefill chunk are read here, at
 gpt2-xl's widths and a few layers, and may hold nothing of the whole cache's
-shape under the model's name but the in-place write itself.
+shape under the model's name but the in-place write itself.  The gathered
+arm's sandwich round the model is held the same way: the pool is written by
+pages, in its own layout, and never copied (``test_pages_are_written_back_whole``).
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process may load the TPU library, and every xdist worker imports
@@ -24,6 +26,8 @@ every test file), with the persistent compile cache off around it — such a
 compile can be written to the cache but not read back without a chip.
 """
 
+import collections
+import functools
 import re
 from typing import NamedTuple, Optional
 
@@ -229,39 +233,76 @@ def _check_cache_plumbing(text, whole_shape, n_writes, scope):
     assert writes == n_writes, f"{writes} whole-cache writes, expected {n_writes}"
 
 
-def _xl_programs(one_chip, program, direct):
+def _deepseek_fields():
+    """DeepSeek-V2's widths (``bench/configs/deepseek-v2.json``) cut to two
+    layers: the dense one and one expert layer holding 4 experts."""
+    import json
+    from pathlib import Path
+
+    fields = json.loads((Path(__file__).resolve().parents[1] / "bench" / "configs"
+                         / "deepseek-v2.json").read_text())["transformer"]
+    fields.update(num_layers=2, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    fields["experts"] = dict(fields["experts"], held=[0, 4])
+    return fields
+
+
+#: the serve cells' pools: fields, lanes, pages a lane, K's and V's rows ``[H, D]``
+_POOLS = {
+    "xl": (lambda: XL, XL_LANES, XL_PAGES_PER_LANE, ((25, 64), (25, 64))),
+    "deepseek": (_deepseek_fields, 16, 64, ((1, 512), (1, 64))),
+}
+SPECULATE_K = 3
+
+
+class _Program(NamedTuple):
+    compiled: object
+    pool_shapes: tuple       # K's and V's, as the HLO prints them
+    view_shapes: tuple
+
+
+@pytest.fixture(scope="module")
+def paged_program(one_chip):
+    """``(config, program, direct) -> _Program``, each compiled once a module."""
     from accelerate_tpu.models.transformer import Transformer, TransformerConfig
     from accelerate_tpu.serving import pool
-
-    model = Transformer(TransformerConfig(**XL))
-    L, n, p = XL["num_layers"], XL_LANES, XL_PAGES_PER_LANE
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    params = jax.tree_util.tree_map(
-        lambda a: spec(a.shape, a.dtype),
-        jax.eval_shape(lambda: model.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
-    )
-    num_pages = n * p + 1
-    pages = spec((L, num_pages, 25, XL_PAGE, 64), jnp.bfloat16)
-    scales = (spec((L, num_pages, 25), jnp.float32),) * 2 if direct else ()
-    pool_shape = f"bf16[{L},{num_pages},25,{XL_PAGE},64]"
-    if program == "decode":
-        fn = pool.make_paged_decode_window(model, XL_WINDOW, direct=direct)
+    @functools.cache
+    def build(config, program, direct):
+        fields, n, p, rows = _POOLS[config]
+        model = Transformer(TransformerConfig(**fields()))
+        L, page = model.config.num_layers, XL_PAGE
+        params = jax.tree_util.tree_map(
+            lambda a: spec(a.shape, a.dtype),
+            jax.eval_shape(lambda: model.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
+        )
+        num_pages = n * p + 1
+        pages = [spec((L, num_pages, h, page, d), jnp.bfloat16) for h, d in rows]
+        scales = [spec((L, num_pages, h), jnp.float32) for h, _ in rows] if direct else []
         i32, f32 = (lambda *s: spec(s, jnp.int32)), (lambda *s: spec(s, jnp.float32))
         flag = lambda *s: spec(s, jnp.bool_)
-        args = (params, pages, pages, *scales, i32(n, p), i32(n),
-                i32(n), flag(n), i32(n), flag(n), f32(n), i32(n), f32(n), i32(n),
-                spec((n, 2), jnp.uint32))
-        view_shape = f"bf16[{L},{n},{p * XL_PAGE},25,64]"
-    else:
-        fn = pool.make_paged_prefill_chunk(model, XL_CHUNK, XL_PAGE, direct=direct)
-        args = (params, spec((1, XL_CHUNK), jnp.int32), pages, pages, *scales,
-                spec((p,), jnp.int32), spec((), jnp.int32))
-        view_shape = f"bf16[{L},1,{p * XL_PAGE},25,64]"
-    return fn, args, pool_shape if direct else view_shape
+        lanes = (flag(n), i32(n), flag(n), f32(n), i32(n), f32(n), i32(n),
+                 spec((n, 2), jnp.uint32))
+        if program == "prefill":
+            fn = pool.make_paged_prefill_chunk(model, XL_CHUNK, page, direct=direct)
+            args = (params, i32(1, XL_CHUNK), *pages, *scales, i32(p), i32())
+            n = 1
+        elif program == "decode":
+            fn = pool.make_paged_decode_window(model, XL_WINDOW, direct=direct)
+            args = (params, *pages, *scales, i32(n, p), i32(n), i32(n), *lanes)
+        else:
+            fn = pool.make_paged_verify_window(model, SPECULATE_K, direct=direct)
+            args = (params, *pages, *scales, i32(n, p), i32(n), i32(n, SPECULATE_K + 1), *lanes)
+        return _Program(
+            fn.lower(*args).compile(),
+            tuple(f"bf16[{L},{num_pages},{h},{page},{d}]" for h, d in rows),
+            tuple(f"bf16[{L},{n},{p * page},{h},{d}]" for h, d in rows),
+        )
+
+    return build
 
 
 @pytest.mark.parametrize(
@@ -270,52 +311,85 @@ def _xl_programs(one_chip, program, direct):
     ids=["decode-gathered-lane_index", "decode-direct-lane_index",
          "prefill-gathered-scalar_index", "prefill-direct-lane_index"],
 )
-def test_cache_is_written_in_place(one_chip, program, direct):
+def test_cache_is_written_in_place(paged_program, program, direct):
     """The gathered arm writes the slab view ``[L, N, M, H, D]`` (per-lane
     index in the decode scan, the scalar chunk base in prefill), the direct arm
     the page pool ``[L, NP, H, page, D]`` through the block tables (always per
     lane), both with the XLA read."""
-    fn, args, whole_shape = _xl_programs(one_chip, program, direct)
-    text = fn.lower(*args).compile().as_text()
+    built = paged_program("xl", program, direct)
     _check_cache_plumbing(
-        text, whole_shape, n_writes=2 * XL["num_layers"],
+        built.compiled.as_text(), (built.pool_shapes if direct else built.view_shapes)[0],
+        n_writes=2 * XL["num_layers"],
         scope="while" if program == "decode" else "model",
     )
 
 
-def test_latent_decode_window_fits_at_published_widths(one_chip):
-    """DeepSeek-V2's widths (``bench/configs/deepseek-v2.json``) cut to two
-    layers (the dense one and one expert layer holding 4 experts), 16 lanes of
-    8192: the gathered decode window compiles for the chip, the held experts'
-    products are the compiler's own grouped matmul (``ragged-dot``), and the
-    latent view ``[L, N, M, 1, 512]`` is not padded out on its unit axis (16 x
-    the view would be 5 GB of temporaries where 1.06 GB is measured)."""
-    import json
-    from pathlib import Path
+#: what brings a 2-layer pool into the faster memory space ``S(1)`` and back,
+#: which a 48-layer pool (0.65 GB) cannot have: not the write-back's doing
+_STAGING = {"copy-start", "copy-done", "slice-start", "slice-done"}
+#: view-sized outputs of the sandwich, K's and V's: the gather and its one
+#: layout pass.  DeepSeek-V2's rope key (rows of one head of 64, half a lane
+#: tile; a ninth of the latent's bytes) is carried with the positions minor, so
+#: the compiler re-tiles it on its way into the scan and again on its way to
+#: the page gather: five small passes in the decode window, four in the verify.
+_VIEW_PASSES = {"xl": (2, 2), "deepseek": (2, 5)}
 
-    from accelerate_tpu.models.transformer import Transformer, TransformerConfig
-    from accelerate_tpu.serving.pool import make_paged_decode_window
 
-    fields = json.loads((Path(__file__).resolve().parents[1] / "bench" / "configs"
-                         / "deepseek-v2.json").read_text())["transformer"]
-    fields.update(num_layers=2, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
-    fields["experts"] = dict(fields["experts"], held=[0, 4])
-    model = Transformer(TransformerConfig(**fields))
-    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    params = jax.tree_util.tree_map(
-        lambda a: spec(a.shape, a.dtype),
-        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"],
-    )
-    n, page, pages_per_lane = 16, 128, 64
-    num_pages = n * pages_per_lane + 1
-    lanes = [spec((n,), t) for t in (jnp.int32, jnp.bool_, jnp.int32, jnp.bool_, jnp.float32, jnp.int32,
-                                     jnp.float32, jnp.int32)] + [spec((n, 2), jnp.uint32)]
-    compiled = make_paged_decode_window(model, 4).lower(
-        params, spec((2, num_pages, 1, page, 512), jnp.bfloat16), spec((2, num_pages, 1, page, 64), jnp.bfloat16),
-        spec((n, pages_per_lane), jnp.int32), spec((n,), jnp.int32), *lanes,
-    ).compile()
+def _squeezed(shape):
+    """``bf16[2,1025,1,128,512]`` as the compiler prints it once it has
+    dropped the unit axes, and the number of elements."""
+    dtype, dims = shape.rstrip("]").split("[")
+    dims = [int(d) for d in dims.split(",") if d]
+    return f"{dtype}[{','.join(str(d) for d in dims if d != 1)}]", int(np.prod(dims))
+
+
+@pytest.mark.parametrize("program", ["decode", "verify"])
+@pytest.mark.parametrize("config", list(_POOLS))
+def test_pages_are_written_back_whole(paged_program, config, program):
+    """The gathered windows' write-back (``pool._store_span_pages``), compiled:
+    nothing transposes the view to cut rows out of it (``vmap()/transpose``);
+    in the entry computation an array of the pool's shape comes only out of the
+    in-place write (a fusion whose root is a scatter or dynamic-update-slice),
+    once for K and once for V, so no ``copy`` takes the pool into the layout a
+    row store wants and back; and outside the model the view is passed over no
+    more often than ``_VIEW_PASSES`` says."""
+    built = paged_program(config, program, False)
+    comps, entry = _parse_hlo(built.compiled.as_text())
+    pools = [_squeezed(s)[0] for s in built.pool_shapes]
+    views = [_squeezed(s)[1] for s in built.view_shapes]
+    limits, passes, writes = collections.Counter(), collections.Counter(), 0
+    for size, limit in zip(views, _VIEW_PASSES[config]):
+        limits[size] += limit                 # gpt2-xl's K and V are of one size
+    for name, instructions in comps.items():
+        for ins in instructions:
+            assert not ins.op_name.endswith("vmap()/transpose"), ins.line
+            if (name != entry or ins.shape is None or ins.opcode in _PASS_THROUGH | _STAGING
+                    or "ConcatBitcast" in ins.line):
+                continue
+            shape, size = _squeezed(ins.shape)
+            if shape in pools:
+                root = ins if ins.opcode != "fusion" else next(
+                    i for i in comps[ins.called[0]] if i.is_root)
+                assert root.opcode in _WRITES, ins.line
+                writes += 1
+            elif (shape.startswith("bf16") and size in views and "/Transformer/" not in ins.op_name
+                  and "params" not in ins.line):      # a weight can be of the view's size
+                passes[size] += 1
+    assert writes == 2, f"{writes} writes of the pool, expected K's and V's"
+    assert all(passes[size] <= limits[size] for size in limits), (passes, limits)
+
+
+def test_latent_decode_window_fits_at_published_widths(paged_program):
+    """DeepSeek-V2's widths at two layers, 16 lanes of 8192: the gathered
+    decode window compiles for the chip, the held experts' products are the
+    compiler's own grouped matmul (``ragged-dot``), and the latent view
+    ``[L, N, M, 1, 512]`` is not padded out on its unit axis (16 x the view
+    would be 5 GB of temporaries where 0.71 GB is measured, 1.06 GB before
+    the write-back went by pages)."""
+    compiled = paged_program("deepseek", "decode", False).compiled
     assert "ragged-dot" in compiled.as_text()
     memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < 1.6e9, memory
+    assert memory.temp_size_in_bytes < 0.79e9, memory
     # the pool itself is handed over unpadded: 576 values a token and layer
-    assert memory.alias_size_in_bytes == 2 * num_pages * page * (512 + 64) * 2
+    _, lanes, pages_per_lane, _ = _POOLS["deepseek"]
+    assert memory.alias_size_in_bytes == 2 * (lanes * pages_per_lane + 1) * XL_PAGE * (512 + 64) * 2
