@@ -1,7 +1,7 @@
 # Developer targets (reference Makefile:25-72 test split analog).
 
 .PHONY: test test_fast test_slow test_core test_big_modeling test_cli test_examples \
-        test_multiprocess test_kernels native bench bench-serve chaos quality lint-json
+        test_multiprocess test_kernels native bench chaos quality lint-json
 
 test:
 	python -m pytest tests/ -q
@@ -41,41 +41,18 @@ native:
 
 bench:
 	python bench.py
-	python bench_inference.py
 
-# serving-engine A/Bs: continuous batching vs static generate, prefix-cache
-# on/off, and speculative decoding on/off (the spec run hard-fails unless
-# greedy outputs are token-identical between the two arms)
-bench-serve:
-	python bench_inference.py --task serve
-	python bench_inference.py --task serve --shared-prefix 16
-	python bench_inference.py --task serve --paged-ab
-	python bench_inference.py --task serve --kernel-ab
-	python bench_inference.py --task serve --prefill-ab
-	python bench_inference.py --task serve --hier-ab
-	python bench_inference.py --task serve --tp-ab
-	python bench_inference.py --task serve --async-ab
-	python bench_inference.py --task serve --http-ab
-	python bench_inference.py --task serve --chaos-ab
-	python bench_inference.py --task serve --trace-ab
-	python bench_inference.py --task serve --slo-ab
-	python bench_inference.py --task serve --disagg-ab
-	python bench_inference.py --task spec
-	python bench_inference.py --task spec --tree-ab
-
-# fault-tolerance gate: the deterministic fault-injection test suite plus the
-# chaos A/B (replica kill -> token-identical replay, seeded fault soak, and a
-# faults-off overhead check; every check in the bench is a hard SystemExit)
+# fault-tolerance gate: the deterministic fault-injection test suite (replica
+# kill -> token-identical replay, page exhaustion, deadlines, disconnects)
 chaos:
 	python -m pytest tests/test_fault_tolerance.py -q
-	python bench_inference.py --task serve --chaos-ab
 
 # one process, one AST load per file, all ten rules (tools/atpu_lint/rules/);
 # the lint surface includes the linter itself (docs/development/static-analysis.md)
 quality:
 	python -m compileall -q accelerate_tpu
-	python -m tools.atpu_lint accelerate_tpu tests tools bench.py bench_inference.py
+	python -m tools.atpu_lint accelerate_tpu tests tools bench.py
 
 # machine-readable report for CI artifacts / editor integration
 lint-json:
-	@python -m tools.atpu_lint accelerate_tpu tests tools bench.py bench_inference.py --format json
+	@python -m tools.atpu_lint accelerate_tpu tests tools bench.py --format json

@@ -98,7 +98,7 @@ def filter_logits_batched(
     top-k then top-p — the same pipeline order as :func:`sample_tokens`.
 
     Factored out of :func:`sample_tokens_batched` so the serving engine's
-    speculative verify window (:func:`~accelerate_tpu.serving.pool.make_verify_window`)
+    speculative verify window (:func:`~accelerate_tpu.serving.pool.make_paged_verify_window`)
     can apply the Leviathan accept/resample rule against exactly the
     distribution ordinary decode would have sampled from.  ``top_k <= 0`` and
     ``top_p >= 1`` disable their filters per lane.

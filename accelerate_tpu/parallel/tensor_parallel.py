@@ -45,7 +45,7 @@ DEFAULT_TP_RULES: Tuple[Tuple[str, str], ...] = (
 # psum whose cross-device reduction order differs from the single-device
 # matmul — a few-ulp drift that compounds over autoregressive decode steps
 # until a greedy argmax flips.  Serving promises token-identical output at
-# every tp degree (the ``--tp-ab`` bench enforces it bitwise), so those
+# every tp degree (``tests/test_serving_mesh.py`` holds it bitwise), so those
 # layers and the embedding gather stay replicated: every reduction a sharded
 # serve executes runs over the same unsharded operands, in the same order,
 # as its tp=1 twin.  Column-parallel q/k/v is also what keeps the paged KV

@@ -12,9 +12,9 @@ Examples::
     # a tiny random-weight model on an ephemeral port (smoke test)
     python -m accelerate_tpu.serve --preset tiny --port 8000
 
-    # two paged replicas from a safetensors export, bounded queues
+    # two replicas from a safetensors export, bounded queues
     python -m accelerate_tpu.serve --preset small \
-        --checkpoint /ckpts/step-9000 --replicas 2 --paged \
+        --checkpoint /ckpts/step-9000 --replicas 2 \
         --max-queue 64 --weights-version step-9000 --port 8000
 
     curl -N localhost:8000/v1/completions -d \
@@ -100,7 +100,6 @@ def build_service(args):
             num_slots=args.num_slots,
             max_len=args.max_len,
             decode_window=args.decode_window,
-            paged=args.paged,
             speculate_k=args.speculate_k,
             max_queue=args.max_queue,
             weights_version=args.weights_version,
@@ -145,8 +144,6 @@ def _parser() -> argparse.ArgumentParser:
     p.add_argument("--num-slots", type=int, default=4)
     p.add_argument("--max-len", type=int, default=512)
     p.add_argument("--decode-window", type=int, default=4)
-    p.add_argument("--paged", action="store_true",
-                   help="paged KV pool instead of per-slot slabs")
     p.add_argument("--speculate-k", type=int, default=0)
     p.add_argument("--max-queue", type=int, default=256,
                    help="per-replica admission bound (queue-full -> 429); "

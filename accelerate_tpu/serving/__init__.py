@@ -1,8 +1,8 @@
-"""Continuous-batching serving: slot-based KV pool, in-flight admission,
-chunked prefill — iteration-level scheduling (Orca; vLLM's slot reuse) kept
-inside a fixed set of compiled TPU executables.  With ``paged=True`` the KV
-pool becomes a refcounted page pool behind per-lane block tables
-(:mod:`.paging` — PagedAttention, TPU-native).  See ``docs/usage/serving.md``.
+"""Continuous-batching serving: in-flight admission and chunked prefill —
+iteration-level scheduling (Orca; vLLM's slot reuse) kept inside a fixed set
+of compiled TPU executables — over a refcounted KV page pool behind per-lane
+block tables (:mod:`.paging` — PagedAttention, TPU-native).  See
+``docs/usage/serving.md``.
 """
 
 from .engine import ServingEngine
@@ -12,17 +12,12 @@ from .paging import NULL_PAGE, PageAllocator, PagedKVPool
 from .pool import (
     ServeShardings,
     jit_cache_sizes,
-    make_copy_chunk,
     make_copy_page,
-    make_decode_window,
-    make_insert,
     make_paged_decode_window,
     make_paged_prefill_chunk,
     make_paged_verify_window,
-    make_prefill_chunk,
     make_promote_install,
     make_spill_extract,
-    make_verify_window,
     plan_chunks,
 )
 from .prefix_cache import PrefixCache, PrefixNode, rolling_hash
@@ -52,11 +47,6 @@ __all__ = [
     "PageAllocator",
     "PagedKVPool",
     "plan_chunks",
-    "make_decode_window",
-    "make_verify_window",
-    "make_prefill_chunk",
-    "make_insert",
-    "make_copy_chunk",
     "make_paged_decode_window",
     "make_paged_verify_window",
     "make_paged_prefill_chunk",

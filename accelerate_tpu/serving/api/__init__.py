@@ -14,8 +14,7 @@ Three layers, separable on purpose:
   telemetry surface (``/metrics``, ``/healthz``, ``/debug/*``).
 
 ``python -m accelerate_tpu.serve`` (see :mod:`accelerate_tpu.serve`) wires
-the three into a runnable service; ``bench_inference.py --task serve
---http-ab`` drives them over the wire.  See ``docs/usage/api_server.md``.
+the three into a runnable service.  See ``docs/usage/api_server.md``.
 """
 
 from .frontdoor import FrontDoor, TokenStream

@@ -1,4 +1,4 @@
-"""Continuous-batching serving engine over the slot-based KV pool.
+"""Continuous-batching serving engine over the paged KV pool.
 
 The static ``generate`` path is one whole-batch program: every request starts
 together and runs exactly ``max_new_tokens`` steps, so at mixed request
@@ -13,17 +13,17 @@ against a fixed set of compiled executables (:mod:`.pool`):
 3. freed slots are reused by queued requests without disturbing running lanes.
 
 Everything dynamic lives on the host; the device only ever sees
-``1 + len(prefill_buckets) + 1`` shapes (decode window, per-bucket prefill,
-insert), plus ``len(prefill_buckets)`` fixed copy shapes when the prefix
-cache is enabled, plus one verify-window shape when ``speculate_k > 0``
-(or a tree-verify + draft-forward pair when ``draft_model`` is set).
-See ``docs/usage/serving.md``.
+``1 + len(prefill_buckets) + 2`` shapes (decode window, per-bucket prefill,
+lane install, the copy-on-write page copy), plus one verify-window shape when
+``speculate_k > 0`` (or a tree-verify + draft-forward pair when
+``draft_model`` is set), plus a spill/promote pair per bucket when the host
+prefix tier is on.  See ``docs/usage/serving.md``.
 
 Speculative decoding (``speculate_k > 0``): each cycle the host proposes K
 draft tokens per lane by n-gram prompt-lookup (:mod:`.spec` — incrementally
 indexed per lane, O(K) per cycle) and, when at least one lane drafts, ONE
 verify forward over ``[slots, K+1]`` positions
-(:func:`.pool.make_verify_window`) lands 1..K+1 tokens per lane — greedy
+(:func:`.pool.make_paged_verify_window`) lands 1..K+1 tokens per lane — greedy
 outputs token-exact vs plain decode, sampled outputs distribution-exact
 (Leviathan accept/resample).  Cycles with no draft fall back to the decode
 window, so non-repetitive workloads never regress.
@@ -32,7 +32,7 @@ Tree speculation (``draft_model=``): an on-device draft model — by default a
 truncated-layer head of the served model (:func:`.spec_exec.build_draft`) —
 drafts a ``1 + tree_width * tree_depth``-node token tree per lane in ONE
 small jitted forward (:func:`.spec_exec.make_draft_forward`), and a tree
-verify window (:func:`.pool.make_tree_verify_window`) scores all nodes under
+verify window (:func:`.pool.make_paged_tree_verify_window`) scores all nodes under
 the ancestor attention mask and commits the best root-to-leaf path:
 Leviathan acceptance generalized to branch selection, so outputs stay
 token-exact (greedy) / distribution-exact (sampled).  Unlike n-gram lookup,
@@ -40,11 +40,11 @@ the draft model speculates on *non-repetitive* text; the compiled budget
 grows by exactly two shapes: ``draft_forward`` and ``tree_verify_window``
 (which replaces the linear verify window).  See ``docs/usage/serving.md``.
 
-Prefix caching (:mod:`.prefix_cache`): freshly prefilled full chunks are
-retained as device KV slabs in a radix tree keyed by the token prefix; later
-requests sharing that prefix replay the slabs through one
-``dynamic_update_slice`` per chunk instead of re-running prefill.  Outputs
-are token-exact with the cache on or off — only redundant prefill compute is
+Prefix caching (:mod:`.prefix_cache`): the pages of freshly prefilled full
+chunks are retained (one allocator reference each) in a radix tree keyed by
+the token prefix; later requests sharing that prefix alias the pages through
+their block tables instead of re-running prefill, with no copy.  Outputs are
+token-exact with the cache on or off — only redundant prefill compute is
 skipped; the decode path never changes.
 """
 
@@ -62,7 +62,7 @@ import numpy as np
 
 from ..logging import get_logger
 from ..models.generation import GenerationConfig
-from ..models.transformer import KVCache, Transformer
+from ..models.transformer import Transformer
 from ..telemetry import (
     CostTable,
     MetricsRegistry,
@@ -82,20 +82,14 @@ from .pool import (
     ServeShardings,
     audit_donation,
     jit_cache_sizes,
-    make_copy_chunk,
     make_copy_page,
-    make_decode_window,
-    make_insert,
     make_lane_install,
     make_paged_decode_window,
     make_paged_prefill_chunk,
     make_paged_tree_verify_window,
     make_paged_verify_window,
-    make_prefill_chunk,
     make_promote_install,
     make_spill_extract,
-    make_tree_verify_window,
-    make_verify_window,
     plan_chunks,
 )
 from .prefix_cache import PrefixCache
@@ -175,19 +169,28 @@ def _refuse_unported(cfg, *, kv_dtype, speculate_k, draft_model, decode_kernel,
 
 
 class ServingEngine:
-    """Serve many requests through one slot pool with in-flight admission.
+    """Serve many requests through one page pool with in-flight admission.
+
+    The KV pool is a refcounted *page pool* with per-lane block tables
+    (:mod:`.paging`): pages are allocated as lanes grow, prefix-cache hits
+    alias shared pages with ZERO copies (copy-on-write only on a shared tail
+    page), and page pressure preempts the youngest lane, which requeues for
+    replay through the prefix cache.  Greedy outputs are token-identical to
+    ``generate``, after preemption too; a preempted *sampled* lane resumes on
+    a restarted RNG stream (re-seeded from the request id at install):
+    distribution-correct, not sample-exact, as under speculative decoding.
 
     Parameters
     ----------
     model, params: the flagship ``Transformer`` and its (HBM-resident) params.
-    num_slots: concurrent request lanes in the KV pool.
-    max_len: per-slot KV capacity (default ``config.max_seq_len``).  A request
+    num_slots: concurrent request lanes (rows of the block table).
+    max_len: per-lane KV capacity (default ``config.max_seq_len``).  A request
         needs ``prompt_len + max_new_tokens + decode_window <= max_len``.
     prefill_buckets: fixed chunk sizes for chunked prefill — one compiled
         prefill shape per bucket.  Defaults to ``(128, 512)`` clipped to
         ``max_prompt_len``.
-    max_prompt_len: scratch-cache capacity (longest admissible prompt);
-        defaults to ``max_len``.
+    max_prompt_len: upper bound of the prefill buckets; defaults to
+        ``max_len``.
     prefill_token_budget: max prefill tokens charged per engine step (bounds
         decode-latency jitter while prompts stream in); default: the largest
         bucket.
@@ -200,7 +203,7 @@ class ServingEngine:
         (:mod:`.prefix_cache`); ``0``/``None`` disables it.  Requests opt out
         per-request via ``submit(..., cache_prefix=False)``.
     prefix_host_mb: byte budget (MiB) for the host-RAM spill tier behind the
-        device prefix cache (paged mode only).  Device-tier evictions demote
+        device prefix cache.  Device-tier evictions demote
         their pages host-side via an async D2H gather instead of dropping
         them; a later hit on a spilled prefix promotes it back with an H2D
         scatter-install enqueued BEHIND the in-flight decode window, charging
@@ -244,40 +247,29 @@ class ServingEngine:
         (``/metrics``, ``/healthz``, ``/debug/flight``, ``/debug/stacks``)
         on this port; ``0`` binds an ephemeral port, ``None`` defers to
         ``ATPU_METRICS_PORT`` (off when unset).
-    paged: run the KV pool as a refcounted *page pool* with per-lane block
-        tables (:mod:`.paging`) instead of per-lane ``max_len`` slabs.  Pages
-        are allocated as lanes grow, prefix-cache hits alias shared pages with
-        ZERO copies (copy-on-write only on a shared tail page), and page
-        pressure preempts the youngest lane — it releases its pages and
-        requeues for replay through the prefix cache.  Greedy outputs are
-        token-identical paged on/off (the gathered view is exactly the slab
-        shape, so the attention program is bitwise the same; keep
-        ``max_prompt_len == max_len``, the default, for strict identity) and
-        greedy replay after preemption is token-exact; a preempted *sampled*
-        lane resumes on a restarted RNG stream (the lane RNG re-seeds from
-        the request id at install), so its continuation is
-        distribution-correct but not sample-exact — the same contract as
-        speculative decoding.
-    page_size: tokens per KV page (paged mode).  Must divide every prefill
+    paged: accepted for compatibility only and selects nothing.  ``True``
+        (what the benchmark's workload files pass) is a no-op; ``False``
+        raises — the per-lane slab pool it used to select was removed.
+    page_size: tokens per KV page.  Must divide every prefill
         bucket and ``max_len``; default ``gcd(prefill_buckets)`` — the prefix
         cache's chunk granularity.
-    num_pages: physical pages in the pool (paged mode), the knob that trades
+    num_pages: physical pages in the pool, the knob that trades
         HBM for concurrency: lanes only consume pages they actually use, so
         ``num_pages`` can be far below ``num_slots * max_len / page_size``
         under mixed-length traffic.  Default is the no-preemption worst case
         (``num_slots * max_len / page_size + 1``).
     decode_kernel: attention program for the paged decode/verify windows.
-        ``"xla"`` (default) gathers each lane's pages into a slab-width view
-        and runs the legacy attention einsum — bitwise token-identical with
-        the slab pool.  ``"pallas"`` reads KV pages *in place* through the
+        ``"xla"`` (default) gathers each lane's pages into a ``max_len``-wide
+        view and runs the model's attention einsum over it (the program
+        ``generate`` runs).  ``"pallas"`` reads KV pages *in place* through the
         block tables (:mod:`accelerate_tpu.ops.paged_attention`): no gather
         temporary, no padding reads — one grid program per (lane, kv-head)
         with an online softmax over each lane's live pages only.  Same
         compiled-shape budget (the kernel replaces the decode executables, it
         does not add any); greedy outputs are token-identical in practice
-        (asserted by tests and ``bench_inference.py --kernel-ab``) but the
-        online softmax is not bitwise the full-view softmax.  Requires
-        ``paged=True``; full-causal rope/learned models only.
+        (asserted by ``tests/test_paged_attention.py``) but the online
+        softmax is not bitwise the full-view softmax.  Full-causal
+        rope/learned models only.
     prefill_kernel: attention program for the paged *prefill chunk*
         executables.  ``None`` (default) follows the resolved
         ``decode_kernel`` — a pool that decodes through the Pallas kernel
@@ -290,10 +282,10 @@ class ServingEngine:
         forces the gather/scatter reference path (the only arm under tp>1,
         and the bisection knob when a prefill divergence is suspected).  Same
         compiled-shape budget either way (the kernel replaces the per-bucket
-        prefill executables' attention, it adds none).  Requires
-        ``paged=True``; full-causal rope/learned models only.
+        prefill executables' attention, it adds none).  Full-causal
+        rope/learned models only.
     interleave_prefill: dispatch each step's prefill chunks *behind* the
-        decode window instead of ahead of it (requires ``paged=True``).
+        decode window instead of ahead of it.
         The decode window is issued first and its tokens stay in flight
         (``async_depth=1``) while the host schedules and enqueues the cycle's
         chunks back-to-back behind it; the scheduler charges decode tokens
@@ -305,28 +297,26 @@ class ServingEngine:
         prompt streams.  Greedy/sampled outputs are token-identical to the
         default prefill-ahead ordering (lane RNG folds from the request id,
         never from arrival order).
-    kv_dtype: KV page storage format (requires ``paged=True``).  ``None``
+    kv_dtype: KV page storage format.  ``None``
         keeps the model dtype (token-identical); ``"bf16"`` stores bf16;
         ``"int8"`` / ``"fp8"`` quantize pages with per-(page, kv-head) f32
         scales written at scatter time and dequantized at attention — about
         4x (fp32 models) / 2x (bf16) less KV HBM per token, so the same pool
         bytes hold proportionally more concurrent lanes.  Quantized KV is
         lossy: outputs track the native path within a logit tolerance
-        (``serve/kv_quant_error`` gauges the per-cycle round-trip error;
-        ``--kernel-ab`` hard-enforces a max-logit-divergence threshold).
+        (``serve/kv_quant_error`` gauges the per-cycle round-trip error).
     mesh: a named :class:`jax.sharding.Mesh` for tensor-parallel serving
         (``None``, the default, keeps single-chip behavior byte-for-byte).
         With a ``tp_axis`` of size > 1: params shard by the
         :data:`~accelerate_tpu.parallel.tensor_parallel.DEFAULT_TP_RULES`,
-        the KV pool (slab or paged) shards on the kv-head axis, and every
+        the page pool shards on the kv-head axis, and every
         window executable compiles with explicit in/out shardings
         (:class:`~accelerate_tpu.serving.pool.ServeShardings`) — one model
         spans the axis while block tables, scheduler, prefix-cache radix
         tree, and telemetry stay host-side and replicated.  Greedy outputs
-        are token-identical to tp=1 at every (kernel, kv_dtype, paged)
+        are token-identical to tp=1 at every (kernel, kv_dtype)
         combination and the compiled-executable budget is unchanged; both
-        are pinned by ``tests/test_serving_mesh.py`` and
-        ``bench_inference.py --task serve --tp-ab``.  ``decode_kernel=
+        are pinned by ``tests/test_serving_mesh.py``.  ``decode_kernel=
         "pallas"`` is refused at construction under tp > 1 (the Pallas grid
         reads whole head tiles of an unsharded pool; the XLA einsum
         partitions head-parallel) — it never silently becomes ``"xla"``.
@@ -343,7 +333,7 @@ class ServingEngine:
         sampling mode; the observable differences are lag semantics only: a
         lane that hits EOS at window N is retired one cycle later (it may
         execute one extra masked window whose tokens are discarded — written
-        to the null page in paged mode, overwritten-before-read in the slab),
+        to the null page),
         ``finish_step`` lands one step later, and ``cancel`` of a running
         lane drops the in-flight window's tokens.  Speculative cycles
         synchronize on the previous window before dispatching (drafts and the
@@ -391,7 +381,7 @@ class ServingEngine:
         tree_width: int = 1,
         tree_depth: Optional[int] = None,
         draft_ctx: int = 64,
-        paged: bool = False,
+        paged: bool = True,
         page_size: Optional[int] = None,
         num_pages: Optional[int] = None,
         decode_kernel: str = "xla",
@@ -463,7 +453,11 @@ class ServingEngine:
         #: can reach it and any forced flush drains oldest-first
         self._prev_handle: Optional[Readback] = None
 
-        self.paged = bool(paged)
+        if not paged:
+            raise ValueError(
+                "paged=False: the per-lane slab KV pool was removed; the page "
+                "pool is the engine's only pool (drop the argument)"
+            )
         if decode_kernel not in ("xla", "pallas"):
             raise ValueError(
                 f"decode_kernel must be 'xla' or 'pallas', got {decode_kernel!r}"
@@ -473,26 +467,10 @@ class ServingEngine:
                 f"prefill_kernel must be None, 'xla' or 'pallas', "
                 f"got {prefill_kernel!r}"
             )
-        if (decode_kernel != "xla" or prefill_kernel == "pallas"
-                or kv_dtype is not None) and not self.paged:
-            raise ValueError(
-                "decode_kernel/prefill_kernel/kv_dtype act on the paged KV "
-                "pool; pass paged=True"
-            )
         self.interleave_prefill = bool(interleave_prefill)
-        if self.interleave_prefill and not self.paged:
-            raise ValueError(
-                "interleave_prefill needs the paged pool (the legacy batch-1 "
-                "prefill scratch admits one request at a time); pass paged=True"
-            )
         if role not in ("prefill", "decode", "both"):
             raise ValueError(
                 f"role must be 'prefill', 'decode' or 'both', got {role!r}"
-            )
-        if role != "both" and not self.paged:
-            raise ValueError(
-                "disaggregated roles move lanes between replicas as KV "
-                "pages; role='prefill'/'decode' requires paged=True"
             )
         #: "prefill" runs chunked prefill only — freshly installed lanes
         #: never dispatch a decode window here, they wait for the router's
@@ -515,7 +493,7 @@ class ServingEngine:
         # prefill follows the decode kernel unless forced: a pool decoding
         # through Pallas prefills through its chunk-wide twin
         if prefill_kernel is None:
-            prefill_kernel = decode_kernel if self.paged else "xla"
+            prefill_kernel = decode_kernel
         self.prefill_kernel = resolve_paged_kernel(
             prefill_kernel, mesh, tp_axis, role="prefill"
         )
@@ -525,8 +503,8 @@ class ServingEngine:
         # "direct" windows thread the page pool through the model
         # (PagedKVCache) instead of the gather/scatter sandwich: required for
         # in-place Pallas attention and for scale-aware quantized writes.
-        # Native-dtype XLA stays on the PR-6 gathered path — bitwise identity
-        # with the slab pool, plus the live-page gather mask.
+        # Native-dtype XLA stays on the gathered path: the model's own
+        # attention over a max_len-wide view, plus the live-page gather mask.
         self._direct = self.quantized or decode_kernel == "pallas"
         # the prefill-side twin of the flag: quantized pools and the flash
         # prefill kernel both need the chunk forward to own the page writes
@@ -570,26 +548,25 @@ class ServingEngine:
             self.tree.nodes if self.tree is not None else self.speculate_k + 1
         )
         self._spec_any = self.tree is not None or self.speculate_k > 0
-        if self.paged:
-            self.page_size = int(
-                page_size if page_size is not None
-                else math.gcd(*self.buckets) if len(self.buckets) > 1
-                else self.buckets[0]
-            )
-            for b in self.buckets:
-                if b % self.page_size != 0:
-                    raise ValueError(
-                        f"page_size {self.page_size} must divide every prefill "
-                        f"bucket, got {self.buckets}"
-                    )
-            if self.max_len % self.page_size != 0:
+        self.page_size = int(
+            page_size if page_size is not None
+            else math.gcd(*self.buckets) if len(self.buckets) > 1
+            else self.buckets[0]
+        )
+        for b in self.buckets:
+            if b % self.page_size != 0:
                 raise ValueError(
-                    f"page_size {self.page_size} must divide max_len {self.max_len}"
+                    f"page_size {self.page_size} must divide every prefill "
+                    f"bucket, got {self.buckets}"
                 )
-            self.num_pages = int(
-                num_pages if num_pages is not None
-                else self.num_slots * (self.max_len // self.page_size) + 1
+        if self.max_len % self.page_size != 0:
+            raise ValueError(
+                f"page_size {self.page_size} must divide max_len {self.max_len}"
             )
+        self.num_pages = int(
+            num_pages if num_pages is not None
+            else self.num_slots * (self.max_len // self.page_size) + 1
+        )
         # ------------------------------------------------------ mesh / tp
         self.mesh = mesh
         self.tp_axis = tp_axis
@@ -626,29 +603,14 @@ class ServingEngine:
             self.tp_degree = 1
             self._shardings = None
         self.metrics = registry if registry is not None else get_registry()
-        # device state: per-lane-index slab pool + batch-1 prefill scratch
-        # (legacy), or the shared page pool + host block tables (paged — no
-        # scratch at all: prefill gathers the lane's own view, shared prefix
-        # pages included, and scatters freshly written pages back)
-        if self.paged:
-            self.pool = None
-            self.scratch = None
-            self.kv = PagedKVPool(
-                cfg, self.num_slots, self.max_len, self.page_size,
-                self.num_pages, registry=self.metrics, kv_dtype=kv_dtype,
-                mesh=mesh, tp_axis=tp_axis,
-            )
-        else:
-            self.pool = KVCache.create(cfg, self.num_slots, self.max_len, per_lane_index=True)
-            self.scratch = KVCache.create(cfg, 1, self.max_prompt_len)
-            self.kv = None
-            if self._shardings is not None:
-                # the slab pool and scratch carry kv heads on dim 3, exactly
-                # like the page arrays — place them before the first compile
-                self.pool = jax.device_put(self.pool, self._shardings.cache())
-                self.scratch = jax.device_put(
-                    self.scratch, self._shardings.cache()
-                )
+        # device state: the shared page pool + host block tables.  There is
+        # no prefill scratch: a chunk gathers the lane's own view, shared
+        # prefix pages included, and writes freshly filled pages back
+        self.kv = PagedKVPool(
+            cfg, self.num_slots, self.max_len, self.page_size,
+            self.num_pages, registry=self.metrics, kv_dtype=kv_dtype,
+            mesh=mesh, tp_axis=tp_axis,
+        )
         self.tracer = get_tracer()
         # Forensics + cost accounting (docs/usage/observability.md): request
         # lifecycle events land in the process flight recorder, per-executable
@@ -674,9 +636,10 @@ class ServingEngine:
         # flash kernel under prefill_kernel="pallas", the XLA reference
         # otherwise — either way the page writes go through the same insert
         # path, so the written KV is identical across kernels.
-        if self.paged and self._direct:
-            kmodel = Transformer(dataclasses.replace(cfg, paged_kernel=decode_kernel))
-        if self.paged and self._prefill_direct:
+        wmodel = pmodel = model
+        if self._direct:
+            wmodel = Transformer(dataclasses.replace(cfg, paged_kernel=decode_kernel))
+        if self._prefill_direct:
             pmodel = Transformer(dataclasses.replace(
                 cfg,
                 paged_kernel=("flash_prefill" if self.prefill_kernel == "pallas"
@@ -684,45 +647,31 @@ class ServingEngine:
             ))
         # budget=1 per executable: the engine's whole design promises exactly
         # one compiled shape each — any second signature is a bug worth a warning
-        if self.paged and self._direct:
+        decode_fn = make_paged_decode_window(
+            wmodel, self.window, direct=self._direct, shardings=self._shardings)
+        if self._direct:
             # nested watchdog: serve/paged_attn accounts the in-place paged
             # attention executable itself (budget 1 — the kernel REPLACES the
             # decode executable, it must never add shapes); serve/decode_window
             # keeps its usual accounting on top.  Attribute forwarding lets
             # jit_cache_sizes read straight through both layers.
             decode_fn = RecompileWatchdog(
-                make_paged_decode_window(kmodel, self.window, direct=True,
-                                         shardings=self._shardings),
-                name="serve/paged_attn", budget=1, registry=self.metrics,
+                decode_fn, name="serve/paged_attn", budget=1,
+                registry=self.metrics,
             )
-        elif self.paged:
-            decode_fn = make_paged_decode_window(model, self.window,
-                                                 shardings=self._shardings)
-        else:
-            decode_fn = make_decode_window(model, self.window,
-                                           shardings=self._shardings)
         self._decode = RecompileWatchdog(
             decode_fn, name="serve/decode_window", budget=1, registry=self.metrics,
         )
         self._prefill = {
             b: RecompileWatchdog(
                 make_paged_prefill_chunk(
-                    pmodel if self._prefill_direct else model, b,
-                    self.page_size, direct=self._prefill_direct,
+                    pmodel, b, self.page_size, direct=self._prefill_direct,
                     shardings=self._shardings,
-                ) if self.paged
-                else make_prefill_chunk(model, b, shardings=self._shardings),
+                ),
                 name=f"serve/prefill_{b}", budget=1, registry=self.metrics,
             )
             for b in self.buckets
         }
-        self._insert = (
-            None if self.paged
-            else RecompileWatchdog(
-                make_insert(shardings=self._shardings), name="serve/insert",
-                budget=1, registry=self.metrics
-            )
-        )
         self._lane_install = RecompileWatchdog(
             make_lane_install(shardings=self._shardings),
             name="serve/lane_install", budget=1, registry=self.metrics,
@@ -732,13 +681,9 @@ class ServingEngine:
             # budget grows by exactly {draft_forward, tree_verify_window}
             self._verify = RecompileWatchdog(
                 make_paged_tree_verify_window(
-                    kmodel, self.tree, direct=True, shardings=self._shardings,
-                ) if (self.paged and self._direct)
-                else make_paged_tree_verify_window(model, self.tree,
-                                                   shardings=self._shardings)
-                if self.paged
-                else make_tree_verify_window(model, self.tree,
-                                             shardings=self._shardings),
+                    wmodel, self.tree, direct=self._direct,
+                    shardings=self._shardings,
+                ),
                 name="serve/tree_verify_window", budget=1,
                 registry=self.metrics,
             )
@@ -766,14 +711,9 @@ class ServingEngine:
         elif self.speculate_k:
             self._verify = RecompileWatchdog(
                 make_paged_verify_window(
-                    kmodel, self.speculate_k, direct=True,
+                    wmodel, self.speculate_k, direct=self._direct,
                     shardings=self._shardings,
-                ) if (self.paged and self._direct)
-                else make_paged_verify_window(model, self.speculate_k,
-                                              shardings=self._shardings)
-                if self.paged
-                else make_verify_window(model, self.speculate_k,
-                                        shardings=self._shardings),
+                ),
                 name="serve/verify_window", budget=1, registry=self.metrics,
             )
             self._draft_fwd = None
@@ -786,21 +726,16 @@ class ServingEngine:
             self._draft_window = None
             self._ngram = None
             self.drafter = None
-        self._copy_page = (
-            RecompileWatchdog(
-                make_copy_page(shardings=self._shardings),
-                name="serve/copy_page", budget=1,
-                registry=self.metrics,
-            )
-            if self.paged
-            else None
+        self._copy_page = RecompileWatchdog(
+            make_copy_page(shardings=self._shardings),
+            name="serve/copy_page", budget=1, registry=self.metrics,
         )
         self.prefix_host_bytes = int((prefix_host_mb or 0.0) * 2**20)
         prefix_disk_bytes = int((prefix_disk_mb or 0.0) * 2**20)
-        if self.prefix_host_bytes and not (self.paged and prefix_cache_mb):
+        if self.prefix_host_bytes and not prefix_cache_mb:
             raise ValueError(
-                "prefix_host_mb spills prefix *pages*; it requires paged=True "
-                "and an enabled prefix cache (prefix_cache_mb > 0)"
+                "prefix_host_mb spills the prefix cache's pages; it requires "
+                "an enabled prefix cache (prefix_cache_mb > 0)"
             )
         if prefix_disk_bytes and not self.prefix_host_bytes:
             raise ValueError(
@@ -831,29 +766,14 @@ class ServingEngine:
         if prefix_cache_mb:
             self.prefix_cache: Optional[PrefixCache] = PrefixCache(
                 int(prefix_cache_mb * 2**20), registry=self.metrics,
-                on_evict=self._on_prefix_evict if self.paged else None,
+                on_evict=self._on_prefix_evict,
                 host_capacity_bytes=self.prefix_host_bytes,
                 spill=self._spill_node if self.prefix_host_bytes else None,
                 disk_capacity_bytes=prefix_disk_bytes,
                 disk_dir=prefix_disk_dir,
             )
-            # paged hits alias pages through the block table — no copy
-            # executables exist; legacy replays slabs through one
-            # dynamic_update_slice shape per bucket
-            self._copy = (
-                {}
-                if self.paged
-                else {
-                    b: RecompileWatchdog(
-                        make_copy_chunk(b, shardings=self._shardings),
-                        name=f"serve/copy_{b}", budget=1, registry=self.metrics,
-                    )
-                    for b in self.buckets
-                }
-            )
         else:
             self.prefix_cache = None
-            self._copy = {}
 
         self.scheduler = Scheduler(
             self.buckets,
@@ -883,12 +803,11 @@ class ServingEngine:
         self._top_k = np.zeros(n, np.int32)
         self._top_p = np.ones(n, np.float32)
         self._rngs = np.zeros((n, 2), np.uint32)
-        # host mirror of each lane's KV write index (paged mode): install sets
+        # host mirror of each lane's KV write index: install sets
         # it to prompt_len - 1, decode/verify advance it by exactly what the
         # device committed — integer arithmetic, so the mirror is always exact
         self._lane_len = np.zeros(n, np.int32)
-        #: high-water mark of simultaneously active lanes (the paged-vs-slab
-        #: concurrency headline; tracked in both modes for A/B benches)
+        #: high-water mark of simultaneously active lanes
         self.peak_active_lanes = 0
         self._base_rng = jax.random.PRNGKey(rng_seed)
         # slots held for requests mid-prefill (one per open prefill; a set
@@ -1089,8 +1008,7 @@ class ServingEngine:
                 "serve/kv_quant_error",
                 help="max abs KV round-trip quantization error of the values "
                      "written this cycle (an upper-bound logit-divergence "
-                     "proxy; the --kernel-ab bench measures true logit "
-                     "deltas) — only published under quantized kv_dtype",
+                     "proxy) — only published under quantized kv_dtype",
             )
             if self.quantized
             else None
@@ -1253,14 +1171,13 @@ class ServingEngine:
                 retriable=False,
             )
         # the chunk plan pads the final chunk up to its bucket; that padding
-        # must still fit the prefill write target (the scratch cache, or the
-        # paged lane view) or the tail writes would silently clamp/corrupt
+        # must still fit the lane's view or the tail writes would silently
+        # clamp/corrupt
         padded = sum(b for b, _ in plan_chunks(prompt.size, self.buckets))
-        cap = self.max_len if self.paged else self.max_prompt_len
-        if padded > cap:
+        if padded > self.max_len:
             raise AdmissionError(
                 f"prompt {prompt.size} pads to {padded} prefill tokens under "
-                f"buckets {self.buckets}, exceeding capacity {cap}",
+                f"buckets {self.buckets}, exceeding capacity {self.max_len}",
                 queue_depth=self.scheduler.queue_depth,
                 retriable=False,
             )
@@ -1311,7 +1228,7 @@ class ServingEngine:
 
         Queued requests are dropped before burning any prefill budget; a
         RUNNING lane is frozen immediately — it stops decoding this very
-        step, its slot frees for the next admission, and in paged mode every
+        step, its slot frees for the next admission, and every
         KV page it held returns to the allocator (shared prefix pages survive
         under the cache's own references).  Tokens already streamed stay
         streamed.  Returns True when the request was cancelled (state becomes
@@ -1490,7 +1407,7 @@ class ServingEngine:
                 self.recorder.record(
                     "serve/revive_fetch_failed", error=repr(exc),
                 )
-            if self.paged and hd.deferred_pages:
+            if hd.deferred_pages:
                 hd.settle(self.kv.allocator)
         self._stale_handles.clear()
         self._pending_prefill_qerr.clear()
@@ -1618,23 +1535,14 @@ class ServingEngine:
                     slot = self._next_free_slot()
                     if slot is None:
                         break
-                    if self.paged and not self._admission_pages_ok(
-                            self.scheduler.queue[0]):
+                    if not self._admission_pages_ok(self.scheduler.queue[0]):
                         break
                     self.scheduler.start_next(slot)
                     self._reserved_slots.add(slot)
-                    if not self.paged:
-                        # scratch restarts at position 0; stale KV beyond each
-                        # new write is unreachable (causal mask == valid-entry
-                        # mask)
-                        self.scratch = self.scratch.replace(
-                            index=self._put(jnp.zeros((), jnp.int32))
-                        )
             if not self.scheduler.prefills:
                 return
             took = self.scheduler.take_chunk(
-                budget,
-                ready=self._ensure_prefill_pages if self.paged else None,
+                budget, ready=self._ensure_prefill_pages,
             )
             if took is None:
                 return  # budget spent or page pressure: retry next step
@@ -1649,7 +1557,7 @@ class ServingEngine:
             ptoks = req.prefill_tokens
             if cached:
                 node = req.cache_nodes[req.next_chunk - 1]
-                spilled = self.paged and node.tier != "device"
+                spilled = node.tier != "device"
                 if spilled and not self._promote_node(req, node, bucket):
                     # degraded promotion (fault, page pressure, or a torn
                     # payload): fall through to a plain cache miss — the chunk
@@ -1661,20 +1569,10 @@ class ServingEngine:
                         "serve/promote_degraded", rid=req.rid, bucket=bucket,
                         step=self._step_count,
                     )
-                elif self.paged:
-                    if not spilled:
-                        # the zero-copy hit: alias the node's physical pages
-                        # into this lane's block table — no device work at all
-                        self.kv.lane_append_shared(req.slot, node.pages)
-                else:
-                    # replay the retained slab: one dynamic_update_slice at the
-                    # scratch index, zero budget charged (no forward pass ran)
-                    self.cost_table.capture(
-                        f"serve/copy_{bucket}", self._copy[bucket],
-                        (self.scratch, node.k, node.v),
-                    )
-                    with self.tracer.span("serve/copy_chunk", bucket=bucket, start=start):
-                        self.scratch = self._copy[bucket](self.scratch, node.k, node.v)
+                elif not spilled:
+                    # the zero-copy hit: alias the node's physical pages
+                    # into this lane's block table — no device work at all
+                    self.kv.lane_append_shared(req.slot, node.pages)
                 if cached:
                     self._bump("prefix_hit_tokens", valid)
                     if spilled:
@@ -1682,21 +1580,7 @@ class ServingEngine:
             if not cached:
                 chunk = np.zeros(bucket, np.int32)
                 chunk[:valid] = ptoks[start:start + valid]
-                if self.paged:
-                    self._paged_prefill_chunk(req, bucket, valid, chunk, start)
-                else:
-                    args = (self.params, chunk[None], self.scratch)
-                    if self._routed:
-                        args += (self._put(jnp.int32(valid)),)
-                    self.cost_table.capture(
-                        f"serve/prefill_{bucket}", self._prefill[bucket], args,
-                    )
-                    with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid):
-                        out = self._prefill[bucket](*args)
-                    if self._routed:
-                        out, counts = out
-                        self._pending_moe_counts.append(counts)
-                    self.scratch = out
+                self._paged_prefill_chunk(req, bucket, valid, chunk, start)
                 budget -= bucket
                 self._bump("prefill_chunks")
                 if self.interleave_prefill and self._cycle_decode_tokens:
@@ -1720,9 +1604,9 @@ class ServingEngine:
             if done is not None:
                 self._install(done)
 
-    # ---------------------------------------------------------- paged admission
+    # ---------------------------------------------------------- page admission
     def _on_prefix_evict(self, node) -> None:
-        """Prefix-cache eviction hook (paged mode): drop the cache's allocator
+        """Prefix-cache eviction hook: drop the cache's allocator
         reference on each retained page.  Pages still aliased by running lanes
         survive; unreferenced ones return to the free list.  Spilled nodes
         arrive here with ``pages = None`` — their refs were already dropped at
@@ -2016,11 +1900,9 @@ class ServingEngine:
                         ptoks: np.ndarray) -> None:
         """Retain a freshly prefilled FULL chunk in the prefix cache.
 
-        Legacy: the slab slice ``scratch[:, :, start:start+bucket]`` is an
-        eager device-side copy (a handful of static offsets per geometry,
-        never a per-request shape).  Paged: zero copies — the cache node
-        records the lane's own physical page ids and takes one allocator
-        reference per page, so the KV outlives the lane.  Padded final chunks
+        Zero copies — the cache node records the lane's own physical page
+        ids and takes one allocator reference per page, so the KV outlives
+        the lane.  Padded final chunks
         are skipped — their KV past ``valid`` is garbage — and once one chunk
         fails to retain (budget or collision) the rest of the request's chain
         is abandoned: a child without its ancestors could never be matched.
@@ -2028,32 +1910,17 @@ class ServingEngine:
         if valid != bucket or req.cache_chain_broken:
             return
         parent = req.cache_nodes[-1] if req.cache_nodes else None
-        if self.paged:
-            npg = bucket // self.page_size
-            ids = self.kv.chunk_ids(req.slot, start // self.page_size, npg)
-            node = self.prefix_cache.insert_pages(
-                parent, ptoks[start:start + bucket], ids,
-                nbytes=self.kv.chunk_bytes(npg),
-            )
-            if node is not None and node.pages == tuple(ids):
-                # a NEW node was created: the cache holds its own reference
-                # per page (dropped by _on_prefix_evict); a deduped re-insert
-                # keeps the resident node's pages and refs untouched
-                self.kv.allocator.ref(ids)
-        else:
-            k = self.scratch.k[:, :, start:start + bucket]
-            v = self.scratch.v[:, :, start:start + bucket]
-            if bucket == self.scratch.k.shape[2]:
-                # a full-extent slice can alias the scratch buffer itself
-                # (XLA elides the identity slice) — the cache must own a real
-                # copy, or the next hit's copy executable sees its own donated
-                # scratch arrive again as the node argument and aborts with
-                # `f(donate(a), a)`.  Only possible when a prefill bucket
-                # equals max_prompt_len; strict sub-slices always copy.
-                k, v = jnp.copy(k), jnp.copy(v)
-            node = self.prefix_cache.insert(
-                parent, ptoks[start:start + bucket], k, v,
-            )
+        npg = bucket // self.page_size
+        ids = self.kv.chunk_ids(req.slot, start // self.page_size, npg)
+        node = self.prefix_cache.insert_pages(
+            parent, ptoks[start:start + bucket], ids,
+            nbytes=self.kv.chunk_bytes(npg),
+        )
+        if node is not None and node.pages == tuple(ids):
+            # a NEW node was created: the cache holds its own reference
+            # per page (dropped by _on_prefix_evict); a deduped re-insert
+            # keeps the resident node's pages and refs untouched
+            self.kv.allocator.ref(ids)
         if node is None:
             req.cache_chain_broken = True
         else:
@@ -2092,30 +1959,15 @@ class ServingEngine:
             return
 
     def _install(self, req: Request) -> None:
-        """Hand a fully prefilled request its lane.  Legacy: one
-        ``dynamic_update_slice`` of the scratch slab into the pool.  Paged:
-        the lane's pages ARE the prefilled KV — nothing moves; only the
-        shared tail page (if any) is copy-on-write duplicated before decode
-        starts writing at ``plen - 1``."""
+        """Hand a fully prefilled request its lane.  The lane's pages ARE
+        the prefilled KV — nothing moves; only the shared tail page (if any)
+        is copy-on-write duplicated before decode starts writing at
+        ``plen - 1``."""
         s = req.slot
         ptoks = req.prefill_tokens
         plen = len(ptoks)
-        if self.paged:
-            self._cow_tail_page(s, plen)
-            self._lane_len[s] = plen - 1
-        else:
-            slot_i = self._put(jnp.int32(s))
-            length_i = self._put(jnp.int32(plen - 1))
-            self.cost_table.capture(
-                "serve/insert", self._insert,
-                (self.pool, self.scratch.k, self.scratch.v, slot_i, length_i),
-            )
-            # the in-flight window (if any) consumes the current pool handle;
-            # park it until drain rather than dropping it with the rebind
-            self._stale_handles.append(self.pool)
-            self.pool = self._insert(
-                self.pool, self.scratch.k, self.scratch.v, slot_i, length_i,
-            )
+        self._cow_tail_page(s, plen)
+        self._lane_len[s] = plen - 1
         self.recorder.record(
             "serve/install", rid=req.rid, slot=s, step=self._step_count,
             prompt_len=plen,
@@ -2164,8 +2016,9 @@ class ServingEngine:
         self._slot_ever_used[s] = True
         self._slot_req[s] = req
         self._reserved_slots.discard(s)
-        # the slot owns a full KV copy now; the radix nodes this request read
-        # or populated can be evicted without affecting it
+        # the lane's block table holds its own reference on every page now;
+        # the radix nodes this request read or populated can be evicted
+        # without affecting it
         if self.prefix_cache is not None and req.cache_nodes:
             self.prefix_cache.release(req.cache_nodes)
             req.cache_nodes = []
@@ -2213,23 +2066,20 @@ class ServingEngine:
         inflight = self._inflight
         if inflight is not None and inflight.lane_live(slot):
             self._mask_stale = True
-            if self.paged:
-                inflight.deferred_pages.extend(self.kv.lane_detach(slot))
+            inflight.deferred_pages.extend(self.kv.lane_detach(slot))
         else:
             # no window holds this lane: pages free immediately, and the
             # device mirror only needs its active bit dropped (the dead
             # lane's pending/rng entries are masked out until reinstall)
             self._mask_stale = True
-            if self.paged:
-                freed = self.kv.lane_release(slot)
+            freed = self.kv.lane_release(slot)
         self._active[slot] = False
         self._slot_req[slot] = None
         if self._ngram is not None:
             self._ngram.retire(slot)
         if self._draft_window is not None:
             self._draft_window.retire(slot)
-        if self.paged:
-            self._lane_len[slot] = 0
+        self._lane_len[slot] = 0
         return freed
 
     def _free(self, slot: int, req: Request) -> None:
@@ -2320,14 +2170,13 @@ class ServingEngine:
         if not self._active.any():
             self._drain_inflight()
             return None
-        if self.paged:
-            # map pages for the widest pass this cycle could run (the same
-            # span the admission check reserved headroom for); this may
-            # preempt the youngest lane under pressure, so re-check occupancy
-            self._ensure_decode_capacity(max(self.window, self._spec_span))
-            if not self._active.any():
-                self._drain_inflight()
-                return None
+        # map pages for the widest pass this cycle could run (the same
+        # span the admission check reserved headroom for); this may
+        # preempt the youngest lane under pressure, so re-check occupancy
+        self._ensure_decode_capacity(max(self.window, self._spec_span))
+        if not self._active.any():
+            self._drain_inflight()
+            return None
         n_occupied = int(self._active.sum())
         self.peak_active_lanes = max(self.peak_active_lanes, n_occupied)
         self._occupancy_gauge.set(n_occupied / self.num_slots)
@@ -2495,14 +2344,13 @@ class ServingEngine:
             )
             hd.prefill_qerrs = []
         if hd.kind == "verify":
-            if self.paged:
-                # the write-index mirror advances by what the device actually
-                # committed — but only for lanes still owned by the request
-                # the window was dispatched for (a cancelled lane's mirror
-                # was reset to 0 and must stay there)
-                for s in np.nonzero(hd.active)[0]:
-                    if hd.reqs[s] is not None and self._slot_req[s] is hd.reqs[s]:
-                        self._lane_len[s] += int(counts[s])
+            # the write-index mirror advances by what the device actually
+            # committed — but only for lanes still owned by the request
+            # the window was dispatched for (a cancelled lane's mirror
+            # was reset to 0 and must stay there)
+            for s in np.nonzero(hd.active)[0]:
+                if hd.reqs[s] is not None and self._slot_req[s] is hd.reqs[s]:
+                    self._lane_len[s] += int(counts[s])
             accepted = int(np.maximum(counts[hd.drafted] - 1, 0).sum())
             self._bump("spec_accepted", accepted)
             for s in np.nonzero(hd.drafted)[0]:
@@ -2524,7 +2372,7 @@ class ServingEngine:
             span["tokens"], span["lanes"] = self._emit(
                 toks, counts, mask=hd.active, reqs=hd.reqs, eos=hd.eos,
                 prefreed=hd.prefreed)
-        if self.paged and hd.deferred_pages:
+        if hd.deferred_pages:
             # fetch() above proved the window retired: its masked writes to
             # detached lanes' pages have landed, so the pages can recycle
             hd.settle(self.kv.allocator)
@@ -2560,7 +2408,7 @@ class ServingEngine:
         window still owns."""
         lanes = self._lane_arrays()
         qerr = None
-        if self.paged and self._direct:
+        if self._direct:
             kv = self.kv
             audit_donation(kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales)
             consumed = [kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales,
@@ -2577,7 +2425,7 @@ class ServingEngine:
                     (kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales, toks,
                      pending, rngs, qerr, *moe) = self._decode(*args)
             self._lane_len[self._active] += self.window
-        elif self.paged:
+        else:
             kv = self.kv
             audit_donation(kv.pages_k, kv.pages_v)
             consumed = [kv.pages_k, kv.pages_v, lanes[0], lanes[-1]]
@@ -2596,17 +2444,6 @@ class ServingEngine:
                     self.params, kv.pages_k, kv.pages_v, tables, index, *lanes
                 )
             self._lane_len[self._active] += self.window
-        else:
-            audit_donation(self.pool)
-            consumed = [self.pool, lanes[0], lanes[-1]]
-            if not self.cost_table.captured("serve/decode_window"):
-                self.cost_table.capture(
-                    "serve/decode_window", self._decode, (self.params, self.pool, *lanes)
-                )
-            with self.tracer.span("serve/decode_window", occupied=n_occupied):
-                self.pool, toks, pending, rngs, *moe = self._decode(
-                    self.params, self.pool, *lanes
-                )
         # the carried pending token / rng live on into the next cycle without
         # touching the host (the host pending mirror is refreshed by _emit)
         lanes[0], lanes[-1] = pending, rngs
@@ -2690,7 +2527,7 @@ class ServingEngine:
         self._draft_ms_hist.observe((time.perf_counter() - t0) * 1e3)
         n_drafted = int(drafted.sum())
         qerr = None
-        if self.paged and self._direct:
+        if self._direct:
             kv = self.kv
             audit_donation(kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales)
             consumed = [kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales,
@@ -2710,7 +2547,7 @@ class ServingEngine:
                                       kernel=self.decode_kernel):
                     (kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales, out,
                      n_commit, pending, rngs, qerr) = self._verify(*args)
-        elif self.paged:
+        else:
             kv = self.kv
             audit_donation(kv.pages_k, kv.pages_v)
             consumed = [kv.pages_k, kv.pages_v, lanes[0], lanes[-1]]
@@ -2730,19 +2567,6 @@ class ServingEngine:
                         self.params, kv.pages_k, kv.pages_v, tables, index,
                         tokens, *lanes[1:]
                     )
-                )
-        else:
-            audit_donation(self.pool)
-            consumed = [self.pool, lanes[0], lanes[-1], tokens]
-            if not self.cost_table.captured("serve/tree_verify_window"):
-                self.cost_table.capture(
-                    "serve/tree_verify_window", self._verify,
-                    (self.params, self.pool, tokens, *lanes[1:]),
-                )
-            with self.tracer.span("serve/tree_verify_window",
-                                  occupied=n_occupied, drafted=n_drafted):
-                self.pool, out, n_commit, pending, rngs = self._verify(
-                    self.params, self.pool, tokens, *lanes[1:]
                 )
         lanes[0], lanes[-1] = pending, rngs
         self._bump("decode_steps", tree.depth + 1)
@@ -2777,7 +2601,7 @@ class ServingEngine:
         )
         n_drafted = int(drafted.sum())
         qerr = None
-        if self.paged and self._direct:
+        if self._direct:
             kv = self.kv
             audit_donation(kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales)
             consumed = [kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales,
@@ -2794,7 +2618,7 @@ class ServingEngine:
                 with self.tracer.span("serve/paged_attn", kernel=self.decode_kernel):
                     (kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales, out,
                      n_commit, pending, rngs, qerr) = self._verify(*args)
-        elif self.paged:
+        else:
             kv = self.kv
             audit_donation(kv.pages_k, kv.pages_v)
             consumed = [kv.pages_k, kv.pages_v, lanes[0], lanes[-1]]
@@ -2812,19 +2636,6 @@ class ServingEngine:
                 kv.pages_k, kv.pages_v, out, n_commit, pending, rngs = self._verify(
                     self.params, kv.pages_k, kv.pages_v, tables, index,
                     tokens, *lanes[1:]
-                )
-        else:
-            audit_donation(self.pool)
-            consumed = [self.pool, lanes[0], lanes[-1], tokens]
-            if not self.cost_table.captured("serve/verify_window"):
-                self.cost_table.capture(
-                    "serve/verify_window", self._verify,
-                    (self.params, self.pool, tokens, *lanes[1:]),
-                )
-            with self.tracer.span("serve/verify_window", occupied=n_occupied,
-                                  drafted=n_drafted):
-                self.pool, out, n_commit, pending, rngs = self._verify(
-                    self.params, self.pool, tokens, *lanes[1:]
                 )
         lanes[0], lanes[-1] = pending, rngs
         self._bump("decode_steps", k + 1)
@@ -2960,7 +2771,7 @@ class ServingEngine:
     def _step_impl(self) -> None:
         if self._has_deadlines:
             self._shed_blown_deadlines()
-        if (faults.ACTIVE is not None and self.paged and self._active.any()
+        if (faults.ACTIVE is not None and self._active.any()
                 and faults.ACTIVE.fire("page_exhaustion")):
             # stand-in for the pool running dry: run the reclaim ladder's
             # last resort (preempt the youngest lane for front-of-queue
@@ -3045,8 +2856,7 @@ class ServingEngine:
                 self._hit_rate_device_gauge.set((hit - host_hit) / covered)
                 self._hit_rate_host_gauge.set(host_hit / covered)
         self._update_prefill_gauges()
-        if self.paged:
-            self.kv.publish_gauges()
+        self.kv.publish_gauges()
         self._step_count += 1
         # Progress heartbeat for the stall detector / /healthz; also the
         # ring's per-step record of what the pool looked like.
@@ -3065,9 +2875,8 @@ class ServingEngine:
 
     def _update_tenant_kv_gauges(self) -> None:
         """Per-tenant KV occupancy gauges (``serve/kv_pages_tenant_<t>``):
-        pages held by each tenant's active lanes in paged mode, lanes held in
-        legacy slab mode.  Walks the slot array — metrics-tick cadence only,
-        never the per-step hot path.  A tenant with no live lane reads 0
+        pages held by each tenant's active lanes.  Walks the slot array —
+        metrics-tick cadence only, never the per-step hot path.  A tenant with no live lane reads 0
         (the gauge is not deleted: dashboards want the series to zero, not
         vanish)."""
         if not self._tenant_stats:
@@ -3077,8 +2886,8 @@ class ServingEngine:
             req = self._slot_req[s]
             if req is None or req.tenant is None:
                 continue
-            n = int(self.kv.lane_npages[s]) if self.paged else 1
-            held[req.tenant] = held.get(req.tenant, 0) + n
+            held[req.tenant] = (held.get(req.tenant, 0)
+                                + int(self.kv.lane_npages[s]))
         for tenant in self._tenant_stats:
             gauge = self._tenant_kv_gauges.get(tenant)
             if gauge is None:
@@ -3190,29 +2999,20 @@ class ServingEngine:
         return snap
 
     def kv_pool_bytes(self) -> int:
-        """PER-DEVICE HBM the KV state occupies: the page pool (paged — the
-        knob ``num_pages`` sizes), or the slab pool plus the prefill scratch
-        (legacy).  Under a tp mesh the pool shards on the kv-head axis, so
-        each device holds exactly ``1 / tp_degree`` of the logical bytes —
-        the like-for-like number capacity benches compare.  The A/B bench
-        holds this equal across both arms."""
-        if self.paged:
-            return self.kv.kv_bytes_per_device()
-        return (int(self.pool.k.nbytes) + int(self.pool.v.nbytes)
-                + int(self.scratch.k.nbytes)
-                + int(self.scratch.v.nbytes)) // self.tp_degree
+        """PER-DEVICE HBM the page pool occupies (the knob ``num_pages``
+        sizes it).  Under a tp mesh the pool shards on the kv-head axis, so
+        each device holds exactly ``1 / tp_degree`` of the logical bytes."""
+        return self.kv.kv_bytes_per_device()
 
     def compiled_executable_counts(self) -> dict:
         """Per-executable jit-cache sizes — the no-retrace contract: after any
-        workload each entry is at most 1 (copy entries exist only while the
-        prefix cache is enabled and stay 0 until the first hit; the
-        verify_window entry exists only when ``speculate_k > 0`` and stays 0
-        until the first drafted cycle; tree speculation swaps it for exactly
-        two entries, ``tree_verify_window`` and ``draft_forward``).  Paged mode swaps insert and the
-        per-bucket copies for a single ``copy_page`` (0 until the first
-        copy-on-write); cache hits alias pages, so the hit path adds no
-        executable at all.  ``lane_install`` is the one-slot lane-vector
-        scatter admissions enqueue once the device mirror exists — 0 when
+        workload each entry is at most 1 (the verify_window entry exists only
+        when ``speculate_k > 0`` and stays 0 until the first drafted cycle;
+        tree speculation swaps it for exactly two entries,
+        ``tree_verify_window`` and ``draft_forward``).  ``copy_page`` stays 0
+        until the first copy-on-write; cache hits alias pages, so the hit
+        path adds no executable at all.  ``lane_install`` is the one-slot
+        lane-vector scatter admissions enqueue once the device mirror exists — 0 when
         every install landed before the first window.  The host spill tier
         (``prefix_host_mb > 0``) adds exactly one ``spill_<bucket>`` D2H
         gather and one ``promote_<bucket>`` H2D scatter-install per prefill
@@ -3224,11 +3024,8 @@ class ServingEngine:
         ``serving.transfer.migration_executables``, so engines that never
         participate in a migration gain neither entry."""
         out = {"decode_window": jit_cache_sizes(self._decode),
-               "lane_install": jit_cache_sizes(self._lane_install)}
-        if self.paged:
-            out["copy_page"] = jit_cache_sizes(self._copy_page)
-        else:
-            out["insert"] = jit_cache_sizes(self._insert)
+               "lane_install": jit_cache_sizes(self._lane_install),
+               "copy_page": jit_cache_sizes(self._copy_page)}
         if self._verify is not None:
             out["tree_verify_window" if self.tree is not None
                 else "verify_window"] = jit_cache_sizes(self._verify)
@@ -3236,8 +3033,6 @@ class ServingEngine:
             out["draft_forward"] = jit_cache_sizes(self._draft_fwd)
         for b, f in self._prefill.items():
             out[f"prefill_{b}"] = jit_cache_sizes(f)
-        for b, f in self._copy.items():
-            out[f"copy_{b}"] = jit_cache_sizes(f)
         for b, f in self._spill_extract.items():
             out[f"spill_{b}"] = jit_cache_sizes(f)
         for b, f in self._promote_install.items():

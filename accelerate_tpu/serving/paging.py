@@ -18,10 +18,10 @@ while all allocation, refcounting, and copy-on-write stay host-side numpy:
   ``[L, num_pages, Hkv, page_size, Dh]`` plus per-lane block tables
   (host ``[num_slots, pages_per_lane]`` int32, uploaded per cycle — a few KB).
   ``pages_per_lane * page_size == max_len`` exactly: the gathered per-lane
-  view has the *same* width as the legacy slab, so paged decode runs the
-  bitwise-identical attention program (a wider view would change the softmax
-  reduction shape and with it the last-ulp rounding — measured, not
-  hypothetical).
+  view has the *same* width as ``generate``'s contiguous cache, so paged
+  decode runs the bitwise-identical attention program (a wider view would
+  change the softmax reduction shape and with it the last-ulp rounding —
+  measured, not hypothetical).
 
 Sharing model: the prefix cache pins pages (one allocator ref per caching
 node), every lane aliasing a cached prefix takes its own ref per page, and a
@@ -123,11 +123,11 @@ class PagedKVPool:
     Parameters
     ----------
     config: the model's ``TransformerConfig`` (layer/head/dim geometry; pages
-        use ``config.dtype`` exactly like the legacy slab pool).
+        use ``config.dtype`` unless ``kv_dtype`` says otherwise).
     num_slots: lane count (the decode batch dimension).
     max_len: per-lane logical KV capacity.  Must be a multiple of
         ``page_size`` — the gathered view is exactly this wide, which is what
-        makes paged decode bitwise-identical to the contiguous slab.
+        makes paged decode bitwise-identical to a contiguous cache.
     page_size: tokens per page (the prefix-cache chunk granularity must be a
         multiple of it; the engine uses gcd(prefill buckets) by default).
     num_pages: physical pages including the null page.  Must be at least

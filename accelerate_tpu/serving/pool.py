@@ -1,50 +1,66 @@
-"""Slot-based KV pool: the fixed-shape compiled executables behind the engine.
+"""The fixed-shape compiled executables behind the serving engine.
 
 Iteration-level scheduling (Orca) and block-structured KV management (vLLM)
 win their 2-10x serving throughput by decoupling request lifetimes from the
 batch program: a request that finishes frees its KV capacity *immediately* and
 a queued request takes its place without restarting anyone else.  The TPU-first
 translation keeps everything inside a handful of fixed-shape executables — no
-per-request retracing:
+per-request retracing — over ONE KV store, the shared page pool
+``[L, num_pages, Hkv, page, Dh]`` of :mod:`.paging`, reached through per-lane
+block tables:
 
-* **pool** — one :class:`~accelerate_tpu.models.transformer.KVCache` of
-  ``num_slots`` lanes with a *per-lane* ``index`` vector (each slot sits at its
-  own sequence position).  The model's cache path writes each lane at its own
-  index and masks attention per lane, so a single batched forward serves
-  whatever mix of requests currently occupies the pool.
-* **decode window** (:func:`make_decode_window`) — ONE jitted executable:
-  ``lax.scan`` over ``window`` masked decode steps.  Per-request sampling
-  knobs (eos / temperature / top-k / top-p) enter as traced *vectors*, so a
-  new request never forces a retrace.  Inactive or EOS-done lanes are frozen:
-  their index stops advancing and their emissions are masked to the pad token.
-  Greedy lanes take the same argmax ``generate`` takes — token-exact.
-* **prefill chunks** (:func:`make_prefill_chunk`) — one executable per chunk
-  *bucket* (e.g. 128/512).  A prompt prefills into a batch-1 scratch cache in
-  fixed-size chunks; only the final chunk is padded, and padded positions are
-  never attended (the causal mask is the valid-entry mask).
-* **insert** (:func:`make_insert`) — one executable: ``dynamic_update_slice``
-  of the scratch KV into a freed slot + setting that lane's length, without
-  disturbing running lanes.
-* **copy chunk** (:func:`make_copy_chunk`) — one executable per chunk bucket:
-  ``dynamic_update_slice`` of a cached prefix-KV slab (:mod:`.prefix_cache`)
-  into the scratch cache at its index — a cache hit replays retained KV
-  instead of re-running the prefill forward.
-* **verify window** (:func:`make_verify_window`) — one executable per
+* **decode window** (:func:`make_paged_decode_window`) — ONE jitted
+  executable: ``lax.scan`` over ``window`` masked decode steps
+  (:func:`_decode_scan`).  Per-request sampling knobs (eos / temperature /
+  top-k / top-p) enter as traced *vectors*, so a new request never forces a
+  retrace.  Inactive or EOS-done lanes are frozen: their index stops advancing
+  and their emissions are masked to the pad token.  Greedy lanes take the same
+  argmax ``generate`` takes — token-exact.
+* **prefill chunks** (:func:`make_paged_prefill_chunk`) — one executable per
+  chunk *bucket* (e.g. 128/512).  A prompt prefills into its own lane's pages
+  in fixed-size chunks; only the final chunk is padded, and padded positions
+  are never attended (the causal mask is the valid-entry mask).  A
+  prefix-cache hit (:mod:`.prefix_cache`) aliases the cached pages into the
+  lane's block table on the host — no executable runs.
+* **verify window** (:func:`make_paged_verify_window`) — one executable per
   configured ``speculate_k``: a single forward over ``[slots, K+1]`` drafted
   positions (pending token + K host-drafted tokens, :mod:`.spec`), the
   token-exact acceptance prefix per lane, and an index rollback past the
-  first rejected draft.  Lands a variable 1..K+1 tokens per lane per call
-  while preserving exactly the tokens sequential decode would emit.
+  first rejected draft (:func:`_verify_body`).  Lands a variable 1..K+1
+  tokens per lane per call while preserving exactly the tokens sequential
+  decode would emit.  Model-based tree speculation (``draft_model=``) swaps
+  it for exactly two: the tree verify window
+  (:func:`make_paged_tree_verify_window` — the ``[slots, tree_nodes]`` bucket
+  is static per engine, never call-varying) and one draft forward
+  (:func:`~accelerate_tpu.serving.spec_exec.make_draft_forward`).
+* **lane install** (:func:`make_lane_install`) and **copy page**
+  (:func:`make_copy_page`, copy-on-write of a shared tail page) — one
+  executable each; the host prefix tier adds a spill/promote pair per bucket
+  (:func:`make_spill_extract`, :func:`make_promote_install`).
+
+Each window and chunk has two arms.  The gathered arm (``direct=False``)
+gathers every lane's pages into a contiguous ``max_len``-wide view once a
+call, runs the model on it as a plain
+:class:`~accelerate_tpu.models.transformer.KVCache` — the attention program
+``generate`` runs — then stores the pages the call wrote into back whole
+(:func:`_store_span_pages`; a prefill chunk's span is page-aligned and is its
+pages).  What the view costs is once a CALL: the gather and its one layout
+pass (the pool keeps ``page`` minor on the chip, the view ``Dh``), a
+view-sized temporary for K and for V, and a write-back of two pages a lane,
+scattered in the pool's own layout.  Rows are never stored singly: with
+``page`` minor a row is a strided write, and the compiler would copy the whole
+pool into a row-minor layout and back to serve it.  Inside the call the model
+writes the view in place — each layer its new rows, each scan step of a decode
+window 2 x L small scatters into the carried view; nothing of the view's size
+is copied per step.  The in-place arm (``direct=True``) hands the model the
+pool itself (:class:`~accelerate_tpu.models.transformer.PagedKVCache`): no
+view, the same in-place write through the block tables, pages read where they
+lie.
 
 Compiled-shape budget for an engine instance: ``1 (decode window) +
-len(prefill_buckets) + 1 (insert)``, plus ``len(prefill_buckets)`` copy
-executables when the prefix cache is enabled, plus ``1`` verify executable
-when ``speculate_k > 0`` — asserted by the serving tests via the jit cache
-counters.  Model-based tree speculation (``draft_model=``) swaps the verify
-executable for exactly two: ``1`` tree verify window
-(:func:`make_tree_verify_window` — the ``[slots, tree_nodes]`` bucket is
-static per engine, never call-varying) and ``1`` draft forward
-(:func:`~accelerate_tpu.serving.spec_exec.make_draft_forward`).
+len(prefill_buckets) + 1 (lane install) + 1 (copy page)``, plus ``1`` verify
+executable when ``speculate_k > 0`` (or the tree pair) — asserted by the
+serving tests via the jit cache counters.
 """
 
 from __future__ import annotations
@@ -66,11 +82,10 @@ from .paging import NULL_PAGE
 class ServeShardings:
     """The engine's placement vocabulary under a tensor-parallel mesh.
 
-    Every serving executable moves arrays from exactly four families: KV
-    slabs ``[L, N, max_len, Hkv, D]`` (``kv``: sharded on the kv-head axis,
-    dim 3), page pools and page chunks ``[L, NP, Hkv, page, D]`` (``pages``:
-    kv-head axis at dim 2), per-page quantization scales ``[L, NP, Hkv]``
-    (head axis last), and host-side control state (tokens, tables, indices,
+    Every serving executable moves arrays from exactly three families: page
+    pools and page chunks ``[L, NP, Hkv, page, D]`` (``pages``: kv-head axis
+    at dim 2), per-page quantization scales ``[L, NP, Hkv]`` (head axis
+    last), and host-side control state (tokens, tables, indices,
     sampling knobs — replicated).  Params carry the :data:`~accelerate_tpu.parallel
     .tensor_parallel.DEFAULT_TP_RULES` placement computed by the engine.
 
@@ -86,7 +101,6 @@ class ServeShardings:
         self.tp_degree = mesh_axis_size(mesh, tp_axis)
         ax = tp_axis if self.tp_degree > 1 else None
         self.replicated = NamedSharding(mesh, PartitionSpec())
-        self.kv = NamedSharding(mesh, PartitionSpec(None, None, None, ax, None))
         self.pages = NamedSharding(mesh, PartitionSpec(None, None, ax, None, None))
         self.scales = NamedSharding(mesh, PartitionSpec(None, None, ax))
         self.params = params
@@ -94,10 +108,6 @@ class ServeShardings:
     def rep(self, n: int) -> tuple:
         """``n`` replicated placements — the control-state tail of a signature."""
         return (self.replicated,) * n
-
-    def cache(self) -> KVCache:
-        """Placement pytree for a slab :class:`KVCache` (scratch or pool)."""
-        return KVCache(k=self.kv, v=self.kv, index=self.replicated)
 
 
 def _serve_jit(fn, *, donate_argnums=(), in_shardings=None, out_shardings=None):
@@ -118,8 +128,8 @@ def audit_donation(*trees) -> None:
     """Assert no leaf of ``trees`` has already been donated (its buffer
     deleted by a prior dispatch).  The engine calls this on the KV state it
     is about to donate into a window: under the pipelined loop
-    (``async_depth=1``) every window's outputs rebind ``self.pool`` / the
-    page arrays *at dispatch*, so the next dispatch always donates the fresh
+    (``async_depth=1``) every window's outputs rebind the page arrays *at
+    dispatch*, so the next dispatch always donates the fresh
     handles — this audit turns any future violation of that invariant (a
     double donation, which XLA reports as a use-after-free much later and
     far from the cause) into an immediate, attributable error.  Host-only
@@ -172,11 +182,21 @@ def _forward(model: Transformer, params, tokens, cache, live):
 
 def _decode_scan(model: Transformer, window: int, params, cache, tokens, active,
                  eos, do_sample, temperature, top_k, top_p, pad, rngs):
-    """The masked decode scan shared by the slab and paged decode windows —
-    one traced program, so the paged path cannot drift from the legacy
-    numerics.  Returns ``(cache, out_tokens [N, window], pending, rngs,
-    counts)``; ``counts`` is the window's ``moe_*`` counters (:func:`_forward`)
-    or ``None``."""
+    """The masked decode scan shared by the gathered and in-place decode
+    windows — one traced program, so the two arms cannot drift apart.
+    Returns ``(cache, out_tokens [N, window], pending, rngs, counts)``;
+    ``pending`` is the scan's final carry token per lane — the token the next
+    window will feed — returned device-side so the engine's lane-state mirrors
+    never round-trip through the host between windows; ``counts`` is the
+    window's ``moe_*`` counters (:func:`_forward`) or ``None``.
+
+    Semantics per scan step (matching ``generate``'s loop body lane-by-lane):
+    the pending token is fed at each lane's own position, its KV is written
+    there, the next token is sampled per-lane, and lanes that are inactive or
+    have emitted their EOS freeze — index stops advancing and outputs are
+    masked to ``pad``.  Frozen lanes still execute (static shapes) but only
+    ever overwrite their own dead rows (gathered view) or the null page
+    (in-place), so running lanes are untouched."""
     counted = _routed(model)
 
     def step(carry, _):
@@ -184,8 +204,8 @@ def _decode_scan(model: Transformer, window: int, params, cache, tokens, active,
         prev_index = cache.index
         if isinstance(cache, PagedKVCache):
             # direct paged cache: route frozen lanes' writes to the null page
-            # per step.  In the slab (and gathered-view) paths a frozen lane
-            # harmlessly overwrites its own dead slot, but a quantized page
+            # per step.  In the gathered view a frozen lane harmlessly
+            # overwrites its own dead rows, but a quantized page
             # write REQUANTIZES the whole touched page — pad-token garbage
             # must not keep churning a page that still holds real history.
             cache = cache.replace(active=~done)
@@ -228,56 +248,11 @@ def _unrouted(model: Transformer, shardings):
     return shardings
 
 
-def make_decode_window(model: Transformer, window: int,
-                       shardings: Optional[ServeShardings] = None):
-    """One jitted ``window``-step masked decode over the whole slot pool.
-
-    ``(params, cache, tokens [N], active [N], eos [N], do_sample [N],
-    temperature [N], top_k [N], top_p [N], pad [N], rngs [N,2])
-    -> (cache, out_tokens [N, window], new_pending [N], new_rngs)``
-
-    ``new_pending`` is the scan's final carry token per lane — the token the
-    next window will feed — returned device-side so the engine's lane-state
-    mirrors never round-trip through the host between windows.
-
-    Return packing is readback-friendly by design: ``out_tokens`` is its own
-    output leaf (never folded into the carried cache/lane state), so the
-    pipelined engine can park just that handle in a :class:`.readback.Readback`
-    and dispatch the next window — which donates and rebinds the cache —
-    without the deferred token fetch ever touching a donated buffer.  All
-    outputs of one call materialize together, so fetching ``out_tokens``
-    also proves the window's KV writes landed.
-
-    Semantics per scan step (matching ``generate``'s loop body lane-by-lane):
-    the pending token is fed at each lane's own position, its KV is written
-    there, the next token is sampled per-lane, and lanes that are inactive or
-    have emitted their EOS freeze — index stops advancing and outputs are
-    masked to ``pad``.  Frozen lanes still execute (static shapes) but only
-    ever overwrite their own dead slot, so running lanes are untouched.
-    """
-
-    def decode_window(params, cache, tokens, active, eos, do_sample, temperature,
-                      top_k, top_p, pad, rngs):
-        return _with_counts(*_decode_scan(
-            model, window, params, cache, tokens, active, eos, do_sample,
-            temperature, top_k, top_p, pad, rngs))
-
-    s = _unrouted(model, shardings)
-    return _serve_jit(
-        decode_window,
-        donate_argnums=(1,),
-        in_shardings=None if s is None else (s.params, s.cache(), *s.rep(9)),
-        out_shardings=None if s is None else (s.cache(), *s.rep(3)),
-    )
-
-
-def make_verify_window(model: Transformer, k: int,
-                       shardings: Optional[ServeShardings] = None):
-    """One jitted speculative verify pass: K+1 positions per lane, one forward.
-
-    ``(params, cache, tokens [N, K+1], active [N], eos [N], do_sample [N],
-    temperature [N], top_k [N], top_p [N], pad [N], rngs [N,2])
-    -> (cache, out [N, K+1], n_commit [N], new_pending [N], new_rngs)``
+def _verify_body(model: Transformer, k: int, params, cache, tokens, active, eos,
+                 do_sample, temperature, top_k, top_p, pad, rngs):
+    """Forward + accept/commit of one speculative verify pass — K+1 positions
+    per lane, one forward — shared by the gathered and in-place verify
+    windows (one traced program, no numeric drift).
 
     ``tokens[:, 0]`` is each lane's pending token, ``tokens[:, 1:]`` its K
     host-drafted tokens (:mod:`.spec`).  The single forward writes KV for all
@@ -305,26 +280,8 @@ def make_verify_window(model: Transformer, k: int,
     ``prev_index + n_commit`` — KV for the pending token and accepted drafts
     stays (it was computed from correct inputs), KV past the first rejection
     is unreachable and gets overwritten by subsequent decode.  Frozen lanes
-    (``~active``) commit nothing and keep their index.
-    """
-    def verify_window(params, cache, tokens, active, eos, do_sample,
-                      temperature, top_k, top_p, pad, rngs):
-        return _verify_body(model, k, params, cache, tokens, active, eos,
-                            do_sample, temperature, top_k, top_p, pad, rngs)
-
-    s = shardings
-    return _serve_jit(
-        verify_window,
-        donate_argnums=(1,),
-        in_shardings=None if s is None else (s.params, s.cache(), *s.rep(9)),
-        out_shardings=None if s is None else (s.cache(), *s.rep(4)),
-    )
-
-
-def _verify_body(model: Transformer, k: int, params, cache, tokens, active, eos,
-                 do_sample, temperature, top_k, top_p, pad, rngs):
-    """Forward + accept/commit of one speculative verify pass — shared by the
-    slab and paged verify windows (one traced program, no numeric drift)."""
+    (``~active``) commit nothing and keep their index.  Returns ``(cache,
+    out [N, K+1], n_commit [N], new_pending [N], new_rngs)``."""
     from ..models.generation import filter_logits_batched
 
     kp1 = k + 1
@@ -392,16 +349,15 @@ def _verify_body(model: Transformer, k: int, params, cache, tokens, active, eos,
     return cache, out, n_commit, new_pending, new_rngs
 
 
-def make_tree_verify_window(model: Transformer, tree,
-                            shardings: Optional[ServeShardings] = None):
-    """One jitted *tree* speculative verify pass: ``S = tree.nodes`` drafted
-    tree positions per lane, one forward — the generalization of
-    :func:`make_verify_window` from a linear ``[slots, K+1]`` window to a
-    token tree ``[slots, S]``.
-
-    ``(params, cache, tokens [N, S], active [N], eos [N], do_sample [N],
-    temperature [N], top_k [N], top_p [N], pad [N], rngs [N, 2])
-    -> (cache, out [N, D+1], n_commit [N], new_pending [N], new_rngs)``
+def _tree_verify_body(model: Transformer, tree, params, cache, tokens, active,
+                      eos, do_sample, temperature, top_k, top_p, pad, rngs):
+    """Forward + branch-select/commit of one *tree* speculative verify pass —
+    ``S = tree.nodes`` drafted tree positions per lane, one forward: the
+    generalization of :func:`_verify_body` from a linear ``[slots, K+1]``
+    window to a token tree ``[slots, S]``.  Shared by the gathered and
+    in-place tree windows (one traced accept program, no numeric drift).
+    Returns ``(cache, out [N, D+1], n_commit [N], new_pending [N],
+    new_rngs)``.
 
     ``tree`` is a :class:`~accelerate_tpu.serving.spec_exec.TreeSpec`:
     ``tokens[:, 0]`` is each lane's pending token (tree root), node ``i``'s
@@ -427,28 +383,7 @@ def make_tree_verify_window(model: Transformer, tree,
     frontier (losing branches' rows are overwritten or left dead past the
     rolled-back index) and the index advances by ``n_commit`` — so the cache
     layout a subsequent window sees is byte-for-byte what linear decode would
-    have produced.
-    """
-    def tree_verify_window(params, cache, tokens, active, eos, do_sample,
-                           temperature, top_k, top_p, pad, rngs):
-        return _tree_verify_body(model, tree, params, cache, tokens, active,
-                                 eos, do_sample, temperature, top_k, top_p,
-                                 pad, rngs)
-
-    s = shardings
-    return _serve_jit(
-        tree_verify_window,
-        donate_argnums=(1,),
-        in_shardings=None if s is None else (s.params, s.cache(), *s.rep(9)),
-        out_shardings=None if s is None else (s.cache(), *s.rep(4)),
-    )
-
-
-def _tree_verify_body(model: Transformer, tree, params, cache, tokens, active,
-                      eos, do_sample, temperature, top_k, top_p, pad, rngs):
-    """Forward + branch-select/commit of one tree verify pass — shared by the
-    slab, gathered-paged and direct-paged tree windows (one traced accept
-    program, no numeric drift between pool layouts)."""
+    have produced."""
     from ..models.generation import filter_logits_batched
 
     w, depth = tree.width, tree.depth
@@ -595,70 +530,6 @@ def _tree_verify_body(model: Transformer, tree, params, cache, tokens, active,
     return cache, out, n_commit, new_pending, new_rngs
 
 
-def make_prefill_chunk(model: Transformer, chunk_len: int,
-                       shardings: Optional[ServeShardings] = None):
-    """Jitted ``(params, tokens [1, chunk_len], scratch) -> scratch`` prefill.
-
-    Writes the chunk's KV into the batch-1 scratch cache at
-    ``scratch.index .. scratch.index + chunk_len`` and advances the index.
-    The final chunk of a prompt may be padded past the prompt's end: padded
-    positions write garbage KV *beyond* the valid length, which the causal
-    mask never lets any later query read (and :func:`make_insert` copies but
-    decode progressively overwrites).  Logits are discarded — the first
-    generated token comes from the shared decode step re-processing the last
-    prompt token, so prefill and decode share one sampling path.
-    """
-
-    s = _unrouted(model, shardings)
-    if _routed(model):
-        def prefill_chunk(params, tokens, scratch, valid):
-            live = jnp.arange(chunk_len)[None, :] < valid
-            _, scratch, counts = _forward(model, params, tokens, scratch, live)
-            return scratch, counts
-
-        return _serve_jit(prefill_chunk, donate_argnums=(2,))
-
-    def prefill_chunk(params, tokens, scratch):
-        _, scratch = model.apply({"params": params}, tokens, cache=scratch)
-        return scratch
-
-    return _serve_jit(
-        prefill_chunk,
-        donate_argnums=(2,),
-        in_shardings=None if s is None else (s.params, s.replicated, s.cache()),
-        out_shardings=None if s is None else s.cache(),
-    )
-
-
-def make_insert(shardings: Optional[ServeShardings] = None):
-    """Jitted ``insert_request``: copy a prefilled scratch KV into a freed slot.
-
-    ``(pool, scratch_k [L,1,Mp,H,D], scratch_v, slot, length) -> pool`` —
-    ``dynamic_update_slice`` at ``(0, slot, 0, 0, 0)`` writes one lane only;
-    running lanes' KV and indices are untouched (the property the slot-reuse
-    and permutation tests pin down).  ``length`` is ``prompt_len - 1``: the
-    last prompt token is left pending so the decode window computes the first
-    generated token through the same executable as every later token.
-    """
-
-    def insert_request(pool: KVCache, scratch_k, scratch_v, slot, length):
-        k = jax.lax.dynamic_update_slice(
-            pool.k, scratch_k.astype(pool.k.dtype), (0, slot, 0, 0, 0)
-        )
-        v = jax.lax.dynamic_update_slice(
-            pool.v, scratch_v.astype(pool.v.dtype), (0, slot, 0, 0, 0)
-        )
-        return pool.replace(k=k, v=v, index=pool.index.at[slot].set(length))
-
-    s = shardings
-    return _serve_jit(
-        insert_request,
-        donate_argnums=(0,),
-        in_shardings=None if s is None else (s.cache(), s.kv, s.kv, *s.rep(2)),
-        out_shardings=None if s is None else s.cache(),
-    )
-
-
 def make_lane_install(shardings: Optional[ServeShardings] = None):
     """Jitted one-slot edit of the device-resident lane vectors.
 
@@ -699,66 +570,9 @@ def make_lane_install(shardings: Optional[ServeShardings] = None):
     )
 
 
-def make_copy_chunk(chunk_len: int,
-                    shardings: Optional[ServeShardings] = None):
-    """Jitted ``(scratch, slab_k, slab_v) -> scratch``: replay one cached chunk.
-
-    The prefix-cache hit path: a retained KV slab ``[L, 1, chunk_len, H, D]``
-    (what :func:`make_prefill_chunk` computed for these tokens under this
-    exact prefix) is ``dynamic_update_slice``-d into the batch-1 scratch cache
-    at ``scratch.index`` — the same shape family as :func:`make_insert`, so
-    the compiled-shape budget grows by exactly one executable per bucket, not
-    per request.  The index advances by the full ``chunk_len`` just as a real
-    prefill of this chunk would.
-    """
-
-    def copy_chunk(scratch: KVCache, slab_k, slab_v):
-        k = jax.lax.dynamic_update_slice(
-            scratch.k, slab_k.astype(scratch.k.dtype), (0, 0, scratch.index, 0, 0)
-        )
-        v = jax.lax.dynamic_update_slice(
-            scratch.v, slab_v.astype(scratch.v.dtype), (0, 0, scratch.index, 0, 0)
-        )
-        return scratch.replace(k=k, v=v, index=scratch.index + chunk_len)
-
-    s = shardings
-    return _serve_jit(
-        copy_chunk,
-        donate_argnums=(0,),
-        in_shardings=None if s is None else (s.cache(), s.kv, s.kv),
-        out_shardings=None if s is None else s.cache(),
-    )
-
-
-# --------------------------------------------------------------------- paged
-# Block-table variants (ServingEngine(paged=True), :mod:`.paging`): KV lives
-# in a shared page pool ``[L, num_pages, Hkv, page, Dh]``.  Each executable has
-# two arms.  The gathered arm (``direct=False``) gathers every lane's pages
-# into a contiguous view of the slab's width once a call, runs the *same*
-# traced decode/verify/prefill body as the slab path on it, then stores the
-# pages the call wrote into back whole (:func:`_store_span_pages`; a prefill
-# chunk's span is page-aligned and is its pages).  The view width equals the
-# slab width (``pages_per_lane * page == max_len``), so the attention program —
-# and with it every greedy argmax — is bitwise identical to the legacy pool.
-# What the view costs is once a CALL: the gather and its one layout pass (the
-# pool keeps ``page`` minor on the chip, the view ``Dh``), a view-sized
-# temporary for K and for V, and a write-back of two pages a lane, scattered
-# in the pool's own layout.  Rows are never stored singly: with ``page`` minor
-# a row is a strided write, and the compiler would copy the whole pool into a
-# row-minor layout and back to serve it.  Inside the call the model writes the
-# view in place — each layer its new rows, each scan step of a decode window
-# 2 x L small scatters into the carried view
-# (:class:`~accelerate_tpu.models.transformer.KVCache`); nothing of the view's
-# size is copied per step.  The in-place arm (``direct=True``) hands the model
-# the pool itself (:class:`~accelerate_tpu.models.transformer.PagedKVCache`):
-# no view, the same in-place write through the block tables, pages read where
-# they lie.  Compiled-shape budget: one paged executable per legacy shape plus
-# ONE ``copy_page`` (copy-on-write), still bounded by bucket count.
-
-
 def _gather_view(pages, tables):
     """``pages [L, NP, H, page, D]`` gathered through ``tables [N, P]`` into a
-    contiguous per-lane slab view ``[L, N, P * page, H, D]``."""
+    contiguous per-lane view ``[L, N, P * page, H, D]``."""
     L, _, H, page, D = pages.shape
     N, P = tables.shape
     return (pages[:, tables]                             # [L, N, P, H, page, D]
@@ -822,9 +636,14 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
 
     Gathers the prefilling lane's full view (shared prefix pages included —
     this is how a partial cache hit feeds context to the chunks after it
-    without any copy), runs the slab prefill forward at scalar index ``base``,
-    and scatters the chunk's ``chunk_len / page_size`` freshly-written pages
-    back.  ``base`` and the chunk span are page-aligned by construction: every
+    without any copy), runs the prefill forward at scalar index ``base``, and
+    scatters the chunk's ``chunk_len / page_size`` freshly-written pages back.
+    The final chunk of a prompt may be padded past the prompt's end: padded
+    positions write garbage KV *beyond* the valid length, which the causal
+    mask never lets any later query read and decode progressively overwrites.
+    Logits are discarded — the first generated token comes from the shared
+    decode step re-processing the last prompt token, so prefill and decode
+    share one sampling path.  ``base`` and the chunk span are page-aligned by construction: every
     bucket is a multiple of ``page_size`` and chunk starts are sums of
     buckets, so a chunk never writes into a shared page.
 
@@ -918,11 +737,19 @@ def make_paged_decode_window(model: Transformer, window: int,
     tokens, active, eos, do_sample, temperature, top_k, top_p, pad, rngs)
     -> (pages_k, pages_v, out_tokens [N, window], new_pending, new_rngs)``.
 
-    Gather view -> the shared :func:`_decode_scan` (bitwise the slab program)
-    -> store back whole the pages each active lane's ``window`` new positions
-    lie in (:func:`_store_span_pages`: two a lane).  The engine tracks
-    each lane's index on the host (install/advance arithmetic is exact), so
-    no index array needs to round-trip.
+    Gather view -> the shared :func:`_decode_scan` -> store back whole the
+    pages each active lane's ``window`` new positions lie in
+    (:func:`_store_span_pages`: two a lane).  The engine tracks each lane's
+    index on the host (install/advance arithmetic is exact), so no index
+    array needs to round-trip.
+
+    Return packing is readback-friendly by design: ``out_tokens`` is its own
+    output leaf (never folded into the carried pages/lane state), so the
+    pipelined engine can park just that handle in a :class:`.readback.Readback`
+    and dispatch the next window — which donates and rebinds the pages —
+    without the deferred token fetch ever touching a donated buffer.  All
+    outputs of one call materialize together, so fetching ``out_tokens``
+    also proves the window's KV writes landed.
 
     ``direct=True`` drops the gather/scatter sandwich: the model runs on a
     :class:`~accelerate_tpu.models.transformer.PagedKVCache`, attention reads
@@ -995,10 +822,10 @@ def make_paged_decode_window(model: Transformer, window: int,
 
 def make_paged_verify_window(model: Transformer, k: int, direct: bool = False,
                              shardings: Optional[ServeShardings] = None):
-    """Paged speculative verify: the slab :func:`_verify_body` over a gathered
-    view, storing back the pages all ``K+1`` written positions lie in
+    """Paged speculative verify: :func:`_verify_body` over a gathered view,
+    storing back the pages all ``K+1`` written positions lie in
     (:func:`_store_span_pages`; rejected positions' KV is unreachable past the
-    committed index and gets overwritten later, exactly as in the slab path).
+    committed index and gets overwritten later).
     ``(params, pages_k, pages_v, tables, index, tokens [N, K+1], ...) ->
     (pages_k, pages_v, out, n_commit, new_pending, new_rngs)`` — the engine
     advances its host index mirror by ``n_commit``.
@@ -1068,7 +895,7 @@ def make_paged_verify_window(model: Transformer, k: int, direct: bool = False,
 def _tree_commit_paged(cache: PagedKVCache, prev_index, path):
     """Commit a tree verify's winning path inside the page pool: gather the
     ``D+1`` path nodes' KV rows through each lane's block table and re-insert
-    them contiguously at the lane frontier — the paged twin of the slab
+    them contiguously at the lane frontier — the in-place twin of the view
     compaction in :func:`_tree_verify_body`.  Quantized pools dequantize the
     gathered rows and requantize at insert (the same scatter-time scale
     discipline as every other paged write; the round-trip error folds into
@@ -1125,13 +952,13 @@ def _tree_commit_paged(cache: PagedKVCache, prev_index, path):
 def make_paged_tree_verify_window(model: Transformer, tree,
                                   direct: bool = False,
                                   shardings: Optional[ServeShardings] = None):
-    """Paged tree speculative verify — :func:`make_tree_verify_window` over
-    the page pool.  ``(params, pages_k, pages_v, tables, index,
+    """Paged tree speculative verify — :func:`_tree_verify_body` over the
+    page pool.  ``(params, pages_k, pages_v, tables, index,
     tokens [N, S], ...) -> (pages_k, pages_v, out [N, D+1], n_commit,
     new_pending, new_rngs)``.
 
-    ``direct=False`` runs the slab :func:`_tree_verify_body` (including its
-    slab compaction) over a gathered per-lane view and stores back the pages
+    ``direct=False`` runs :func:`_tree_verify_body` (including its view
+    compaction) over a gathered per-lane view and stores back the pages
     all ``S`` written positions lie in (:func:`_store_span_pages`) — rows past
     the compacted frontier are unreachable garbage, exactly like rejected
     positions in the linear paged verify.
@@ -1320,7 +1147,9 @@ def plan_chunks(prompt_len: int, buckets: Sequence[int]) -> Tuple[Tuple[int, int
     Returns ``((bucket_len, valid_len), ...)``: greedy largest-fit, so only
     the final chunk can be padded (``valid_len < bucket_len``).  KV for the
     prompt's last token is still *written* by prefill but re-written by the
-    first decode step — see :func:`make_insert`.
+    first decode step: the engine installs a lane at ``prompt_len - 1`` and
+    leaves the last prompt token pending, so the decode window computes the
+    first generated token through the same executable as every later token.
     """
     buckets = sorted(set(int(b) for b in buckets))
     if not buckets or buckets[0] <= 0:

@@ -5,27 +5,28 @@ recompute KV the pool already produced for an earlier request.  SGLang's
 RadixAttention and vLLM's automatic prefix caching reuse that KV across
 requests; the TPU-native translation caches at **chunk granularity** — the
 exact bucket boundaries :func:`~accelerate_tpu.serving.pool.plan_chunks`
-already prefills at — so reuse rides ONE fixed-shape copy executable per
-bucket (:func:`~accelerate_tpu.serving.pool.make_copy_chunk`) and the
-compiled-shape budget stays static no matter how requests share.
+already prefills at, each a whole number of KV pages (:mod:`.paging`) — so a
+hit is a host-side edit of the lane's block table, no executable runs, and
+the compiled-shape budget stays static no matter how requests share.
 
 Structure: a tree whose edges are *full* chunks of token ids.  A node's
 identity is the whole token prefix from the root; its key inside the parent is
 a rolling hash of that prefix (:func:`rolling_hash`), verified token-exact on
 every lookup so a hash collision can never serve wrong KV.  Each node retains
-the device KV slab ``[L, 1, chunk, H, D]`` (k and v) that prefill computed for
-its chunk *given its full prefix* — KV at a position depends on every earlier
-token through attention, which is why only exact whole-prefix matches are
-reusable and why partial (padded) final chunks are never cached.
+the physical page ids holding the KV that prefill computed for its chunk
+*given its full prefix*, with one allocator reference per page — KV at a
+position depends on every earlier token through attention, which is why only
+exact whole-prefix matches are reusable and why partial (padded) final chunks
+are never cached.
 
 Lifecycle: nodes are pinned (``refs``) while any request between admission and
-slot insertion depends on them; eviction is leaf-only LRU among unpinned
+lane installation depends on them; eviction is leaf-only LRU among unpinned
 nodes, under a byte ``capacity`` (``ServingEngine(prefix_cache_mb=...)``).
 Evicting a leaf may expose its parent as the next candidate — interior nodes
-are never dropped from under their children, so every resident slab's prefix
+are never dropped from under their children, so every resident node's prefix
 chain stays resident.
 
-Tiering (paged engine only): with ``host_capacity_bytes > 0`` and a ``spill``
+Tiering: with ``host_capacity_bytes > 0`` and a ``spill``
 hook installed, a device-tier eviction *demotes* the node instead of dropping
 it — the hook D2H-extracts the node's pages (data **and** per-page quant
 scales, so int8/fp8 entries spill at their quantized density) into a host-RAM
@@ -42,8 +43,7 @@ a device node's ancestors are device-resident, so any matched chain is
 ``device* host* disk*`` in order.
 
 All of this is host-side bookkeeping; the only device work a cache hit costs
-is one ``dynamic_update_slice`` per reused chunk (slot pool) or an H2D install
-per *spilled* chunk (paged pool — device-tier hits stay zero-copy).
+is an H2D install per *spilled* chunk — device-tier hits are zero-copy.
 """
 
 from __future__ import annotations
@@ -76,31 +76,25 @@ def rolling_hash(prev: int, tokens) -> int:
 
 
 class PrefixNode:
-    """One cached chunk: token ids + the retained KV — either a device slab
-    (``k``/``v``, the slot-pool engine) or physical page ids into the shared
-    page pool (``pages``, the paged engine; see :mod:`.paging`).  A page node
-    holds one allocator reference per page for as long as it is device-tier
+    """One cached chunk: token ids + the retained KV as physical page ids
+    into the shared page pool (``pages``; see :mod:`.paging`).  A node holds
+    one allocator reference per page for as long as it is device-tier
     resident; a spilled node (``tier != "device"``) holds no pages and keeps
     its KV in ``host`` instead — a tuple of per-layer page/scale arrays (still
     device handles while the D2H extract is in flight, host ndarrays once the
     drain lands it) or, for the disk tier, the path of the ring file."""
 
-    __slots__ = ("key", "tokens", "parent", "children", "k", "v", "pages",
+    __slots__ = ("key", "tokens", "parent", "children", "pages",
                  "nbytes", "refs", "last_used", "tier", "host")
 
-    def __init__(self, key: int, tokens: Optional[np.ndarray], parent, k, v,
-                 pages: Optional[Tuple[int, ...]] = None, nbytes: Optional[int] = None):
+    def __init__(self, key: int, tokens: Optional[np.ndarray], parent,
+                 pages: Optional[Tuple[int, ...]] = None, nbytes: int = 0):
         self.key = key
         self.tokens = tokens                 # [chunk] int32; None for the root
         self.parent = parent
         self.children: Dict[int, "PrefixNode"] = {}
-        self.k = k                           # [L, 1, chunk, H, D] device slab
-        self.v = v
-        self.pages = pages                   # physical page ids (paged mode)
-        if nbytes is not None:
-            self.nbytes = int(nbytes)
-        else:
-            self.nbytes = (int(k.nbytes) + int(v.nbytes)) if k is not None else 0
+        self.pages = pages                   # physical page ids; None once spilled
+        self.nbytes = int(nbytes)
         self.refs = 0
         self.last_used = 0
         self.tier = "device"                 # "device" | "host" | "disk"
@@ -113,17 +107,17 @@ class PrefixNode:
 
 
 class PrefixCache:
-    """Host-managed radix cache of device KV slabs with LRU byte budgeting.
+    """Host-managed radix cache of KV page references with LRU byte budgeting.
 
     Parameters
     ----------
-    capacity_bytes: retained-slab budget (device tier).  Pinned (``refs > 0``)
+    capacity_bytes: retained-page budget (device tier).  Pinned (``refs > 0``)
         nodes never evict, so in-flight requests can transiently hold the
         cache over budget; eviction restores it as soon as pins release.
     registry: metrics registry for the ``serve/prefix_*`` gauges and the
         eviction/spill/promotion counters (default: the process registry).
     on_evict: called with each node as it leaves the cache *entirely* — the
-        paged engine uses this to drop the allocator references its page nodes
+        engine uses this to drop the allocator references the node's pages
         hold (the pages themselves survive while lanes still alias them;
         refcounting, not residency in this tree, decides when HBM is
         reclaimed).  A demotion to the host ring is NOT an eviction: the
@@ -156,7 +150,7 @@ class PrefixCache:
         self.disk_dir = disk_dir
         if self.disk_capacity > 0 and not disk_dir:
             raise ValueError("disk_capacity_bytes > 0 requires disk_dir")
-        self.root = PrefixNode(_HASH_SEED, None, None, None, None)
+        self.root = PrefixNode(_HASH_SEED, None, None)
         self.bytes = 0
         self.host_bytes = 0
         self.disk_bytes = 0
@@ -171,7 +165,7 @@ class PrefixCache:
         self._clock = 0
         registry = registry if registry is not None else get_registry()
         self._bytes_gauge = registry.gauge(
-            "serve/prefix_cache_bytes", help="retained prefix KV slab bytes"
+            "serve/prefix_cache_bytes", help="retained prefix KV page bytes"
         )
         self._nodes_gauge = registry.gauge(
             "serve/prefix_cache_nodes", help="resident prefix cache nodes"
@@ -227,7 +221,7 @@ class PrefixCache:
 
     # --------------------------------------------------------------- pinning
     def acquire(self, nodes: Iterable[PrefixNode]) -> None:
-        """Pin ``nodes`` against eviction (a request depends on their slabs)."""
+        """Pin ``nodes`` against eviction (a request depends on their pages)."""
         for n in nodes:
             n.refs += 1
 
@@ -240,49 +234,22 @@ class PrefixCache:
             self._touch(n)
 
     # -------------------------------------------------------------- mutation
-    def insert(self, parent: Optional[PrefixNode], tokens, k, v
-               ) -> Optional[PrefixNode]:
-        """Retain one freshly prefilled chunk under ``parent`` (None = root).
-
-        Returns the resident node — the existing one if this exact chunk is
-        already cached — or ``None`` when it cannot be retained (the byte
-        budget cannot be met even after eviction, or a hash collision with a
-        different token sequence occupies the key; both leave the cache
-        untouched, and the caller must then stop extending this chain).
-        """
-        parent = parent if parent is not None else self.root
-        tokens = np.ascontiguousarray(np.asarray(tokens, np.int32))
-        key = rolling_hash(parent.key, tokens)
-        existing = parent.children.get(key)
-        if existing is not None:
-            if np.array_equal(existing.tokens, tokens):
-                self._touch(existing)
-                return existing
-            return None  # 61-bit hash collision: keep the resident entry
-        nbytes = int(k.nbytes) + int(v.nbytes)
-        if not self._make_room(nbytes):
-            return None
-        node = PrefixNode(key, tokens, parent, k, v)
-        self._touch(node)
-        parent.children[key] = node
-        self._nodes.append(node)
-        self.bytes += nbytes
-        self._publish()
-        return node
-
     def insert_pages(self, parent: Optional[PrefixNode], tokens,
                      page_ids: Sequence[int], nbytes: int
                      ) -> Optional[PrefixNode]:
-        """Retain one freshly prefilled chunk as *page references* (the paged
-        engine: zero copies — the lane's own pages are aliased, the caller
-        takes one allocator ref per page iff a NEW node was created OR a
-        spilled node was re-admitted in place, which it detects by
-        ``node.pages == tuple(page_ids)``).
+        """Retain one freshly prefilled chunk under ``parent`` (None = root)
+        as *page references*: zero copies — the lane's own pages are aliased,
+        the caller takes one allocator ref per page iff a NEW node was created
+        OR a spilled node was re-admitted in place, which it detects by
+        ``node.pages == tuple(page_ids)``.
 
-        Same contract as :meth:`insert`: returns the resident node (the
-        existing one on an exact re-insert — whose ``pages`` will differ from
-        ``page_ids`` unless the re-insert healed a spilled node), or ``None``
-        when the chunk cannot be retained.
+        Returns the resident node — the existing one on an exact re-insert,
+        whose ``pages`` will differ from ``page_ids`` unless the re-insert
+        healed a spilled node — or ``None`` when the chunk cannot be retained
+        (the byte budget cannot be met even after eviction, or a hash
+        collision with a different token sequence occupies the key; both
+        leave the cache untouched, and the caller must then stop extending
+        this chain).
         """
         parent = parent if parent is not None else self.root
         tokens = np.ascontiguousarray(np.asarray(tokens, np.int32))
@@ -299,7 +266,7 @@ class PrefixCache:
             return None  # 61-bit hash collision: keep the resident entry
         if not self._make_room(int(nbytes)):
             return None
-        node = PrefixNode(key, tokens, parent, None, None,
+        node = PrefixNode(key, tokens, parent,
                           pages=tuple(int(p) for p in page_ids), nbytes=nbytes)
         self._touch(node)
         parent.children[key] = node
@@ -310,7 +277,7 @@ class PrefixCache:
 
     def evict_one(self) -> bool:
         """Force one LRU device-tier eviction (page-pressure reclaim in the
-        paged engine) — a demotion to the host ring when tiering is on, a drop
+        engine) — a demotion to the host ring when tiering is on, a drop
         otherwise; either way the node's page refs are released.  Returns
         False when nothing is evictable."""
         skip: set = set()
@@ -470,7 +437,7 @@ class PrefixCache:
         any spilled descendants) otherwise.  False when neither is possible
         (e.g. a pinned spilled descendant)."""
         if (self.host_capacity > 0 and self.spill is not None
-                and node.pages and self._demote(node)):
+                and self._demote(node)):
             return True
         return self._drop_subtree(node)
 
@@ -609,7 +576,7 @@ class PrefixCache:
         return len(self._nodes)
 
     def stats(self) -> Dict[str, Any]:
-        """Plain-dict snapshot for the engine's legacy stats surface."""
+        """Plain-dict snapshot for the engine's stats surface."""
         return {
             "capacity_bytes": self.capacity,
             "bytes": self.bytes,

@@ -16,8 +16,8 @@ Policy ``"affinity"`` (default): rolling-hash the prompt's leading chunks
 against each replica's radix tree (:meth:`PrefixCache.match` — a pure
 host-side walk, no device work, no pinning) and score each replica by the
 matched token count; the best positive scorer wins, load breaking ties, and
-zero-scorers fall back to least-loaded.  Policy ``"round_robin"`` is the
-baseline A/B arm (``bench_inference.py --task serve --tp-ab``).
+zero-scorers fall back to least-loaded.  Policy ``"round_robin"`` ignores
+the caches and deals requests out in turn.
 
 Policy ``"disaggregated"`` splits the fleet by :class:`ServingEngine` role:
 new requests route (affinity-scored) to prefill-capable replicas only, and
@@ -123,11 +123,6 @@ class ReplicaRouter:
                 raise ValueError(
                     "disaggregated policy needs at least one decode-capable "
                     f"replica (role 'decode' or 'both'); got roles {roles}"
-                )
-            if not all(e.paged for e in engines):
-                raise ValueError(
-                    "disaggregated routing moves lanes between replicas as "
-                    "KV pages; every replica needs paged=True"
                 )
         self.engines: List[ServingEngine] = list(engines)
         # stable per-replica identities, parallel to ``engines``: positions

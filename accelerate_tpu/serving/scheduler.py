@@ -149,7 +149,7 @@ class Scheduler:
     every running request for its whole prefill (chunked prefill,
     Sarathi-style).
 
-    ``max_prefills > 1`` (the interleaved paged engine) keeps several
+    ``max_prefills > 1`` (the interleaved engine) keeps several
     requests mid-prefill at once: admission is still FCFS, but
     :meth:`take_chunk` picks the chunk to run each step
     shortest-remaining-first among the open prefills, so a short chat prompt
@@ -277,7 +277,7 @@ class Scheduler:
         )
 
     def drop_cache_pins(self) -> int:
-        """Release every *queued* request's prefix-cache pins (the paged
+        """Release every *queued* request's prefix-cache pins (the
         engine's last-resort page reclaim: pinned nodes block eviction, and a
         queued request can always re-match at admission).  Returns how many
         requests were unpinned."""
@@ -381,7 +381,7 @@ class Scheduler:
         next chunk fits the budget — a chat prompt's single chunk lands ahead
         of a mega-prompt's hundredth without starving it (every candidate
         stays eligible each step).  ``ready`` is an optional per-request
-        gate — the paged engine passes its page-reservation check, so a
+        gate — the engine passes its page-reservation check, so a
         request short on pages this step doesn't block a smaller one that
         fits.
 

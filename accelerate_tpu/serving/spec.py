@@ -20,7 +20,7 @@ the prompt) where the continuation literally already appears in the context,
 and degrades to nothing on high-entropy text — which is why the engine falls
 back to the plain decode window whenever no lane drafts.
 
-Device-side verification lives in :func:`~.pool.make_verify_window`; the
+Device-side verification lives in :func:`~.pool.make_paged_verify_window`; the
 engine (:mod:`.engine`) wires the two together per cycle.
 
 Drafting is the one serve-loop stage that is *inherently sequential* with

@@ -208,7 +208,7 @@ def build_draft(cfg: TransformerConfig, params, draft_model, *,
     * **(cfg, params) tuple** — explicit draft (tests, pre-built heads).
 
     The draft config is the served config with the truncated depth, the
-    ``xla`` paged kernel (the draft runs a slab scratch cache — no pages),
+    ``xla`` paged kernel (the draft runs a contiguous scratch ``KVCache`` — no pages),
     and a ``max_seq_len`` wide enough for the context window plus the chain
     rollout.  Returned params are host arrays; the engine places them
     replicated (the draft is small — sharding it would serialize its many

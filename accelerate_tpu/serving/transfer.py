@@ -181,11 +181,10 @@ def adopt(engine, request: Request) -> Request:
             queue_depth=engine.scheduler.queue_depth, retriable=False,
         )
     padded = sum(b for b, _ in plan_chunks(eff, engine.buckets))
-    cap = engine.max_len if engine.paged else engine.max_prompt_len
-    if padded > cap:
+    if padded > engine.max_len:
         raise AdmissionError(
             f"replayed length {eff} pads to {padded} prefill tokens under "
-            f"buckets {engine.buckets}, exceeding capacity {cap}",
+            f"buckets {engine.buckets}, exceeding capacity {engine.max_len}",
             queue_depth=engine.scheduler.queue_depth, retriable=False,
         )
     old_rid = request.rid
@@ -286,8 +285,6 @@ class PageMigrator:
         the gathered chunk feeds the destination's install bit-for-bit."""
         if src is dst:
             return "source and destination are the same engine"
-        if not (src.paged and dst.paged):
-            return "both engines must run the paged KV pool"
         if src.config.latent_attention is not None or dst.config.latent_attention is not None:
             return "page migration is not ported to a latent-attention cache yet"
         if src.kv.page_size != dst.kv.page_size:

@@ -132,8 +132,8 @@ class FlightRecorder:
 
     def tagged(self, **tags: Any) -> "_TaggedRecorder":
         """A view that stamps ``tags`` (e.g. ``engine="e0"``) onto every
-        ``record``/``heartbeat``.  Multi-replica runs (router, ``--tp-ab``,
-        chaos bench) share the process-global ring; without per-source tags
+        ``record``/``heartbeat``.  Multi-replica runs (router, chaos tests)
+        share the process-global ring; without per-source tags
         their events interleave indistinguishably."""
         return _TaggedRecorder(self, tags)
 

@@ -50,8 +50,8 @@ addressable by the ``X-Request-Id`` the API server emits via
 ``GET /debug/requests/<id>`` (see ``telemetry/server.py``).
 
 ``ATPU_TELEMETRY=0`` disables tracing with the rest of telemetry;
-:func:`set_enabled` overrides just this module (the ``--trace-ab`` bench
-uses it to isolate tracing overhead from the rest of the stack).
+:func:`set_enabled` overrides just this module (to isolate tracing from
+the rest of the stack).
 """
 
 from __future__ import annotations

@@ -16,9 +16,7 @@ no fusion, python step overhead) → 550-750 samples/s; we take 650 (≈17.6%
 A100 MFU) as the reference point.  Beating it at higher MFU on a smaller
 chip is the honest win condition.
 
-Run ``python bench_inference.py`` for the big-model streaming-inference
-benchmark (tokens/s, the reference ``benchmarks/big_model_inference.py``
-analog), and ``python bench.py --task mrpc`` to time the actual
+Run ``python bench.py --task mrpc`` to time the actual
 examples/nlp_example.py task instead of the synthetic LM proxy.
 
 Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"}.

@@ -273,7 +273,7 @@ def _serve_arm(model, params, prompts, **kw):
     from accelerate_tpu.telemetry import MetricsRegistry
 
     eng = ServingEngine(
-        model, params, num_slots=4, max_len=model.config.max_seq_len, paged=True,
+        model, params, num_slots=4, max_len=model.config.max_seq_len,
         registry=MetricsRegistry(), **kw,
     )
     gen = GenerationConfig(max_new_tokens=NEW_TOKENS, do_sample=False, eos_token_id=None)
@@ -397,7 +397,7 @@ def _http_completion(server, prompt, stream=False, timeout=1100.0):
 def _serve_args(seed, preset, max_len, param_dtype, replicas=1):
     from accelerate_tpu import serve
 
-    argv = ["--preset", preset, "--max-len", str(max_len), "--paged", "--port", "0",
+    argv = ["--preset", preset, "--max-len", str(max_len), "--port", "0",
             "--seed", str(seed), "--replicas", str(replicas)]
     if param_dtype:
         argv += ["--param-dtype", param_dtype]

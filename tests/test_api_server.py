@@ -50,7 +50,7 @@ class Service:
         )["params"]
         self.registry = MetricsRegistry()
         self.engine = ServingEngine(
-            self.model, self.params, registry=self.registry, paged=True,
+            self.model, self.params, registry=self.registry,
             page_size=4, num_pages=65, **ENGINE_KW,
         )
         rng = np.random.default_rng(7)
@@ -93,6 +93,22 @@ def svc():
     service = Service()
     yield service
     service.stop()
+
+
+def test_cli_builds_page_pool_replicas_and_has_no_paged_flag():
+    from accelerate_tpu import serve
+
+    with pytest.raises(SystemExit):
+        serve.parse_args(["--preset", "tiny", "--paged"])
+    router, frontdoor, server = serve.build_service(serve.parse_args(
+        ["--preset", "tiny", "--max-len", "64", "--replicas", "2", "--port", "0"]))
+    try:
+        assert len(router.engines) == 2
+        assert all(e.kv.allocator.free_count == e.num_pages - 1
+                   for e in router.engines)
+    finally:
+        server.stop()
+        frontdoor.stop()
 
 
 def _settle(predicate, timeout=30.0):
@@ -305,7 +321,7 @@ def test_client_disconnect_cancels_and_frees_pages(svc):
 
 def test_drain_replica_completes_in_flight_lanes(svc):
     second = ServingEngine(
-        svc.model, svc.params, registry=MetricsRegistry(), paged=True,
+        svc.model, svc.params, registry=MetricsRegistry(),
         page_size=4, num_pages=65, **ENGINE_KW,
     )
     rid2 = svc.frontdoor.add_replica(second)

@@ -322,7 +322,7 @@ def paged_run(tiny):
     engine_module.fetch = lambda *a: fetches.append(len(a)) or real(*a)
     try:
         engine = ServingEngine(model, params, num_slots=3, max_len=128, prefill_buckets=(16, 32),
-                               decode_window=4, paged=True, prefix_cache_mb=None, registry=MetricsRegistry())
+                               decode_window=4, prefix_cache_mb=None, registry=MetricsRegistry())
         prompts = [_ids(20 + i, (n,)) for i, n in enumerate((5, 40, 70, 33))]
         served = _served(engine, prompts)
     finally:
@@ -336,22 +336,13 @@ def test_paged_engine_prefills_by_chunks_and_decodes_the_references_tokens(tiny,
     _check_against_reference(tiny[2], prompts, served)
 
 
-def test_slab_engine_serves_what_the_paged_engine_serves(tiny, paged_run):
-    model, params, _ = tiny
-    _, prompts, served, _ = paged_run
-    engine = ServingEngine(model, params, num_slots=3, max_len=128, prefill_buckets=(16, 32),
-                           decode_window=4, paged=False, prefix_cache_mb=None, registry=MetricsRegistry())
-    assert _served(engine, prompts) == served
-    assert engine.stats["moe_pairs_total"] > 0
-
-
 def test_engine_prefix_cache_preemption_and_cancel_work_unchanged(tiny):
     model, params, ref_params = tiny
     shared = _ids(30, (32,))
     prompts = [np.concatenate([shared, _ids(31 + i, (n,))]) for i, n in enumerate((9, 20, 3, 27))]
     # a pool too small for three lanes at once: the youngest is preempted and replayed
     tight = ServingEngine(model, params, num_slots=3, max_len=128, prefill_buckets=(16, 32), decode_window=4,
-                          paged=True, num_pages=9, interleave_prefill=True, registry=MetricsRegistry())
+                          num_pages=9, interleave_prefill=True, registry=MetricsRegistry())
     first = _served(tight, prompts[:1], max_new=12)
     rest = _served(tight, prompts[1:], max_new=40)      # lanes outgrow the pool while they decode
     assert tight.stats["prefix_hit_tokens"] >= 32 and tight.stats["preemptions"] > 0
@@ -391,13 +382,13 @@ def test_model_without_routed_experts_has_no_such_counters():
 
 
 REFUSALS = {
-    "kv_dtype": dict(paged=True, kv_dtype="int8"),
+    "kv_dtype": dict(kv_dtype="int8"),
     "speculate_k": dict(speculate_k=2),
     "draft_model": dict(draft_model=1),
-    "decode_kernel": dict(paged=True, decode_kernel="pallas"),
-    "prefill_kernel": dict(paged=True, prefill_kernel="pallas"),
-    "prefix_host_mb": dict(paged=True, prefix_host_mb=1.0),
-    "role": dict(paged=True, role="prefill"),
+    "decode_kernel": dict(decode_kernel="pallas"),
+    "prefill_kernel": dict(prefill_kernel="pallas"),
+    "prefix_host_mb": dict(prefix_host_mb=1.0),
+    "role": dict(role="prefill"),
     "mesh": dict(mesh="tp2"),
 }
 
@@ -416,7 +407,7 @@ def test_engine_refuses_by_name_what_a_latent_cache_does_not_have(tiny, option):
 
 def test_page_migration_and_in_place_paged_cache_refuse_a_latent_model(tiny):
     model, params, _ = tiny
-    engines = [ServingEngine(model, params, max_len=64, prefill_buckets=(16,), paged=True,
+    engines = [ServingEngine(model, params, max_len=64, prefill_buckets=(16,),
                              registry=MetricsRegistry()) for _ in range(2)]
     assert "latent-attention" in PageMigrator.compatible(*engines)
     with pytest.raises(ValueError, match="latent_attention"):

@@ -91,7 +91,7 @@ def _tiny_model(seed=0, **kw):
 
 def _engine(model, params, **kw):
     defaults = dict(num_slots=2, max_len=64, prefill_buckets=(4, 8),
-                    prefill_token_budget=8, decode_window=2, paged=True,
+                    prefill_token_budget=8, decode_window=2,
                     prefix_cache_mb=0.01, async_depth=1,
                     registry=MetricsRegistry())
     defaults.update(kw)
@@ -137,12 +137,6 @@ class TestRoleAndPolicyValidation:
         model, params = _tiny_model()
         with pytest.raises(ValueError, match="role"):
             _engine(model, params, role="decoder")
-
-    def test_role_requires_paged(self):
-        model, params = _tiny_model()
-        with pytest.raises(ValueError, match="paged"):
-            _engine(model, params, role="prefill", paged=False,
-                    prefix_cache_mb=0.0)
 
     def test_role_gauge_and_health(self):
         model, params = _tiny_model()

@@ -116,7 +116,7 @@ class Service:
 
         def build():
             return ServingEngine(
-                self.model, self.params, registry=self.registry, paged=True,
+                self.model, self.params, registry=self.registry,
                 page_size=4, num_pages=65, **ENGINE_KW,
             )
 

@@ -312,7 +312,7 @@ def _tiny_model(seed=0, **kw):
 
 def _engine(model, params, **kw):
     defaults = dict(num_slots=2, max_len=64, prefill_buckets=(4, 8),
-                    prefill_token_budget=8, decode_window=2, paged=True,
+                    prefill_token_budget=8, decode_window=2,
                     prefix_cache_mb=0.01, async_depth=1,
                     registry=MetricsRegistry())
     defaults.update(kw)
@@ -502,9 +502,6 @@ class TestCompiledBudget:
 
     def test_knob_validation(self):
         model, params = _tiny_model()
-        with pytest.raises(ValueError):
-            _engine(model, params, paged=False, prefix_host_mb=8.0,
-                    num_pages=None)
         with pytest.raises(ValueError):
             _engine(model, params, prefix_host_mb=8.0, prefix_cache_mb=0)
         with pytest.raises(ValueError):

@@ -377,7 +377,7 @@ def _tiny_model(seed=0, **kw):
 
 def _engine(model, params, **kw):
     defaults = dict(num_slots=2, max_len=64, prefill_buckets=(4, 8),
-                    prefill_token_budget=8, decode_window=2, paged=True)
+                    prefill_token_budget=8, decode_window=2)
     defaults.update(kw)
     return ServingEngine(model, params, **defaults)
 
@@ -440,13 +440,6 @@ class TestEngineKernelIdentity:
         assert counts["decode_window"] == 1
         assert not eng._decode.over_budget()
 
-    def test_non_paged_engine_rejects_kernel_and_dtype(self):
-        model, params = _tiny_model()
-        with pytest.raises(ValueError):
-            _engine(model, params, paged=False, decode_kernel="pallas")
-        with pytest.raises(ValueError):
-            _engine(model, params, paged=False, kv_dtype="int8")
-
 
 class TestEngineQuantizedKV:
     @pytest.mark.parametrize("fmt", ["int8", "fp8"])
@@ -470,6 +463,27 @@ class TestEngineQuantizedKV:
         native = _engine(model, params, registry=MetricsRegistry())
         assert eng.kv.page_kv_bytes < native.kv.page_kv_bytes / 2
         assert kv_qmax(eng.kv.pages_k.dtype) is not None
+
+    def test_byte_equal_int8_pool_holds_more_lanes(self):
+        """The same pool bytes hold proportionally more concurrent lanes: a
+        native pool two lanes wide against an int8 pool of no more bytes,
+        both offered near-full-lane requests so concurrency is page-bound."""
+        model, params = _tiny_model()
+        rng = np.random.default_rng(26)
+        prompts = [rng.integers(1, model.config.vocab_size, (16,)).astype(np.int32)
+                   for _ in range(8)]
+        gen = GenerationConfig(max_new_tokens=40, do_sample=False, eos_token_id=None)
+        kw = dict(num_slots=8, prefix_cache_mb=None)
+        native = _engine(model, params, num_pages=2 * 16 + 1,
+                         registry=MetricsRegistry(), **kw)
+        # fp32 pages against int8 pages with their f32 scales: 3 to a page's bytes
+        quant = _engine(model, params, kv_dtype="int8", num_pages=3 * (2 * 16 + 1),
+                        registry=MetricsRegistry(), **kw)
+        assert quant.kv.kv_bytes() <= native.kv.kv_bytes()
+        for eng in (native, quant):
+            reqs = eng.serve([p.copy() for p in prompts], configs=gen)
+            assert all(len(r.tokens) == 40 for r in reqs)
+        assert quant.peak_active_lanes >= 1.8 * native.peak_active_lanes
 
     def test_quantized_budget_matches_native_paged(self):
         if not jit_cache_supported():

@@ -1,10 +1,10 @@
 """Paged KV allocator: refcounting, sharing, copy-on-write, preemption.
 
-The paged engine's contract extends the serving engine's: block-table
-indirection is *invisible* in the outputs.  Greedy decode through the page
-pool is token-identical to both the legacy slab pool and the static
-``generate`` path — the gathered per-lane view has exactly the slab's width,
-so the attention program is bitwise the same — while prefix-cache hits alias
+The page pool's contract: block-table indirection is *invisible* in the
+outputs.  Greedy decode through the page pool is token-identical to the
+static ``generate`` path — the gathered per-lane view has exactly the width
+of ``generate``'s contiguous cache, so the attention program is bitwise the
+same — while prefix-cache hits alias
 physical pages with zero KV copies, shared pages survive eviction pressure
 for as long as anything references them, and page pressure preempts the
 youngest lane instead of corrupting anyone's KV.
@@ -17,6 +17,7 @@ import pytest
 
 from accelerate_tpu.models.generation import GenerationConfig, generate
 from accelerate_tpu.models.transformer import Transformer, TransformerConfig
+from accelerate_tpu.parallel.mesh import build_mesh
 from accelerate_tpu.serving import NULL_PAGE, PageAllocator, PagedKVPool, ServingEngine
 from accelerate_tpu.telemetry import MetricsRegistry
 from accelerate_tpu.utils.jax_compat import jit_cache_supported
@@ -88,7 +89,7 @@ class TestPageAllocator:
 class TestPagedKVPool:
     def test_geometry_validation(self):
         cfg = TransformerConfig.tiny(max_seq_len=64)
-        with pytest.raises(ValueError):  # view width must equal slab width
+        with pytest.raises(ValueError):  # view width must equal max_len
             PagedKVPool(cfg, 2, max_len=10, page_size=4, num_pages=8,
                         registry=MetricsRegistry())
         with pytest.raises(ValueError):  # one full lane must always fit
@@ -113,23 +114,49 @@ class TestPagedKVPool:
         assert pool.allocator.used_count == 0
 
 
+class TestOnePool:
+    def test_default_engine_runs_the_page_pool_and_returns_every_page(self):
+        model, params = _tiny_model()
+        eng = ServingEngine(model, params, max_len=64, prefill_buckets=(4, 8),
+                            registry=MetricsRegistry())
+        assert eng.kv.allocator.used_count == 0
+        rng = np.random.default_rng(6)
+        prompts = [rng.integers(1, model.config.vocab_size, (n,)).astype(np.int32)
+                   for n in (7, 12, 8, 5, 3)]
+        gen = GenerationConfig(max_new_tokens=6, do_sample=False, eos_token_id=None)
+        reqs = eng.serve([p.copy() for p in prompts], configs=gen)
+        assert eng.kv.allocator.used_count > 0           # the cache holds prefixes
+        for req, prompt in zip(reqs, prompts):
+            assert req.tokens == _expected(model, params, prompt, gen)
+        while eng.prefix_cache.evict_one():
+            pass
+        assert eng.kv.allocator.used_count == 0
+
+    def test_paged_false_is_refused_by_name(self):
+        model, params = _tiny_model()
+        with pytest.raises(ValueError, match="paged=False"):
+            ServingEngine(model, params, paged=False)
+        # what the benchmark's workload files pass still constructs
+        assert ServingEngine(model, params, max_len=64, paged=True,
+                             registry=MetricsRegistry()).kv is not None
+
+
 class TestPagedTokenIdentity:
-    """The acceptance gate: greedy outputs are token-identical paged on/off."""
+    """The acceptance gate: outputs through the page pool are ``generate``'s,
+    and do not depend on which lanes or which loop carried them."""
 
     def _serve(self, model, params, prompts, gen, **kw):
         eng = _engine(model, params, registry=MetricsRegistry(), **kw)
         reqs = eng.serve([p.copy() for p in prompts], configs=gen)
         return eng, [r.tokens for r in reqs]
 
-    def test_mixed_lengths_match_legacy_and_generate(self):
+    def test_mixed_lengths_match_generate(self):
         model, params = _tiny_model()
         rng = np.random.default_rng(7)
         prompts = [rng.integers(1, model.config.vocab_size, (n,)).astype(np.int32)
                    for n in (5, 9, 3, 12, 7, 16)]
         gen = GenerationConfig(max_new_tokens=8, do_sample=False, eos_token_id=None)
-        _, legacy = self._serve(model, params, prompts, gen, paged=False)
-        eng, paged = self._serve(model, params, prompts, gen, paged=True)
-        assert paged == legacy
+        eng, paged = self._serve(model, params, prompts, gen)
         for toks, prompt in zip(paged, prompts):
             assert toks == _expected(model, params, prompt, gen)
         # every page came back once the pool drained and the cache let go
@@ -137,34 +164,42 @@ class TestPagedTokenIdentity:
             pass
         assert eng.kv.allocator.used_count == 0
 
-    def test_sampled_stream_matches_legacy(self):
+    @pytest.mark.parametrize("other", [
+        dict(slot_order=(1, 0)), dict(async_depth=0),
+        dict(interleave_prefill=True), dict(mesh="tp2"),
+    ], ids=["slot_order", "async_depth", "interleave_prefill", "tp2"])
+    def test_sampled_stream_is_independent_of(self, other):
         # same base seed + same per-rid fold-in => the identical sample stream,
-        # paged or not (the traced decode body is shared, not just equivalent)
+        # whichever lanes carry the requests, whether the loop is pipelined,
+        # whether chunks queue ahead of or behind the window, and whether the
+        # pool is whole or head-sharded over two devices; none of them may
+        # add a device program either
+        if other.get("mesh") == "tp2":
+            other = dict(mesh=build_mesh({"tp": 2}, devices=jax.devices()[:2]))
         model, params = _tiny_model()
         rng = np.random.default_rng(8)
         prompts = [rng.integers(1, model.config.vocab_size, (n,)).astype(np.int32)
                    for n in (6, 11, 9)]
         gen = GenerationConfig(max_new_tokens=6, do_sample=True, temperature=0.8,
                                top_k=50, eos_token_id=None)
-        _, legacy = self._serve(model, params, prompts, gen, paged=False)
-        _, paged = self._serve(model, params, prompts, gen, paged=True)
-        assert paged == legacy
+        e0, base = self._serve(model, params, prompts, gen)
+        e1, varied = self._serve(model, params, prompts, gen, **other)
+        assert varied == base
+        assert e1.compiled_executable_counts() == e0.compiled_executable_counts()
 
-    def test_speculative_paged_matches_legacy(self, cycling_prompts):
+    def test_speculative_paged_matches_generate(self, cycling_prompts):
         model, params = _tiny_model()
         prompts = cycling_prompts(model, params, new_tokens=8, k=2)
         gen = GenerationConfig(max_new_tokens=8, do_sample=False, eos_token_id=None)
-        _, legacy = self._serve(model, params, prompts, gen, paged=False, speculate_k=2)
-        eng, paged = self._serve(model, params, prompts, gen, paged=True, speculate_k=2)
-        assert paged == legacy
+        eng, paged = self._serve(model, params, prompts, gen, speculate_k=2)
         assert paged == [_expected(model, params, p, gen) for p in prompts]
         # the prompts' continuations are ones the drafter provably predicts,
         # so the verify path ran AND committed drafts
         assert eng.stats["spec_accepted"] > 0
 
     def test_compiled_shape_budget(self):
-        """Paged swaps insert + per-bucket copies for one copy_page: the whole
-        device program set is decode + per-bucket prefill + copy_page."""
+        """The whole device program set is decode + per-bucket prefill +
+        lane_install + copy_page: a prefix hit adds no executable."""
         if not jit_cache_supported():
             pytest.skip("this jax hides the pjit executable-cache counter")
         model, params = _tiny_model()
@@ -172,7 +207,7 @@ class TestPagedTokenIdentity:
         prompts = [rng.integers(1, model.config.vocab_size, (n,)).astype(np.int32)
                    for n in (5, 9, 12, 8)]
         gen = GenerationConfig(max_new_tokens=4, do_sample=False, eos_token_id=None)
-        eng, _ = self._serve(model, params, prompts, gen, paged=True)
+        eng, _ = self._serve(model, params, prompts, gen)
         counts = eng.compiled_executable_counts()
         assert set(counts) == {"decode_window", "copy_page", "lane_install",
                                "prefill_4", "prefill_8"}
@@ -195,9 +230,8 @@ class TestPagedPrefixSharing:
         prompts = [np.concatenate([shared, rng.integers(1, vocab, (5,)).astype(np.int32)])
                    for _ in range(3)]
         gen = GenerationConfig(max_new_tokens=5, do_sample=False, eos_token_id=None)
-        legacy = _engine(model, params, registry=MetricsRegistry())
-        expect = [r.tokens for r in legacy.serve([p.copy() for p in prompts], configs=gen)]
-        eng = _engine(model, params, paged=True, registry=MetricsRegistry())
+        expect = [_expected(model, params, p, gen) for p in prompts]
+        eng = _engine(model, params, registry=MetricsRegistry())
         reqs = eng.serve([p.copy() for p in prompts], configs=gen)
         assert [r.tokens for r in reqs] == expect
         assert eng.stats["prefix_hit_tokens"] > 0
@@ -212,7 +246,7 @@ class TestPagedPrefixSharing:
         shared = rng.integers(1, model.config.vocab_size, (8,)).astype(np.int32)
         gen = GenerationConfig(max_new_tokens=6, do_sample=False, eos_token_id=None)
         expect = _expected(model, params, shared, gen)
-        eng = _engine(model, params, paged=True, registry=MetricsRegistry())
+        eng = _engine(model, params, registry=MetricsRegistry())
         reqs = eng.serve([shared.copy(), shared.copy(), shared.copy()], configs=gen)
         assert all(r.tokens == expect for r in reqs)
         assert eng.stats["cow_copies"] >= 1
@@ -228,12 +262,11 @@ class TestPagedPrefixSharing:
         prompts = [np.concatenate([shared, rng.integers(1, vocab, (n,)).astype(np.int32)])
                    for n in (4, 6, 5, 7)]
         gen = GenerationConfig(max_new_tokens=6, do_sample=False, eos_token_id=None)
-        legacy = _engine(model, params, registry=MetricsRegistry())
-        expect = [r.tokens for r in legacy.serve([p.copy() for p in prompts], configs=gen)]
+        expect = [_expected(model, params, p, gen) for p in prompts]
         # ~2.5 bucket-8 chunk-nodes of budget: inserts evict constantly
         cfg = model.config
         page_bytes = 2 * 4 * cfg.num_kv_heads * cfg.resolved_head_dim * cfg.num_layers * 4
-        eng = _engine(model, params, paged=True,
+        eng = _engine(model, params,
                       prefix_cache_mb=2.5 * 2 * page_bytes / 2**20,
                       registry=MetricsRegistry())
         reqs = eng.serve([p.copy() for p in prompts], configs=gen)
@@ -251,7 +284,7 @@ class TestPagedPrefixSharing:
         rng = np.random.default_rng(13)
         prompt = rng.integers(1, model.config.vocab_size, (8,)).astype(np.int32)
         gen = GenerationConfig(max_new_tokens=20, do_sample=False, eos_token_id=None)
-        eng = _engine(model, params, paged=True, registry=MetricsRegistry())
+        eng = _engine(model, params, registry=MetricsRegistry())
         req = eng.submit(prompt, config=gen)
         while not eng._active.any():
             eng.step()
@@ -276,15 +309,14 @@ class TestPagedPressure:
     def test_preemption_stays_token_exact(self):
         """A pool barely over one lane's worth of pages forces preemption:
         the youngest lane releases its pages, requeues, replays, and every
-        output stays identical to the slab engine's."""
+        output stays identical to ``generate``'s."""
         model, params = _tiny_model()
         rng = np.random.default_rng(14)
         prompts = [rng.integers(1, model.config.vocab_size, (n,)).astype(np.int32)
                    for n in (12, 16, 9, 14)]
         gen = GenerationConfig(max_new_tokens=28, do_sample=False, eos_token_id=None)
-        legacy = _engine(model, params, prefix_cache_mb=None, registry=MetricsRegistry())
-        expect = [r.tokens for r in legacy.serve([p.copy() for p in prompts], configs=gen)]
-        eng = _engine(model, params, paged=True, prefix_cache_mb=None,
+        expect = [_expected(model, params, p, gen) for p in prompts]
+        eng = _engine(model, params, prefix_cache_mb=None,
                       num_pages=17, registry=MetricsRegistry())  # Pmax=16 + null
         reqs = eng.serve([p.copy() for p in prompts], configs=gen)
         assert [r.tokens for r in reqs] == expect
@@ -302,7 +334,7 @@ class TestPagedPressure:
         # of the synchronous loop.  Under the depth-1 pipeline the pages are
         # deferred until the in-flight window retires — that path is covered
         # by test_serving_async.py::test_cancel_running_mid_flight.
-        eng = _engine(model, params, paged=True, prefix_cache_mb=None,
+        eng = _engine(model, params, prefix_cache_mb=None,
                       registry=MetricsRegistry(), async_depth=0)
         r1 = eng.submit(p1, config=gen)
         r2 = eng.submit(p2, config=gen)
@@ -322,7 +354,7 @@ class TestPagedPressure:
         rng = np.random.default_rng(16)
         prompt = rng.integers(1, model.config.vocab_size, (9,)).astype(np.int32)
         reg = MetricsRegistry()
-        eng = _engine(model, params, paged=True, registry=reg)
+        eng = _engine(model, params, registry=reg)
         eng.serve([prompt], configs=GenerationConfig(
             max_new_tokens=4, do_sample=False, eos_token_id=None))
         snap = reg.snapshot()

@@ -189,7 +189,7 @@ class TestPreemptionSingleTrace:
         prompts = _prompts(14, (12, 16, 9, 14), model.config.vocab_size)
         gen = GenerationConfig(max_new_tokens=28, do_sample=False,
                                eos_token_id=None)
-        eng = _engine(model, params, paged=True, prefix_cache_mb=None,
+        eng = _engine(model, params, prefix_cache_mb=None,
                       num_pages=17)  # Pmax = 16 + null: forces preemption
         reqs = eng.serve([p.copy() for p in prompts], gen)
         assert eng.stats["preemptions"] >= 1
@@ -299,7 +299,7 @@ class Service:
 
         def build():
             return ServingEngine(
-                self.model, self.params, registry=self.registry, paged=True,
+                self.model, self.params, registry=self.registry,
                 page_size=4, num_pages=65, **self.ENGINE_KW,
             )
 

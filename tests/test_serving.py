@@ -9,6 +9,8 @@ executable, one insert, one prefill per bucket — asserted via the jit cache
 counters (the no-per-request-retrace property that makes this TPU-viable).
 """
 
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -169,11 +171,10 @@ class TestCompiledShapes:
         eng = _engine(model, params, num_slots=2)
         eng.serve(prompts, gens)
         counts = eng.compiled_executable_counts()
-        # copy executables exist (prefix cache on by default) but stay
-        # uncompiled: random prompts share no prefixes
-        assert counts == {"decode_window": 1, "insert": 1, "lane_install": 1,
-                          "prefill_4": 1, "prefill_8": 1, "copy_4": 0,
-                          "copy_8": 0}
+        # copy_page exists but stays uncompiled: no prompt ends on a whole
+        # cached chunk, so no lane's tail page is shared with the cache
+        assert counts == {"decode_window": 1, "copy_page": 0, "lane_install": 1,
+                          "prefill_4": 1, "prefill_8": 1}
 
     def test_mixed_sampling_configs_share_decode_executable(self):
         """Per-request knobs (greedy vs sampled, different temps/top-k/eos)
@@ -263,15 +264,15 @@ class TestServingTelemetry:
         assert snap["serve/ttft_s"]["count"] == 3
         assert snap["serve/ttft_s"]["p99"] > 0
         assert snap["serve/token_latency_s"]["count"] == eng.stats["tokens_generated"]
-        # counters mirror the legacy stats dict exactly
+        # counters mirror the stats dict exactly
         for key, value in eng.stats.items():
             assert snap[f"serve/{key}_total"] == value
         # each executable behind the watchdog compiled exactly one signature
         assert snap["compile/serve/decode_window/count"] == 1
-        assert snap["compile/serve/insert/count"] == 1
+        assert snap["compile/serve/prefill_4/count"] == 1
         assert all(
             not wd.over_budget()
-            for wd in [eng._decode, eng._insert, *eng._prefill.values()]
+            for wd in [eng._decode, eng._copy_page, *eng._prefill.values()]
         )
         assert 0.0 < snap["serve/slot_occupancy"] <= 1.0
 
@@ -324,13 +325,19 @@ class TestServingTelemetry:
         assert not [r for r in caplog.records if "serve health" in r.getMessage()]
 
 
-def _slab(chunk, fill=0.0):
-    """A tiny fake KV slab [L=2, 1, chunk, H=2, D=4]: 64*chunk bytes each."""
-    return np.full((2, 1, chunk, 2, 4), fill, np.float32)
+PAGE_BYTES = 128        # a fake page of 4 tokens: the cache only counts bytes
+_page_ids = itertools.count(1)
+
+
+def _insert(cache, parent, tokens):
+    """Retain ``tokens`` (a multiple of 4) as that many fresh 4-token pages."""
+    n = len(tokens) // 4
+    return cache.insert_pages(parent, tokens, [next(_page_ids) for _ in range(n)],
+                              nbytes=n * PAGE_BYTES)
 
 
 class TestPrefixCacheUnit:
-    """Radix-tree mechanics in isolation: numpy slabs, no engine, no device."""
+    """Radix-tree mechanics in isolation: page ids, no engine, no device."""
 
     def test_rolling_hash_composes(self):
         a, b = np.arange(4, dtype=np.int32), np.arange(4, 9, dtype=np.int32)
@@ -342,63 +349,57 @@ class TestPrefixCacheUnit:
         prompt = np.arange(1, 13, dtype=np.int32)           # 12 tokens
         chunks = plan_chunks(12, (4, 8))                    # ((8, 8), (4, 4))
         assert cache.match(prompt, chunks) == []
-        n1 = cache.insert(None, prompt[:8], _slab(8), _slab(8))
-        n2 = cache.insert(n1, prompt[8:12], _slab(4), _slab(4))
+        n1 = _insert(cache, None, prompt[:8])
+        n2 = _insert(cache, n1, prompt[8:12])
         assert [n1, n2] == cache.match(prompt, chunks)
         # an 11-token prompt shares only the full first chunk: (8,8),(4,3)
         assert cache.match(prompt[:11], plan_chunks(11, (4, 8))) == [n1]
         # same tokens, different alignment: a (4,4) head chunk is a miss
         assert cache.match(prompt[:4], plan_chunks(4, (4, 8))) == []
         # re-inserting an already-resident chunk returns the existing node
-        assert cache.insert(n1, prompt[8:12], _slab(4), _slab(4)) is n2
+        assert _insert(cache, n1, prompt[8:12]) is n2
         assert cache.num_nodes == 2
 
     def test_lru_eviction_under_tiny_budget(self):
-        slab_bytes = 2 * _slab(4).nbytes                    # k + v = 1024
-        cache = PrefixCache(2 * slab_bytes, registry=MetricsRegistry())
+        cache = PrefixCache(2 * PAGE_BYTES, registry=MetricsRegistry())
         ta = np.arange(0, 4, dtype=np.int32)
         tb = np.arange(4, 8, dtype=np.int32)
         tc = np.arange(8, 12, dtype=np.int32)
-        a = cache.insert(None, ta, _slab(4), _slab(4))
-        assert cache.insert(None, tb, _slab(4), _slab(4)) is not None
+        a = _insert(cache, None, ta)
+        assert _insert(cache, None, tb) is not None
         cache.match(ta, ((4, 4),))                          # touch a: b is now LRU
-        assert cache.insert(None, tc, _slab(4), _slab(4)) is not None
+        assert _insert(cache, None, tc) is not None
         assert cache.evictions == 1 and cache.num_nodes == 2
         assert cache.match(ta, ((4, 4),)) == [a]            # survived
         assert cache.match(tb, ((4, 4),)) == []             # evicted
-        # a slab larger than the whole budget is refused outright
-        assert cache.insert(None, np.arange(32, dtype=np.int32),
-                            _slab(32), _slab(32)) is None
+        # a chunk larger than the whole budget is refused outright
+        assert _insert(cache, None, np.arange(32, dtype=np.int32)) is None
 
     def test_refcount_pins_mid_prefill_hit(self):
-        """A pinned node (a request mid-prefill depends on its slab) never
+        """A pinned node (a request mid-prefill depends on its pages) never
         evicts, even as fresh inserts churn everything unpinned around it."""
-        slab_bytes = 2 * _slab(4).nbytes
-        cache = PrefixCache(2 * slab_bytes, registry=MetricsRegistry())
+        cache = PrefixCache(2 * PAGE_BYTES, registry=MetricsRegistry())
         ta = np.arange(0, 4, dtype=np.int32)
-        a = cache.insert(None, ta, _slab(4), _slab(4))
+        a = _insert(cache, None, ta)
         cache.acquire([a])                                  # hit is mid-prefill
         for i in range(1, 4):                               # churn: b, c, d
             t = np.arange(4 * i, 4 * i + 4, dtype=np.int32)
-            assert cache.insert(None, t, _slab(4), _slab(4)) is not None
+            assert _insert(cache, None, t) is not None
         assert cache.match(ta, ((4, 4),)) == [a]            # pinned throughout
         cache.release([a])
         # release also LRU-touched it, so one more insert evicts the OTHER node
-        assert cache.insert(None, np.arange(40, 44, dtype=np.int32),
-                            _slab(4), _slab(4)) is not None
+        assert _insert(cache, None, np.arange(40, 44, dtype=np.int32)) is not None
         assert cache.match(ta, ((4, 4),)) == [a]
         with pytest.raises(RuntimeError, match="underflow"):
             cache.release([a])
 
     def test_interior_nodes_never_evict_before_leaves(self):
-        slab_bytes = 2 * _slab(4).nbytes
-        cache = PrefixCache(3 * slab_bytes, registry=MetricsRegistry())
+        cache = PrefixCache(3 * PAGE_BYTES, registry=MetricsRegistry())
         prompt = np.arange(0, 8, dtype=np.int32)
-        parent = cache.insert(None, prompt[:4], _slab(4), _slab(4))
-        child = cache.insert(parent, prompt[4:], _slab(4), _slab(4))
+        parent = _insert(cache, None, prompt[:4])
+        child = _insert(cache, parent, prompt[4:])
         cache.match(prompt[:4], ((4, 4),))                  # parent is MRU, child LRU
-        assert cache.insert(None, np.arange(20, 28, dtype=np.int32),
-                            _slab(8), _slab(8)) is not None
+        assert _insert(cache, None, np.arange(20, 28, dtype=np.int32)) is not None
         # the leaf went, not the (older-but-interior would break the chain) parent
         assert cache.match(prompt, ((4, 4), (4, 4))) == [parent]
         assert child not in cache._nodes
@@ -449,34 +450,10 @@ class TestPrefixCacheEngine:
         for req, prompt in zip(reqs, warm[:2]):
             assert req.tokens == _expected(model, params, prompt, gen)
 
-    def test_compiled_shape_budget_includes_copies(self):
-        """Hits replay through exactly one fixed copy executable per bucket —
-        the compiled-shape budget grows by len(buckets) and nothing else."""
-        if not jit_cache_supported():
-            pytest.skip("this jax hides the pjit executable-cache counter")
-        model, params = _tiny_model()
-        rng = np.random.default_rng(23)
-        vocab = model.config.vocab_size
-        p8 = rng.integers(1, vocab, (8,)).astype(np.int32)
-        p4 = rng.integers(1, vocab, (4,)).astype(np.int32)
-        eng = _engine(model, params)
-        gen = GenerationConfig(max_new_tokens=3)
-        # duplicates at each bucket length + varied offsets/partials around them
-        prompts = [p8, p8.copy(), p4, p4.copy(),
-                   np.concatenate([p8, p4]), np.concatenate([p8, p4, p4[:1]])]
-        reqs = eng.serve(prompts, [gen] * len(prompts))
-        for req, prompt in zip(reqs, prompts):
-            assert req.tokens == _expected(model, params, prompt, gen)
-        assert eng.compiled_executable_counts() == {
-            "decode_window": 1, "insert": 1, "lane_install": 1,
-            "prefill_4": 1, "prefill_8": 1, "copy_4": 1, "copy_8": 1,
-        }
-        assert not any(wd.over_budget() for wd in eng._copy.values())
-
-    def test_compiled_shape_budget_paged(self):
-        """The paged engine's whole program set: one decode window, one
-        prefill per bucket, one copy_page — no insert, no per-bucket copies
-        (hits alias pages), and nothing retraces across a workload that mixes
+    def test_compiled_shape_budget_with_hits(self):
+        """The engine's whole program set: one decode window, one prefill per
+        bucket, one lane_install, one copy_page — hits alias pages, so they
+        add no executable, and nothing retraces across a workload that mixes
         cold prompts, duplicate-prefix hits, and copy-on-write."""
         if not jit_cache_supported():
             pytest.skip("this jax hides the pjit executable-cache counter")
@@ -486,7 +463,7 @@ class TestPrefixCacheEngine:
         p8 = rng.integers(1, vocab, (8,)).astype(np.int32)
         prompts = [p8, p8.copy(), np.concatenate([p8, p8[:5]]),
                    rng.integers(1, vocab, (11,)).astype(np.int32)]
-        eng = _engine(model, params, paged=True)
+        eng = _engine(model, params)
         gen = GenerationConfig(max_new_tokens=3)
         reqs = eng.serve(prompts, [gen] * len(prompts))
         for req, prompt in zip(reqs, prompts):
@@ -499,13 +476,13 @@ class TestPrefixCacheEngine:
         assert not eng._copy_page.over_budget()
 
     def test_eviction_under_tiny_engine_budget_stays_exact(self):
-        """A budget far below the workload's slab footprint churns the cache
+        """A budget far below the workload's page footprint churns the cache
         hard (insert/evict on nearly every chunk) without touching outputs."""
         model, params = _tiny_model()
         rng = np.random.default_rng(24)
         prompts = _prompts(rng, [8, 12, 9, 16, 8], model.config.vocab_size)
         gens = [GenerationConfig(max_new_tokens=n) for n in (4, 6, 3, 5, 4)]
-        # one float32 8-chunk slab for the tiny model is ~4 KiB; 6 KiB holds
+        # a float32 8-token chunk's pages for the tiny model are ~4 KiB; 6 KiB holds
         # barely one, so every new full chunk forces an eviction decision
         eng = _engine(model, params, prefix_cache_mb=6 / 1024)
         reqs = eng.serve(prompts, gens)
@@ -666,9 +643,8 @@ class TestSpeculative:
         # mixed drafted + fallback cycles ran; exactly ONE verify signature
         assert eng.stats["spec_drafted"] > 0
         assert eng.compiled_executable_counts() == {
-            "decode_window": 1, "insert": 1, "verify_window": 1,
+            "decode_window": 1, "copy_page": 1, "verify_window": 1,
             "lane_install": 1, "prefill_4": 1, "prefill_8": 1,
-            "copy_4": 0, "copy_8": 0,
         }
         assert not eng._verify.over_budget()
 
@@ -743,7 +719,7 @@ class TestInterleavedPrefill:
         return _prompts(rng, lens, model.config.vocab_size)
 
     def _serve(self, model, params, prompts, gen, **kw):
-        defaults = dict(paged=True, page_size=4, async_depth=1)
+        defaults = dict(page_size=4, async_depth=1)
         defaults.update(kw)
         eng = _engine(model, params, **defaults)
         reqs = eng.serve([p.copy() for p in prompts], configs=gen)
@@ -836,19 +812,15 @@ class TestInterleavedPrefill:
     def test_prefill_kernel_validation(self):
         model, params = _tiny_model()
         with pytest.raises(ValueError):
-            _engine(model, params, paged=True, prefill_kernel="mosaic")
-        with pytest.raises(ValueError):
-            _engine(model, params, paged=False, prefill_kernel="pallas")
-        with pytest.raises(ValueError):
-            _engine(model, params, paged=False, interleave_prefill=True)
+            _engine(model, params, prefill_kernel="mosaic")
 
     def test_prefill_kernel_follows_decode_kernel_by_default(self):
         model, params = _tiny_model()
-        eng = _engine(model, params, paged=True, decode_kernel="pallas")
+        eng = _engine(model, params, decode_kernel="pallas")
         assert eng.prefill_kernel == "pallas"
-        eng = _engine(model, params, paged=True)
+        eng = _engine(model, params)
         assert eng.prefill_kernel == "xla"
-        eng = _engine(model, params, paged=True, decode_kernel="pallas",
+        eng = _engine(model, params, decode_kernel="pallas",
                       prefill_kernel="xla")
         assert eng.prefill_kernel == "xla"
 
@@ -857,7 +829,7 @@ class TestInterleavedPrefill:
         prompts = self._workload(model, seed=45)
         gen = GenerationConfig(max_new_tokens=8, do_sample=False, eos_token_id=None)
         reg = MetricsRegistry()
-        eng = _engine(model, params, paged=True, page_size=4, async_depth=1,
+        eng = _engine(model, params, page_size=4, async_depth=1,
                       interleave_prefill=True, registry=reg)
         reqs = [eng.submit(p, config=gen,
                            request_class="chat" if i % 2 else "bulk")

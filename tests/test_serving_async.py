@@ -92,34 +92,39 @@ class TestTokenIdentity:
         assert e1.compiled_executable_counts() == e0.compiled_executable_counts()
         return t1
 
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_greedy(self, paged):
+    # the window gathers a view of each lane's pages, or reads them in place
+    ARMS = pytest.mark.parametrize("arm", [
+        {}, dict(decode_kernel="pallas"),
+    ], ids=["gathered", "direct"])
+
+    @ARMS
+    def test_greedy(self, arm):
         model, params = _tiny_model()
         gen = GenerationConfig(max_new_tokens=12, do_sample=False)
-        self._pair(model, params, gen, paged=paged)
+        self._pair(model, params, gen, **arm)
 
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_sampled(self, paged):
+    @ARMS
+    def test_sampled(self, arm):
         model, params = _tiny_model()
         gen = GenerationConfig(max_new_tokens=12, do_sample=True,
                                temperature=0.8, top_k=8, top_p=0.95)
-        self._pair(model, params, gen, paged=paged, rng_seed=7)
+        self._pair(model, params, gen, rng_seed=7, **arm)
 
     def test_speculative(self):
         model, params = _tiny_model()
         gen = GenerationConfig(max_new_tokens=12, do_sample=False)
-        self._pair(model, params, gen, paged=True, speculate_k=2)
+        self._pair(model, params, gen, speculate_k=2)
 
     def test_int8_kv(self):
         model, params = _tiny_model()
         gen = GenerationConfig(max_new_tokens=12, do_sample=False)
-        self._pair(model, params, gen, paged=True, kv_dtype="int8")
+        self._pair(model, params, gen, kv_dtype="int8")
 
     def test_tp2(self):
         model, params = _tiny_model()
         gen = GenerationConfig(max_new_tokens=12, do_sample=False)
         mesh = build_mesh({"tp": 2}, devices=jax.devices()[:2])
-        self._pair(model, params, gen, paged=True, mesh=mesh, num_slots=4)
+        self._pair(model, params, gen, mesh=mesh, num_slots=4)
 
     def test_eos_lag_is_invisible(self):
         """A lane hitting EOS (or max_new_tokens) mid-pipeline runs one extra
@@ -143,7 +148,7 @@ class TestCancelMidFlight:
         p1, p2 = _prompts(15, (12, 16), model.config.vocab_size)
         gen = GenerationConfig(max_new_tokens=16, do_sample=False, eos_token_id=None)
         expect2 = _expected(model, params, p2, gen)
-        eng = _engine(model, params, paged=True, prefix_cache_mb=None)
+        eng = _engine(model, params, prefix_cache_mb=None)
         r1 = eng.submit(p1, config=gen)
         r2 = eng.submit(p2, config=gen)
         while r1.state.value != "running":
@@ -181,13 +186,12 @@ class TestPreemptionMidFlight:
     def test_preemption_token_exact_under_pipeline(self):
         """Page pressure with a window in flight: reclaim drains the pipeline
         to collect deferred pages before preempting, and replay stays
-        token-exact against the slab engine."""
+        token-exact against ``generate``."""
         model, params = _tiny_model()
         prompts = _prompts(14, (12, 16, 9, 14), model.config.vocab_size)
         gen = GenerationConfig(max_new_tokens=28, do_sample=False, eos_token_id=None)
-        legacy = _engine(model, params, prefix_cache_mb=None)
-        expect = [r.tokens for r in legacy.serve([p.copy() for p in prompts], gen)]
-        eng = _engine(model, params, paged=True, prefix_cache_mb=None,
+        expect = [_expected(model, params, p, gen) for p in prompts]
+        eng = _engine(model, params, prefix_cache_mb=None,
                       num_pages=17)  # Pmax = 16 + null: forces preemption
         reqs = eng.serve([p.copy() for p in prompts], gen)
         assert [r.tokens for r in reqs] == expect

@@ -63,11 +63,10 @@ class TestShardedPoolGeometry:
 
     def test_engine_reports_per_device_bytes(self):
         model, params = _tiny_model()
-        for paged in (False, True):
-            e1 = _engine(model, params, paged=paged)
-            e2 = _engine(model, params, paged=paged, mesh=_mesh_tp2())
-            assert e2.tp_degree == 2
-            assert e2.kv_pool_bytes() * 2 == e1.kv_pool_bytes()
+        e1 = _engine(model, params)
+        e2 = _engine(model, params, mesh=_mesh_tp2())
+        assert e2.tp_degree == 2
+        assert e2.kv_pool_bytes() * 2 == e1.kv_pool_bytes()
 
     def test_indivisible_heads_rejected(self):
         model, params = _tiny_model(hidden_size=48, num_heads=6, num_kv_heads=3)
@@ -79,7 +78,7 @@ class TestShardedPoolGeometry:
 
         model, params = _tiny_model()
         reg = MetricsRegistry()
-        eng = _engine(model, params, paged=True, mesh=_mesh_tp2(), registry=reg)
+        eng = _engine(model, params, mesh=_mesh_tp2(), registry=reg)
         assert reg.gauge("serve/tp_degree").value == 2.0
         # column-parallel only: o_proj/down_proj replicated (token identity)
         sharded = {}
@@ -100,7 +99,7 @@ class TestShardedPoolGeometry:
             resolve_paged_kernel("pallas", mesh)
         model, params = _tiny_model()
         with pytest.raises(ValueError, match="single-chip"):
-            _engine(model, params, paged=True, mesh=mesh, decode_kernel="pallas")
+            _engine(model, params, mesh=mesh, decode_kernel="pallas")
         assert resolve_paged_kernel("pallas", None) == "pallas"
         assert resolve_paged_kernel("xla", mesh) == "xla"
         dp = build_mesh({"dp": 2}, devices=jax.devices()[:2])
@@ -116,27 +115,26 @@ class TestTokenIdentity:
         reqs = eng.serve(prompts, gens)
         return [list(r.tokens) for r in reqs], eng
 
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_greedy(self, paged):
+    def test_greedy(self):
         model, params = _tiny_model()
         gen = GenerationConfig(max_new_tokens=12, do_sample=False)
-        t1, e1 = self._serve(model, params, gen, None, paged=paged)
-        t2, e2 = self._serve(model, params, gen, _mesh_tp2(), paged=paged)
+        t1, e1 = self._serve(model, params, gen, None)
+        t2, e2 = self._serve(model, params, gen, _mesh_tp2())
         assert t1 == t2
         assert e1.compiled_executable_counts() == e2.compiled_executable_counts()
 
     def test_sampled(self):
         model, params = _tiny_model()
         gen = GenerationConfig(max_new_tokens=12, do_sample=True, temperature=0.8)
-        t1, _ = self._serve(model, params, gen, None, paged=True, rng_seed=7)
-        t2, _ = self._serve(model, params, gen, _mesh_tp2(), paged=True, rng_seed=7)
+        t1, _ = self._serve(model, params, gen, None, rng_seed=7)
+        t2, _ = self._serve(model, params, gen, _mesh_tp2(), rng_seed=7)
         assert t1 == t2
 
     def test_speculative(self):
         model, params = _tiny_model()
         gen = GenerationConfig(max_new_tokens=12, do_sample=False)
-        t1, e1 = self._serve(model, params, gen, None, paged=True, speculate_k=2)
-        t2, e2 = self._serve(model, params, gen, _mesh_tp2(), paged=True,
+        t1, e1 = self._serve(model, params, gen, None, speculate_k=2)
+        t2, e2 = self._serve(model, params, gen, _mesh_tp2(),
                              speculate_k=2)
         assert t1 == t2
         assert e1.compiled_executable_counts() == e2.compiled_executable_counts()
@@ -144,8 +142,8 @@ class TestTokenIdentity:
     def test_int8_kv(self):
         model, params = _tiny_model()
         gen = GenerationConfig(max_new_tokens=12, do_sample=False)
-        t1, e1 = self._serve(model, params, gen, None, paged=True, kv_dtype="int8")
-        t2, e2 = self._serve(model, params, gen, _mesh_tp2(), paged=True,
+        t1, e1 = self._serve(model, params, gen, None, kv_dtype="int8")
+        t2, e2 = self._serve(model, params, gen, _mesh_tp2(),
                              kv_dtype="int8")
         assert t1 == t2
         assert e2.kv_pool_bytes() * 2 == e1.kv_pool_bytes()
@@ -158,10 +156,10 @@ class TestTokenIdentity:
         model, params = _tiny_model()
         gen = GenerationConfig(max_new_tokens=12, do_sample=False)
         with pytest.raises(ValueError, match="single-chip"):
-            _engine(model, params, mesh=_mesh_tp2(), paged=True,
+            _engine(model, params, mesh=_mesh_tp2(),
                     prefill_kernel="pallas", interleave_prefill=True)
-        t1, _ = self._serve(model, params, gen, None, paged=True)
-        t2, e2 = self._serve(model, params, gen, _mesh_tp2(), paged=True,
+        t1, _ = self._serve(model, params, gen, None)
+        t2, e2 = self._serve(model, params, gen, _mesh_tp2(),
                              interleave_prefill=True)
         assert t1 == t2
         assert e2.prefill_kernel == "xla"
@@ -301,10 +299,10 @@ class TestRouterOverTpReplicas:
         gen = GenerationConfig(max_new_tokens=8, do_sample=False)
         prompts = _prompts(10, (8, 12, 5, 9), model.config.vocab_size)
         # single-chip reference
-        ref = _engine(model, params, paged=True)
+        ref = _engine(model, params)
         expected = [list(r.tokens) for r in ref.serve(prompts, gen)]
         engines = [
-            _engine(model, params, paged=True, mesh=m, prefix_cache_mb=4.0)
+            _engine(model, params, mesh=m, prefix_cache_mb=4.0)
             for m in replica_meshes(2, {"tp": 2})
         ]
         router = ReplicaRouter(engines, policy="affinity")

@@ -463,7 +463,7 @@ def test_tenant_rollup_exact_across_preemption():
     model, params = _tiny_model()
     registry = MetricsRegistry()
     # Pmax=16 + null page: the pool is one lane's worth, forcing preemption
-    eng = _engine(model, params, paged=True, page_size=4, num_pages=17,
+    eng = _engine(model, params, page_size=4, num_pages=17,
                   max_queue=8, registry=registry)
     rng = np.random.default_rng(14)
     prompts = [rng.integers(1, model.config.vocab_size, (n,)).astype(np.int32)
@@ -511,9 +511,9 @@ def test_tenant_rollup_exact_across_preemption():
 def test_tenant_survives_export_adopt():
     model, params = _tiny_model()
     registry = MetricsRegistry()
-    e1 = _engine(model, params, paged=True, page_size=4, num_pages=33,
+    e1 = _engine(model, params, page_size=4, num_pages=33,
                  max_queue=8, registry=registry)
-    e2 = _engine(model, params, paged=True, page_size=4, num_pages=33,
+    e2 = _engine(model, params, page_size=4, num_pages=33,
                  max_queue=8, registry=registry)
     rng = np.random.default_rng(7)
     prompt = rng.integers(1, model.config.vocab_size, (8,)).astype(np.int32)

@@ -3,7 +3,7 @@
 The contract mirrors linear speculation's: the whole apparatus — the
 truncated-layer draft head, the one-forward token-tree verify, branch
 selection, per-lane KV commit/rollback — must be INVISIBLE in greedy token
-streams (bitwise identical to the speculation-off engine, slab and paged,
+streams (bitwise identical to the speculation-off engine, gathered and in-place,
 float and quantized KV alike) and visible only in the stats.  On top of
 that the device program set grows by exactly two executables
 (``draft_forward`` + ``tree_verify_window``), each with one signature.
@@ -22,7 +22,7 @@ from accelerate_tpu.models.transformer import KVCache, Transformer, TransformerC
 from accelerate_tpu.parallel.mesh import build_mesh
 from accelerate_tpu.serving import ServingEngine
 from accelerate_tpu.serving.paging import DraftContextWindow
-from accelerate_tpu.serving.pool import make_tree_verify_window
+from accelerate_tpu.serving.pool import make_paged_tree_verify_window
 from accelerate_tpu.serving.spec import propose_ngram_draft
 from accelerate_tpu.serving.spec_exec import (
     NgramDrafter,
@@ -271,9 +271,26 @@ class TestDraftForward:
         return out
 
 
-def _copy(cache):
-    # the verify window donates its cache argument; probe calls need replicas
-    return jax.tree_util.tree_map(lambda a: jnp.array(a), cache)
+PAGE = 4
+
+
+def _paged(cache):
+    """A lane's contiguous cache cut into pages 1..P of a fresh pool (page 0
+    is the null page), with the block table that maps them back in order.
+    Fresh arrays every call: the verify window donates its pages."""
+    def cut(x):                                      # [L, 1, T, H, D]
+        L, _, T, H, D = x.shape
+        pages = x[:, 0].reshape(L, T // PAGE, PAGE, H, D).swapaxes(2, 3)
+        return jnp.concatenate([jnp.zeros_like(pages[:, :1]), pages], axis=1)
+
+    tables = jnp.arange(1, cache.k.shape[2] // PAGE + 1, dtype=jnp.int32)[None]
+    return cut(cache.k), cut(cache.v), tables
+
+
+def _rows(pages, lo, hi):
+    """Positions ``[lo, hi)`` of the lane laid out by :func:`_paged`."""
+    L, _, H, _, D = pages.shape
+    return np.asarray(pages[:, 1:].swapaxes(2, 3).reshape(L, -1, H, D)[:, lo:hi])
 
 
 class TestTreeVerifyWindowDirect:
@@ -287,17 +304,22 @@ class TestTreeVerifyWindowDirect:
         return cache, int(jnp.argmax(logits[0, -1]))
 
     def _greedy_chain(self, model, params, cache, pending, n):
+        """``n`` sequential greedy steps: the tokens, and the cache they
+        leave (what a tree verify's committed rows are held to)."""
         c, tok, out = cache, pending, []
         for _ in range(n):
             lg, c = model.apply({"params": params},
                                 jnp.asarray([[tok]], jnp.int32), cache=c)
             tok = int(jnp.argmax(lg[0, 0]))
             out.append(tok)
-        return out
+        return out, c
 
     def _call(self, win, params, cache, tokens, eos=-1, do_sample=False,
               top_k=0):
-        return win(params, _copy(cache), jnp.asarray(tokens, jnp.int32),
+        """``(pages_k, pages_v, out, n_commit, pending, rngs)``."""
+        pages_k, pages_v, tables = _paged(cache)
+        return win(params, pages_k, pages_v, tables, cache.index,
+                   jnp.asarray(tokens, jnp.int32),
                    jnp.ones(1, bool), jnp.full(1, eos, jnp.int32),
                    jnp.full(1, do_sample, bool), jnp.ones(1, jnp.float32),
                    jnp.full(1, top_k, jnp.int32), jnp.ones(1, jnp.float32),
@@ -310,8 +332,9 @@ class TestTreeVerifyWindowDirect:
             1, model.config.vocab_size, (8,)).astype(np.int32)
         cache, pending = self._lane(model, params, prompt)
         tree = TreeSpec(2, 3)
-        win = make_tree_verify_window(model, tree)
-        g = self._greedy_chain(model, params, cache, pending, tree.depth + 1)
+        win = make_paged_tree_verify_window(model, tree)
+        g, linear = self._greedy_chain(model, params, cache, pending,
+                                       tree.depth + 1)
         alt = next(t for t in range(1, model.config.vocab_size)
                    if t not in set(g) and t != pending)
         # branch 0 carries the true greedy chain, branch 1 a loser made of a
@@ -319,19 +342,32 @@ class TestTreeVerifyWindowDirect:
         tokens = np.array([[pending, g[0], g[1], g[2], alt, alt, alt]],
                           np.int32)
         return dict(model=model, params=params, cache=cache, win=win,
-                    tree=tree, g=g, alt=alt, tokens=tokens, plen=len(prompt))
+                    tree=tree, g=g, alt=alt, tokens=tokens, plen=len(prompt),
+                    linear=linear)
+
+    def _assert_committed_rows_are_linear_decodes(self, scene, pages_k,
+                                                  pages_v, n):
+        """The ``n`` rows the window committed at the lane frontier are the
+        rows sequential decode writes there (the winning path compacted)."""
+        lo, hi = scene["plen"], scene["plen"] + n
+        for pages, want in ((pages_k, scene["linear"].k),
+                            (pages_v, scene["linear"].v)):
+            np.testing.assert_allclose(
+                _rows(pages, lo, hi), np.asarray(want[:, 0, lo:hi]),
+                rtol=1e-5, atol=1e-5)
 
     def test_full_accept_commits_depth_plus_bonus(self, scene):
-        cache, out, n_commit, _, _ = self._call(
+        pages_k, pages_v, out, n_commit, _, _ = self._call(
             scene["win"], scene["params"], scene["cache"], scene["tokens"])
         assert int(n_commit[0]) == scene["tree"].depth + 1
         assert np.asarray(out)[0].tolist() == scene["g"]
-        assert int(cache.index[0]) == scene["plen"] + scene["tree"].depth + 1
+        self._assert_committed_rows_are_linear_decodes(
+            scene, pages_k, pages_v, scene["tree"].depth + 1)
 
     def test_eos_on_losing_branch_does_not_terminate(self, scene):
         # the loser branch is ALL eos tokens; the winning path must commit
         # in full and never emit the eos that only losing nodes carried
-        _, out, n_commit, _, _ = self._call(
+        _, _, out, n_commit, _, _ = self._call(
             scene["win"], scene["params"], scene["cache"], scene["tokens"],
             eos=scene["alt"])
         assert int(n_commit[0]) == scene["tree"].depth + 1
@@ -339,19 +375,19 @@ class TestTreeVerifyWindowDirect:
         assert committed == scene["g"] and scene["alt"] not in committed
 
     def test_eos_on_accepted_path_masks_deeper_commits(self, scene):
-        cache, out, n_commit, _, _ = self._call(
+        pages_k, pages_v, out, n_commit, _, _ = self._call(
             scene["win"], scene["params"], scene["cache"], scene["tokens"],
             eos=scene["g"][1])
         assert int(n_commit[0]) == 2                 # g0, then the eos itself
         assert np.asarray(out)[0].tolist()[:2] == scene["g"][:2]
         assert np.asarray(out)[0, 2:].tolist() == [0, 0]   # pad past the clamp
-        assert int(cache.index[0]) == scene["plen"] + 2
+        self._assert_committed_rows_are_linear_decodes(scene, pages_k, pages_v, 2)
 
     def test_sampled_point_mass_equals_greedy(self, scene):
         # top_k=1 collapses every node distribution to its argmax: the
         # multi-try branch point and the Leviathan chain both accept exactly
         # the greedy path, bonus draw included
-        _, out, n_commit, _, _ = self._call(
+        _, _, out, n_commit, _, _ = self._call(
             scene["win"], scene["params"], scene["cache"], scene["tokens"],
             do_sample=True, top_k=1)
         assert int(n_commit[0]) == scene["tree"].depth + 1
@@ -365,14 +401,16 @@ class TestTreeEngine:
     def _workload(self, model, rng, lens=(9, 5, 12)):
         return _prompts(rng, lens, model.config.vocab_size)
 
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_greedy_token_exact(self, paged):
+    @pytest.mark.parametrize("arm", [
+        {}, dict(decode_kernel="pallas"),
+    ], ids=["gathered", "direct"])
+    def test_greedy_token_exact(self, arm):
         model, params = _tiny_model()
         prompts = self._workload(model, np.random.default_rng(41))
         gens = [GenerationConfig(max_new_tokens=n) for n in (12, 8, 10)]
         outs = {}
         for tree_on in (False, True):
-            eng = _engine(model, params, paged=paged,
+            eng = _engine(model, params, **arm,
                           **(TREE_KW if tree_on else {}))
             reqs = eng.serve(prompts, gens)
             outs[tree_on] = [r.tokens for r in reqs]
@@ -386,8 +424,8 @@ class TestTreeEngine:
         model, params = _tiny_model()
         prompts = self._workload(model, np.random.default_rng(42))
         gen = GenerationConfig(max_new_tokens=10)
-        base = _engine(model, params, paged=True, decode_kernel="pallas")
-        tree = _engine(model, params, paged=True, decode_kernel="pallas",
+        base = _engine(model, params, decode_kernel="pallas")
+        tree = _engine(model, params, decode_kernel="pallas",
                        **TREE_KW)
         t0 = [r.tokens for r in base.serve(prompts, gen)]
         t1 = [r.tokens for r in tree.serve(prompts, gen)]
@@ -400,7 +438,7 @@ class TestTreeEngine:
         model, params = _tiny_model()
         prompts = self._workload(model, np.random.default_rng(43))
         gen = GenerationConfig(max_new_tokens=10)
-        kw = dict(paged=True, kv_dtype="int8", page_size=1)
+        kw = dict(kv_dtype="int8", page_size=1)
         t0 = [r.tokens for r in _engine(model, params, **kw).serve(prompts, gen)]
         t1 = [r.tokens
               for r in _engine(model, params, **kw, **TREE_KW).serve(prompts, gen)]
@@ -412,19 +450,21 @@ class TestTreeEngine:
         prompts = self._workload(model, np.random.default_rng(44))
         gen = GenerationConfig(max_new_tokens=10)
         t1 = [r.tokens
-              for r in _engine(model, params, paged=True, **TREE_KW)
+              for r in _engine(model, params, **TREE_KW)
               .serve(prompts, gen)]
         with pytest.raises(ValueError, match="single-chip"):
-            _engine(model, params, paged=True, mesh=mesh,
+            _engine(model, params, mesh=mesh,
                     decode_kernel="pallas", **TREE_KW)
-        e2 = _engine(model, params, paged=True, mesh=mesh, **TREE_KW)
+        e2 = _engine(model, params, mesh=mesh, **TREE_KW)
         t2 = [r.tokens for r in e2.serve(prompts, gen)]
         assert e2.decode_kernel == "xla"
         assert t2 == t1
 
-    @pytest.mark.parametrize("paged", [False, True])
+    @pytest.mark.parametrize("arm", [
+        {}, dict(decode_kernel="pallas"),
+    ], ids=["gathered", "direct"])
     @pytest.mark.parametrize("sampled", [False, True])
-    def test_eos_on_accepted_path_truncates(self, paged, sampled):
+    def test_eos_on_accepted_path_truncates(self, arm, sampled):
         """An EOS the model itself emits mid-window must cut the stream at
         exactly the point sequential decode would — deeper committed tokens
         from the same verify pass never surface."""
@@ -441,7 +481,7 @@ class TestTreeEngine:
         want = _expected(model, params, prompt, gen)
         assert want[-1] == eos and len(want) < 10
         for kw in ({}, TREE_KW):
-            (req,) = _engine(model, params, paged=paged, **kw).serve(
+            (req,) = _engine(model, params, **arm, **kw).serve(
                 [prompt], [gen])
             assert req.tokens == want
 
@@ -467,13 +507,16 @@ class TestTreeEngine:
         gens = [GenerationConfig(max_new_tokens=n) for n in (10, 6, 8)]
         eng = _engine(model, params, **TREE_KW)
         eng.serve(prompts, gens)
+        eng.serve(prompts, gens)         # a second pass must retrace nothing
         assert eng.stats["spec_drafted"] > 0
         # every decode cycle rode the draft+tree pair; ONE signature each,
-        # and the plain decode window never compiled
+        # and the plain decode window never compiled (copy_page: the
+        # 12-token prompt is two whole cached chunks, so its tail page is
+        # shared with the prefix cache and copied before decode writes it)
         assert eng.compiled_executable_counts() == {
-            "decode_window": 0, "insert": 1, "tree_verify_window": 1,
+            "decode_window": 0, "copy_page": 1, "tree_verify_window": 1,
             "draft_forward": 1, "lane_install": 1, "prefill_4": 1,
-            "prefill_8": 1, "copy_4": 0, "copy_8": 0,
+            "prefill_8": 1,
         }
         assert not eng._verify.over_budget()
         assert not eng._draft_fwd.over_budget()
@@ -506,7 +549,7 @@ class TestTreeEngine:
         with pytest.raises(ValueError, match="tree_width"):
             _engine(model, params, tree_width=2)   # no draft model
         with pytest.raises(ValueError, match="32"):
-            _engine(model, params, paged=True, decode_kernel="pallas",
+            _engine(model, params, decode_kernel="pallas",
                     draft_model=1, tree_width=8, tree_depth=4)  # 33 nodes
         sw_model, sw_params = _tiny_model(sliding_window=8)
         with pytest.raises(ValueError, match="sliding"):

@@ -24,7 +24,7 @@ from .core import Project, Report, Runner
 from .rules import ALL_RULES, get_rules
 
 #: default lint surface — everything `make quality` covers
-DEFAULT_PATHS = ["accelerate_tpu", "tests", "tools", "bench.py", "bench_inference.py"]
+DEFAULT_PATHS = ["accelerate_tpu", "tests", "tools", "bench.py"]
 
 
 def repo_root() -> Path:
