@@ -946,7 +946,9 @@ class StreamingTransformer(StreamingExecutor):
     def init_cache(self, batch_size: int, max_len: int, dtype=None,
                    per_lane_index: bool = False):
         """Per-chunk KV caches on the exec device: ``{"chunks": [(ks, vs), ...],
-        "index": scalar}`` where ks/vs are per-layer ``[B, max_len, Hkv, D]``.
+        "index": scalar}`` where ks/vs are per-layer ``[B, Hkv * D, max_len]``
+        (rows flat, positions minor: one layer of a per-head
+        :class:`~accelerate_tpu.models.transformer.KVCache`).
 
         Unlike the monolithic :class:`~accelerate_tpu.models.transformer.KVCache`
         (stacked over depth), chunk-grained caches keep ONE decode executable
@@ -960,7 +962,7 @@ class StreamingTransformer(StreamingExecutor):
         cfg = self.config
         dtype = dtype if dtype is not None else getattr(cfg, "dtype", jnp.bfloat16)
         hd = cfg.resolved_head_dim
-        shape = (batch_size, max_len, cfg.num_kv_heads, hd)
+        shape = (batch_size, cfg.num_kv_heads * hd, max_len)
         chunks = []
         for c in self._chunks:
             ks = tuple(jax.device_put(jnp.zeros(shape, dtype), self.device) for _ in c)
@@ -1043,7 +1045,7 @@ class StreamingTransformer(StreamingExecutor):
         else:
             idx = jax.device_get(cache["index"])
             used = int(idx.max()) if getattr(idx, "ndim", 0) else int(idx)
-            max_len = cache["chunks"][0][0][0].shape[1]
+            max_len = cache["chunks"][0][0][0].shape[-1]
             if used + s + max_new_tokens > max_len:
                 raise ValueError(
                     f"cache max_len {max_len} < {used} already written + prompt {s} + "

@@ -398,17 +398,36 @@ class KVCache(struct.PyTreeNode):
     The reference's published benchmark is token generation
     (``/root/reference/benchmarks/big_model_inference.py:108-139``); its cache
     lives inside transformers' dynamic python objects.  TPU-first the cache is
-    one pytree of fixed-shape arrays — ``[num_layers, batch, max_len, kv_heads,
-    head_dim]`` — so ONE decode executable serves every token.  The unrolled
-    forward threads the STACKED arrays through the layers: layer ``i`` writes
-    only its new rows at ``[i, lane, index : index + S]`` (:func:`_write_rows`)
-    and attends over the static slice ``k[i]``.  No layer's slab is sliced
-    out, copied or stacked back, which is what lets XLA keep the write in
-    place — in a donated cache and in a ``lax.scan`` carry alike (a slice ->
-    update -> ``jnp.stack`` round trip compiles to several copies of the
-    whole cache per forward; ``tests/test_tpu_compile.py`` holds the compiled
-    decode window to that).  ``scan_layers=True`` instead lets ``nn.scan``
-    slice and restack per-layer slabs itself.
+    one pytree of fixed-shape arrays, so ONE decode executable serves every
+    token.  The attention kind that owns the cache decides its layout, from
+    the configuration (``cache_row_shapes``, ``latent_attention``):
+
+    * per-head rows (:class:`Attention`): ``[num_layers, batch, kv_heads *
+      head_dim, max_len]`` — a position's keys (values) of every head flat in
+      one dimension, positions minor.  The TPU tiles an array's two minor
+      dimensions to (sublanes, 128 lanes) whatever order the program writes
+      them in; with ``(kv_heads, head_dim)`` minor, GPT-2-XL's 25 heads pad to
+      32 and its 64 values to 128, so a ``[.., max_len, 25, 64]`` cache
+      occupies, and every decode step reads, 2.56 x its bytes.  ``kv_heads *
+      head_dim`` by ``max_len`` pads no more than the last tile, and it is the
+      serving page pool's own order on the chip (``[.., Hkv, Dh, page]``,
+      ``page`` minor), so a gather of pages into this cache moves whole tiles.
+      Attention reads it as ``[B, Hkv, Dh, M]`` (:func:`cached_attention`): a
+      reshape that splits a dimension on a tile boundary.
+    * latent rows (:mod:`~accelerate_tpu.models.latent_attention`):
+      ``[num_layers, batch, max_len, 1, width]`` — one latent (rope key) of
+      512 (64) values a position, position-major: 512 lanes tile exactly.
+
+    The unrolled forward threads the STACKED arrays through the layers: layer
+    ``i`` writes only its new positions at ``[i, lane, .., index : index + S]``
+    (:func:`_write_columns`; latent rows: :func:`_write_rows`) and attends over
+    the static slice ``k[i]``.  No layer's slab is sliced out, copied or
+    stacked back, which is what lets XLA keep the write in place — in a
+    donated cache and in a ``lax.scan`` carry alike (a slice -> update ->
+    ``jnp.stack`` round trip compiles to several copies of the whole cache per
+    forward; ``tests/test_tpu_compile.py`` holds the compiled decode window to
+    that).  ``scan_layers=True`` instead lets ``nn.scan`` slice and restack
+    per-layer slabs itself.
 
     ``index`` is either a scalar (the whole batch decodes in lockstep — the
     ``generate`` path) or a per-lane ``[B]`` vector (each lane sits at its own
@@ -417,30 +436,32 @@ class KVCache(struct.PyTreeNode):
     and attention masking follow whichever form is present.
     """
 
-    k: jax.Array            # [L, B, max_len, n_kv_heads, head_dim]
-    v: jax.Array            # [L, B, max_len, n_kv_heads, head_dim]
+    k: jax.Array            # [L, B, n_kv_heads * head_dim, max_len]
+    v: jax.Array            # [L, B, n_kv_heads * head_dim, max_len]
     index: jax.Array        # int32 next write position: scalar, or [B] per lane
-    # The row shapes are the configuration's (``cache_row_shapes``), not
-    # derived from heads: under latent attention ``k`` holds the latent
-    # ``c_kv`` rows ``[.., 1, kv_rank]`` and ``v`` the shared rope key
-    # ``[.., 1, rope_dim]`` — every consumer below reads shapes off the arrays.
+    # Under latent attention ``k`` holds the latent ``c_kv`` rows ``[L, B,
+    # max_len, 1, kv_rank]`` and ``v`` the shared rope key ``[.., 1,
+    # rope_dim]``: the rank of the arrays says which kind built them.
 
     @classmethod
     def create(cls, config: "TransformerConfig", batch_size: int, max_len: Optional[int] = None,
                dtype: Any = None, per_lane_index: bool = False) -> "KVCache":
         max_len = max_len if max_len is not None else config.max_seq_len
-        k_row, v_row = config.cache_row_shapes
-        lead = (config.num_layers, batch_size, max_len)
+        lead = (config.num_layers, batch_size)
+        if config.latent_attention is None:
+            shapes = [lead + (heads * width, max_len) for heads, width in config.cache_row_shapes]
+        else:
+            shapes = [lead + (max_len,) + row for row in config.cache_row_shapes]
         dtype = dtype if dtype is not None else config.dtype
         return cls(
-            k=jnp.zeros(lead + k_row, dtype),
-            v=jnp.zeros(lead + v_row, dtype),
+            k=jnp.zeros(shapes[0], dtype),
+            v=jnp.zeros(shapes[1], dtype),
             index=jnp.zeros((batch_size,) if per_lane_index else (), jnp.int32),
         )
 
     @property
     def max_len(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[3] if self.k.ndim == 4 else self.k.shape[2]
 
 
 class PagedKVCache(struct.PyTreeNode):
@@ -479,7 +500,8 @@ class PagedKVCache(struct.PyTreeNode):
 
 def cached_attention(q, k, v, q_positions, window=None, alibi=False,
                      tree_mask=None):
-    """Attention of ``q`` [B,S,Hq,D] against a full cache ``k``/``v`` [B,M,Hkv,D].
+    """Attention of ``q`` [B,S,Hq,D] against a full cache ``k``/``v``
+    [B,Hkv*D,M]: one layer of a per-head :class:`KVCache`, positions minor.
 
     Key slot ``j`` is visible to query ``i`` iff ``j <= q_positions[i]`` —
     since the cache is written contiguously from 0, this is simultaneously the
@@ -503,12 +525,15 @@ def cached_attention(q, k, v, q_positions, window=None, alibi=False,
     rope/learned models).
     """
     b, s, n_q, d = q.shape
-    n_kv = k.shape[2]
+    m = k.shape[2]
+    n_kv = k.shape[1] // d
     rep = n_q // n_kv
     qg = q.reshape(b, s, n_kv, rep, d)
+    k = k.reshape(b, n_kv, d, m)
+    v = v.reshape(b, n_kv, d, m)
     scale = d ** -0.5
-    logits = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k).astype(jnp.float32) * scale
-    j = jnp.arange(k.shape[1])
+    logits = jnp.einsum("bqhrd,bhdk->bhrqk", qg, k).astype(jnp.float32) * scale
+    j = jnp.arange(m)
     if tree_mask is not None:
         if window is not None or alibi:
             raise ValueError(
@@ -526,7 +551,7 @@ def cached_attention(q, k, v, q_positions, window=None, alibi=False,
         mask = allowed[:, None, None, :, :]             # [B,1,1,S,M]
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhrqk,bkhd->bqhrd", probs, v)
+        out = jnp.einsum("bhrqk,bhdk->bqhrd", probs, v)
         return out.reshape(b, s, n_q, d)
     if alibi:
         rel = (j[None, None, None, None, :]
@@ -540,7 +565,7 @@ def cached_attention(q, k, v, q_positions, window=None, alibi=False,
         )
     logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhrqk,bkhd->bqhrd", probs, v)
+    out = jnp.einsum("bhrqk,bhdk->bqhrd", probs, v)
     return out.reshape(b, s, n_q, d)
 
 
@@ -671,15 +696,15 @@ def make_norm(cfg: "TransformerConfig", name: Optional[str] = None):
 
 
 def _write_rows(buf, new, index, layer=None):
-    """Write ``new [B, S, H, D]`` into a KV buffer at rows ``index .. index +
-    S - 1`` of every lane, in place.
+    """Write ``new [B, S, H, D]`` into a position-major KV buffer (the latent
+    rows of :mod:`~accelerate_tpu.models.latent_attention`) at rows ``index ..
+    index + S - 1`` of every lane, in place.
 
-    ``buf`` is one layer's slab ``[B, M, H, D]`` (``layer=None``: the callers
-    that hold per-layer arrays, ``ScanBody`` and ``big_modeling``'s streamed
-    decode) or the stacked cache ``[L, B, M, H, D]`` addressed at the static
-    ``layer``.  Scalar ``index`` (generate, a prefill chunk) is one
-    ``dynamic_update_slice``; a per-lane ``index [B]`` (decode, verify and
-    tree windows of the serving pool) is one scatter of the ``B * S`` new
+    ``buf`` is one layer's slab ``[B, M, H, D]`` (``layer=None``) or the
+    stacked cache ``[L, B, M, H, D]`` addressed at the static ``layer``.
+    Scalar ``index`` (generate, a prefill chunk) is one
+    ``dynamic_update_slice``; a per-lane ``index [B]`` (the decode windows of
+    the serving pool) is one scatter of the ``B * S`` new
     rows.  The start is clamped so the span fits, as ``dynamic_update_slice``
     clamps it: every write the engine admits is in range already (its
     admission check), and a frozen lane's stale index can only land on that
@@ -699,6 +724,38 @@ def _write_rows(buf, new, index, layer=None):
     )
 
 
+def _write_columns(buf, new, index, layer=None):
+    """Write ``new [B, S, H, D]`` into a per-head KV buffer (positions minor:
+    :class:`KVCache`) as columns ``index .. index + S - 1`` of every lane, in
+    place.
+
+    ``buf`` is one layer's slab ``[B, H*D, M]`` (``layer=None``: the callers
+    that hold per-layer arrays, ``ScanBody`` and ``big_modeling``'s streamed
+    decode) or the stacked cache ``[L, B, H*D, M]`` addressed at the static
+    ``layer``.  Scalar ``index`` (generate, a prefill chunk) is one
+    ``dynamic_update_slice`` of ``[B, H*D, S]``; a per-lane ``index [B]``
+    (decode, verify and tree windows of the serving pool) is one
+    ``dynamic_update_slice`` of ``[H*D, S]`` a lane, each at its lane's own
+    index.  Not a scatter: for a scatter of columns the TPU compiler carries
+    the buffer position-major and passes the gathered view into that layout
+    first; a ``dynamic_update_slice`` leaves the layout to the buffer.  The
+    start is clamped so the span fits (``dynamic_update_slice`` does):
+    every write the engine admits is in range already (its admission check),
+    and a frozen lane's stale index can only land on that lane's own dead
+    columns."""
+    b, s = new.shape[:2]
+    lead = () if layer is None else (layer,)
+    cols = new.astype(buf.dtype).reshape(b, s, -1).swapaxes(1, 2)   # [B, H*D, S]
+    cols = cols[(None,) * len(lead)]
+    if jnp.ndim(index) == 0:
+        return jax.lax.dynamic_update_slice(buf, cols, (*lead, 0, 0, index))
+    for lane in range(b):
+        buf = jax.lax.dynamic_update_slice(
+            buf, cols[..., lane:lane + 1, :, :], (*lead, lane, 0, index[lane])
+        )
+    return buf
+
+
 class Attention(nn.Module):
     config: TransformerConfig
 
@@ -716,7 +773,7 @@ class Attention(nn.Module):
           paged cache's ``quant_err``) replaced, ``index`` as it was — the
           unrolled :class:`Transformer` loop advances it once.
         * a tuple — this layer's own arrays, for the callers that hold them
-          per layer: ``(k_cache [B,M,Hkv,D], v_cache, index)`` returns
+          per layer: ``(k_cache [B,Hkv*D,M], v_cache, index)`` returns
           ``(out, (k_cache, v_cache))``; the paged ``(pages_k, pages_v,
           k_scales, v_scales, tables, index, active)`` returns ``(out,
           (pages_k, pages_v, k_scales, v_scales, quant_err))``.
@@ -816,8 +873,8 @@ class Attention(nn.Module):
             return out, (pages_k, pages_v, k_scales, v_scales, err)
         if cache is not None:
             k_cache, v_cache, index = cache_arrays
-            k_cache = _write_rows(k_cache, k, index, layer)
-            v_cache = _write_rows(v_cache, v, index, layer)
+            k_cache = _write_columns(k_cache, k, index, layer)
+            v_cache = _write_columns(v_cache, v, index, layer)
             out = cached_attention(q, at(k_cache), at(v_cache), positions,
                                    window=cfg.sliding_window,
                                    alibi=cfg.positional == "alibi",

@@ -274,8 +274,10 @@ def paged_attention_reference(q, pages_k, pages_v, tables, lengths,
     else:
         k = k.astype(q.dtype)
         v = v.astype(q.dtype)
-    k = k.transpose(0, 1, 3, 2, 4).reshape(n, num_p * page, hkv, d)
-    v = v.transpose(0, 1, 3, 2, 4).reshape(n, num_p * page, hkv, d)
+    # [N, Hkv*D, M]: a per-head KVCache layer, positions minor (the pool's own
+    # order on the chip: a move of whole (D, page) tiles)
+    k = k.transpose(0, 2, 4, 1, 3).reshape(n, hkv * d, num_p * page)
+    v = v.transpose(0, 2, 4, 1, 3).reshape(n, hkv * d, num_p * page)
     q_positions = lengths[:, None] + jnp.arange(s)[None, :]
     return cached_attention(q, k, v, q_positions, window=window, alibi=alibi,
                             tree_mask=tree_mask)

@@ -44,14 +44,25 @@ call, runs the model on it as a plain
 :class:`~accelerate_tpu.models.transformer.KVCache` — the attention program
 ``generate`` runs — then stores the pages the call wrote into back whole
 (:func:`_store_span_pages`; a prefill chunk's span is page-aligned and is its
-pages).  What the view costs is once a CALL: the gather and its one layout
-pass (the pool keeps ``page`` minor on the chip, the view ``Dh``), a
-view-sized temporary for K and for V, and a write-back of two pages a lane,
-scattered in the pool's own layout.  Rows are never stored singly: with
+pages).  The view has the layout of the ``KVCache`` that the model's attention
+kind reads (:func:`_flat_view`, from the configuration).  For the per-head
+block it is ``[L, N, Hkv * Dh, max_len]``, rows flat and positions minor: the
+chip tiles an array's two minor dimensions, so ``(Hkv, Dh)`` minor padded
+GPT-2-XL's 25 heads to 32 sublanes and its 64 values to 128 lanes, 2.56 x the
+bytes in every pass and every decode step, while ``Hkv * Dh`` by ``max_len``
+pads nothing and is the pool's own order on the chip (``[.., Hkv, Dh, page]``,
+``page`` minor): a page IS a block of the view's columns, put in and cut out
+by one ``dynamic_update_slice`` / ``dynamic_slice`` each, with no pass over the
+view between the pool and the model.  Latent rows (one of 512 values a
+position) keep the position-major view ``[L, N, max_len, 1, width]``, which
+tiles exactly; it goes through the compiler's gather and one layout pass.
+What the view costs is once a CALL: the gather, a view-sized temporary for K
+and for V, and a write-back of two pages a lane, stored in the pool's own
+layout.  Rows are never stored singly: with
 ``page`` minor a row is a strided write, and the compiler would copy the whole
 pool into a row-minor layout and back to serve it.  Inside the call the model
 writes the view in place — each layer its new rows, each scan step of a decode
-window 2 x L small scatters into the carried view; nothing of the view's size
+window 2 x L small updates of the carried view; nothing of the view's size
 is copied per step.  The in-place arm (``direct=True``) hands the model the
 pool itself (:class:`~accelerate_tpu.models.transformer.PagedKVCache`): no
 view, the same in-place write through the block tables, pages read where they
@@ -70,6 +81,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..models.generation import sample_tokens_batched
@@ -513,9 +525,9 @@ def _tree_verify_body(model: Transformer, tree, params, cache, tokens, active,
         cache = cache.replace(index=prev_index + n_commit)
     else:
         def _compact(kv):
-            def lane(kv_lane, idx, p):
-                rows = jnp.take(kv_lane, idx + p, axis=1)    # [L, D+1, H, Dh]
-                return jax.lax.dynamic_update_slice(kv_lane, rows, (0, idx, 0, 0))
+            def lane(kv_lane, idx, p):                       # [L, H*Dh, M]
+                cols = jnp.take(kv_lane, idx + p, axis=2)    # [L, H*Dh, D+1]
+                return jax.lax.dynamic_update_slice(kv_lane, cols, (0, 0, idx))
 
             return jax.vmap(lane, in_axes=(1, 0, 0), out_axes=1)(
                 kv, prev_index, path
@@ -570,14 +582,44 @@ def make_lane_install(shardings: Optional[ServeShardings] = None):
     )
 
 
-def _gather_view(pages, tables):
+def _flat_view(model: Transformer) -> bool:
+    """Which gathered view the model's attention reads, from its
+    configuration: the per-head block's ``[L, N, H * D, M]`` (rows flat,
+    positions minor) or latent attention's position-major ``[L, N, M, 1,
+    width]``: :class:`~accelerate_tpu.models.transformer.KVCache`."""
+    return model.config.latent_attention is None
+
+
+def _gather_view(pages, tables, flat: bool):
     """``pages [L, NP, H, page, D]`` gathered through ``tables [N, P]`` into a
-    contiguous per-lane view ``[L, N, P * page, H, D]``."""
+    contiguous per-lane view: ``[L, N, H * D, P * page]`` if ``flat``, else
+    ``[L, N, P * page, H, D]``.
+
+    The flat view is filled page by page: on the chip the pool is ``[.., H, D,
+    page]``, ``page`` minor, so a page is a block ``[L, H * D, page]`` of whole
+    tiles that goes into the view's columns ``p * page ..`` as it lies.  One
+    ``dynamic_update_slice`` a (lane, page slot), both offsets static and only
+    the page's id traced: the compiler's own gather collects the pages in
+    table order first and then passes the whole view into its layout (twice,
+    once to transpose and once to re-tile), an update at a traced column is
+    served element by element, and for a traced lane the pool is copied
+    page-major first."""
     L, _, H, page, D = pages.shape
     N, P = tables.shape
-    return (pages[:, tables]                             # [L, N, P, H, page, D]
-            .transpose(0, 1, 2, 4, 3, 5)
-            .reshape(L, N, P * page, H, D))
+    if not flat:
+        return (pages[:, tables]                         # [L, N, P, H, page, D]
+                .transpose(0, 1, 2, 4, 3, 5)
+                .reshape(L, N, P * page, H, D))
+
+    view = jnp.zeros((L, N, H * D, P * page), pages.dtype)
+    for n in range(N):
+        for p in range(P):
+            block = jax.lax.dynamic_slice_in_dim(pages, tables[n, p], 1, axis=1)
+            block = block.swapaxes(3, 4).reshape(L, 1, H * D, page)
+            view = jax.lax.dynamic_update_slice(view, block, (0, n, 0, p * page))
+    # as written: left alone the compiler fills the view lane-major (the order
+    # it gives the first block's unit axis) and copies it for the model
+    return with_layout_constraint(view, Layout(major_to_minor=(0, 1, 2, 3)))
 
 
 def _live_tables(tables, live):
@@ -594,15 +636,20 @@ def _live_tables(tables, live):
     return jnp.where(jnp.arange(num_p)[None, :] < live[:, None], tables, NULL_PAGE)
 
 
-def _store_span_pages(pages, view, tables, start, width: int, active):
-    """Write ``view[:, n, start[n] : start[n] + width]`` back through lane
-    ``n``'s block table, for every ACTIVE lane, by storing whole the pages the
-    span touches: a static ``(width + page - 2) // page + 1`` a lane.  The
+def _store_span_pages(pages, view, tables, start, width: int, active, flat: bool):
+    """Write positions ``start[n] .. start[n] + width - 1`` of lane ``n``'s
+    ``view`` (:func:`_gather_view`) back through its block table, for every
+    ACTIVE lane, by storing whole the pages the span touches: a static
+    ``(width + page - 2) // page + 1`` a lane.  The
     pool's minor dimension on the chip is ``page``, so one row is a strided
     write that the compiler serves by copying the whole pool into another
-    layout and back; a page is a contiguous block, scattered in place.  The
-    page blocks are cut out of the view by one gather over (lane, page slot);
-    the view itself is never transposed.
+    layout and back; a page is a contiguous block, stored in place.  The
+    page blocks are cut out of the view by one gather over (lane, page slot)
+    and scattered; out of the flat view (its columns ``slot * page ..`` are the
+    page as the pool holds it) by one ``dynamic_slice`` a block, each put into
+    the pool by a ``dynamic_update_slice``: a gather over page slots would
+    have the whole view re-tiled with the slot second-minor first.  The view
+    itself is never passed into another layout.
 
     A touched page is stored over itself plus its new rows: it is live in
     :func:`_live_tables`, so the view's copy of it was gathered from that same
@@ -622,10 +669,25 @@ def _store_span_pages(pages, view, tables, start, width: int, active):
                & (slot <= ((start + width - 1) // page)[:, None]))
     slot = jnp.minimum(slot, P - 1)
     ids = jnp.where(reached, jnp.take_along_axis(tables, slot, axis=1), NULL_PAGE)
-    blocks = view.reshape(L, N, P, page, H, D)[:, jnp.arange(N)[:, None], slot]
-    return pages.at[:, ids.reshape(-1)].set(
-        blocks.reshape(L, N * touched, page, H, D).swapaxes(2, 3)
-    )
+    if not flat:
+        blocks = view.reshape(L, N, P, page, H, D)[:, jnp.arange(N)[:, None], slot]
+        return pages.at[:, ids.reshape(-1)].set(
+            blocks.reshape(L, N * touched, page, H, D).swapaxes(2, 3))
+
+    for n in range(N):
+        for t in range(touched):
+            pages = _store_flat_page(pages, view, n, slot[n, t] * page, ids[n, t])
+    return pages
+
+
+def _store_flat_page(pages, view, lane: int, column, page_id):
+    """Columns ``column .. column + page - 1`` of lane ``lane`` of the flat
+    ``view [L, N, H * D, M]`` stored as page ``page_id`` of ``pages``, in
+    place: on the chip the two blocks are the same tiles in the same order."""
+    L, _, H, page, D = pages.shape
+    block = jax.lax.dynamic_slice(view, (0, lane, 0, column), (L, 1, H * D, page))
+    return jax.lax.dynamic_update_slice(
+        pages, block.reshape(L, 1, H, D, page).swapaxes(3, 4), (0, page_id, 0, 0, 0))
 
 
 def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
@@ -664,6 +726,7 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
     npg = chunk_len // page_size
     s = _unrouted(model, shardings)
     counted = _routed(model)
+    flat = _flat_view(model)
 
     if direct:
         def direct_prefill_chunk(params, tokens, pages_k, pages_v, k_scales,
@@ -694,8 +757,8 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
         live = (base + chunk_len - 1) // page_size + 1
         gt = _live_tables(table, live)
         cache = KVCache(
-            k=_gather_view(pages_k, gt[None]),
-            v=_gather_view(pages_v, gt[None]),
+            k=_gather_view(pages_k, gt[None], flat),
+            v=_gather_view(pages_v, gt[None], flat),
             index=base,
         )
         rows = None if valid is None else jnp.arange(chunk_len)[None, :] < valid
@@ -704,6 +767,10 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
 
         def write_back(pages, view):
             L, _, H, page, D = pages.shape          # K's and V's rows may differ
+            if flat:
+                for i in range(npg):
+                    pages = _store_flat_page(pages, view, 0, base + i * page, ids[i])
+                return pages
             w = jax.lax.dynamic_slice(view, (0, 0, base, 0, 0), (L, 1, chunk_len, H, D))
             return pages.at[:, ids].set(w.reshape(L, npg, page, H, D).swapaxes(2, 3))
 
@@ -764,6 +831,7 @@ def make_paged_decode_window(model: Transformer, window: int,
     """
 
     s = _unrouted(model, shardings)
+    flat = _flat_view(model)
 
     if direct:
         def direct_decode_window(params, pages_k, pages_v, k_scales, v_scales,
@@ -800,16 +868,16 @@ def make_paged_decode_window(model: Transformer, window: int,
         page = pages_k.shape[3]
         gt = _live_tables(tables, (index + window - 1) // page + 1)
         cache = KVCache(
-            k=_gather_view(pages_k, gt),
-            v=_gather_view(pages_v, gt),
+            k=_gather_view(pages_k, gt, flat),
+            v=_gather_view(pages_v, gt, flat),
             index=index,
         )
         cache, toks, tok, rngs, counts = _decode_scan(
             model, window, params, cache, tokens, active, eos, do_sample,
             temperature, top_k, top_p, pad, rngs,
         )
-        pages_k = _store_span_pages(pages_k, cache.k, tables, index, window, active)
-        pages_v = _store_span_pages(pages_v, cache.v, tables, index, window, active)
+        pages_k = _store_span_pages(pages_k, cache.k, tables, index, window, active, flat)
+        pages_v = _store_span_pages(pages_v, cache.v, tables, index, window, active, flat)
         return _with_counts(pages_k, pages_v, toks, tok, rngs, counts)
 
     return _serve_jit(
@@ -836,6 +904,7 @@ def make_paged_verify_window(model: Transformer, k: int, direct: bool = False,
     """
     kp1 = k + 1
     s = shardings
+    flat = _flat_view(model)
 
     if direct:
         def direct_verify_window(params, pages_k, pages_v, k_scales, v_scales,
@@ -872,16 +941,16 @@ def make_paged_verify_window(model: Transformer, k: int, direct: bool = False,
         page = pages_k.shape[3]
         gt = _live_tables(tables, (index + kp1 - 1) // page + 1)
         cache = KVCache(
-            k=_gather_view(pages_k, gt),
-            v=_gather_view(pages_v, gt),
+            k=_gather_view(pages_k, gt, flat),
+            v=_gather_view(pages_v, gt, flat),
             index=index,
         )
         cache, out, n_commit, new_pending, new_rngs = _verify_body(
             model, k, params, cache, tokens, active, eos, do_sample,
             temperature, top_k, top_p, pad, rngs,
         )
-        pages_k = _store_span_pages(pages_k, cache.k, tables, index, kp1, active)
-        pages_v = _store_span_pages(pages_v, cache.v, tables, index, kp1, active)
+        pages_k = _store_span_pages(pages_k, cache.k, tables, index, kp1, active, flat)
+        pages_v = _store_span_pages(pages_v, cache.v, tables, index, kp1, active, flat)
         return pages_k, pages_v, out, n_commit, new_pending, new_rngs
 
     return _serve_jit(
@@ -969,6 +1038,7 @@ def make_paged_tree_verify_window(model: Transformer, tree,
     """
     s_nodes = tree.nodes
     s = shardings
+    flat = _flat_view(model)
 
     if direct:
         def direct_tree_verify_window(params, pages_k, pages_v, k_scales,
@@ -1006,16 +1076,16 @@ def make_paged_tree_verify_window(model: Transformer, tree,
         page = pages_k.shape[3]
         gt = _live_tables(tables, (index + s_nodes - 1) // page + 1)
         cache = KVCache(
-            k=_gather_view(pages_k, gt),
-            v=_gather_view(pages_v, gt),
+            k=_gather_view(pages_k, gt, flat),
+            v=_gather_view(pages_v, gt, flat),
             index=index,
         )
         cache, out, n_commit, new_pending, new_rngs = _tree_verify_body(
             model, tree, params, cache, tokens, active, eos, do_sample,
             temperature, top_k, top_p, pad, rngs,
         )
-        pages_k = _store_span_pages(pages_k, cache.k, tables, index, s_nodes, active)
-        pages_v = _store_span_pages(pages_v, cache.v, tables, index, s_nodes, active)
+        pages_k = _store_span_pages(pages_k, cache.k, tables, index, s_nodes, active, flat)
+        pages_v = _store_span_pages(pages_v, cache.v, tables, index, s_nodes, active, flat)
         return pages_k, pages_v, out, n_commit, new_pending, new_rngs
 
     return _serve_jit(

@@ -10,7 +10,8 @@ cache byte for byte.
 The oracle is the old forward, kept here: a flax method interceptor hands each
 layer's attention the per-layer tuple it used to get (``cache.k[i]``, a slice)
 and stacks what comes back, and for the slab cache the write itself is the
-deleted ``dynamic_update_slice`` / ``vmap(_write)`` pair.  The paged oracle
+deleted ``dynamic_update_slice`` / ``vmap(_write)`` pair, on the cache's
+layout of today (rows flat, positions minor).  The paged oracle
 writes through the per-layer ``paged_insert`` / ``paged_quantized_insert``
 (``layer=None``), whose own contents ``tests/test_paged_attention.py`` pins.
 """
@@ -48,16 +49,18 @@ def _model(**kw):
 
 
 def _old_write(slab, new, index, layer=None):
-    """The deleted write into ONE layer's slab ``[B, M, H, D]``."""
+    """The deleted write into ONE layer's slab, in the cache's layout of today
+    (``[B, H*D, M]``: rows flat, positions minor)."""
     assert layer is None
-    new = new.astype(slab.dtype)
+    b, s = new.shape[:2]
+    cols = new.astype(slab.dtype).reshape(b, s, -1).swapaxes(1, 2)
     if jnp.ndim(index) == 0:
-        return jax.lax.dynamic_update_slice(slab, new, (0, index, 0, 0))
+        return jax.lax.dynamic_update_slice(slab, cols, (0, 0, index))
 
     def _write(c, u, i):
-        return jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
+        return jax.lax.dynamic_update_slice(c, u, (0, i))
 
-    return jax.vmap(_write)(slab, new, index)
+    return jax.vmap(_write)(slab, cols, index)
 
 
 def _restack(stack, i, layer_i):
@@ -102,7 +105,7 @@ def _slice_update_stack(sliced, next_fun, args, kwargs, context):
 def _oracle(monkeypatch, model, params, tokens, cache, **kw):
     sliced = []
     with monkeypatch.context() as m:
-        m.setattr(tfm, "_write_rows", _old_write)
+        m.setattr(tfm, "_write_columns", _old_write)
         with nn.intercept_methods(functools.partial(_slice_update_stack, sliced)):
             out = model.apply({"params": params}, tokens, cache=cache, **kw)
     assert sliced == list(range(model.config.num_layers))
@@ -136,7 +139,7 @@ def _both_ways(monkeypatch, model, params, spec, cache):
 def _slab_cache(cfg, batch, index, dtype=jnp.float32, seed=1):
     """A cache that already holds something everywhere, so a write that lands
     in the wrong row or layer shows."""
-    shape = (cfg.num_layers, batch, MAX_LEN, cfg.num_kv_heads, cfg.resolved_head_dim)
+    shape = (cfg.num_layers, batch, cfg.num_kv_heads * cfg.resolved_head_dim, MAX_LEN)
     kk, kv = jax.random.split(jax.random.PRNGKey(seed))
     return KVCache(
         k=jax.random.normal(kk, shape, jnp.float32).astype(dtype),

@@ -20,7 +20,13 @@ from accelerate_tpu.models.generation import (
     make_prefill_step,
     sample_tokens,
 )
-from accelerate_tpu.models.transformer import KVCache, Transformer, TransformerConfig
+from accelerate_tpu.models.transformer import (
+    KVCache,
+    Transformer,
+    TransformerConfig,
+    alibi_slopes,
+    cached_attention,
+)
 
 
 def _tiny(scan_layers=False, **kw):
@@ -32,6 +38,83 @@ def _model_and_params(cfg, batch=2, seq=10, seed=0):
     ids = jax.random.randint(jax.random.PRNGKey(seed + 1), (batch, seq), 0, cfg.vocab_size)
     params = model.init(jax.random.PRNGKey(seed), ids)["params"]
     return model, params, ids
+
+
+def _plain_attention(q, k, v, q_positions, window=None, alibi=False, tree_mask=None):
+    """Float32 attention of ``q [B,S,Hq,D]`` over position-major ``k``/``v``
+    ``[B,M,Hkv,D]``, every mask written out: the oracle for
+    :func:`cached_attention`, which reads the cache rows flat and positions
+    minor."""
+    b, s, n_q, d = q.shape
+    m, n_kv = k.shape[1], k.shape[2]
+    k = jnp.repeat(k, n_q // n_kv, axis=2).astype(jnp.float32)
+    v = jnp.repeat(v, n_q // n_kv, axis=2).astype(jnp.float32)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k) / np.sqrt(d)
+    j = np.arange(m)
+    pos = np.asarray(q_positions)
+    if tree_mask is not None:
+        allowed = np.zeros((b, s, m), bool)
+        for lane in range(b):
+            base = pos[lane, 0]
+            allowed[lane, :, :base] = True
+            allowed[lane, :, base:base + s] = tree_mask
+    else:
+        allowed = j[None, None, :] <= pos[:, :, None]
+        if window is not None:
+            allowed &= j[None, None, :] > pos[:, :, None] - window
+    if alibi:
+        rel = (j[None, None, :] - pos[:, :, None]).astype(np.float32)
+        logits = logits + alibi_slopes(n_q)[None, :, None, None] * rel[:, None]
+    logits = jnp.where(allowed[:, None], logits, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, axis=-1), v)
+
+
+def _chain_mask(s):
+    """Ancestor-or-self mask of a root with two chains under it."""
+    parent = [0, 0, 1, 0, 3][:s]
+    mask = np.eye(s, dtype=bool)
+    for node in range(1, s):
+        mask[node] |= mask[parent[node]]
+    return mask
+
+
+#: heads (query, kv, width), queries a lane, the lanes' positions, the masks
+_ATTENTION_CASES = {
+    "gpt2_xl_heads_decode": dict(heads=(25, 25, 64), s=1, index=[3, 40, 63]),
+    "gqa_8q_2kv_chunk": dict(heads=(8, 2, 16), s=6, index=[0, 17, 30]),
+    "window": dict(heads=(8, 2, 16), s=3, index=[2, 20, 45], window=7),
+    "alibi": dict(heads=(6, 3, 16), s=2, index=[5, 33, 50], alibi=True),
+    "tree_mask": dict(heads=(4, 2, 16), s=5, index=[4, 21, 40], tree=True),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_ATTENTION_CASES))
+def test_cached_attention_matches_plain_attention(case, dtype):
+    """The per-head cache's layout (``[B, Hkv*D, M]``) changes where the
+    operands lie, not what is computed: in float32 the result is the plain
+    attention's to rounding; a bfloat16 cache under a float32 softmax stays
+    within bfloat16's own step of it."""
+    c = _ATTENTION_CASES[case]
+    (n_q, n_kv, d), s, m = c["heads"], c["s"], 64
+    index = np.asarray(c["index"])
+    b = len(index)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(31), 3)
+    q = jax.random.normal(kq, (b, s, n_q, d), jnp.float32).astype(dtype)
+    k = jax.random.normal(kk, (b, m, n_kv, d), jnp.float32).astype(dtype)
+    v = jax.random.normal(kv, (b, m, n_kv, d), jnp.float32).astype(dtype)
+    tree = _chain_mask(s) if c.get("tree") else None
+    depth = tree.sum(axis=1) - 1 if tree is not None else np.arange(s)
+    positions = index[:, None] + depth[None, :]
+    flat = lambda x: x.reshape(b, m, n_kv * d).swapaxes(1, 2)      # [B, Hkv*D, M]
+    got = cached_attention(q, flat(k), flat(v), jnp.asarray(positions),
+                           window=c.get("window"), alibi=c.get("alibi", False),
+                           tree_mask=tree)
+    want = _plain_attention(q, k, v, positions, window=c.get("window"),
+                            alibi=c.get("alibi", False), tree_mask=tree)
+    assert got.shape == (b, s, n_q, d) and got.dtype == q.dtype
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), rtol=tol, atol=tol)
 
 
 class TestKVCacheDecode:

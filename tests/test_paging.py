@@ -387,6 +387,7 @@ _STORE_CASES = {
 
 
 def _row_store(pages, view, tables, start, width, active):
+    """``view`` position-major ``[L, N, M, H, D]``."""
     out, page = pages.copy(), pages.shape[3]
     for lane in np.flatnonzero(active):
         for pos in range(start[lane], start[lane] + width):
@@ -394,8 +395,14 @@ def _row_store(pages, view, tables, start, width, active):
     return out
 
 
-@pytest.mark.parametrize("case", list(_STORE_CASES))
-def test_store_span_pages_matches_row_store(case):
+# the per-head view is ``[L, N, H * D, M]`` (rows flat, positions minor), the
+# latent one ``[L, N, M, 1, width]``: a latent model's rows go through the
+# position-major arm only
+@pytest.mark.parametrize("case,flat", [
+    (case, flat) for case in _STORE_CASES for flat in (True, False)
+    if not (flat and "rows" in _STORE_CASES[case])
+])
+def test_store_span_pages_matches_row_store(case, flat):
     from accelerate_tpu.serving import pool
 
     c = _STORE_CASES[case]
@@ -409,12 +416,59 @@ def test_store_span_pages_matches_row_store(case):
         # the view a window sees, then the rows its forward wrote (an inactive
         # lane's are garbage that must never reach the pool)
         live = pool._live_tables(jnp.asarray(tables), jnp.asarray((start + width - 1) // page + 1))
-        view = np.array(pool._gather_view(jnp.asarray(pages), live))
+        view = np.array(pool._gather_view(jnp.asarray(pages), live, flat))
+        lanes, max_len = tables.shape[0], tables.shape[1] * page
+        if flat:
+            assert view.shape == (layers, lanes, heads * dim, max_len)
+            # positions major for the oracle: [L, N, M, H, D]
+            rows = view.reshape(layers, lanes, heads, dim, max_len).transpose(0, 1, 4, 2, 3).copy()
+        else:
+            assert view.shape == (layers, lanes, max_len, heads, dim)
+            rows = view
         for lane, at in enumerate(start):
-            view[:, lane, at:at + width] = rng.standard_normal((layers, width, heads, dim))
-        got = jax.jit(pool._store_span_pages, static_argnums=4)(
+            rows[:, lane, at:at + width] = rng.standard_normal((layers, width, heads, dim))
+        if flat:
+            view = rows.transpose(0, 1, 3, 4, 2).reshape(view.shape)
+        got = jax.jit(pool._store_span_pages, static_argnums=(4, 6))(
             jnp.asarray(pages), jnp.asarray(view), jnp.asarray(tables), jnp.asarray(start),
-            width, jnp.asarray(active))
-        want = _row_store(pages, view, tables, start, width, active)
+            width, jnp.asarray(active), flat)
+        want = _row_store(pages, rows, tables, start, width, active)
         np.testing.assert_array_equal(np.asarray(got)[:, 1:], want[:, 1:])
         assert not np.array_equal(want[:, 1:], pages[:, 1:])     # the span was stored
+
+
+# the in-place arm's XLA read gathers each layer's pages itself
+# (``paged_attention_reference``); the gathered arm reads the view
+# ``pool._gather_view`` built once a call.  Same attention program, same
+# operand order in memory: equal bit for bit on the CPU.
+_READ_CASES = {
+    "decode": dict(s=1, heads=(4, 2, 8)),
+    "verify_span": dict(s=4, heads=(4, 2, 8)),
+    "mha_window": dict(s=2, heads=(3, 3, 8), window=5),
+    "alibi": dict(s=1, heads=(4, 4, 8), alibi=True),
+}
+
+
+@pytest.mark.parametrize("case", list(_READ_CASES))
+def test_in_place_read_equals_gathered_read(case):
+    from accelerate_tpu.models.transformer import cached_attention
+    from accelerate_tpu.ops.paged_attention import paged_attention_reference
+    from accelerate_tpu.serving import pool
+
+    c = _READ_CASES[case]
+    (n_q, n_kv, d), s, page = c["heads"], c["s"], 8
+    tables = jnp.asarray([[3, 1, 5], [2, 6, 4]], jnp.int32)
+    lengths = jnp.asarray([9, 17], jnp.int32)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(kq, (2, s, n_q, d), jnp.float32)
+    pages_k = jax.random.normal(kk, (1, 7, n_kv, page, d), jnp.float32)
+    pages_v = jax.random.normal(kv, (1, 7, n_kv, page, d), jnp.float32)
+    kw = dict(window=c.get("window"), alibi=c.get("alibi", False))
+    in_place = paged_attention_reference(q, pages_k[0], pages_v[0], tables, lengths, **kw)
+    live = pool._live_tables(tables, (lengths + s - 1) // page + 1)
+    view_k = pool._gather_view(pages_k, live, True)
+    view_v = pool._gather_view(pages_v, live, True)
+    assert view_k.shape == (1, 2, n_kv * d, 3 * page)
+    positions = lengths[:, None] + jnp.arange(s)[None, :]
+    gathered = cached_attention(q, view_k[0], view_v[0], positions, **kw)
+    np.testing.assert_array_equal(np.asarray(in_place), np.asarray(gathered))

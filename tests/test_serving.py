@@ -80,6 +80,10 @@ class TestPerLaneCache:
         per_lane = KVCache.create(cfg, 3, 16, per_lane_index=True)
         assert per_lane.index.shape == (3,)
         assert per_lane.index.dtype == jnp.int32
+        # per-head rows: flat (kv heads x width), positions minor
+        flat = cfg.num_kv_heads * cfg.resolved_head_dim
+        assert per_lane.k.shape == per_lane.v.shape == (cfg.num_layers, 3, flat, 16)
+        assert per_lane.max_len == 16
 
     def test_per_lane_decode_matches_lockstep(self):
         """A per-lane-index cache with every lane at the same position must

@@ -150,7 +150,7 @@ XL = dict(vocab_size=50257, hidden_size=1600, intermediate_size=6400, num_layers
 XL_LANES, XL_PAGES_PER_LANE, XL_PAGE, XL_WINDOW, XL_CHUNK = 4, 8, 128, 4, 128
 #: opcodes that hand a buffer on without writing it
 _PASS_THROUGH = {"parameter", "get-tuple-element", "tuple", "bitcast"}
-#: the in-place writes: what ``_write_rows`` / ``paged_insert`` lower to
+#: the in-place writes: what ``_write_columns`` / ``_write_rows`` / ``paged_insert`` lower to
 _WRITES = {"scatter", "dynamic-update-slice"}
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->\s+.*\{\s*$")
@@ -296,10 +296,12 @@ def paged_program(one_chip):
         else:
             fn = pool.make_paged_verify_window(model, SPECULATE_K, direct=direct)
             args = (params, *pages, *scales, i32(n, p), i32(n), i32(n, SPECULATE_K + 1), *lanes)
+        flat = model.config.latent_attention is None      # per-head rows: [L, N, H*D, M]
         return _Program(
             fn.lower(*args).compile(),
             tuple(f"bf16[{L},{num_pages},{h},{page},{d}]" for h, d in rows),
-            tuple(f"bf16[{L},{n},{p * page},{h},{d}]" for h, d in rows),
+            tuple(f"bf16[{L},{n},{h * d},{p * page}]" if flat
+                  else f"bf16[{L},{n},{p * page},{h},{d}]" for h, d in rows),
         )
 
     return build
@@ -312,14 +314,17 @@ def paged_program(one_chip):
          "prefill-gathered-scalar_index", "prefill-direct-lane_index"],
 )
 def test_cache_is_written_in_place(paged_program, program, direct):
-    """The gathered arm writes the slab view ``[L, N, M, H, D]`` (per-lane
+    """The gathered arm writes the view ``[L, N, H * D, M]`` (per-lane
     index in the decode scan, the scalar chunk base in prefill), the direct arm
     the page pool ``[L, NP, H, page, D]`` through the block tables (always per
-    lane), both with the XLA read."""
+    lane), both with the XLA read.  The view's per-lane write is one
+    ``dynamic_update_slice`` a lane (``_write_columns``), everything else one
+    write a layer and array."""
     built = paged_program("xl", program, direct)
+    per_layer = XL_LANES if (program, direct) == ("decode", False) else 1
     _check_cache_plumbing(
         built.compiled.as_text(), (built.pool_shapes if direct else built.view_shapes)[0],
-        n_writes=2 * XL["num_layers"],
+        n_writes=2 * XL["num_layers"] * per_layer,
         scope="while" if program == "decode" else "model",
     )
 
@@ -327,12 +332,19 @@ def test_cache_is_written_in_place(paged_program, program, direct):
 #: what brings a 2-layer pool into the faster memory space ``S(1)`` and back,
 #: which a 48-layer pool (0.65 GB) cannot have: not the write-back's doing
 _STAGING = {"copy-start", "copy-done", "slice-start", "slice-done"}
-#: view-sized outputs of the sandwich, K's and V's: the gather and its one
-#: layout pass.  DeepSeek-V2's rope key (rows of one head of 64, half a lane
-#: tile; a ninth of the latent's bytes) is carried with the positions minor, so
-#: the compiler re-tiles it on its way into the scan and again on its way to
-#: the page gather: five small passes in the decode window, four in the verify.
-_VIEW_PASSES = {"xl": (2, 2), "deepseek": (2, 5)}
+#: view-sized outputs of the sandwich, K's and V's, that are not an in-place
+#: write of one page into the view.  GPT-2-XL's flat view: the zero fill the
+#: pages are put into, nothing else (no layout pass: the pool's pages are the
+#: view's column blocks as they lie).  DeepSeek-V2's position-major view: the
+#: gather and its one layout pass; its rope key (rows of one head of 64, half a
+#: lane tile; a ninth of the latent's bytes) is carried with the positions
+#: minor, so the compiler re-tiles it on its way into the scan and again on its
+#: way to the page gather: five small passes in the decode window, four in the
+#: verify.
+_VIEW_PASSES = {"xl": (1, 1), "deepseek": (2, 5)}
+#: in-place writes of the pool: one scatter an array; the flat view's pages go
+#: back one ``dynamic_update_slice`` a (lane, touched page): two a lane
+_POOL_WRITES = {"xl": 2 * XL_LANES * 2, "deepseek": 2}
 
 
 def _squeezed(shape):
@@ -349,8 +361,8 @@ def test_pages_are_written_back_whole(paged_program, config, program):
     """The gathered windows' write-back (``pool._store_span_pages``), compiled:
     nothing transposes the view to cut rows out of it (``vmap()/transpose``);
     in the entry computation an array of the pool's shape comes only out of the
-    in-place write (a fusion whose root is a scatter or dynamic-update-slice),
-    once for K and once for V, so no ``copy`` takes the pool into the layout a
+    in-place write (a fusion whose root is a scatter or dynamic-update-slice;
+    ``_POOL_WRITES`` of them), so no ``copy`` takes the pool into the layout a
     row store wants and back; and outside the model the view is passed over no
     more often than ``_VIEW_PASSES`` says."""
     built = paged_program(config, program, False)
@@ -367,16 +379,68 @@ def test_pages_are_written_back_whole(paged_program, config, program):
                     or "ConcatBitcast" in ins.line):
                 continue
             shape, size = _squeezed(ins.shape)
+            root = ins if ins.opcode != "fusion" else next(
+                i for i in comps[ins.called[0]] if i.is_root)
             if shape in pools:
-                root = ins if ins.opcode != "fusion" else next(
-                    i for i in comps[ins.called[0]] if i.is_root)
                 assert root.opcode in _WRITES, ins.line
                 writes += 1
             elif (shape.startswith("bf16") and size in views and "/Transformer/" not in ins.op_name
-                  and "params" not in ins.line):      # a weight can be of the view's size
+                  and "params" not in ins.line         # a weight can be of the view's size
+                  and root.opcode != "dynamic-update-slice"):    # a page put into the view
                 passes[size] += 1
-    assert writes == 2, f"{writes} writes of the pool, expected K's and V's"
+    assert writes == _POOL_WRITES[config], f"{writes} writes of the pool"
     assert all(passes[size] <= limits[size] for size in limits), (passes, limits)
+
+
+_LAYOUT = re.compile(r"= (bf16)\[([\d,]+)\]\{([\d,]+):T\(8,128\)\(2,1\)")
+
+
+def _padded_elements(dims, minor_to_major):
+    """Elements a bfloat16 array occupies under the tiling ``T(8,128)(2,1)``:
+    the minor dimension in lanes of 128, the next in 8 sublanes of 2."""
+    dims = list(dims)
+    dims[minor_to_major[0]] = -(-dims[minor_to_major[0]] // 128) * 128
+    if len(minor_to_major) > 1:
+        dims[minor_to_major[1]] = -(-dims[minor_to_major[1]] // 16) * 16
+    return int(np.prod(dims))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_gathered_view_pads_nothing(paged_program, program):
+    """GPT-2-XL's gathered window and chunk, compiled: no bfloat16 array of the
+    view's element count, anywhere in the program, lies in a layout that pads
+    it (``[.., M, 25, 64]`` with ``(25, 64)`` tiled occupied 2.56 x: 25 heads
+    to 32 sublanes, 64 values to 128 lanes); the view the scan carries is
+    ``[L, N, 1600, 1024]`` as written; no ``copy`` or transpose of the whole
+    view stands between the gather and the model; and the window's temporaries
+    are the two views plus what the model needs, not two padded copies more
+    (0.176 GB here, 0.253 GB with the padded view; at 48 layers 2.30 against
+    4.39 GB: PERF.md)."""
+    built = paged_program("xl", program, False)
+    text = built.compiled.as_text()
+    view = built.view_shapes[0]
+    elements = _squeezed(view)[1]
+    seen = 0
+    for dtype, dims, layout in _LAYOUT.findall(text):
+        dims = [int(d) for d in dims.split(",")]
+        if int(np.prod(dims)) != elements:
+            continue
+        seen += 1
+        padded = _padded_elements(dims, [int(i) for i in layout.split(",")])
+        assert padded <= 1.05 * elements, f"{dtype}{dims}{{{layout}}} occupies {padded / elements:.2f} x"
+    assert seen, "no array of the view's size in the program"
+    assert f"{view}{{3,2,1,0:" in text, "the view is not carried as written"
+    comps, entry = _parse_hlo(text)
+    for ins in comps[entry]:
+        if ins.shape is None or _squeezed(ins.shape)[1] != elements or "params" in ins.line:
+            continue
+        root = ins if ins.opcode != "fusion" else next(
+            i for i in comps[ins.called[0]] if i.is_root)
+        assert root.opcode not in ("copy", "transpose"), ins.line
+    if program == "decode":
+        memory = built.compiled.memory_analysis()
+        unpadded = 2 * elements * 2                   # K's and V's views, bfloat16
+        assert memory.temp_size_in_bytes <= 0.15e9 + unpadded, memory
 
 
 def test_latent_decode_window_fits_at_published_widths(paged_program):

@@ -278,12 +278,14 @@ def _paged(cache):
     """A lane's contiguous cache cut into pages 1..P of a fresh pool (page 0
     is the null page), with the block table that maps them back in order.
     Fresh arrays every call: the verify window donates its pages."""
-    def cut(x):                                      # [L, 1, T, H, D]
-        L, _, T, H, D = x.shape
-        pages = x[:, 0].reshape(L, T // PAGE, PAGE, H, D).swapaxes(2, 3)
+    heads, dim = 2, cache.k.shape[2] // 2            # TransformerConfig.tiny: 2 kv heads
+
+    def cut(x):                                      # [L, 1, H*D, T]
+        L, _, _, T = x.shape
+        pages = x[:, 0].reshape(L, heads, dim, T // PAGE, PAGE).transpose(0, 3, 1, 4, 2)
         return jnp.concatenate([jnp.zeros_like(pages[:, :1]), pages], axis=1)
 
-    tables = jnp.arange(1, cache.k.shape[2] // PAGE + 1, dtype=jnp.int32)[None]
+    tables = jnp.arange(1, cache.max_len // PAGE + 1, dtype=jnp.int32)[None]
     return cut(cache.k), cut(cache.v), tables
 
 
@@ -291,6 +293,13 @@ def _rows(pages, lo, hi):
     """Positions ``[lo, hi)`` of the lane laid out by :func:`_paged`."""
     L, _, H, _, D = pages.shape
     return np.asarray(pages[:, 1:].swapaxes(2, 3).reshape(L, -1, H, D)[:, lo:hi])
+
+
+def _cache_rows(x, lo, hi):
+    """The same positions of lane 0 of a per-head ``KVCache`` array ``[L, B,
+    H*D, M]``, as rows ``[L, hi - lo, H, D]``."""
+    L, _, HD, _ = x.shape
+    return np.asarray(x[:, 0, :, lo:hi]).swapaxes(1, 2).reshape(L, hi - lo, 2, HD // 2)
 
 
 class TestTreeVerifyWindowDirect:
@@ -353,7 +362,7 @@ class TestTreeVerifyWindowDirect:
         for pages, want in ((pages_k, scene["linear"].k),
                             (pages_v, scene["linear"].v)):
             np.testing.assert_allclose(
-                _rows(pages, lo, hi), np.asarray(want[:, 0, lo:hi]),
+                _rows(pages, lo, hi), _cache_rows(want, lo, hi),
                 rtol=1e-5, atol=1e-5)
 
     def test_full_accept_commits_depth_plus_bonus(self, scene):
