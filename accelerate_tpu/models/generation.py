@@ -31,7 +31,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from .transformer import KVCache, Transformer
+from .transformer import KVCache, Transformer, create_cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -261,7 +261,7 @@ def generate(
     b, s = input_ids.shape
     total = s + gen.max_new_tokens
     if cache is None:
-        cache = KVCache.create(model.config, b, total)
+        cache = create_cache(model.config, b, total)
     else:
         # account for already-written entries: dynamic_update_slice CLAMPS
         # out-of-range writes, which would silently corrupt the cache.  A

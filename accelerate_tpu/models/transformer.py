@@ -259,6 +259,14 @@ class TransformerConfig:
     # dense_layers.  Both None: the parameter tree is what it always was.
     latent_attention: Optional[LatentAttentionSpec] = None
     experts: Optional[ExpertSpec] = None
+    # Power retention (models/retention.py, a RetentionSpec or its dict)
+    # replaces Attention in every layer, and the cache of rows a token by a
+    # recurrent state of fixed size a lane (StateCache).
+    retention: Optional[Any] = None
+    # Qwen3-family head norm: an RMSNorm over each head's width on q and on k
+    # (one learned scale of head_dim each, shared by the heads), before rope.
+    # Off: no such parameters, the tree is what it was.
+    qk_norm: bool = False
     # Attention program for PagedKVCache forwards (the serving engine's
     # in-model paged windows): "xla" is the live-masked-gather reference —
     # bitwise identical to the contiguous slab; "pallas" the in-place paged
@@ -296,6 +304,35 @@ class TransformerConfig:
             object.__setattr__(self, "latent_attention", LatentAttentionSpec(**self.latent_attention))
         if isinstance(self.experts, dict):
             object.__setattr__(self, "experts", ExpertSpec(**self.experts))
+        if self.retention is not None:
+            from .retention import RetentionSpec
+
+            if isinstance(self.retention, dict):
+                object.__setattr__(self, "retention", RetentionSpec(**self.retention))
+            excluded = {
+                "sliding_window": self.sliding_window is not None,
+                "latent_attention": self.latent_attention is not None,
+                "quantization": self.quantization is not None,
+                "use_fp8": self.use_fp8,
+                "paged_kernel": self.paged_kernel != "xla",
+                "attention_impl": self.attention_impl != "xla",
+                "positional": self.positional != "rope",
+                "scan_layers": self.scan_layers,
+            }
+            for name, used in excluded.items():
+                if used:
+                    raise ValueError(
+                        f"retention excludes {name}: a retention layer is a full-causal "
+                        f"rope layer in the model's dtype with a state of its own, and "
+                        f"{name} must keep its default"
+                    )
+            if self.num_heads % self.num_kv_heads or self.retention.gate_heads not in (
+                    None, self.num_kv_heads):
+                raise ValueError(
+                    "retention keeps one state and one gate a key/value head: num_kv_heads "
+                    f"{self.num_kv_heads} must divide num_heads {self.num_heads} and equal "
+                    f"gate_heads {self.retention.gate_heads}"
+                )
         if self.latent_attention is not None and (
             self.positional != "rope" or self.sliding_window is not None
             or self.quantization is not None or self.use_fp8 or self.paged_kernel != "xla"
@@ -496,6 +533,19 @@ class PagedKVCache(struct.PyTreeNode):
     @property
     def max_len(self) -> int:
         return self.tables.shape[1] * self.pages_k.shape[3]
+
+
+def create_cache(config: "TransformerConfig", batch_size: int, max_len: Optional[int] = None,
+                 **kw):
+    """The cache ``generate`` threads for ``config``, by the kind its attention
+    keeps: rows a token up to ``max_len`` (:class:`KVCache`), or a retention
+    model's state, which has no ``max_len``
+    (:class:`~accelerate_tpu.models.retention.StateCache`)."""
+    if config.retention is not None:
+        from .retention import StateCache
+
+        return StateCache.create(config, batch_size, max_len, **kw)
+    return KVCache.create(config, batch_size, max_len, **kw)
 
 
 def cached_attention(q, k, v, q_positions, window=None, alibi=False,
@@ -797,6 +847,9 @@ class Attention(nn.Module):
         q = q.reshape(b, s, cfg.num_heads, hd)
         k = k.reshape(b, s, cfg.num_kv_heads, hd)
         v = v.reshape(b, s, cfg.num_kv_heads, hd)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name="q_norm")(q)
+            k = RMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name="k_norm")(k)
         if cfg.positional == "rope":
             q = _apply_rope(q, positions, cfg)
             k = _apply_rope(k, positions, cfg)
@@ -984,6 +1037,10 @@ class DecoderLayer(nn.Module):
             from .latent_attention import LatentAttention
 
             attn = LatentAttention(cfg, name="attn")
+        elif cfg.retention is not None:
+            from .retention import PowerRetention
+
+            attn = PowerRetention(cfg, name="attn")
         else:
             attn = Attention(cfg, name="attn")
         attn_out = attn(normed, positions, cache=cache, tree_mask=tree_mask, layer=layer)

@@ -77,7 +77,7 @@ from ..telemetry import (
 )
 from . import faults, transfer
 from .errors import AdmissionError
-from .paging import DraftContextWindow, PagedKVPool
+from .paging import DraftContextWindow, PagedKVPool, StatePool
 from .pool import (
     ServeShardings,
     audit_donation,
@@ -90,6 +90,9 @@ from .pool import (
     make_paged_verify_window,
     make_promote_install,
     make_spill_extract,
+    make_state_decode_window,
+    make_state_install,
+    make_state_prefill_chunk,
     plan_chunks,
 )
 from .prefix_cache import PrefixCache
@@ -136,12 +139,27 @@ class _Stats(dict):
 
 
 def _refuse_unported(cfg, *, kv_dtype, speculate_k, draft_model, decode_kernel,
-                     prefill_kernel, prefix_host_mb, role, mesh, tp_axis) -> None:
+                     prefill_kernel, prefix_host_mb, prefix_disk_mb, role, mesh,
+                     tp_axis) -> None:
     """Refuse at construction, by name, each option that a latent cache
-    (``config.latent_attention``) or routed experts (``config.experts``) do
-    not have yet.  Everything else — chunked prefill, the prefix cache,
-    preemption, cancellation, the scheduler — is the same code for them."""
+    (``config.latent_attention``), routed experts (``config.experts``) or a
+    recurrent state (``config.retention``) do not have yet.  Everything else —
+    chunked prefill, cancellation, the scheduler, failover replay — is the
+    same code for them."""
     asked = {}
+    if cfg.retention is not None:
+        # the state has no rows to quantise, no page to read in place, to
+        # spill or to ship, and no way yet to roll back past a rejected draft
+        asked.update({
+            "kv_dtype": kv_dtype is not None,
+            "speculate_k": bool(speculate_k),
+            "draft_model": draft_model is not None,
+            "decode_kernel": decode_kernel != "xla",
+            "prefill_kernel": prefill_kernel not in (None, "xla"),
+            "prefix_host_mb": bool(prefix_host_mb),
+            "prefix_disk_mb": bool(prefix_disk_mb),
+            "role": role != "both",
+        })
     if cfg.latent_attention is not None:
         # the latent and the rope key live in the gathered view's two arrays;
         # everything below reads K and V per kv head from the pool in place,
@@ -155,13 +173,15 @@ def _refuse_unported(cfg, *, kv_dtype, speculate_k, draft_model, decode_kernel,
             "prefix_host_mb": bool(prefix_host_mb),
             "role": role != "both",
         })
-    if cfg.latent_attention is not None or cfg.experts is not None:
+    if cfg.latent_attention is not None or cfg.experts is not None or cfg.retention is not None:
         from ..parallel.mesh import mesh_axis_size
 
         asked["mesh"] = mesh is not None and mesh_axis_size(mesh, tp_axis) > 1
     for name, used in asked.items():
         if used:
-            what = "a latent-attention cache" if cfg.latent_attention is not None else "routed experts"
+            what = ("a recurrent state" if cfg.retention is not None
+                    else "a latent-attention cache" if cfg.latent_attention is not None
+                    else "routed experts")
             raise ValueError(
                 f"{name} is not ported to {what} yet: serve this model with the "
                 f"default {name} (see ROADMAP.md Reach for what is missing)"
@@ -179,6 +199,14 @@ class ServingEngine:
     ``generate``, after preemption too; a preempted *sampled* lane resumes on
     a restarted RNG stream (re-seeded from the request id at install):
     distribution-correct, not sample-exact, as under speculative decoding.
+
+    A retention model (``config.retention``) has no pages: its pool is the
+    lanes' recurrent state (:class:`~accelerate_tpu.serving.paging.StatePool`),
+    a lane is admitted when a slot is free and its state zeroed on the device
+    when the slot is taken, no prefix cache is built, and the options a state
+    does not have yet are refused by name (:func:`_refuse_unported`).  Chunked
+    prefill, the scheduler, cancellation, failover replay and streaming are
+    the same code.
 
     Parameters
     ----------
@@ -402,10 +430,15 @@ class ServingEngine:
         _refuse_unported(cfg, kv_dtype=kv_dtype, speculate_k=speculate_k,
                          draft_model=draft_model, decode_kernel=decode_kernel,
                          prefill_kernel=prefill_kernel, prefix_host_mb=prefix_host_mb,
-                         role=role, mesh=mesh, tp_axis=tp_axis)
+                         prefix_disk_mb=prefix_disk_mb, role=role, mesh=mesh,
+                         tp_axis=tp_axis)
         #: routed experts: decode windows and prefill chunks return the
         #: ``moe_*`` counters, fetched with the window's tokens
         self._routed = cfg.experts is not None
+        #: a retention model: the pool is the lanes' recurrent state
+        #: (:class:`StatePool`), its windows return the ``state_*`` counters,
+        #: and every page-speaking step of the lane lifecycle has nothing to do
+        self._stateful = cfg.retention is not None
         self.num_slots = int(num_slots)
         self.max_len = int(max_len if max_len is not None else cfg.max_seq_len)
         self.max_prompt_len = int(
@@ -606,11 +639,17 @@ class ServingEngine:
         # device state: the shared page pool + host block tables.  There is
         # no prefill scratch: a chunk gathers the lane's own view, shared
         # prefix pages included, and writes freshly filled pages back
-        self.kv = PagedKVPool(
-            cfg, self.num_slots, self.max_len, self.page_size,
-            self.num_pages, registry=self.metrics, kv_dtype=kv_dtype,
-            mesh=mesh, tp_axis=tp_axis,
-        )
+        if self._stateful:
+            self.kv = StatePool(
+                cfg, self.num_slots, registry=self.metrics,
+                sharding=None if self._shardings is None else self._shardings.replicated,
+            )
+        else:
+            self.kv = PagedKVPool(
+                cfg, self.num_slots, self.max_len, self.page_size,
+                self.num_pages, registry=self.metrics, kv_dtype=kv_dtype,
+                mesh=mesh, tp_axis=tp_axis,
+            )
         self.tracer = get_tracer()
         # Forensics + cost accounting (docs/usage/observability.md): request
         # lifecycle events land in the process flight recorder, per-executable
@@ -647,8 +686,15 @@ class ServingEngine:
             ))
         # budget=1 per executable: the engine's whole design promises exactly
         # one compiled shape each — any second signature is a bug worth a warning
-        decode_fn = make_paged_decode_window(
-            wmodel, self.window, direct=self._direct, shardings=self._shardings)
+        if self._stateful:
+            decode_fn = make_state_decode_window(wmodel, self.window, shardings=self._shardings)
+            self._state_install = RecompileWatchdog(
+                make_state_install(shardings=self._shardings),
+                name="serve/state_install", budget=1, registry=self.metrics,
+            )
+        else:
+            decode_fn = make_paged_decode_window(
+                wmodel, self.window, direct=self._direct, shardings=self._shardings)
         if self._direct:
             # nested watchdog: serve/paged_attn accounts the in-place paged
             # attention executable itself (budget 1 — the kernel REPLACES the
@@ -664,6 +710,8 @@ class ServingEngine:
         )
         self._prefill = {
             b: RecompileWatchdog(
+                make_state_prefill_chunk(pmodel, shardings=self._shardings)
+                if self._stateful else
                 make_paged_prefill_chunk(
                     pmodel, b, self.page_size, direct=self._prefill_direct,
                     shardings=self._shardings,
@@ -763,7 +811,9 @@ class ServingEngine:
         else:
             self._spill_extract = {}
             self._promote_install = {}
-        if prefix_cache_mb:
+        # a state has no pages for a hit to map: a retention model builds no
+        # prefix cache, whatever ``prefix_cache_mb`` says
+        if prefix_cache_mb and not self._stateful:
             self.prefix_cache: Optional[PrefixCache] = PrefixCache(
                 int(prefix_cache_mb * 2**20), registry=self.metrics,
                 on_evict=self._on_prefix_evict,
@@ -852,6 +902,10 @@ class ServingEngine:
             # here, and (decode windows) held experts that got a row, summed
             # over steps and layers: live lanes and valid prompt rows only
             self.stats.update(moe_pairs_total=0, moe_pairs_here=0, moe_experts_hit=0)
+        if self._stateful:
+            # lane-steps whose state a decode window read and rewrote (lanes x
+            # steps), those that emitted a token, and lanes zeroed at install
+            self.stats.update(state_lane_steps=0, state_live_lane_steps=0, state_installs=0)
         self.stats.engine = self
         self._counters = {
             k: self.metrics.counter(f"serve/{k}_total") for k in self.stats
@@ -1539,6 +1593,8 @@ class ServingEngine:
                         break
                     self.scheduler.start_next(slot)
                     self._reserved_slots.add(slot)
+                    if self._stateful:
+                        self._zero_lane_state(slot)
             if not self.scheduler.prefills:
                 return
             took = self.scheduler.take_chunk(
@@ -1738,12 +1794,28 @@ class ServingEngine:
             if self.prefix_cache is not None and node.host is handles:
                 self.prefix_cache.settle_payload(node, arrays)
 
+    def _zero_lane_state(self, slot: int) -> None:
+        """A retention model's install: zero the lane's state on the device
+        (enqueued behind whatever window still runs over the lane), before the
+        request's first prefill chunk folds its prompt into it."""
+        kv = self.kv
+        audit_donation(kv.s, kv.z)
+        # the in-flight window consumes these handles: park them until its
+        # drain, so that the rebind below never drops a consumed handle
+        self._stale_handles += [kv.s, kv.z]
+        with self.tracer.span("serve/state_install", slot=slot):
+            kv.s, kv.z = self._state_install(kv.s, kv.z, self._put(np.int32(slot)))
+        self._bump("state_installs")
+
     def _admission_pages_ok(self, req: Request) -> bool:
         """Can the queue head's whole prefill be paged in?  Conservative
         (cached chunks alias pages and cost nothing; the count uses the match
         from submit, which admission may improve).  Reclaims WITHOUT
         preemption — evicting a running lane to admit behind it would invert
-        FCFS and can livelock under steady overload."""
+        FCFS and can livelock under steady overload.  A state pool has no
+        page pressure: a free slot is all a request needs."""
+        if self._stateful:
+            return True
         padded = sum(b for b, _ in req.chunks)
         # only device-tier cached chunks alias for free; spilled chunks
         # promote into freshly allocated pages and must be charged
@@ -1761,7 +1833,7 @@ class ServingEngine:
         inside ``take_chunk``).  False skips this request for this engine step
         — running lanes keep decoding, their completions free pages, and the
         stalled chunk retries next step (or SRTF picks a smaller prefill)."""
-        if req.next_chunk >= len(req.chunks):
+        if self._stateful or req.next_chunk >= len(req.chunks):
             return True
         if req.next_chunk < req.cached_chunks:
             node = (req.cache_nodes[req.next_chunk]
@@ -1782,6 +1854,17 @@ class ServingEngine:
         included, which is how a partial hit feeds context to the chunks after
         it — and scatters back only the chunk's own (page-aligned) span."""
         s = req.slot
+        if self._stateful:
+            # the prompt's last token stays out of the state: the first decode
+            # step feeds it as the lane's pending token and folds it in then
+            last = start + valid == len(req.prefill_tokens)
+            kv = self.kv
+            args = (self.params, chunk[None], kv.s, kv.z, self._put(np.int32(s)),
+                    self._put(np.int32(start)), self._put(np.int32(valid - last)))
+            self.cost_table.capture(f"serve/prefill_{bucket}", self._prefill[bucket], args)
+            with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid):
+                kv.s, kv.z = self._prefill[bucket](*args)
+            return
         ids = self.kv.allocator.alloc(bucket // self.page_size)
         if ids is None:  # _ensure_prefill_pages runs first; this cannot happen
             raise RuntimeError("KV page pool exhausted mid-prefill")
@@ -1879,6 +1962,8 @@ class ServingEngine:
         full reclaim ladder runs, preemption included — the youngest lane
         funds the older ones, and if a lane preempts ITSELF the loop simply
         moves on (its pages are already free)."""
+        if self._stateful:
+            return
         page = self.page_size
         for s in np.nonzero(self._active)[0]:
             need = (int(self._lane_len[s]) + width - 1) // page + 1
@@ -1935,6 +2020,8 @@ class ServingEngine:
         frontier and every later page is freshly allocated.  Re-checks after
         each reclaim — eviction can dissolve the sharing and make the copy
         unnecessary."""
+        if self._stateful:
+            return
         pslot = (plen - 1) // self.page_size
         pid = int(self.kv.tables[s, pslot])
         while int(self.kv.allocator.refs[pid]) > 1:
@@ -2295,6 +2382,11 @@ class ServingEngine:
             counts = got[1] if commits else np.full(self.num_slots, hd.width)
             moe = got[1 + len(commits):]
         t1 = time.perf_counter()
+        if self._stateful:
+            for steps, live in moe:
+                self._bump("state_lane_steps", int(steps))
+                self._bump("state_live_lane_steps", int(live))
+            moe = ()
         for total, here, hit in moe:
             self._bump("moe_pairs_total", int(total))
             self._bump("moe_pairs_here", int(here))
@@ -2408,7 +2500,18 @@ class ServingEngine:
         window still owns."""
         lanes = self._lane_arrays()
         qerr = None
-        if self._direct:
+        if self._stateful:
+            kv = self.kv
+            audit_donation(kv.s, kv.z)
+            index = self._put(self._lane_len)
+            consumed = [kv.s, kv.z, lanes[0], lanes[-1], index]
+            args = (self.params, kv.s, kv.z, index, *lanes)
+            if not self.cost_table.captured("serve/decode_window"):
+                self.cost_table.capture("serve/decode_window", self._decode, args)
+            with self.tracer.span("serve/decode_window", occupied=n_occupied):
+                kv.s, kv.z, toks, pending, rngs, *moe = self._decode(*args)
+            self._lane_len[self._active] += self.window
+        elif self._direct:
             kv = self.kv
             audit_donation(kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales)
             consumed = [kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales,
@@ -2879,7 +2982,7 @@ class ServingEngine:
         metrics-tick cadence only, never the per-step hot path.  A tenant with no live lane reads 0
         (the gauge is not deleted: dashboards want the series to zero, not
         vanish)."""
-        if not self._tenant_stats:
+        if not self._tenant_stats or self._stateful:
             return
         held: dict = {}
         for s in range(self.num_slots):
@@ -2974,6 +3077,9 @@ class ServingEngine:
         out["hit_rate"] = out["prefix_hit_tokens"] / covered if covered else 0.0
         if self.prefix_cache is not None:
             out.update(self.prefix_cache.stats())
+        if self._stateful:
+            # a recurrent state has no pages for a hit to map
+            out["built"] = False
         return out
 
     def analyze_costs(self) -> dict:
@@ -3026,6 +3132,8 @@ class ServingEngine:
         out = {"decode_window": jit_cache_sizes(self._decode),
                "lane_install": jit_cache_sizes(self._lane_install),
                "copy_page": jit_cache_sizes(self._copy_page)}
+        if self._stateful:
+            out["state_install"] = jit_cache_sizes(self._state_install)
         if self._verify is not None:
             out["tree_verify_window" if self.tree is not None
                 else "verify_window"] = jit_cache_sizes(self._verify)

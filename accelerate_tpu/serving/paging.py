@@ -23,6 +23,9 @@ while all allocation, refcounting, and copy-on-write stay host-side numpy:
   change the softmax reduction shape and with it the last-ulp rounding —
   measured, not hypothetical).
 
+* :class:`StatePool` — the pool of a model that keeps no rows a token (a
+  recurrent state of fixed size a lane): no pages, no tables, no allocator.
+
 Sharing model: the prefix cache pins pages (one allocator ref per caching
 node), every lane aliasing a cached prefix takes its own ref per page, and a
 page returns to the free list only at refcount zero.  Copy-on-write happens in
@@ -329,6 +332,52 @@ class PagedKVPool:
         self._shared_gauge.set(
             self.allocator.shared_extra_refs() * self.page_kv_bytes
         )
+
+
+class StatePool:
+    """The pool of a retention model (``config.retention``): the recurrent
+    state of every layer for ``num_slots`` lanes, ``S [L, lanes, Hkv, D, Dh]``
+    and ``z [L, lanes, Hkv, D]`` (:mod:`accelerate_tpu.models.retention`), and
+    nothing else.  A lane's state has one size whatever its context, so there
+    are no pages, no block tables, no allocator and no page pressure: a lane
+    is admitted when a slot is free and released by being left (the next
+    request's install zeroes it on the device,
+    :func:`~accelerate_tpu.serving.pool.make_state_install`).  It answers the
+    calls the engine's lane lifecycle makes of :class:`PagedKVPool`."""
+
+    def __init__(self, config, num_slots: int,
+                 registry: Optional[MetricsRegistry] = None, sharding=None):
+        from ..models.retention import state_shapes
+
+        self.num_slots = int(num_slots)
+        s_shape, z_shape = state_shapes(config, self.num_slots)
+        dtype = config.retention.dtype
+        # allocated in place on the replica's own device, as the page pool is
+        self.s = jnp.zeros(s_shape, dtype, device=sharding)
+        self.z = jnp.zeros(z_shape, dtype, device=sharding)
+        registry = registry if registry is not None else get_registry()
+        self._bytes_gauge = registry.gauge(
+            "serve/state_bytes",
+            help="device bytes of the recurrent state pool (every lane, every layer)",
+        )
+        self.publish_gauges()
+
+    def lane_release(self, slot: int) -> int:
+        """Nothing to hand back: the lane's state stays where it is until the
+        next install zeroes it."""
+        return 0
+
+    def lane_detach(self, slot: int) -> List[int]:
+        return []
+
+    def kv_bytes(self) -> int:
+        return int(self.s.nbytes) + int(self.z.nbytes)
+
+    def kv_bytes_per_device(self) -> int:
+        return self.kv_bytes()
+
+    def publish_gauges(self) -> None:
+        self._bytes_gauge.set(self.kv_bytes())
 
 
 class DraftContextWindow:

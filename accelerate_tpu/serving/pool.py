@@ -68,6 +68,15 @@ pool itself (:class:`~accelerate_tpu.models.transformer.PagedKVCache`): no
 view, the same in-place write through the block tables, pages read where they
 lie.
 
+A model whose cache is a recurrent state a lane (``config.retention``,
+:mod:`~accelerate_tpu.models.retention`) has neither arm: nothing to gather,
+nothing to write back.  Its three programs (:func:`make_state_decode_window`,
+:func:`make_state_prefill_chunk`, :func:`make_state_install`) hand the model the
+donated state of :class:`~accelerate_tpu.serving.paging.StatePool` as a
+:class:`~accelerate_tpu.models.retention.StateCache`; the decode window is the
+same :func:`_decode_scan`, which rewrites every lane's state in place at every
+step and leaves a frozen lane's as it was.
+
 Compiled-shape budget for an engine instance: ``1 (decode window) +
 len(prefill_buckets) + 1 (lane install) + 1 (copy page)``, plus ``1`` verify
 executable when ``speculate_k > 0`` (or the tree pair) — asserted by the
@@ -85,6 +94,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..models.generation import sample_tokens_batched
+from ..models.retention import StateCache
 from ..models.transformer import KVCache, PagedKVCache, Transformer
 from ..parallel.mesh import mesh_axis_size
 from ..utils.jax_compat import jit_cache_size
@@ -163,6 +173,12 @@ def _routed(model: Transformer) -> bool:
     return getattr(model.config, "experts", None) is not None
 
 
+def _stateful(model: Transformer) -> bool:
+    """Does the model keep a recurrent state for a cache (``config.retention``)?
+    Its decode window returns the ``state_*`` counters."""
+    return getattr(model.config, "retention", None) is not None
+
+
 def _forward(model: Transformer, params, tokens, cache, live):
     """``model.apply`` on a cache: ``(logits, cache, counts)``.  For a model
     with routed experts ``counts`` is ``int32 [3]``, computed here on the
@@ -171,10 +187,15 @@ def _forward(model: Transformer, params, tokens, cache, live):
     through the static shapes but are no work): token-expert pairs chosen,
     those that fell on experts held here, and held experts that got at least
     one row, summed over the layers.  ``None`` for every other model, whose
-    program is what it was."""
+    program is what it was.  For a retention model ``counts`` is ``int32
+    [2]``: the lane-steps whose state this call read and rewrote (every lane,
+    live or frozen: the shapes are static) and those that were live."""
     if not _routed(model):
         logits, cache = model.apply({"params": params}, tokens, cache=cache)
-        return logits, cache, None
+        counts = None
+        if _stateful(model):
+            counts = jnp.stack([jnp.int32(live.shape[0]), jnp.sum(live[:, 0])]).astype(jnp.int32)
+        return logits, cache, counts
     (logits, cache), sown = model.apply(
         {"params": params}, tokens, cache=cache, mutable=["intermediates"]
     )
@@ -209,11 +230,15 @@ def _decode_scan(model: Transformer, window: int, params, cache, tokens, active,
     masked to ``pad``.  Frozen lanes still execute (static shapes) but only
     ever overwrite their own dead rows (gathered view) or the null page
     (in-place), so running lanes are untouched."""
-    counted = _routed(model)
+    counted = _routed(model) or _stateful(model)
 
     def step(carry, _):
         cache, tok, done, rngs, counts = carry
         prev_index = cache.index
+        if isinstance(cache, StateCache):
+            # a frozen lane's row runs through the static shapes and must leave
+            # its state as it is: the lane may be mid-prefill
+            cache = cache.replace(live=(~done).astype(jnp.int32))
         if isinstance(cache, PagedKVCache):
             # direct paged cache: route frozen lanes' writes to the null page
             # per step.  In the gathered view a frozen lane harmlessly
@@ -239,7 +264,7 @@ def _decode_scan(model: Transformer, window: int, params, cache, tokens, active,
         return (cache, nxt, done, split[:, 1], counts), nxt
 
     done0 = ~active
-    counts0 = jnp.zeros((3,), jnp.int32) if counted else None
+    counts0 = jnp.zeros((2 if _stateful(model) else 3,), jnp.int32) if counted else None
     (cache, tok, _, rngs, counts), toks = jax.lax.scan(
         step, (cache, tokens, done0, rngs, counts0), None, length=window
     )
@@ -579,6 +604,81 @@ def make_lane_install(shardings: Optional[ServeShardings] = None):
         lane_install,
         in_shardings=None if s is None else s.rep(16),
         out_shardings=None if s is None else s.rep(8),
+    )
+
+
+def make_state_install(shardings: Optional[ServeShardings] = None):
+    """``(s, z, slot) -> (s, z)`` with lane ``slot``'s state zeroed in every
+    layer, in place (the pool is donated).  The engine runs it when a slot is
+    taken for a request, before the first prefill chunk: a page pool never had
+    to clear a lane, because a fresh lane's rows were masked by its index; a
+    state is read whole."""
+
+    def state_install(s, z, slot):
+        zero = lambda a: jax.lax.dynamic_update_slice_in_dim(
+            a, jnp.zeros(a.shape[:1] + (1,) + a.shape[2:], a.dtype), slot, axis=1)
+        return zero(s), zero(z)
+
+    sh = shardings
+    return _serve_jit(
+        state_install, donate_argnums=(0, 1),
+        in_shardings=None if sh is None else sh.rep(3),
+        out_shardings=None if sh is None else sh.rep(2),
+    )
+
+
+def make_state_prefill_chunk(model: Transformer,
+                             shardings: Optional[ServeShardings] = None):
+    """Prefill chunk of a retention model: ``(params, tokens [1, chunk_len], s,
+    z, slot, base, valid) -> (s, z)``.  Lane ``slot``'s state is cut out of the
+    donated pool, the chunked form carries it over the chunk's first ``valid``
+    rows at positions ``base ..`` (the padding of a prompt's last chunk must
+    not enter a state, where a page pool let it write rows nobody reads), and
+    it is put back.  Logits are discarded, as by the paged chunk.  The engine
+    holds back a prompt's last token (``valid`` counts up to it): the decode
+    window feeds it as the lane's pending token, and a state, unlike a row
+    written twice, would count it twice.  One builder call a bucket: the engine
+    holds each bucket's program to one compiled shape."""
+
+    def state_prefill_chunk(params, tokens, s, z, slot, base, valid):
+        lane = lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1)
+        cache = StateCache(s=lane(s), z=lane(z), index=base, live=valid.reshape(1))
+        _, cache = model.apply({"params": params}, tokens, cache=cache)
+        put = lambda a, new: jax.lax.dynamic_update_slice_in_dim(a, new, slot, axis=1)
+        return put(s, cache.s), put(z, cache.z)
+
+    sh = shardings
+    return _serve_jit(
+        state_prefill_chunk, donate_argnums=(2, 3),
+        in_shardings=None if sh is None else (sh.params, *sh.rep(6)),
+        out_shardings=None if sh is None else sh.rep(2),
+    )
+
+
+def make_state_decode_window(model: Transformer, window: int,
+                             shardings: Optional[ServeShardings] = None):
+    """Decode window of a retention model: ``(params, s, z, index [N], tokens,
+    active, eos, do_sample, temperature, top_k, top_p, pad, rngs) -> (s, z,
+    out_tokens [N, window], new_pending, new_rngs, counts [2])``.  Nothing is
+    gathered and nothing written back: the shared :func:`_decode_scan` carries
+    the donated state, every step rewrites it in place, and it comes out as the
+    pool.  ``counts`` are the window's ``state_*`` counters
+    (:func:`_forward`)."""
+
+    def state_decode_window(params, s, z, index, tokens, active, eos, do_sample,
+                            temperature, top_k, top_p, pad, rngs):
+        cache = StateCache(s=s, z=z, index=index, live=active.astype(jnp.int32))
+        cache, toks, tok, rngs, counts = _decode_scan(
+            model, window, params, cache, tokens, active, eos, do_sample,
+            temperature, top_k, top_p, pad, rngs,
+        )
+        return cache.s, cache.z, toks, tok, rngs, counts
+
+    sh = shardings
+    return _serve_jit(
+        state_decode_window, donate_argnums=(1, 2),
+        in_shardings=None if sh is None else (sh.params, *sh.rep(12)),
+        out_shardings=None if sh is None else sh.rep(6),
     )
 
 

@@ -287,6 +287,8 @@ class PageMigrator:
             return "source and destination are the same engine"
         if src.config.latent_attention is not None or dst.config.latent_attention is not None:
             return "page migration is not ported to a latent-attention cache yet"
+        if src.config.retention is not None or dst.config.retention is not None:
+            return "page migration is not ported to a recurrent state yet"
         if src.kv.page_size != dst.kv.page_size:
             return (f"page_size differs ({src.kv.page_size} vs "
                     f"{dst.kv.page_size})")

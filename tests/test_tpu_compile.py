@@ -457,3 +457,86 @@ def test_latent_decode_window_fits_at_published_widths(paged_program):
     # the pool itself is handed over unpadded: 576 values a token and layer
     _, lanes, pages_per_lane, _ = _POOLS["deepseek"]
     assert memory.alias_size_in_bytes == 2 * (lanes * pages_per_lane + 1) * XL_PAGE * (512 + 64) * 2
+
+
+# ------------------------------------------------------------- the state pool
+#: Brumby-14B's widths (``bench/configs/brumby-14b.json``) at two layers, the
+#: serve cell's 8 lanes, window and larger chunk
+STATE_LAYERS, STATE_LANES, STATE_WINDOW, STATE_CHUNK = 2, 8, 4, 512
+
+
+@pytest.fixture(scope="module")
+def state_program(one_chip):
+    """``program -> (compiled, shape of S as the program sees it)``: the decode
+    window over the whole state pool, or a prefill chunk over one lane of it."""
+    import json
+    from pathlib import Path
+
+    from accelerate_tpu.models.retention import state_shapes
+    from accelerate_tpu.models.transformer import Transformer, TransformerConfig
+    from accelerate_tpu.serving import pool
+
+    fields = json.loads((Path(__file__).resolve().parents[1] / "bench" / "configs"
+                         / "brumby-14b.json").read_text())["transformer"]
+    fields.update(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    n = STATE_LANES
+    i32, f32 = (lambda *s: spec(s, jnp.int32)), (lambda *s: spec(s, jnp.float32))
+    flag = lambda *s: spec(s, jnp.bool_)
+
+    @functools.cache
+    def build(program, layers=STATE_LAYERS):
+        model = Transformer(TransformerConfig(**dict(fields, num_layers=layers)))
+        params = jax.tree_util.tree_map(
+            lambda a: spec(a.shape, a.dtype),
+            jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+        s_shape, z_shape = state_shapes(model.config, n)
+        state = (spec(s_shape, jnp.float32), spec(z_shape, jnp.float32))
+        if program == "decode":
+            fn = pool.make_state_decode_window(model, STATE_WINDOW)
+            args = (params, *state, i32(n), i32(n), flag(n), i32(n), flag(n), f32(n), i32(n), f32(n), i32(n),
+                    spec((n, 2), jnp.uint32))
+            seen = s_shape
+        else:
+            fn = pool.make_state_prefill_chunk(model)
+            args = (params, i32(1, STATE_CHUNK), *state, i32(), i32(), i32())
+            seen = s_shape[:1] + (1,) + s_shape[2:]
+        return fn.lower(*args).compile(), "f32[" + ",".join(map(str, seen)) + "]"
+
+    return build
+
+
+@pytest.mark.parametrize("program,scope,temp_gb", [("decode", "while", 0.20), ("prefill", "model", 0.75)])
+def test_retention_state_is_updated_in_place(state_program, program, scope, temp_gb):
+    """The retention decode window and 512-chunk at the published widths, two
+    layers, 8 lanes (a state pool of 0.55 GB): each layer rewrites its slice of
+    the stacked state in place, once (one ``dynamic-update-slice`` a layer; no
+    layer cut out, nothing stacked back), the donated pool comes out as the
+    result, and the program holds no temporary of the state's size: 0.16 GB
+    for the window, 0.67 GB for the chunk (``phi`` of 512 rows of 40 heads in
+    sub-chunks of 128; one lane's state is 0.07 GB)."""
+    compiled, seen = state_program(program)
+    _check_cache_plumbing(compiled.as_text(), seen, n_writes=STATE_LAYERS, scope=scope)
+    memory = compiled.memory_analysis()
+    state_bytes = 4 * STATE_LAYERS * STATE_LANES * 8 * 8320 * (128 + 1)
+    assert memory.alias_size_in_bytes == state_bytes, memory
+    assert memory.temp_size_in_bytes < temp_gb * 1e9, memory
+    if program == "decode":
+        assert memory.temp_size_in_bytes < state_bytes / STATE_LAYERS, memory     # not one layer's state
+
+
+def test_retention_decode_window_at_the_cells_ten_layers_fits_and_is_not_rematerialised(state_program):
+    """The window the serve cell runs: ten layers, 8 lanes, 12.47 GB of weights
+    and state handed in.  It fits the chip with 0.76 GB of temporaries, and the
+    compiler rematerialises nothing: while the read-out read the OLD state (the
+    update fused into it a second time) the ten-layer window, and only it, came
+    out with ``add_dynamic-update-slice_fusion.20.remat``: layer 0's in-place
+    update run twice a step on one buffer, every served token wrong on the
+    chip and nothing to see at two layers or on the CPU (PERF.md, PR 33).
+    ``retention_step_stored`` stores first and reads behind a barrier."""
+    compiled, _ = state_program("decode", 10)
+    assert not re.findall(r"%[\w.\-]+\.remat[\w.]*", compiled.as_text())       # an instruction run twice
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 4 * 10 * STATE_LANES * 8 * 8320 * (128 + 1), memory
+    assert memory.temp_size_in_bytes < 0.85e9, memory
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9, memory
