@@ -31,7 +31,10 @@ is 65 lane rotations and no gather (:func:`phi`).
 the call as latent attention chooses its forms:
 
 * **recurrent** (``retention/step``) — a cached call with one new row a lane
-  (a decode step): the three lines above, the state updated in place.
+  (a decode step): the three lines above, the state updated in place.  On a
+  TPU, at a head width of whole lanes, one Pallas kernel a layer that reads
+  the state once (:mod:`accelerate_tpu.ops.retention`); elsewhere XLA, which
+  needs three passes (:func:`retention_step_stored`).
 * **chunked** (``retention/chunk_intra``, ``retention/chunk_state``) — every
   other call (a full forward, ``generate``'s prompt pass, a prefill chunk), in
   sub-chunks of ``RetentionSpec.chunk`` rows carried by a scan: inside a
@@ -61,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.retention import onepass_applies, retention_step_onepass
 from .transformer import RMSNorm, TransformerConfig, _apply_rope, _tag_proj, functools_partial_dense
 
 #: precision of the products against the float32 state (and of the float32
@@ -178,13 +182,19 @@ def _einsum(spec, *operands):
     return jnp.einsum(spec, *operands, precision=STATE_PRECISION, preferred_element_type=jnp.float32)
 
 
+def gated(k, log_g, live):
+    """``(g_t, phi(k̂_t))`` as the state takes them: ``k [B,Hk,D]`` is
+    ``phi(k̂)``, ``log_g [B,Hk]``, ``live [B]`` bool.  A frozen lane decays by 1
+    and adds 0, so its state stays as it is."""
+    return jnp.where(live[:, None], jnp.exp(log_g), 1.0), jnp.where(live[:, None, None], k, 0.0)
+
+
 def state_update(k, v, log_g, s, z, live):
     """``S_t = g_t S_{t-1} + phi(k̂_t) v_t^T`` and ``z_t`` alike for one new row
     a lane: ``k [B,Hk,D]`` is ``phi(k̂)``, ``v [B,Hk,d]``, ``log_g [B,Hk]``, ``s
     [B,Hk,D,d]``, ``z [B,Hk,D]`` float32, ``live [B]`` bool (a frozen lane's
     state stays as it is)."""
-    gate = jnp.where(live[:, None], jnp.exp(log_g), 1.0)
-    pk = jnp.where(live[:, None, None], k, 0.0)
+    gate, pk = gated(k, log_g, live)
     s = gate[..., None, None] * s + pk[..., None] * v.astype(jnp.float32)[..., None, :]
     return s, gate[..., None] * z + pk
 
@@ -203,18 +213,33 @@ def retention_step(q, k, v, log_g, s, z, live, degree: int, eps: float):
     return state_read(phi(q, degree), s, z, eps), s, z
 
 
-def retention_step_stored(q, k, v, log_g, cache, layer: int, degree: int, eps: float):
-    """:func:`retention_step` on the stacked ``cache``: layer ``layer``'s state
-    is rewritten in place FIRST and the read-out reads what was stored, behind
-    an ``optimization_barrier``.  Returns ``(y [B,Hk,G,d], cache)``.
+def retention_step_stored(q, k, v, log_g, cache, layer: int, degree: int, eps: float,
+                          interpret: Optional[bool] = None):
+    """:func:`retention_step` on the stacked ``cache``, layer ``layer``'s state
+    rewritten in place.  Returns ``(y [B,Hk,G,d], cache)``.  One algorithm, two
+    executions, picked by what the call can observe
+    (:func:`~accelerate_tpu.ops.retention.onepass_applies`):
 
-    Left to itself the TPU compiler fuses the update into the read-out a second
-    time (two readers of the old state), and at ten layers its rematerialisation
-    then ran a layer's in-place update twice in one step: every served token
-    wrong on the chip, nothing to see at two layers or on the CPU (PERF.md
-    section 6, PR 33; ``tests/test_tpu_compile.py`` compiles the ten-layer
-    window and looks for it)."""
+    * a float32 state of the symmetric square with a head width of whole lanes,
+      on a TPU (or with ``interpret`` given, as the CPU tests do): one Pallas
+      kernel a layer that reads the state once, updates it where it lies and
+      takes the read-out from the tile it holds
+      (:func:`~accelerate_tpu.ops.retention.retention_step_onepass`);
+    * anything else (a narrow head, ``degree == 1``, a CPU): XLA, the state
+      stored FIRST and the read-out reading what was stored, behind an
+      ``optimization_barrier``: three passes over the state.  Left to itself
+      the TPU compiler fuses the update into the read-out a second time (two
+      readers of the old state), and at ten layers its rematerialisation then
+      ran a layer's in-place update twice in one step: every served token
+      wrong on the chip, nothing to see at two layers or on the CPU (PERF.md
+      section 6, PR 33; ``tests/test_tpu_compile.py`` compiles the ten-layer
+      window and looks for it)."""
     alive = jnp.ones(q.shape[:1], bool) if cache.live is None else cache.live > 0
+    if onepass_applies(cache.s, degree, interpret):
+        gate, pk = gated(phi(k, degree), log_g, alive)
+        num, den, s, z = retention_step_onepass(phi(q, degree), pk, v, gate, cache.s, cache.z, layer,
+                                                interpret=interpret)
+        return normalise(num, den, eps), cache.replace(s=s, z=z)
     s, z = state_update(phi(k, degree), v, log_g, cache.s[layer].astype(jnp.float32),
                         cache.z[layer].astype(jnp.float32), alive)
     stored = jax.lax.optimization_barrier((cache.s.at[layer].set(s.astype(cache.s.dtype)),

@@ -1,5 +1,5 @@
-"""ops subpackage: attention dispatch, pallas flash attention, fp8 matmuls,
-weight-only quantization."""
+"""ops subpackage: attention dispatch, pallas flash attention, the one-pass
+retention decode step, fp8 matmuls, weight-only quantization."""
 
 from .fp8 import (
     DelayedScalingState,
@@ -22,3 +22,4 @@ from .quantization import (
     quantized_matmul,
     quantized_nbytes,
 )
+from .retention import retention_step_onepass

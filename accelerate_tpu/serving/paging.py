@@ -348,6 +348,7 @@ class StatePool:
     def __init__(self, config, num_slots: int,
                  registry: Optional[MetricsRegistry] = None, sharding=None):
         from ..models.retention import state_shapes
+        from ..ops.retention import onepass_applies
 
         self.num_slots = int(num_slots)
         s_shape, z_shape = state_shapes(config, self.num_slots)
@@ -360,6 +361,12 @@ class StatePool:
             "serve/state_bytes",
             help="device bytes of the recurrent state pool (every lane, every layer)",
         )
+        self._onepass_gauge = registry.gauge(
+            "serve/state_step_onepass",
+            help="1 where the decode window's retention step is the one-pass kernel, 0 where it is XLA's three passes",
+        )
+        #: the form ``retention_step_stored`` picks when the window is traced
+        self.step_onepass = onepass_applies(self.s, config.retention.degree)
         self.publish_gauges()
 
     def lane_release(self, slot: int) -> int:
@@ -378,6 +385,7 @@ class StatePool:
 
     def publish_gauges(self) -> None:
         self._bytes_gauge.set(self.kv_bytes())
+        self._onepass_gauge.set(int(self.step_onepass))
 
 
 class DraftContextWindow:

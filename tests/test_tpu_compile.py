@@ -43,6 +43,7 @@ from accelerate_tpu.ops.paged_attention import (
     paged_attention,
     paged_flash_prefill,
 )
+from accelerate_tpu.ops.retention import retention_step_onepass
 
 NUM_PAGES, PAGE, PAGES_PER_LANE, LANES = 512, 16, 64, 4
 HEADS = [(25, 25, 64), (12, 12, 64)]          # (q heads, kv heads, head dim)
@@ -501,22 +502,56 @@ def state_program(one_chip):
             fn = pool.make_state_prefill_chunk(model)
             args = (params, i32(1, STATE_CHUNK), *state, i32(), i32(), i32())
             seen = s_shape[:1] + (1,) + s_shape[2:]
-        return fn.lower(*args).compile(), "f32[" + ",".join(map(str, seen)) + "]"
+        # the step asks the platform which form to take, and this process sees a
+        # CPU: say "a TPU" while the window is traced, as the chip would
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr("accelerate_tpu.ops.retention._platform_compiles", lambda: True)
+            compiled = fn.lower(*args).compile()
+        return compiled, "f32[" + ",".join(map(str, seen)) + "]"
 
     return build
+
+
+_STEP_KERNEL = re.compile(r"^\s+%retention_step_onepass[\w.]* = .*custom_call_target=\"tpu_custom_call\".*$", re.M)
+
+
+def _check_state_kernel(text, whole_shape, layers):
+    """The decode window with the one-pass step: its loop body holds one
+    ``retention_step_onepass`` kernel a layer, each handing the whole ``S`` and
+    ``z`` back through the operands they came in by; nothing else in the
+    program makes an array of the state's shape (no ``dynamic-update-slice``,
+    no copy), and no fusion takes the state as an operand."""
+    kernels = _STEP_KERNEL.findall(text)
+    assert len(kernels) == layers, f"{len(kernels)} one-pass kernels, expected {layers}"
+    for line in kernels:
+        assert "/while/body/" in line and "output_to_operand_aliasing={{2}: (4, {}), {3}: (5, {})}" in line, line[:400]
+        assert whole_shape + "{" in line.split("custom-call(")[0], line[:400]          # the whole state comes back
+    comps, _ = _parse_hlo(text)
+    fused = {ins.called[0] for instructions in comps.values() for ins in instructions if ins.opcode == "fusion"}
+    for name, instructions in comps.items():
+        for ins in instructions:
+            if ins.shape != whole_shape:
+                continue
+            assert name not in fused, f"a fusion reads or writes the state: {name}: {ins.line}"
+            assert ins.opcode in _PASS_THROUGH, ins.line
 
 
 @pytest.mark.parametrize("program,scope,temp_gb", [("decode", "while", 0.20), ("prefill", "model", 0.75)])
 def test_retention_state_is_updated_in_place(state_program, program, scope, temp_gb):
     """The retention decode window and 512-chunk at the published widths, two
     layers, 8 lanes (a state pool of 0.55 GB): each layer rewrites its slice of
-    the stacked state in place, once (one ``dynamic-update-slice`` a layer; no
-    layer cut out, nothing stacked back), the donated pool comes out as the
-    result, and the program holds no temporary of the state's size: 0.16 GB
-    for the window, 0.67 GB for the chunk (``phi`` of 512 rows of 40 heads in
-    sub-chunks of 128; one lane's state is 0.07 GB)."""
+    the stacked state in place, once (the window: one aliased one-pass kernel a
+    layer in the loop's body, ``_check_state_kernel``; the chunk: one
+    ``dynamic-update-slice`` a layer; no layer cut out, nothing stacked back),
+    the donated pool comes out as the result, and the program holds no
+    temporary of the state's size: 0.16 GB for the window, 0.67 GB for the
+    chunk (``phi`` of 512 rows of 40 heads in sub-chunks of 128; one lane's
+    state is 0.07 GB)."""
     compiled, seen = state_program(program)
-    _check_cache_plumbing(compiled.as_text(), seen, n_writes=STATE_LAYERS, scope=scope)
+    if program == "decode":
+        _check_state_kernel(compiled.as_text(), seen, STATE_LAYERS)
+    else:
+        _check_cache_plumbing(compiled.as_text(), seen, n_writes=STATE_LAYERS, scope=scope)
     memory = compiled.memory_analysis()
     state_bytes = 4 * STATE_LAYERS * STATE_LANES * 8 * 8320 * (128 + 1)
     assert memory.alias_size_in_bytes == state_bytes, memory
@@ -533,10 +568,36 @@ def test_retention_decode_window_at_the_cells_ten_layers_fits_and_is_not_remater
     out with ``add_dynamic-update-slice_fusion.20.remat``: layer 0's in-place
     update run twice a step on one buffer, every served token wrong on the
     chip and nothing to see at two layers or on the CPU (PERF.md, PR 33).
-    ``retention_step_stored`` stores first and reads behind a barrier."""
-    compiled, _ = state_program("decode", 10)
+    The XLA form of ``retention_step_stored`` stores first and reads behind a
+    barrier; the one-pass kernel this window runs leaves XLA no update to
+    duplicate: ten kernels, and no fusion that touches the state at all (a
+    copy of ``phi(q)`` into the kernel's layout was rematerialised until the
+    kernel took whole sublanes of query heads)."""
+    compiled, seen = state_program("decode", 10)
     assert not re.findall(r"%[\w.\-]+\.remat[\w.]*", compiled.as_text())       # an instruction run twice
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == 4 * 10 * STATE_LANES * 8 * 8320 * (128 + 1), memory
     assert memory.temp_size_in_bytes < 0.85e9, memory
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9, memory
+    _check_state_kernel(compiled.as_text(), seen, 10)
+
+
+def test_retention_step_kernel_compiles_at_the_published_shapes(one_chip):
+    """The kernel alone, as the serve cell calls it: 8 lanes, 8 key/value heads
+    of 5 query heads, the ten-layer float32 state ``[10, 8, 8, 8320, 128]``
+    donated.  Mosaic takes the whole-(lane, head) tile (4.26 MB, four buffers)
+    under the kernel's fast-memory limit, the state and ``z`` alias through,
+    and nothing of the state's size is left over."""
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    lanes, heads, groups, rows, width = 8, 8, 5, 8320, 128
+
+    def fn(pq, pk, v, gate, s, z):
+        return retention_step_onepass(pq, pk, v, gate, s, z, 3, interpret=False)
+
+    compiled = jax.jit(fn, donate_argnums=(4, 5)).lower(
+        f32(lanes, heads, groups, rows), f32(lanes, heads, rows), f32(lanes, heads, width), f32(lanes, heads),
+        f32(10, lanes, heads, rows, width), f32(10, lanes, heads, rows)).compile()
+    assert len(_STEP_KERNEL.findall(compiled.as_text())) == 1
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 4 * 10 * lanes * heads * rows * (width + 1), memory
+    assert memory.temp_size_in_bytes < 32e6, memory          # phi(q) padded to whole sublanes: 17 MB
