@@ -17,6 +17,7 @@ the standard Llama-2/3 recipe, written TPU-first:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Optional, Tuple
 
@@ -130,7 +131,15 @@ class ExpertSpec:
     [lo, hi)`` and computes their part of the result.  Gates are ``scaling *
     score``, renormalised over the chosen instead where ``norm_topk``.  The
     first ``dense_layers`` layers keep a dense MLP of ``dense_width``
-    (``intermediate_size`` when None)."""
+    (``intermediate_size`` when None).
+
+    ``score_func`` is what the router's logits pass through before the choice
+    (``"softmax"`` over all experts, or ``"sigmoid"`` of each).  With
+    ``select_bias`` the layer holds a leaf ``expert_bias [num_routed]`` that is
+    added to the scores for the CHOICE only (the correction that balances the
+    load without an auxiliary loss); the gates are the chosen experts' scores
+    without it.  ``scale_normed``: the renormalised gates of ``norm_topk`` are
+    multiplied by ``scaling`` as well."""
 
     num_routed: int
     top_k: int
@@ -143,8 +152,15 @@ class ExpertSpec:
     shared_width: int = 0
     dense_layers: int = 0
     dense_width: Optional[int] = None
+    score_func: str = "softmax"
+    select_bias: bool = False
+    scale_normed: bool = False
 
     def __post_init__(self):
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"Unknown score_func {self.score_func!r}; choose 'softmax' or 'sigmoid'")
+        if self.select_bias and self.n_group > 1:
+            raise ValueError("select_bias chooses among all experts: n_group must be 1")
         held = (0, self.num_routed) if self.held is None else tuple(int(e) for e in self.held)
         object.__setattr__(self, "held", held)
         if not 0 <= held[0] < held[1] <= self.num_routed:
@@ -267,6 +283,22 @@ class TransformerConfig:
     # (one learned scale of head_dim each, shared by the heads), before rope.
     # Off: no such parameters, the tree is what it was.
     qk_norm: bool = False
+    # Two kinds of attention layer in one stack: one entry a layer, "window"
+    # (sees the last ``sliding_window`` positions) or "full" (every position).
+    # ``sliding_window`` then applies to the "window" layers only, and the
+    # serving pool keeps a ring of pages for them and whole tables for the
+    # "full" ones (serving/paging.py MixedKVPool).  ``rope_full_layers=False``
+    # leaves the "full" layers without any positional encoding.  None: every
+    # layer is what ``sliding_window`` and ``positional`` say.
+    layer_types: Optional[Tuple[str, ...]] = None
+    rope_full_layers: bool = True
+    # A sigmoid gate on the attention output, one value a query head and
+    # channel, projected from the layer's normed input (``attn/gate_proj``).
+    attention_gate: bool = False
+    # A norm on each branch's OUTPUT as well as its input: ``x + norm(attn(
+    # norm(x)))``, then the same round the MLP (``attn_out_norm``,
+    # ``mlp_out_norm``).
+    sandwich_norm: bool = False
     # Attention program for PagedKVCache forwards (the serving engine's
     # in-model paged windows): "xla" is the live-masked-gather reference —
     # bitwise identical to the contiguous slab; "pallas" the in-place paged
@@ -291,6 +323,11 @@ class TransformerConfig:
         if la is not None:
             return (1, la.kv_rank), (1, la.rope_dim)
         return (self.num_kv_heads, self.resolved_head_dim), (self.num_kv_heads, self.resolved_head_dim)
+
+    def layer_kind(self, layer: int) -> Optional[str]:
+        """``"window"`` / ``"full"`` for a stack of two kinds (``layer_types``),
+        None where every layer is one kind."""
+        return None if self.layer_types is None else self.layer_types[layer]
 
     def resolved_expert_capacity(self, n_tokens: int) -> int:
         """Per-expert token buffer: factor * even-split share, rounded up to a
@@ -333,6 +370,33 @@ class TransformerConfig:
                     f"{self.num_kv_heads} must divide num_heads {self.num_heads} and equal "
                     f"gate_heads {self.retention.gate_heads}"
                 )
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            kinds = set(self.layer_types)
+            if len(self.layer_types) != self.num_layers or not kinds <= {"window", "full"}:
+                raise ValueError(
+                    f"layer_types needs one of 'window' / 'full' for each of the "
+                    f"{self.num_layers} layers, got {self.layer_types}"
+                )
+            if "window" in kinds and self.sliding_window is None:
+                raise ValueError("layer_types has 'window' layers: set sliding_window")
+            excluded = {
+                "latent_attention": self.latent_attention is not None,
+                "retention": self.retention is not None,
+                "scan_layers": self.scan_layers,
+                "positional": self.positional != "rope",
+                "paged_kernel": self.paged_kernel != "xla",
+            }
+            for name, used in excluded.items():
+                if used:
+                    raise ValueError(
+                        f"layer_types excludes {name}: layers of two kinds are unrolled "
+                        f"rope (or position-free) per-head attention on the 'xla' paged "
+                        f"path, and {name} must keep its default"
+                    )
+        if self.sandwich_norm and self.parallel_residual:
+            raise ValueError("sandwich_norm norms each branch of a sequential block: "
+                             "parallel_residual must stay off")
         if self.latent_attention is not None and (
             self.positional != "rope" or self.sliding_window is not None
             or self.quantization is not None or self.use_fp8 or self.paged_kernel != "xla"
@@ -535,6 +599,39 @@ class PagedKVCache(struct.PyTreeNode):
         return self.tables.shape[1] * self.pages_k.shape[3]
 
 
+class MixedKVCache(struct.PyTreeNode):
+    """The cache of a stack of two kinds of layer (``layer_types``) as the
+    serving pool gathers it: each kind's layers stacked in arrays of their own,
+    because the two keep different numbers of positions.
+
+    * ``k`` / ``v`` ``[n_full, B, Hkv * Dh, max_len]``: the "full" layers, a
+      :class:`KVCache`'s layout, position ``p`` in column ``p``.
+    * ``k_ring`` / ``v_ring`` ``[n_window, B, Hkv * Dh, W]``: the "window"
+      layers, a ring: position ``p`` lives in column ``p % W``.  ``W`` is the
+      pool's ring of pages (window + the largest prefill chunk, rounded up to
+      pages, + one page), so a column is overwritten only by a position at
+      least ``W`` later, which no query that still sees the old one can have
+      written (:func:`cached_attention`, ``ring=True``).
+
+    ``index`` is a :class:`KVCache`'s.  ``page`` (static) is the granule of a
+    chunk's write: a chunk starts on a page boundary and is whole pages long,
+    so it lands in the ring page by page and a page never straddles the ring's
+    end.  ``generate`` does not use this cache: its contiguous
+    :class:`KVCache` keeps ``max_len`` columns for every layer and masks the
+    window layers by the band."""
+
+    k: jax.Array
+    v: jax.Array
+    k_ring: jax.Array
+    v_ring: jax.Array
+    index: jax.Array
+    page: int = struct.field(pytree_node=False, default=1)
+
+    @property
+    def max_len(self) -> int:
+        return self.k.shape[3]
+
+
 def create_cache(config: "TransformerConfig", batch_size: int, max_len: Optional[int] = None,
                  **kw):
     """The cache ``generate`` threads for ``config``, by the kind its attention
@@ -549,7 +646,7 @@ def create_cache(config: "TransformerConfig", batch_size: int, max_len: Optional
 
 
 def cached_attention(q, k, v, q_positions, window=None, alibi=False,
-                     tree_mask=None):
+                     tree_mask=None, ring=False):
     """Attention of ``q`` [B,S,Hq,D] against a full cache ``k``/``v``
     [B,Hkv*D,M]: one layer of a per-head :class:`KVCache`, positions minor.
 
@@ -573,6 +670,13 @@ def cached_attention(q, k, v, q_positions, window=None, alibi=False,
     its own root-to-self chain inside the tree span.  Mutually exclusive with
     ``window``/``alibi`` (the engine only builds tree windows for full-causal
     rope/learned models).
+
+    ``ring`` (with ``window``): the cache is a ring of ``M`` columns, position
+    ``p`` in column ``p % M`` (:class:`MixedKVCache`).  Column ``j`` then holds
+    the newest position congruent to it that has been written, ``hi - ((hi -
+    j) mod M)`` with ``hi`` the lane's last query position (the call's writes
+    precede its reads), and the band mask is taken over those positions;
+    columns not yet written come out negative and are masked.
     """
     b, s, n_q, d = q.shape
     m = k.shape[2]
@@ -608,11 +712,17 @@ def cached_attention(q, k, v, q_positions, window=None, alibi=False,
                - q_positions[:, None, None, :, None]).astype(jnp.float32)
         slopes = alibi_slopes(n_q).reshape(n_kv, rep)
         logits = logits + slopes[None, :, :, None, None] * rel
-    mask = j[None, None, None, None, :] <= q_positions[:, None, None, :, None]  # [B,1,1,S,M]
-    if window is not None:
-        mask = mask & (
-            j[None, None, None, None, :] > q_positions[:, None, None, :, None] - window
-        )
+    if ring:
+        hi = q_positions[:, -1:]                                     # [B, 1]
+        held = (hi - jnp.mod(hi - j[None, :], m))[:, None, None, None, :]
+        at = q_positions[:, None, None, :, None]
+        mask = (held <= at) & (held > at - window) & (held >= 0)     # [B,1,1,S,M]
+    else:
+        mask = j[None, None, None, None, :] <= q_positions[:, None, None, :, None]  # [B,1,1,S,M]
+        if window is not None:
+            mask = mask & (
+                j[None, None, None, None, :] > q_positions[:, None, None, :, None] - window
+            )
     logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhrqk,bhdk->bqhrd", probs, v)
@@ -806,8 +916,30 @@ def _write_columns(buf, new, index, layer=None):
     return buf
 
 
+def _write_ring(buf, new, index, layer, page: int):
+    """:func:`_write_columns` into a ring ``[L, B, H*D, W]``: position ``p``
+    goes to column ``p % W``.  A decode step's one column a lane cannot
+    straddle the ring's end; a chunk (scalar ``index``, a page boundary, whole
+    pages long, ``W`` whole pages too) is written page by page."""
+    width, s = buf.shape[-1], new.shape[1]
+    if s == 1:
+        return _write_columns(buf, new, index % width, layer)
+    if jnp.ndim(index) or s % page or width % page:
+        raise ValueError(
+            f"a ring of {width} columns takes one column a lane or a chunk of whole "
+            f"pages of {page} at a scalar index, got {s} rows at index of rank {jnp.ndim(index)}"
+        )
+    for i in range(s // page):
+        column = ((index // page + i) % (width // page)) * page
+        buf = _write_columns(buf, new[:, i * page:(i + 1) * page], column, layer)
+    return buf
+
+
 class Attention(nn.Module):
     config: TransformerConfig
+    # "window" / "full" in a stack of two kinds (``config.layer_types``; set
+    # by :class:`DecoderLayer`), None where every layer is one kind
+    kind: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, cache=None,
@@ -850,9 +982,42 @@ class Attention(nn.Module):
         if cfg.qk_norm:
             q = RMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name="k_norm")(k)
-        if cfg.positional == "rope":
+        # a "full" layer of two kinds sees every position, and carries no
+        # positional encoding where the configuration says so
+        window = None if self.kind == "full" else cfg.sliding_window
+        if cfg.positional == "rope" and (self.kind != "full" or cfg.rope_full_layers):
             q = _apply_rope(q, positions, cfg)
             k = _apply_rope(k, positions, cfg)
+
+        # the device scope of a layer of two kinds (``attn/window``, ``attn/full``)
+        kind_scope = lambda: (jax.named_scope(f"attn/{self.kind}") if self.kind
+                              else contextlib.nullcontext())
+
+        def project_out(out):
+            """``out [B, S, Hq, D]`` through the output gate, where there is
+            one, and ``o_proj``."""
+            out = out.reshape(b, s, cfg.num_heads * hd)
+            if cfg.attention_gate:
+                with jax.named_scope("attn/gate"):
+                    gate = dense("gate_proj", cfg.num_heads * hd)(x)
+                    out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+            return dense("o_proj", cfg.hidden_size)(out)
+
+        if isinstance(cache, MixedKVCache):
+            # this kind's arrays, the layer's place among its kind
+            at_kind = cfg.layer_types[:layer].count(self.kind)
+            with kind_scope():
+                if self.kind == "window":
+                    k_all = _write_ring(cache.k_ring, k, cache.index, at_kind, cache.page)
+                    v_all = _write_ring(cache.v_ring, v, cache.index, at_kind, cache.page)
+                    cache = cache.replace(k_ring=k_all, v_ring=v_all)
+                else:
+                    k_all = _write_columns(cache.k, k, cache.index, at_kind)
+                    v_all = _write_columns(cache.v, v, cache.index, at_kind)
+                    cache = cache.replace(k=k_all, v=v_all)
+                out = cached_attention(q, k_all[at_kind], v_all[at_kind], positions,
+                                       window=window, ring=self.kind == "window")
+            return project_out(out), cache
         # the stacked cache of every layer, addressed at ``layer``; or this
         # layer's own arrays in a tuple, addressed whole
         stacked = isinstance(cache, (KVCache, PagedKVCache))
@@ -912,11 +1077,10 @@ class Attention(nn.Module):
             else:
                 out = paged_attention_reference(
                     q, at(pages_k), at(pages_v), tables, index,
-                    k_scales=sk, v_scales=sv, window=cfg.sliding_window,
+                    k_scales=sk, v_scales=sv, window=window,
                     alibi=cfg.positional == "alibi", tree_mask=tree_mask,
                 )
-            out = out.reshape(b, s, cfg.num_heads * hd)
-            out = dense("o_proj", cfg.hidden_size)(out)
+            out = project_out(out)
             if stacked:
                 return out, cache.replace(
                     pages_k=pages_k, pages_v=pages_v,
@@ -928,12 +1092,12 @@ class Attention(nn.Module):
             k_cache, v_cache, index = cache_arrays
             k_cache = _write_columns(k_cache, k, index, layer)
             v_cache = _write_columns(v_cache, v, index, layer)
-            out = cached_attention(q, at(k_cache), at(v_cache), positions,
-                                   window=cfg.sliding_window,
-                                   alibi=cfg.positional == "alibi",
-                                   tree_mask=tree_mask)
-            out = out.reshape(b, s, cfg.num_heads * hd)
-            out = dense("o_proj", cfg.hidden_size)(out)
+            with kind_scope():
+                out = cached_attention(q, at(k_cache), at(v_cache), positions,
+                                       window=window,
+                                       alibi=cfg.positional == "alibi",
+                                       tree_mask=tree_mask)
+            out = project_out(out)
             if stacked:
                 return out, cache.replace(k=k_cache, v=v_cache)
             return out, (k_cache, v_cache)
@@ -942,13 +1106,13 @@ class Attention(nn.Module):
         bias = None
         if cfg.positional == "alibi":
             bias = _alibi_bias(cfg.num_heads, s)
-        out = dot_product_attention(
-            q, k, v, causal=True, implementation=cfg.attention_impl,
-            segment_ids=segment_ids, ring_layout=cfg.ring_attention_layout,
-            window=cfg.sliding_window, bias=bias,
-        )
-        out = out.reshape(b, s, cfg.num_heads * hd)
-        return _tag_proj(dense("o_proj", cfg.hidden_size)(out))
+        with kind_scope():
+            out = dot_product_attention(
+                q, k, v, causal=True, implementation=cfg.attention_impl,
+                segment_ids=segment_ids, ring_layout=cfg.ring_attention_layout,
+                window=window, bias=bias,
+            )
+        return _tag_proj(project_out(out))
 
 
 def functools_partial_dense(cfg: TransformerConfig, use_bias: Optional[bool] = None):
@@ -1028,6 +1192,8 @@ class DecoderLayer(nn.Module):
     # one of ``config.experts.dense_layers`` leading layers: a dense MLP where
     # the layers after it route (set by :class:`Transformer`'s loop)
     leading_dense: bool = False
+    # this layer's entry of ``config.layer_types`` (set by the same loop)
+    kind: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, positions, cache=None, tree_mask=None, layer=None):
@@ -1042,7 +1208,7 @@ class DecoderLayer(nn.Module):
 
             attn = PowerRetention(cfg, name="attn")
         else:
-            attn = Attention(cfg, name="attn")
+            attn = Attention(cfg, self.kind, name="attn")
         attn_out = attn(normed, positions, cache=cache, tree_mask=tree_mask, layer=layer)
         new_kv = None
         if cache is not None:
@@ -1065,6 +1231,9 @@ class DecoderLayer(nn.Module):
             # GPT-J (shared_norm) reuses the attention branch's norm
             mlp_in = normed if cfg.shared_norm else make_norm(cfg, "post_attn_norm")(x)
             x = x + attn_out + mlp(mlp_in)
+        elif cfg.sandwich_norm:
+            x = x + make_norm(cfg, "attn_out_norm")(attn_out)
+            x = x + make_norm(cfg, "mlp_out_norm")(mlp(make_norm(cfg, "post_attn_norm")(x)))
         else:
             x = x + attn_out
             x = x + mlp(make_norm(cfg, "post_attn_norm")(x))
@@ -1179,7 +1348,7 @@ class Transformer(nn.Module):
             for i in range(cfg.num_layers):
                 block = layer_cls(
                     cfg, cfg.experts is not None and i < cfg.experts.dense_layers,
-                    name=f"layers_{i}",
+                    cfg.layer_kind(i), name=f"layers_{i}",
                 )
                 if cache is None:
                     x = block(x, positions)

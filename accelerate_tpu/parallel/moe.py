@@ -126,12 +126,15 @@ class MoEMLP(nn.Module):
 # (``num_experts``: its einsums are what GSPMD turns into the all-to-all).
 
 
-def route_top_k(scores: jax.Array, spec) -> Tuple[jax.Array, jax.Array]:
-    """``(experts [N, k] int32, gates [N, k] f32)`` from the router's softmax
-    ``scores [N, num_routed]``: group-limited greedy choice (the ``top_k``
-    largest among the experts of the ``topk_group`` groups whose best score is
-    largest; one group: plain top-k), gates ``scaling * score`` or, with
-    ``norm_topk``, renormalised over the chosen."""
+def route_top_k(scores: jax.Array, spec, bias=None) -> Tuple[jax.Array, jax.Array]:
+    """``(experts [N, k] int32, gates [N, k] f32)`` from the router's
+    ``scores [N, num_routed]`` (softmax or sigmoid, ``spec.score_func``):
+    group-limited greedy choice (the ``top_k`` largest among the experts of the
+    ``topk_group`` groups whose best score is largest; one group: plain
+    top-k), gates ``scaling * score`` or, with ``norm_topk``, renormalised over
+    the chosen (and then scaled where ``scale_normed``).  ``bias
+    [num_routed]`` (``spec.select_bias``) moves the choice only: the ``top_k``
+    largest ``score + bias`` are chosen and gated by their scores without it."""
     n, e = scores.shape
     masked = scores
     if spec.n_group > 1:
@@ -139,9 +142,15 @@ def route_top_k(scores: jax.Array, spec) -> Tuple[jax.Array, jax.Array]:
         _, kept = jax.lax.top_k(best, spec.topk_group)
         allowed = jnp.any(jax.nn.one_hot(kept, spec.n_group, dtype=bool), axis=1)
         masked = jnp.where(jnp.repeat(allowed, e // spec.n_group, axis=1), scores, 0.0)
-    gates, experts = jax.lax.top_k(masked, spec.top_k)
+    if bias is None:
+        gates, experts = jax.lax.top_k(masked, spec.top_k)
+    else:
+        _, experts = jax.lax.top_k(masked + bias, spec.top_k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
     if spec.norm_topk and spec.top_k > 1:
         gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        if spec.scale_normed:
+            gates = gates * spec.scaling
     else:
         gates = gates * spec.scaling
     return experts.astype(jnp.int32), gates
@@ -180,7 +189,8 @@ class RoutedExperts(nn.Module):
     :class:`~accelerate_tpu.models.transformer.ExpertSpec`).
 
     The router (float32, highest precision: a choice is a comparison of
-    nearly equal numbers) scores all ``num_routed`` experts and
+    nearly equal numbers) scores all ``num_routed`` experts (softmax or
+    sigmoid, with a bias on the choice where the spec says so) and
     :func:`route_top_k` chooses among all of them; of the ``N * top_k`` pairs
     those on ``[lo, hi)`` are computed here, whatever their number: no
     capacity, so a token's result does not depend on what shares its batch.
@@ -208,7 +218,17 @@ class RoutedExperts(nn.Module):
                 kernel_init=nn.initializers.normal(0.02), precision=jax.lax.Precision.HIGHEST,
                 name="router",
             )(xf.astype(jnp.float32))
-            experts, gates = route_top_k(jax.nn.softmax(logits, axis=-1), spec)
+            if spec.score_func == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+            else:
+                scores = jax.nn.softmax(logits, axis=-1)
+            bias = None
+            if spec.select_bias:
+                bias = self.param("expert_bias", nn.initializers.zeros,
+                                  (spec.num_routed,), jnp.float32)
+            # (a router without a bias is called as it always was)
+            experts, gates = (route_top_k(scores, spec) if bias is None
+                              else route_top_k(scores, spec, bias))
             here = (experts >= lo) & (experts < hi)
             local = jnp.where(here, experts - lo, spec.num_held)          # elsewhere sorts last
             self.sow("intermediates", "routed_here",

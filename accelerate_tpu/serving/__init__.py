@@ -8,7 +8,7 @@ block tables (:mod:`.paging` — PagedAttention, TPU-native).  See
 from .engine import ServingEngine
 from .errors import AdmissionError, DeadlineExceeded
 from .faults import FaultInjected, FaultInjector, FaultPlan
-from .paging import NULL_PAGE, PageAllocator, PagedKVPool
+from .paging import NULL_PAGE, MixedKVPool, PageAllocator, PagedKVPool
 from .pool import (
     ServeShardings,
     jit_cache_sizes,
@@ -44,6 +44,7 @@ __all__ = [
     "PrefixNode",
     "rolling_hash",
     "NULL_PAGE",
+    "MixedKVPool",
     "PageAllocator",
     "PagedKVPool",
     "plan_chunks",

@@ -77,7 +77,7 @@ from ..telemetry import (
 )
 from . import faults, transfer
 from .errors import AdmissionError
-from .paging import DraftContextWindow, PagedKVPool, StatePool
+from .paging import DraftContextWindow, MixedKVPool, PagedKVPool, StatePool
 from .pool import (
     ServeShardings,
     audit_donation,
@@ -90,6 +90,8 @@ from .pool import (
     make_paged_verify_window,
     make_promote_install,
     make_spill_extract,
+    make_mixed_decode_window,
+    make_mixed_prefill_chunk,
     make_state_decode_window,
     make_state_install,
     make_state_prefill_chunk,
@@ -140,13 +142,30 @@ class _Stats(dict):
 
 def _refuse_unported(cfg, *, kv_dtype, speculate_k, draft_model, decode_kernel,
                      prefill_kernel, prefix_host_mb, prefix_disk_mb, role, mesh,
-                     tp_axis) -> None:
+                     tp_axis, prefix_cache_mb=None) -> None:
     """Refuse at construction, by name, each option that a latent cache
-    (``config.latent_attention``), routed experts (``config.experts``) or a
-    recurrent state (``config.retention``) do not have yet.  Everything else —
-    chunked prefill, cancellation, the scheduler, failover replay — is the
-    same code for them."""
+    (``config.latent_attention``), routed experts (``config.experts``), a
+    recurrent state (``config.retention``) or a pool of two retention rules
+    (``config.layer_types``) do not have yet.  Everything else — chunked
+    prefill, cancellation, the scheduler, failover replay — is the same code
+    for them."""
     asked = {}
+    if cfg.layer_types is not None:
+        # a window layer's pages go back to the free list while the lane
+        # lives: a released page cannot be shared with a later prompt, rolled
+        # back to, spilled or shipped; the ring is read through the gathered
+        # view only, in the model's dtype, on one device
+        asked.update({
+            "prefix_cache_mb": bool(prefix_cache_mb),
+            "kv_dtype": kv_dtype is not None,
+            "speculate_k": bool(speculate_k),
+            "draft_model": draft_model is not None,
+            "decode_kernel": decode_kernel != "xla",
+            "prefill_kernel": prefill_kernel not in (None, "xla"),
+            "prefix_host_mb": bool(prefix_host_mb),
+            "prefix_disk_mb": bool(prefix_disk_mb),
+            "role": role != "both",
+        })
     if cfg.retention is not None:
         # the state has no rows to quantise, no page to read in place, to
         # spill or to ship, and no way yet to roll back past a rejected draft
@@ -173,7 +192,8 @@ def _refuse_unported(cfg, *, kv_dtype, speculate_k, draft_model, decode_kernel,
             "prefix_host_mb": bool(prefix_host_mb),
             "role": role != "both",
         })
-    if cfg.latent_attention is not None or cfg.experts is not None or cfg.retention is not None:
+    if (cfg.latent_attention is not None or cfg.experts is not None
+            or cfg.retention is not None or cfg.layer_types is not None):
         from ..parallel.mesh import mesh_axis_size
 
         asked["mesh"] = mesh is not None and mesh_axis_size(mesh, tp_axis) > 1
@@ -181,6 +201,7 @@ def _refuse_unported(cfg, *, kv_dtype, speculate_k, draft_model, decode_kernel,
         if used:
             what = ("a recurrent state" if cfg.retention is not None
                     else "a latent-attention cache" if cfg.latent_attention is not None
+                    else "a pool of window and full layers" if cfg.layer_types is not None
                     else "routed experts")
             raise ValueError(
                 f"{name} is not ported to {what} yet: serve this model with the "
@@ -207,6 +228,15 @@ class ServingEngine:
     does not have yet are refused by name (:func:`_refuse_unported`).  Chunked
     prefill, the scheduler, cancellation, failover replay and streaming are
     the same code.
+
+    A stack of window and full attention layers (``config.layer_types``) gets
+    a pool of two retention rules
+    (:class:`~accelerate_tpu.serving.paging.MixedKVPool`): whole block tables
+    for the full layers, a ring of pages a lane for the window layers, whose
+    pages go back to the free list as they fall behind the window
+    (``serve/page_release``; ``docs/usage/mixed_attention_stack.md``).  It
+    shares nothing between lanes, so ``prefix_cache_mb`` must be 0; the other
+    options it does not have yet are refused by name too.
 
     Parameters
     ----------
@@ -431,7 +461,7 @@ class ServingEngine:
                          draft_model=draft_model, decode_kernel=decode_kernel,
                          prefill_kernel=prefill_kernel, prefix_host_mb=prefix_host_mb,
                          prefix_disk_mb=prefix_disk_mb, role=role, mesh=mesh,
-                         tp_axis=tp_axis)
+                         tp_axis=tp_axis, prefix_cache_mb=prefix_cache_mb)
         #: routed experts: decode windows and prefill chunks return the
         #: ``moe_*`` counters, fetched with the window's tokens
         self._routed = cfg.experts is not None
@@ -439,6 +469,10 @@ class ServingEngine:
         #: (:class:`StatePool`), its windows return the ``state_*`` counters,
         #: and every page-speaking step of the lane lifecycle has nothing to do
         self._stateful = cfg.retention is not None
+        #: window and full layers in one stack: the pool keeps a ring of pages
+        #: a lane for the one kind and whole tables for the other
+        #: (:class:`MixedKVPool`), its windows return the ``kv_rows_*`` counters
+        self._mixed = cfg.layer_types is not None
         self.num_slots = int(num_slots)
         self.max_len = int(max_len if max_len is not None else cfg.max_seq_len)
         self.max_prompt_len = int(
@@ -644,6 +678,14 @@ class ServingEngine:
                 cfg, self.num_slots, registry=self.metrics,
                 sharding=None if self._shardings is None else self._shardings.replicated,
             )
+        elif self._mixed:
+            # the ring: the window, the largest chunk written behind it, and
+            # the page a decode step is writing
+            ring_pages = -(-(cfg.sliding_window + self.buckets[-1]) // self.page_size) + 1
+            self.kv = MixedKVPool(
+                cfg, self.num_slots, self.max_len, self.page_size,
+                self.num_pages, ring_pages, registry=self.metrics,
+            )
         else:
             self.kv = PagedKVPool(
                 cfg, self.num_slots, self.max_len, self.page_size,
@@ -692,6 +734,8 @@ class ServingEngine:
                 make_state_install(shardings=self._shardings),
                 name="serve/state_install", budget=1, registry=self.metrics,
             )
+        elif self._mixed:
+            decode_fn = make_mixed_decode_window(wmodel, self.window)
         else:
             decode_fn = make_paged_decode_window(
                 wmodel, self.window, direct=self._direct, shardings=self._shardings)
@@ -712,6 +756,8 @@ class ServingEngine:
             b: RecompileWatchdog(
                 make_state_prefill_chunk(pmodel, shardings=self._shardings)
                 if self._stateful else
+                make_mixed_prefill_chunk(pmodel, b, self.page_size)
+                if self._mixed else
                 make_paged_prefill_chunk(
                     pmodel, b, self.page_size, direct=self._prefill_direct,
                     shardings=self._shardings,
@@ -906,6 +952,14 @@ class ServingEngine:
             # lane-steps whose state a decode window read and rewrote (lanes x
             # steps), those that emitted a token, and lanes zeroed at install
             self.stats.update(state_lane_steps=0, state_live_lane_steps=0, state_installs=0)
+        if self._mixed:
+            # pages the allocators handed out (both kinds) and window-layer
+            # pages handed back because they fell behind the window (counted
+            # on the host, where the allocator acts); the keys a live lane's
+            # decode step could see, in all layers and in the window layers,
+            # summed over lane-steps (counted on the device, in the window)
+            self.stats.update(kv_pages_taken=0, kv_pages_released_window=0,
+                              kv_rows_live=0, kv_rows_live_window=0)
         self.stats.engine = self
         self._counters = {
             k: self.metrics.counter(f"serve/{k}_total") for k in self.stats
@@ -1824,6 +1878,9 @@ class ServingEngine:
             if i < len(req.cache_nodes) and req.cache_nodes[i].tier == "device"
         )
         need = (padded - cached) // self.page_size
+        if self._mixed and (min(padded // self.page_size, self.kv.ring_pages)
+                            > self.kv.ring_allocator.free_count):
+            return False      # both rules' pages are counted; a whole ring a slot, so it has them
         if self.kv.allocator.free_count >= need:
             return True
         return self._reclaim_pages(need, allow_preempt=False)
@@ -1872,6 +1929,22 @@ class ServingEngine:
         kv = self.kv
         table = self._put(kv.tables[s])
         base = self._put(jnp.int32(start))
+        if self._mixed:
+            # the window layers' ring: pages behind the chunk's first query's
+            # window go back, the chunk's own are mapped
+            with self.tracer.span("serve/page_release"):
+                taken, released = kv.ring_advance(s, start, start + bucket - 1)
+            self._bump("kv_pages_taken", len(ids) + taken)
+            self._bump("kv_pages_released_window", released)
+            args = (self.params, chunk[None], kv.pages_k, kv.pages_v, kv.ring_k, kv.ring_v,
+                    table, self._put(kv.ring_tables[s]), base)
+            if self._routed:
+                args += (self._put(jnp.int32(valid)),)
+            self.cost_table.capture(f"serve/prefill_{bucket}", self._prefill[bucket], args)
+            with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid):
+                kv.pages_k, kv.pages_v, kv.ring_k, kv.ring_v, *counts = self._prefill[bucket](*args)
+            self._pending_moe_counts.extend(counts)
+            return
         if self._prefill_direct:
             args = (self.params, chunk[None], kv.pages_k, kv.pages_v,
                     kv.k_scales, kv.v_scales, table, base)
@@ -1974,12 +2047,26 @@ class ServingEngine:
                 ids = self.kv.allocator.alloc(missing)
                 if ids is not None:
                     self.kv.lane_append_owned(s, ids)
+                    if self._mixed:
+                        self._bump("kv_pages_taken", missing)
                     break
                 if not self._reclaim_pages(missing, allow_preempt=True):
                     raise RuntimeError(
                         "KV page pool exhausted: no cache leaf, lane, or pin "
                         "left to reclaim for a decoding lane"
                     )
+        if self._mixed:
+            # the window layers' rings: what fell behind each lane's window
+            # goes back before the pages of its next ``width`` writes are mapped
+            taken = released = 0
+            with self.tracer.span("serve/page_release") as span:
+                for s in np.nonzero(self._active)[0]:
+                    n = int(self._lane_len[s])
+                    t, r = self.kv.ring_advance(int(s), n, n + width - 1)
+                    taken, released = taken + t, released + r
+                span["released"] = released
+            self._bump("kv_pages_taken", taken)
+            self._bump("kv_pages_released_window", released)
 
     def _populate_cache(self, req: Request, bucket: int, valid: int, start: int,
                         ptoks: np.ndarray) -> None:
@@ -2387,6 +2474,16 @@ class ServingEngine:
                 self._bump("state_lane_steps", int(steps))
                 self._bump("state_live_lane_steps", int(live))
             moe = ()
+        if self._mixed:
+            # a decode window's own vector ends with the rows its live lanes'
+            # steps could see (after the experts' three, where it has those);
+            # a chunk's carries none
+            width = 2 + 3 * self._routed
+            for c in moe:
+                if len(c) == width:
+                    self._bump("kv_rows_live", int(c[-2]))
+                    self._bump("kv_rows_live_window", int(c[-1]))
+            moe = [c[:3] for c in moe] if self._routed else ()
         for total, here, hit in moe:
             self._bump("moe_pairs_total", int(total))
             self._bump("moe_pairs_here", int(here))
@@ -2510,6 +2607,21 @@ class ServingEngine:
                 self.cost_table.capture("serve/decode_window", self._decode, args)
             with self.tracer.span("serve/decode_window", occupied=n_occupied):
                 kv.s, kv.z, toks, pending, rngs, *moe = self._decode(*args)
+            self._lane_len[self._active] += self.window
+        elif self._mixed:
+            kv = self.kv
+            audit_donation(kv.pages_k, kv.pages_v, kv.ring_k, kv.ring_v)
+            consumed = [kv.pages_k, kv.pages_v, kv.ring_k, kv.ring_v, lanes[0], lanes[-1]]
+            tables, ring_tables = self._put(kv.tables), self._put(kv.ring_tables)
+            index = self._put(self._lane_len)
+            consumed += [tables, ring_tables, index]
+            args = (self.params, kv.pages_k, kv.pages_v, kv.ring_k, kv.ring_v, tables,
+                    ring_tables, index, *lanes)
+            if not self.cost_table.captured("serve/decode_window"):
+                self.cost_table.capture("serve/decode_window", self._decode, args)
+            with self.tracer.span("serve/decode_window", occupied=n_occupied):
+                (kv.pages_k, kv.pages_v, kv.ring_k, kv.ring_v, toks, pending, rngs,
+                 *moe) = self._decode(*args)
             self._lane_len[self._active] += self.window
         elif self._direct:
             kv = self.kv

@@ -23,6 +23,11 @@ while all allocation, refcounting, and copy-on-write stay host-side numpy:
   change the softmax reduction shape and with it the last-ulp rounding —
   measured, not hypothetical).
 
+* :class:`MixedKVPool` — the pool of a stack of two kinds of layer
+  (``config.layer_types``), one retention rule a kind: the "full" layers keep
+  every position of a lane, as above; the "window" layers keep a **ring** of
+  pages a lane, and a page that falls wholly behind every position a later
+  query can see goes back to the free list.
 * :class:`StatePool` — the pool of a model that keeps no rows a token (a
   recurrent state of fixed size a lane): no pages, no tables, no allocator.
 
@@ -148,7 +153,7 @@ class PagedKVPool:
     def __init__(self, config, num_slots: int, max_len: int, page_size: int,
                  num_pages: int, registry: Optional[MetricsRegistry] = None,
                  kv_dtype: Optional[str] = None, mesh=None,
-                 tp_axis: str = "tp"):
+                 tp_axis: str = "tp", num_layers: Optional[int] = None):
         if max_len % page_size != 0:
             raise ValueError(
                 f"max_len {max_len} must be a multiple of page_size {page_size} "
@@ -188,7 +193,10 @@ class PagedKVPool:
         # The row shapes are the configuration's: K and V of every kv head,
         # or a latent-attention model's one latent and one rope key a token.
         (k_heads, k_width), (v_heads, v_width) = cfg.cache_row_shapes
-        lead = (cfg.num_layers, self.num_pages)
+        # the layers whose rows these arrays hold: all of them, or the one
+        # kind of a two-rule pool (:class:`MixedKVPool`)
+        num_layers = cfg.num_layers if num_layers is None else int(num_layers)
+        lead = (num_layers, self.num_pages)
         k_shape = lead + (k_heads, self.page_size, k_width)
         v_shape = lead + (v_heads, self.page_size, v_width)
         if mesh is not None:
@@ -215,7 +223,7 @@ class PagedKVPool:
         #: bytes of k+v one page holds, scales included — the sharing/HBM
         #: accounting unit
         itemsize = jnp.zeros((), self.storage_dtype).itemsize
-        self.page_kv_bytes = int(cfg.num_layers * (
+        self.page_kv_bytes = int(num_layers * (
             (k_heads * k_width + v_heads * v_width) * self.page_size * itemsize
             + (k_heads + v_heads) * 4
         ))
@@ -334,6 +342,120 @@ class PagedKVPool:
         )
 
 
+class MixedKVPool(PagedKVPool):
+    """The pool of a stack of two kinds of attention layer
+    (``config.layer_types``): page arrays, block tables and free list are kept
+    a kind, because the two keep different numbers of positions.
+
+    * The **full** layers are a :class:`PagedKVPool` over those layers alone
+      (``pages_k`` / ``pages_v`` ``[n_full, num_pages, ...]``, ``tables
+      [num_slots, max_len / page]``, ``allocator``): every position of a lane
+      stays mapped until the lane ends.
+    * The **window** layers keep a ring of ``ring_pages`` pages a lane
+      (``ring_k`` / ``ring_v`` ``[n_window, num_slots * ring_pages + 1, ...]``,
+      ``ring_tables [num_slots, ring_pages]``, ``ring_allocator``).  Logical
+      page ``p`` (positions ``p * page ..``) maps to ring slot ``p %
+      ring_pages``; :meth:`ring_advance` hands back every page that lies
+      wholly behind the first position the next query can see (``query -
+      window + 1``) before it maps the pages the next write needs.  With
+      ``ring_pages = ceil((window + largest chunk) / page) + 1`` the pages a
+      lane needs at once (a chunk's own, plus the window behind its first
+      query) always fit, and the gathered view is the ring's width.
+
+    The ring's array holds ``num_slots`` whole rings, so the window kind is
+    never under page pressure and its pages are released the moment the host
+    decides: every device program takes the arrays the one before it returned,
+    so a page's next owner writes it in a later program than any that still
+    reads it.  Page pressure, preemption and the deferred release of a retired
+    lane (:meth:`lane_detach`) are the full kind's, as in the base class.
+    There is no sharing: the engine builds no prefix cache on this pool (a
+    released page cannot be shared)."""
+
+    def __init__(self, config, num_slots: int, max_len: int, page_size: int,
+                 num_pages: int, ring_pages: int,
+                 registry: Optional[MetricsRegistry] = None):
+        n_window, n_full = (config.layer_types.count(kind) for kind in ("window", "full"))
+        registry = registry if registry is not None else get_registry()
+        # (before the base class, whose constructor publishes the gauges)
+        self.ring_pages = int(ring_pages)
+        self.ring_allocator = PageAllocator(int(num_slots) * self.ring_pages + 1)
+        self._kind_gauges = {
+            kind: registry.gauge(
+                f"serve/kv_pages_in_use_{kind}",
+                help=f"allocated KV pages of the {kind} layers' pool (null page excluded)",
+            ) for kind in ("full", "window")
+        }
+        super().__init__(config, num_slots, max_len, page_size, num_pages,
+                         registry=registry, num_layers=n_full)
+        self.window = int(config.sliding_window)
+        if self.ring_pages * self.page_size < self.window + self.page_size:
+            raise ValueError(
+                f"a ring of {ring_pages} pages of {page_size} cannot hold a window of "
+                f"{self.window} and the page being written"
+            )
+        (k_heads, k_width), (v_heads, v_width) = config.cache_row_shapes
+        lead = (n_window, self.ring_allocator.num_pages)
+        self.ring_k = jnp.zeros(lead + (k_heads, self.page_size, k_width), self.storage_dtype)
+        self.ring_v = jnp.zeros(lead + (v_heads, self.page_size, v_width), self.storage_dtype)
+        self.ring_tables = np.zeros((self.num_slots, self.ring_pages), np.int32)
+        #: logical pages ``[ring_lo, ring_hi)`` of each lane are mapped
+        self.ring_lo = np.zeros(self.num_slots, np.int64)
+        self.ring_hi = np.zeros(self.num_slots, np.int64)
+
+    # ------------------------------------------------------------- the ring
+    def ring_advance(self, slot: int, query: int, last: int):
+        """Make lane ``slot``'s ring ready for a program whose first query is
+        at position ``query`` and whose last write is at ``last``: release the
+        pages wholly behind ``query - window + 1`` (no query from here on can
+        see them), then map the pages up to ``last``'s.  Returns ``(taken,
+        released)`` page counts."""
+        page, ring = self.page_size, self.ring_pages
+        keep = max(0, query - self.window + 1) // page
+        lo, hi = int(self.ring_lo[slot]), int(self.ring_hi[slot])
+        behind = [p % ring for p in range(lo, min(keep, hi))]
+        self.ring_allocator.deref([int(self.ring_tables[slot, c]) for c in behind])
+        self.ring_tables[slot, behind] = NULL_PAGE
+        lo = max(lo, keep)
+        hi = max(hi, lo)
+        need = last // page + 1
+        if need - lo > ring:
+            raise RuntimeError(
+                f"lane {slot} needs pages {lo}..{need - 1} of its window layers at once: "
+                f"more than its ring of {ring}"
+            )
+        ids = self.ring_allocator.alloc(max(need - hi, 0))
+        if ids is None:
+            raise RuntimeError("the window layers' page pool is exhausted: a ring leaked")
+        for p, pid in zip(range(hi, need), ids):
+            self.ring_tables[slot, p % ring] = pid
+        self.ring_lo[slot], self.ring_hi[slot] = lo, max(hi, need)
+        return len(ids), len(behind)
+
+    def ring_held(self, slot: int) -> int:
+        """Pages lane ``slot`` holds of the window layers' pool."""
+        return int(self.ring_hi[slot] - self.ring_lo[slot])
+
+    # -------------------------------------------------------------- lane ops
+    def lane_detach(self, slot: int) -> List[int]:
+        """The base class's detach for the full kind (its ids come back to the
+        caller, who derefs them when the in-flight window has retired); the
+        lane's ring is handed back at once (see the class docstring)."""
+        self.ring_allocator.deref([int(p) for p in self.ring_tables[slot]])
+        self.ring_tables[slot, :] = NULL_PAGE
+        self.ring_lo[slot] = self.ring_hi[slot] = 0
+        return super().lane_detach(slot)
+
+    # ------------------------------------------------------------- accounting
+    def kv_bytes(self) -> int:
+        return super().kv_bytes() + int(self.ring_k.nbytes) + int(self.ring_v.nbytes)
+
+    def publish_gauges(self) -> None:
+        super().publish_gauges()
+        self._in_use_gauge.set(self.allocator.used_count + self.ring_allocator.used_count)
+        self._kind_gauges["full"].set(self.allocator.used_count)
+        self._kind_gauges["window"].set(self.ring_allocator.used_count)
+
+
 class StatePool:
     """The pool of a retention model (``config.retention``): the recurrent
     state of every layer for ``num_slots`` lanes, ``S [L, lanes, Hkv, D, Dh]``
@@ -441,4 +563,4 @@ class DraftContextWindow:
         self.length[slot] = 0
 
 
-__all__ = ["NULL_PAGE", "DraftContextWindow", "PageAllocator", "PagedKVPool"]
+__all__ = ["NULL_PAGE", "DraftContextWindow", "MixedKVPool", "PageAllocator", "PagedKVPool"]

@@ -77,6 +77,15 @@ donated state of :class:`~accelerate_tpu.serving.paging.StatePool` as a
 same :func:`_decode_scan`, which rewrites every lane's state in place at every
 step and leaves a frozen lane's as it was.
 
+A stack of two kinds of attention layer (``config.layer_types``) has the
+gathered arm twice over (:func:`make_mixed_decode_window`,
+:func:`make_mixed_prefill_chunk`): the pool keeps page arrays and block tables
+a kind (:class:`~accelerate_tpu.serving.paging.MixedKVPool`), the full layers'
+view is ``max_len`` wide as above, the window layers' view is the lane's ring
+of pages, position ``p`` in column ``p % width``
+(:class:`~accelerate_tpu.models.transformer.MixedKVCache`), and the pages a
+call wrote into go back whole into both pools.
+
 Compiled-shape budget for an engine instance: ``1 (decode window) +
 len(prefill_buckets) + 1 (lane install) + 1 (copy page)``, plus ``1`` verify
 executable when ``speculate_k > 0`` (or the tree pair) — asserted by the
@@ -95,7 +104,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..models.generation import sample_tokens_batched
 from ..models.retention import StateCache
-from ..models.transformer import KVCache, PagedKVCache, Transformer
+from ..models.transformer import KVCache, MixedKVCache, PagedKVCache, Transformer
 from ..parallel.mesh import mesh_axis_size
 from ..utils.jax_compat import jit_cache_size
 from .paging import NULL_PAGE
@@ -179,6 +188,24 @@ def _stateful(model: Transformer) -> bool:
     return getattr(model.config, "retention", None) is not None
 
 
+def _two_kinds(model: Transformer) -> bool:
+    """Is the stack of two kinds of layer (``config.layer_types``)?  Its pool
+    keeps a ring of pages for the window layers and whole tables for the full
+    ones, and its decode window returns the ``kv_rows_*`` counters."""
+    return getattr(model.config, "layer_types", None) is not None
+
+
+def _rows_live(config, index, live):
+    """``int32 [2]``: the keys the decode step of every live lane can see
+    (query at ``index [N]``, itself included), summed over the layers, and
+    those of them in window layers: ``min(index + 1, window)`` in each window
+    layer, ``index + 1`` in each full one."""
+    n_window, n_full = (config.layer_types.count(kind) for kind in ("window", "full"))
+    seen = jnp.where(live, index + 1, 0)
+    in_window = n_window * jnp.sum(jnp.minimum(seen, config.sliding_window))
+    return jnp.stack([in_window + n_full * jnp.sum(seen), in_window]).astype(jnp.int32)
+
+
 def _forward(model: Transformer, params, tokens, cache, live):
     """``model.apply`` on a cache: ``(logits, cache, counts)``.  For a model
     with routed experts ``counts`` is ``int32 [3]``, computed here on the
@@ -230,7 +257,8 @@ def _decode_scan(model: Transformer, window: int, params, cache, tokens, active,
     masked to ``pad``.  Frozen lanes still execute (static shapes) but only
     ever overwrite their own dead rows (gathered view) or the null page
     (in-place), so running lanes are untouched."""
-    counted = _routed(model) or _stateful(model)
+    mixed = _two_kinds(model)
+    counted = _routed(model) or _stateful(model) or mixed
 
     def step(carry, _):
         cache, tok, done, rngs, counts = carry
@@ -247,6 +275,11 @@ def _decode_scan(model: Transformer, window: int, params, cache, tokens, active,
             # must not keep churning a page that still holds real history.
             cache = cache.replace(active=~done)
         logits, cache, seen = _forward(model, params, tok[:, None], cache, ~done[:, None])
+        if mixed:
+            # a stack of two kinds: the rows its live lanes' step could see,
+            # after the experts' counters where it has those too
+            rows = _rows_live(model.config, prev_index, ~done)
+            seen = rows if seen is None else jnp.concatenate([seen, rows])
         if counted:
             counts = counts + seen
         # model.apply advanced every lane; frozen lanes roll back
@@ -264,7 +297,8 @@ def _decode_scan(model: Transformer, window: int, params, cache, tokens, active,
         return (cache, nxt, done, split[:, 1], counts), nxt
 
     done0 = ~active
-    counts0 = jnp.zeros((2 if _stateful(model) else 3,), jnp.int32) if counted else None
+    width = 2 if _stateful(model) else 3 * _routed(model) + 2 * mixed
+    counts0 = jnp.zeros((width,), jnp.int32) if counted else None
     (cache, tok, _, rngs, counts), toks = jax.lax.scan(
         step, (cache, tokens, done0, rngs, counts0), None, length=window
     )
@@ -722,6 +756,26 @@ def _gather_view(pages, tables, flat: bool):
     return with_layout_constraint(view, Layout(major_to_minor=(0, 1, 2, 3)))
 
 
+def _gather_columns(pages, tables):
+    """:func:`_gather_view`'s flat view ``[L, N, H * D, P * page]`` by the
+    compiler's own gather and layout passes, bit-equal to it.  A kind's arrays
+    of a two-rule pool hold one to a few layers, so a page is a block of a
+    quarter of a megabyte and a lane's table hundreds of slots: filled page by
+    page the views cost thousands of ``dynamic_update_slice``s to compile (the
+    cell's decode window 163 s here for the described chip and 245 s on it,
+    against 22 s; a 512-chunk 23 s against 9).  The mixed PREFILL CHUNK takes
+    this form: one lane's views, and it runs as fast either way (19.3 against
+    19.8 ms; my chip runs, PR 35).  The mixed DECODE WINDOW keeps the page-wide
+    updates: alone this gather fills 8 lanes of 256 one-layer pages in 2.4 ms
+    against 6.6, but the window that holds it runs 41.5 ms against 35.4 (the
+    gather's output passes through two more layouts before the scan takes it)."""
+    L, _, H, page, D = pages.shape
+    N, P = tables.shape
+    return (pages[:, tables]                             # [L, N, P, H, page, D]
+            .transpose(0, 1, 3, 5, 2, 4)
+            .reshape(L, N, H * D, P * page))
+
+
 def _live_tables(tables, live):
     """Mask table slots at or past each lane's live page count to the null
     page, so gathers only move pages that can hold a visible key.  ``live``
@@ -736,7 +790,8 @@ def _live_tables(tables, live):
     return jnp.where(jnp.arange(num_p)[None, :] < live[:, None], tables, NULL_PAGE)
 
 
-def _store_span_pages(pages, view, tables, start, width: int, active, flat: bool):
+def _store_span_pages(pages, view, tables, start, width: int, active, flat: bool,
+                      ring: bool = False):
     """Write positions ``start[n] .. start[n] + width - 1`` of lane ``n``'s
     ``view`` (:func:`_gather_view`) back through its block table, for every
     ACTIVE lane, by storing whole the pages the span touches: a static
@@ -760,14 +815,22 @@ def _store_span_pages(pages, view, tables, start, width: int, active, flat: bool
     boundary crossed; a slot past the table's end) and for every inactive lane:
     a frozen lane's row may be vacant (all-null already), but a lane
     mid-prefill has REAL pages mapped — possibly shared with the prefix cache —
-    and its stale write index must never trample them."""
+    and its stale write index must never trample them.
+
+    ``ring`` (the flat view of a window layer kind, ``tables`` a lane's ring of
+    ``P`` pages): position ``p`` lies in page slot ``(p // page) % P`` of the
+    table and in the view's columns ``p % (P * page)``."""
     L, _, H, page, D = pages.shape                       # K's and V's rows may differ
     N, P = tables.shape
     touched = (width + page - 2) // page + 1
     slot = (start // page)[:, None] + jnp.arange(touched)            # [N, touched]
-    reached = (active[:, None] & (slot < P)
-               & (slot <= ((start + width - 1) // page)[:, None]))
-    slot = jnp.minimum(slot, P - 1)
+    if ring:
+        reached = active[:, None] & (slot <= ((start + width - 1) // page)[:, None])
+        slot = slot % P
+    else:
+        reached = (active[:, None] & (slot < P)
+                   & (slot <= ((start + width - 1) // page)[:, None]))
+        slot = jnp.minimum(slot, P - 1)
     ids = jnp.where(reached, jnp.take_along_axis(tables, slot, axis=1), NULL_PAGE)
     if not flat:
         blocks = view.reshape(L, N, P, page, H, D)[:, jnp.arange(N)[:, None], slot]
@@ -986,6 +1049,94 @@ def make_paged_decode_window(model: Transformer, window: int,
         in_shardings=None if s is None else (s.params, s.pages, s.pages, *s.rep(11)),
         out_shardings=None if s is None else (s.pages, s.pages, *s.rep(3)),
     )
+
+
+def make_mixed_prefill_chunk(model: Transformer, chunk_len: int, page_size: int):
+    """Prefill chunk of a stack of two kinds of layer (``config.layer_types``):
+    ``(params, tokens [1, chunk_len], pages_k, pages_v, ring_k, ring_v, table
+    [P], ring_table [R], base[, valid]) -> (pages_k, pages_v, ring_k, ring_v[,
+    counts])``.  The gathered arm of :func:`make_paged_prefill_chunk` twice
+    over: the full layers' view is ``max_len`` wide through the lane's whole
+    table, the window layers' view is the lane's ring, ``R * page`` wide
+    (:class:`~accelerate_tpu.models.transformer.MixedKVCache`), and the chunk's
+    pages are stored back whole into both pools: into the table's slots
+    ``base // page ..`` and into the ring's slots ``(base // page + i) % R``.
+    ``valid`` and the counters are a routed-experts model's (:func:`_forward`)."""
+    if chunk_len % page_size != 0:
+        raise ValueError(
+            f"chunk bucket {chunk_len} must be a multiple of page_size {page_size}"
+        )
+    npg = chunk_len // page_size
+
+    def chunk(params, tokens, pages_k, pages_v, ring_k, ring_v, table, ring_table, base, valid):
+        gt = _live_tables(table, (base + chunk_len - 1) // page_size + 1)
+        cache = MixedKVCache(
+            k=_gather_columns(pages_k, gt[None]), v=_gather_columns(pages_v, gt[None]),
+            k_ring=_gather_columns(ring_k, ring_table[None]),
+            v_ring=_gather_columns(ring_v, ring_table[None]),
+            index=base, page=page_size,
+        )
+        rows = None if valid is None else jnp.arange(chunk_len)[None, :] < valid
+        _, cache, counts = _forward(model, params, tokens, cache, rows)
+        first = base // page_size
+        ring_slots = (first + jnp.arange(npg)) % ring_table.shape[0]
+        for i in range(npg):
+            pages_k = _store_flat_page(pages_k, cache.k, 0, base + i * page_size, table[first + i])
+            pages_v = _store_flat_page(pages_v, cache.v, 0, base + i * page_size, table[first + i])
+            column, page_id = ring_slots[i] * page_size, ring_table[ring_slots[i]]
+            ring_k = _store_flat_page(ring_k, cache.k_ring, 0, column, page_id)
+            ring_v = _store_flat_page(ring_v, cache.v_ring, 0, column, page_id)
+        return _with_counts(pages_k, pages_v, ring_k, ring_v, counts)
+
+    if _routed(model):
+        def mixed_prefill_chunk(params, tokens, pages_k, pages_v, ring_k, ring_v, table,
+                                ring_table, base, valid):
+            return chunk(params, tokens, pages_k, pages_v, ring_k, ring_v, table, ring_table,
+                         base, valid)
+    else:
+        def mixed_prefill_chunk(params, tokens, pages_k, pages_v, ring_k, ring_v, table,
+                                ring_table, base):
+            return chunk(params, tokens, pages_k, pages_v, ring_k, ring_v, table, ring_table,
+                         base, None)
+
+    return _serve_jit(mixed_prefill_chunk, donate_argnums=(2, 3, 4, 5))
+
+
+def make_mixed_decode_window(model: Transformer, window: int):
+    """Decode window of a stack of two kinds of layer: ``(params, pages_k,
+    pages_v, ring_k, ring_v, tables [N, P], ring_tables [N, R], index [N],
+    tokens, active, eos, do_sample, temperature, top_k, top_p, pad, rngs) ->
+    (pages_k, pages_v, ring_k, ring_v, out_tokens [N, window], new_pending,
+    new_rngs, counts)``.  Gather both kinds' views (the window layers' is the
+    ring's width, not ``max_len``), the shared :func:`_decode_scan`, store back
+    whole the pages each active lane's ``window`` new positions lie in, in both
+    pools.  ``counts`` is the experts' ``moe_*`` counters where the model has
+    them, then ``[kv_rows_live, kv_rows_live_window]`` (:func:`_rows_live`)."""
+
+    def mixed_decode_window(params, pages_k, pages_v, ring_k, ring_v, tables, ring_tables,
+                            index, tokens, active, eos, do_sample, temperature, top_k,
+                            top_p, pad, rngs):
+        page = pages_k.shape[3]
+        gt = _live_tables(tables, (index + window - 1) // page + 1)
+        cache = MixedKVCache(
+            k=_gather_view(pages_k, gt, True), v=_gather_view(pages_v, gt, True),
+            k_ring=_gather_view(ring_k, ring_tables, True),
+            v_ring=_gather_view(ring_v, ring_tables, True),
+            index=index, page=page,
+        )
+        cache, toks, tok, rngs, counts = _decode_scan(
+            model, window, params, cache, tokens, active, eos, do_sample,
+            temperature, top_k, top_p, pad, rngs,
+        )
+        pages_k = _store_span_pages(pages_k, cache.k, tables, index, window, active, True)
+        pages_v = _store_span_pages(pages_v, cache.v, tables, index, window, active, True)
+        ring_k = _store_span_pages(ring_k, cache.k_ring, ring_tables, index, window, active,
+                                   True, ring=True)
+        ring_v = _store_span_pages(ring_v, cache.v_ring, ring_tables, index, window, active,
+                                   True, ring=True)
+        return pages_k, pages_v, ring_k, ring_v, toks, tok, rngs, counts
+
+    return _serve_jit(mixed_decode_window, donate_argnums=(1, 2, 3, 4))
 
 
 def make_paged_verify_window(model: Transformer, k: int, direct: bool = False,

@@ -601,3 +601,55 @@ def test_retention_step_kernel_compiles_at_the_published_shapes(one_chip):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == 4 * 10 * lanes * heads * rows * (width + 1), memory
     assert memory.temp_size_in_bytes < 32e6, memory          # phi(q) padded to whole sublanes: 17 MB
+
+
+# ----------------------------------------------------------- the two-rule pool
+@pytest.mark.parametrize("program,temp_gb", [
+    pytest.param("decode", 3.4, marks=pytest.mark.slow),     # three minutes of compilation: not tier-1
+    ("chunk512", 1.9),
+])
+def test_two_rule_pool_programs_fit_at_the_cells_sizes(one_chip, program, temp_gb):
+    """``bench/configs/trinity-large.json`` whole (five layers, 8.64 GB of
+    weights) with the serve cell's pool: 8 lanes of 32,768 positions, pages of
+    128, a ring of 37 pages a lane for the four window layers and whole tables
+    for the full one.  Both pools alias through (1.697 GB: 1.07 + 0.62, where
+    one rule for all five layers would be 5.37), the window layers' view is
+    the ring's width and not ``max_len``, and arguments and temporaries fit the
+    chip together (the decode window: 10.34 + 3.33 GB).  The chunk's views come
+    from the compiler's gather, the window's from page-wide updates
+    (``serving/pool.py`` ``_gather_columns`` says why)."""
+    import json
+    from pathlib import Path
+
+    from accelerate_tpu.models.transformer import Transformer, TransformerConfig
+    from accelerate_tpu.serving import pool
+
+    fields = json.loads((Path(__file__).resolve().parents[1] / "bench" / "configs"
+                         / "trinity-large.json").read_text())["transformer"]
+    fields["dtype"] = fields["param_dtype"] = jnp.bfloat16
+    model = Transformer(TransformerConfig(**fields))
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    lanes, page, window, table, ring = 8, 128, 4, 32768 // 128, -(-(4096 + 512) // 128) + 1
+    full = [spec((1, lanes * table + 1, 8, page, 128), jnp.bfloat16)] * 2
+    rings = [spec((4, lanes * ring + 1, 8, page, 128), jnp.bfloat16)] * 2
+    i32, f32 = (lambda *s: spec(s, jnp.int32)), (lambda *s: spec(s, jnp.float32))
+    flag = lambda *s: spec(s, jnp.bool_)
+    if program == "decode":
+        vectors = (i32(lanes), flag(lanes), i32(lanes), flag(lanes), f32(lanes), i32(lanes), f32(lanes),
+                   i32(lanes), spec((lanes, 2), jnp.uint32))
+        compiled = pool.make_mixed_decode_window(model, window).lower(
+            params, *full, *rings, i32(lanes, table), i32(lanes, ring), i32(lanes), *vectors).compile()
+    else:
+        compiled = pool.make_mixed_prefill_chunk(model, 512, page).lower(
+            params, i32(1, 512), *full, *rings, i32(table), i32(ring), i32(), i32()).compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert memory.alias_size_in_bytes == 2 * 2 * (lanes * table + 1 + 4 * (lanes * ring + 1)) * 8 * page * 128
+    assert memory.temp_size_in_bytes < temp_gb * 1e9, memory
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9, memory
+    assert "ragged-dot" in text
+    assert (text.count("dynamic-update-slice(") < 400) == (program != "decode")
+    n = lanes if program == "decode" else 1
+    assert f"bf16[4,{n},1024,{ring * page}]" in text and f"bf16[4,{n},1024,32768]" not in text
