@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ..ops.grouped_matmul import grouped_applies, grouped_matmul
+
 
 def top_k_dispatch(
     router_probs: jax.Array,  # [N, E] fp32
@@ -120,7 +122,7 @@ class MoEMLP(nn.Module):
 # The layer the serving engine runs (``TransformerConfig.experts``): no
 # capacity, no dropped token, and a device that holds only ``[lo, hi)`` of the
 # experts.  Token-expert pairs are sorted by expert and the held experts'
-# products are ragged (grouped) matmuls over the sorted rows; pairs that fall on
+# products are grouped matmuls over the sorted rows; pairs that fall on
 # experts held elsewhere sort last and contribute nothing.  The dense dispatch
 # above stays for the configurations trained with it on an ``ep`` mesh
 # (``num_experts``: its einsums are what GSPMD turns into the all-to-all).
@@ -156,9 +158,22 @@ def route_top_k(scores: jax.Array, spec, bias=None) -> Tuple[jax.Array, jax.Arra
     return experts.astype(jnp.int32), gates
 
 
+def held_experts_grouped(config) -> bool:
+    """Whether the held experts' three products of ``config`` run in the Pallas
+    grouped matmul (:mod:`accelerate_tpu.ops.grouped_matmul`) on this platform
+    or as ``jax.lax.ragged_dot``: the engine's gauge ``serve/moe_grouped_kernel``.
+    Up and down swap ``in`` and ``out``, so one answer holds for all three."""
+    spec = config.experts
+    return grouped_applies(jax.ShapeDtypeStruct((1, config.hidden_size), config.dtype),
+                           jax.ShapeDtypeStruct((spec.num_held, config.hidden_size, spec.width), config.dtype))
+
+
 class _ExpertProjection(nn.Module):
     """One projection of every held expert, ``kernel [held, in, out]``, applied
-    to rows sorted by expert: a ragged matmul."""
+    to rows sorted by expert: a grouped matmul.  Its form is picked by what the
+    call can observe (``grouped_applies``: bfloat16, widths of whole lanes, a
+    TPU): the Pallas kernel, which reads each expert that got a row once and
+    visits no row past the groups, or ``jax.lax.ragged_dot``."""
 
     shape: Tuple[int, int, int]
     dtype: Any
@@ -166,8 +181,9 @@ class _ExpertProjection(nn.Module):
 
     @nn.compact
     def __call__(self, rows, group_sizes):
-        kernel = self.param("kernel", nn.initializers.normal(0.02), self.shape, self.param_dtype)
-        return jax.lax.ragged_dot(rows, kernel.astype(self.dtype), group_sizes)
+        kernel = self.param("kernel", nn.initializers.normal(0.02), self.shape, self.param_dtype).astype(self.dtype)
+        dot = grouped_matmul if grouped_applies(rows, kernel) else jax.lax.ragged_dot
+        return dot(rows, kernel, group_sizes)
 
 
 class _HeldExperts(nn.Module):

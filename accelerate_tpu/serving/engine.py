@@ -1111,6 +1111,16 @@ class ServingEngine:
             help="info gauge: disaggregated serving role — 0 = both "
                  "(monolithic), 1 = prefill-only, 2 = decode-only",
         ).set({"both": 0.0, "prefill": 1.0, "decode": 2.0}[self.role])
+        if self._routed:
+            from ..parallel.moe import held_experts_grouped
+
+            #: the form the experts' products take when the programs are traced
+            self.moe_grouped_kernel = held_experts_grouped(cfg)
+            self.metrics.gauge(
+                "serve/moe_grouped_kernel",
+                help="1 where the held experts' products run in the Pallas grouped "
+                     "matmul, 0 where they are jax.lax.ragged_dot",
+            ).set(float(self.moe_grouped_kernel))
         self._kv_quant_gauge = (
             self.metrics.gauge(
                 "serve/kv_quant_error",

@@ -338,12 +338,16 @@ def test_telemetry_kill_switch_disables_fleet_health():
 
 
 def test_debug_slo_route_and_opt_in_healthz():
+    from accelerate_tpu.telemetry.flight_recorder import FlightRecorder
     from accelerate_tpu.telemetry.server import TelemetryEndpoints
 
     reg = MetricsRegistry()
+    # a recorder of the test's own: the process-wide one may carry a heartbeat
+    # that an earlier test of this worker left and that is stale by now
+    recorder = FlightRecorder(registry=reg)
     # uninstalled: the route answers, disabled; /healthz ignores SLOs
     uninstall_slos()
-    eps = TelemetryEndpoints(registry=reg, slo_healthz=True)
+    eps = TelemetryEndpoints(registry=reg, recorder=recorder, slo_healthz=True)
     status, ctype, body = eps.handle("/debug/slo")
     assert status == 200 and ctype == "application/json"
     assert json.loads(body) == {"enabled": False, "slos": {}}
@@ -373,7 +377,7 @@ def test_debug_slo_route_and_opt_in_healthz():
         assert payload["slos"]["lat"]["fast_burning"] is True
         healthy, hbody = eps.health()
         assert healthy is False and hbody["slo_fast_burning"] is True
-        default_eps = TelemetryEndpoints(registry=reg)  # opt-in is off
+        default_eps = TelemetryEndpoints(registry=reg, recorder=recorder)  # opt-in is off
         healthy, hbody = default_eps.health()
         assert healthy is True and "slo_fast_burning" not in hbody
     finally:

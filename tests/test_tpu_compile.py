@@ -38,6 +38,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from accelerate_tpu.ops.flash_attention import flash_attention
+from accelerate_tpu.ops.grouped_matmul import grouped_matmul
 from accelerate_tpu.ops.paged_attention import (
     KV_FORMATS,
     paged_attention,
@@ -653,3 +654,72 @@ def test_two_rule_pool_programs_fit_at_the_cells_sizes(one_chip, program, temp_g
     assert (text.count("dynamic-update-slice(") < 400) == (program != "decode")
     n = lanes if program == "decode" else 1
     assert f"bf16[4,{n},1024,{ring * page}]" in text and f"bf16[4,{n},1024,32768]" not in text
+
+
+# ------------------------------------------------- the held experts' products
+#: both routed cells' held experts ``[held, in, out]`` with a 512-chunk's and a decode window's sorted rows
+GROUPED = [((40, 5120, 1536), 3072), ((40, 5120, 1536), 96), ((40, 1536, 5120), 3072), ((40, 1536, 5120), 96),
+           ((32, 3072, 3072), 2048), ((32, 3072, 3072), 32)]
+
+
+@pytest.mark.parametrize("kernel,rows", GROUPED, ids=[f"{'x'.join(map(str, k))}-{m}rows" for k, m in GROUPED])
+def test_grouped_matmul_compiles_at_the_published_shapes(one_chip, kernel, rows):
+    """``ops/grouped_matmul.py`` at DeepSeek-V2's and Trinity's experts: a whole
+    expert a block (15.7 and 18.9 MB, double-buffered under the raised limit),
+    row tiles of 128 for a chunk and the whole window's rows for a window."""
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = _compiled_text(lambda r, w, g: grouped_matmul(r, w, g, interpret=False),
+                          spec((rows, kernel[1]), jnp.bfloat16), spec(kernel, jnp.bfloat16), spec(kernel[:1], jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "grouped_matmul" in text
+
+
+@pytest.mark.parametrize("program", ["chunk512", "decode"])
+def test_deepseek_programs_run_their_experts_in_the_kernel_and_copy_no_weights(one_chip, monkeypatch, program):
+    """``bench/configs/deepseek-v2.json`` at three layers (the dense one and two
+    expert layers of all 40 held experts) with the serve cell's pool, traced as
+    a TPU traces it (``held_experts_grouped``): no ``ragged-dot`` is left, every
+    live expert layer holds three kernels (the chunk returns no logits, so its
+    last layer's experts are dead code), and nothing in the compiled program
+    outputs an array of a held-experts weight's shape: the kernel reads the
+    stacked leaves where they lie, through no copy, transpose or other layout."""
+    import json
+    from pathlib import Path
+
+    from accelerate_tpu.models.transformer import Transformer, TransformerConfig
+    from accelerate_tpu.ops import grouped_matmul as gm
+    from accelerate_tpu.parallel.moe import held_experts_grouped
+    from accelerate_tpu.serving import pool
+
+    monkeypatch.setattr(gm, "_platform_compiles", lambda: True)
+    fields = json.loads((Path(__file__).resolve().parents[1] / "bench" / "configs"
+                         / "deepseek-v2.json").read_text())["transformer"]
+    fields.update(num_layers=3, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    model = Transformer(TransformerConfig(**fields))
+    assert held_experts_grouped(model.config)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    lanes, per_lane, page, window = 16, 64, 128, 4
+    pages = [spec((3, lanes * per_lane + 1, 1, page, d), jnp.bfloat16) for d in (512, 64)]
+    i32, f32 = (lambda *s: spec(s, jnp.int32)), (lambda *s: spec(s, jnp.float32))
+    flag = lambda *s: spec(s, jnp.bool_)
+    if program == "decode":
+        vectors = (flag(lanes), i32(lanes), flag(lanes), f32(lanes), i32(lanes), f32(lanes), i32(lanes),
+                   spec((lanes, 2), jnp.uint32))
+        compiled = pool.make_paged_decode_window(model, window).lower(
+            params, *pages, i32(lanes, per_lane), i32(lanes), i32(lanes), *vectors).compile()
+        live_layers = 2
+    else:
+        compiled = pool.make_paged_prefill_chunk(model, 512, page).lower(
+            params, i32(1, 512), *pages, i32(per_lane), i32(), i32()).compile()
+        live_layers = 1
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 3 * live_layers
+    assert len(set(re.findall(r"%(grouped_matmul[.\d]*) = ", text))) == 3 * live_layers
+    held = {"bf16[40,5120,1536]", "bf16[40,1536,5120]"}
+    comps, _ = _parse_hlo(text)
+    made = [ins.line for body in comps.values() for ins in body
+            if ins.shape in held and ins.opcode not in _PASS_THROUGH]
+    assert not made, made
