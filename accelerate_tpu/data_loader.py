@@ -30,6 +30,7 @@ from .parallel import mesh as mesh_lib
 from .state import GradientState, PartialState
 from .telemetry import get_flight_recorder as _get_flight_recorder
 from .telemetry import get_registry as _get_telemetry_registry
+from .telemetry import get_tracer as _get_tracer
 from .utils.dataclasses import DataLoaderConfiguration, RNGType
 from .utils.operations import (
     broadcast,
@@ -453,11 +454,17 @@ class DataLoaderShard(DataLoaderStateMixin):
         """``next(raw_iter)`` then device placement, timed separately into the
         ``data/fetch_s`` / ``data/device_put_s`` histograms — a slow input
         pipeline and a slow host-to-device path look identical from step time
-        alone.  ``StopIteration`` propagates to the prefetch loop."""
+        alone.  ``StopIteration`` propagates to the prefetch loop.  The two
+        also stand as the spans ``data/fetch`` and ``data/place`` on the thread
+        that asked for the batch (the training loop's): while a device capture
+        is on they name the part of the device's idle that is the loader's."""
+        tracer = _get_tracer()
         t0 = time.perf_counter()
-        batch = next(raw_iter)
+        with tracer.span("data/fetch"):
+            batch = next(raw_iter)
         t1 = time.perf_counter()
-        placed = self.placer.place(batch)
+        with tracer.span("data/place"):
+            placed = self.placer.place(batch)
         t2 = time.perf_counter()
         registry = _get_telemetry_registry()
         registry.histogram("data/fetch_s", help="host batch fetch wall time").observe(t1 - t0)

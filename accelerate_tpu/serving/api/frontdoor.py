@@ -28,6 +28,7 @@ the moment traffic pauses) and reaps finished requests into their streams.
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -36,6 +37,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from ...logging import get_logger
 from ...models.generation import GenerationConfig
 from ...telemetry import get_flight_recorder, get_reqtrace, get_tracer, slo_tick
+from ...telemetry.tracer import device_trace_active
 from ..errors import AdmissionError, DeadlineExceeded
 from ..router import ReplicaRouter
 from ..scheduler import Request, RequestState
@@ -47,6 +49,11 @@ __all__ = ["FrontDoor", "TokenStream"]
 
 #: Sentinel queued into a TokenStream when the producer side closes.
 _CLOSED = object()
+
+#: While a device capture is on, ``door/idle`` is cut every so many naps: the
+#: profiler drops an annotation still open when it stops, so the idle period
+#: under which a capture ends would name none of the device's idle.
+_TRACED_IDLE_NAPS = 32
 
 
 def _name_os_thread(name: str) -> None:
@@ -118,11 +125,12 @@ class TokenStream:
 class _Ticket:
     """One closure to run on the driver thread, plus the rendezvous."""
 
-    __slots__ = ("fn", "admin", "event", "result", "error")
+    __slots__ = ("fn", "admin", "created", "event", "result", "error")
 
     def __init__(self, fn: Callable[[], Any], admin: bool):
         self.fn = fn
         self.admin = admin
+        self.created = time.perf_counter()  # where ``door/ticket_wait`` starts
         self.event = threading.Event()
         self.result: Any = None
         self.error: Optional[BaseException] = None
@@ -172,6 +180,13 @@ class FrontDoor:
         self._in_admin = False
         self._thread: Optional[threading.Thread] = None
         self._last_heartbeat = 0.0
+        # ONE ``door/idle`` span an idle period, open from the first iteration
+        # that found neither work nor tickets to the first that finds either
+        # (a span a nap would flood the ring; a period is cut only for a device
+        # capture: where one begins or ends under it, and every
+        # ``_TRACED_IDLE_NAPS`` while one is on); ``naps`` counts its sleeps
+        self._idle = contextlib.ExitStack()
+        self._idle_args: Optional[dict] = None
 
     # ------------------------------------------------------------ lifecycle
     def start(self) -> "FrontDoor":
@@ -239,6 +254,7 @@ class FrontDoor:
             # the front-door key becomes the trace's authoritative id: it is
             # what the API server echoes as X-Request-Id, and unlike the
             # engine rid it never changes across failover adoption
+            req.key = stream.rid
             get_reqtrace().rekey(req.trace, str(stream.rid))
             return req, stream
 
@@ -356,9 +372,16 @@ class FrontDoor:
                 stream.close(req.tokens, req.state)
         return len(finished)
 
+    def _end_idle(self) -> None:
+        """This iteration found tickets or work: close the open ``door/idle``."""
+        if self._idle_args is not None:
+            self._idle.close()
+            self._idle_args = None
+
     def _process_tickets(self, skip_admin: bool = False) -> None:
         if self._tickets.empty():
             return  # no span for an empty inbox: an idle server would flood the ring
+        self._end_idle()
         deferred: List[_Ticket] = []
         with self.tracer.span("door/tickets") as span:
             ran = 0
@@ -373,6 +396,10 @@ class FrontDoor:
                     deferred.append(t)
                     continue
                 ran += 1
+                # how long the handler's thread waited for this thread to get
+                # to its ticket: up to a whole engine step
+                self.tracer.record("door/ticket_wait", t.created,
+                                   time.perf_counter(), admin=t.admin)
                 try:
                     t.result = t.fn()
                 except BaseException as exc:  # propagate to the waiting thread
@@ -389,11 +416,13 @@ class FrontDoor:
         the hot-swap drain hook it defers admin ops (the default): the drain
         must keep accepting submits without re-entering another rollout.
         ``door/tickets``, ``router/step`` and ``door/reap`` tile this
-        thread's busy time; an idle iteration opens none of them."""
+        thread's busy time; an idle iteration opens none of them
+        (:meth:`_drive` holds one ``door/idle`` over a run of them)."""
         self._process_tickets(skip_admin=skip_admin)
         if not self.router.has_work:
             self._reap()
             return False
+        self._end_idle()
         self.router.step()
         with self.tracer.span("door/reap") as span:
             span["finished"] = self._reap()
@@ -435,7 +464,19 @@ class FrontDoor:
                 # burning availability budget on sheds it just served)
                 slo_tick()
             if not worked and self._tickets.empty():
+                traced = device_trace_active()
+                idle = self._idle_args
+                if idle is not None and (idle["traced"] != traced
+                                         or traced and idle["naps"] >= _TRACED_IDLE_NAPS):
+                    # a span is mirrored into a device capture only if opened
+                    # while it is on, and kept only if closed before it ends
+                    self._end_idle()
+                if self._idle_args is None:
+                    self._idle_args = self._idle.enter_context(
+                        self.tracer.span("door/idle", naps=0, traced=traced))
+                self._idle_args["naps"] += 1
                 time.sleep(self.idle_sleep_s)
+        self._end_idle()
         # drain: fail any still-waiting tickets rather than strand threads
         while True:
             try:

@@ -114,6 +114,11 @@ logger = get_logger(__name__)
 # (a deep queue on a loaded pool): 24 x2 buckets from 100 us cover it.
 _LATENCY_BUCKETS = tuple(1e-4 * 2.0**i for i in range(24))
 
+# The counters whose movement over one step ``serve/step`` closes with, as
+# ``window`` and ``live``, ``chunks``, ``chunk_tokens`` and ``emitted``.
+_STEP_CARRIED = ("decode_steps", "occupied_lane_steps", "prefill_chunks",
+                 "prefill_tokens", "tokens_generated")
+
 # Process-wide replica ids ("e0", "e1", ...): every flight-recorder event and
 # request-trace phase an engine emits is tagged with its id so multi-replica
 # rings stay disambiguable (the process-global recorder bit PR 14's bench).
@@ -1655,10 +1660,10 @@ class ServingEngine:
                         break
                     if not self._admission_pages_ok(self.scheduler.queue[0]):
                         break
-                    self.scheduler.start_next(slot)
+                    req = self.scheduler.start_next(slot)
                     self._reserved_slots.add(slot)
                     if self._stateful:
-                        self._zero_lane_state(slot)
+                        self._zero_lane_state(slot, req)
             if not self.scheduler.prefills:
                 return
             took = self.scheduler.take_chunk(
@@ -1670,9 +1675,8 @@ class ServingEngine:
             tr = req.trace
             if tr is not None and not tr.queue_done:
                 # first chunk taken: the queue_wait phase ends here
-                tr.queue_done = True
                 self._queue_wait_hist.observe(
-                    tr.phase("queue_wait", queue_depth=self.scheduler.queue_depth)
+                    tr.close_queue(self.scheduler.queue_depth)
                 )
             ptoks = req.prefill_tokens
             if cached:
@@ -1858,7 +1862,7 @@ class ServingEngine:
             if self.prefix_cache is not None and node.host is handles:
                 self.prefix_cache.settle_payload(node, arrays)
 
-    def _zero_lane_state(self, slot: int) -> None:
+    def _zero_lane_state(self, slot: int, req: Request) -> None:
         """A retention model's install: zero the lane's state on the device
         (enqueued behind whatever window still runs over the lane), before the
         request's first prefill chunk folds its prompt into it."""
@@ -1867,7 +1871,7 @@ class ServingEngine:
         # the in-flight window consumes these handles: park them until its
         # drain, so that the rebind below never drops a consumed handle
         self._stale_handles += [kv.s, kv.z]
-        with self.tracer.span("serve/state_install", slot=slot):
+        with self.tracer.span("serve/state_install", slot=slot, req=req.trace_id):
             kv.s, kv.z = self._state_install(kv.s, kv.z, self._put(np.int32(slot)))
         self._bump("state_installs")
 
@@ -1929,7 +1933,8 @@ class ServingEngine:
             args = (self.params, chunk[None], kv.s, kv.z, self._put(np.int32(s)),
                     self._put(np.int32(start)), self._put(np.int32(valid - last)))
             self.cost_table.capture(f"serve/prefill_{bucket}", self._prefill[bucket], args)
-            with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid):
+            with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid,
+                                  req=req.trace_id):
                 kv.s, kv.z = self._prefill[bucket](*args)
             return
         ids = self.kv.allocator.alloc(bucket // self.page_size)
@@ -1951,7 +1956,8 @@ class ServingEngine:
             if self._routed:
                 args += (self._put(jnp.int32(valid)),)
             self.cost_table.capture(f"serve/prefill_{bucket}", self._prefill[bucket], args)
-            with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid):
+            with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid,
+                                  req=req.trace_id):
                 kv.pages_k, kv.pages_v, kv.ring_k, kv.ring_v, *counts = self._prefill[bucket](*args)
             self._pending_moe_counts.extend(counts)
             return
@@ -1961,7 +1967,8 @@ class ServingEngine:
             self.cost_table.capture(
                 f"serve/prefill_{bucket}", self._prefill[bucket], args,
             )
-            with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid):
+            with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid,
+                                  req=req.trace_id):
                 (kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales,
                  qerr) = self._prefill[bucket](*args)
             if self.quantized:
@@ -1974,7 +1981,8 @@ class ServingEngine:
         if self._routed:
             args += (self._put(jnp.int32(valid)),)
         self.cost_table.capture(f"serve/prefill_{bucket}", self._prefill[bucket], args)
-        with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid):
+        with self.tracer.span("serve/prefill_chunk", bucket=bucket, valid=valid,
+                              req=req.trace_id):
             kv.pages_k, kv.pages_v, *counts = self._prefill[bucket](*args)
         self._pending_moe_counts.extend(counts)
 
@@ -2615,7 +2623,8 @@ class ServingEngine:
             args = (self.params, kv.s, kv.z, index, *lanes)
             if not self.cost_table.captured("serve/decode_window"):
                 self.cost_table.capture("serve/decode_window", self._decode, args)
-            with self.tracer.span("serve/decode_window", occupied=n_occupied):
+            with self.tracer.span("serve/decode_window", occupied=n_occupied,
+                                  steps=self.window):
                 kv.s, kv.z, toks, pending, rngs, *moe = self._decode(*args)
             self._lane_len[self._active] += self.window
         elif self._mixed:
@@ -2629,7 +2638,8 @@ class ServingEngine:
                     ring_tables, index, *lanes)
             if not self.cost_table.captured("serve/decode_window"):
                 self.cost_table.capture("serve/decode_window", self._decode, args)
-            with self.tracer.span("serve/decode_window", occupied=n_occupied):
+            with self.tracer.span("serve/decode_window", occupied=n_occupied,
+                                  steps=self.window):
                 (kv.pages_k, kv.pages_v, kv.ring_k, kv.ring_v, toks, pending, rngs,
                  *moe) = self._decode(*args)
             self._lane_len[self._active] += self.window
@@ -2645,7 +2655,8 @@ class ServingEngine:
                     kv.v_scales, tables, index, *lanes)
             if not self.cost_table.captured("serve/decode_window"):
                 self.cost_table.capture("serve/decode_window", self._decode, args)
-            with self.tracer.span("serve/decode_window", occupied=n_occupied):
+            with self.tracer.span("serve/decode_window", occupied=n_occupied,
+                                  steps=self.window):
                 with self.tracer.span("serve/paged_attn", kernel=self.decode_kernel):
                     (kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales, toks,
                      pending, rngs, qerr, *moe) = self._decode(*args)
@@ -2664,7 +2675,8 @@ class ServingEngine:
                     "serve/decode_window", self._decode,
                     (self.params, kv.pages_k, kv.pages_v, tables, index, *lanes),
                 )
-            with self.tracer.span("serve/decode_window", occupied=n_occupied):
+            with self.tracer.span("serve/decode_window", occupied=n_occupied,
+                                  steps=self.window):
                 kv.pages_k, kv.pages_v, toks, pending, rngs, *moe = self._decode(
                     self.params, kv.pages_k, kv.pages_v, tables, index, *lanes
                 )
@@ -2766,8 +2778,8 @@ class ServingEngine:
                 self.cost_table.capture(
                     "serve/tree_verify_window", self._verify, args
                 )
-            with self.tracer.span("serve/tree_verify_window",
-                                  occupied=n_occupied, drafted=n_drafted):
+            with self.tracer.span("serve/tree_verify_window", occupied=n_occupied,
+                                  steps=tree.depth + 1, drafted=n_drafted):
                 with self.tracer.span("serve/paged_attn",
                                       kernel=self.decode_kernel):
                     (kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales, out,
@@ -2785,8 +2797,8 @@ class ServingEngine:
                     (self.params, kv.pages_k, kv.pages_v, tables, index,
                      tokens, *lanes[1:]),
                 )
-            with self.tracer.span("serve/tree_verify_window",
-                                  occupied=n_occupied, drafted=n_drafted):
+            with self.tracer.span("serve/tree_verify_window", occupied=n_occupied,
+                                  steps=tree.depth + 1, drafted=n_drafted):
                 kv.pages_k, kv.pages_v, out, n_commit, pending, rngs = (
                     self._verify(
                         self.params, kv.pages_k, kv.pages_v, tables, index,
@@ -2839,7 +2851,7 @@ class ServingEngine:
             if not self.cost_table.captured("serve/verify_window"):
                 self.cost_table.capture("serve/verify_window", self._verify, args)
             with self.tracer.span("serve/verify_window", occupied=n_occupied,
-                                  drafted=n_drafted):
+                                  steps=k + 1, drafted=n_drafted):
                 with self.tracer.span("serve/paged_attn", kernel=self.decode_kernel):
                     (kv.pages_k, kv.pages_v, kv.k_scales, kv.v_scales, out,
                      n_commit, pending, rngs, qerr) = self._verify(*args)
@@ -2857,7 +2869,7 @@ class ServingEngine:
                      tokens, *lanes[1:]),
                 )
             with self.tracer.span("serve/verify_window", occupied=n_occupied,
-                                  drafted=n_drafted):
+                                  steps=k + 1, drafted=n_drafted):
                 kv.pages_k, kv.pages_v, out, n_commit, pending, rngs = self._verify(
                     self.params, kv.pages_k, kv.pages_v, tables, index,
                     tokens, *lanes[1:]
@@ -2981,11 +2993,22 @@ class ServingEngine:
         if self._poisoned is not None:
             raise self._poisoned
         try:
+            stats = self.stats
+            before = [stats[k] for k in _STEP_CARRIED]
             with self.tracer.span(
                 "serve/step", queue=self.scheduler.queue_depth
             ) as span:
                 self._step_impl()
                 span["occupied"] = int(self._active.sum())
+                # what this step carried, from the counters its parts bumped:
+                # at most one window a step, so the lanes live at its dispatch
+                # are its lane-steps over its width
+                steps, lane_steps, chunks, chunk_tokens, emitted = (
+                    stats[k] - b for k, b in zip(_STEP_CARRIED, before))
+                span["window"] = int(steps > 0)
+                span["live"] = lane_steps // steps if steps else 0
+                span["chunks"], span["chunk_tokens"] = chunks, chunk_tokens
+                span["emitted"] = emitted
         except Exception as exc:
             self._poisoned = exc
             self.recorder.record(

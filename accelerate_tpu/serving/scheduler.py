@@ -100,6 +100,10 @@ class Request:
     # failover ``adopt`` exactly like ``trace`` does, so per-tenant counters
     # stay exact across replays; None stays out of every per-tenant family
     tenant: Optional[str] = None
+    # the front door's id for this request (``TokenStream.rid``: what the API
+    # echoes as X-Request-Id and ``http/stream_write`` carries); None when
+    # submitted straight to an engine.  Unlike ``rid`` it survives adoption.
+    key: Optional[int] = None
     # per-request latency waterfall (telemetry.reqtrace.RequestTrace; None
     # when tracing is off).  The SAME object rides through preemption,
     # export_inflight, and failover adoption, so the waterfall spans replicas
@@ -109,6 +113,11 @@ class Request:
     @property
     def done(self) -> bool:
         return self.state is RequestState.DONE
+
+    @property
+    def trace_id(self) -> int:
+        """The ``req`` this request's spans and records carry on the tracer."""
+        return self.rid if self.key is None else self.key
 
     @property
     def output_ids(self) -> np.ndarray:

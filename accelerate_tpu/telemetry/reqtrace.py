@@ -49,9 +49,16 @@ slowest-K by TTFT or by total latency.  Retained and recent traces stay
 addressable by the ``X-Request-Id`` the API server emits via
 ``GET /debug/requests/<id>`` (see ``telemetry/server.py``).
 
+The request's three milestones are also written onto the process tracer
+(:meth:`Tracer.record`, all with ``req=<id>``), so that a request's path lies
+on the clock of the engine's spans and of the device trace: ``req/queue``
+(submit to the first chunk taken), ``req/prefill`` (from there to the first
+token emitted) and ``req/decode`` (from there to completion).  The first two
+sum to the request's ``serve/ttft_s`` observation by construction.
+
 ``ATPU_TELEMETRY=0`` disables tracing with the rest of telemetry;
 :func:`set_enabled` overrides just this module (to isolate tracing from
-the rest of the stack).
+the rest of the stack): the ``req/*`` records follow this switch.
 """
 
 from __future__ import annotations
@@ -62,6 +69,7 @@ import time
 from typing import Any, Deque, Dict, List, Optional
 
 from . import metrics as _metrics
+from .tracer import get_tracer
 
 _OVERRIDE: Optional[bool] = None
 
@@ -93,7 +101,7 @@ class RequestTrace:
 
     __slots__ = (
         "tid", "key", "rid", "engine", "replicas", "submit_t", "cursor",
-        "queue_done", "first_token_t", "finish_t", "status", "phases",
+        "queue_done", "queue_end_t", "first_token_t", "finish_t", "status", "phases",
         "events", "phases_at_first", "dropped_phases", "max_phases",
         "prompt_len", "tokens", "sse_write_s", "sse_writes", "retained",
     )
@@ -108,6 +116,7 @@ class RequestTrace:
         self.submit_t = submit_t
         self.cursor = submit_t
         self.queue_done = False
+        self.queue_end_t = submit_t         # when the first chunk was taken
         self.first_token_t: Optional[float] = None
         self.finish_t: Optional[float] = None
         self.status = "active"
@@ -173,10 +182,37 @@ class RequestTrace:
         self.sse_write_s += max(dur, 0.0)
         self.sse_writes += 1
 
+    @property
+    def req(self):
+        """The id this request's records carry on the tracer: the front door's
+        key (what ``http/stream_write`` carries) once bound, else the engine's
+        rid."""
+        key = self.key
+        if key is None:
+            return self.rid
+        return int(key) if key.isdigit() else key
+
+    def close_queue(self, queue_depth: int) -> float:
+        """The request's first chunk was taken: close the ``queue_wait`` phase
+        (its duration is returned) and write ``req/queue``."""
+        self.queue_done = True
+        dur = self.phase("queue_wait", queue_depth=queue_depth)
+        self.queue_end_t = self.cursor
+        get_tracer().record("req/queue", self.submit_t, self.queue_end_t, req=self.req,
+                            prompt_tokens=self.prompt_len, queue=queue_depth)
+        return dur
+
     def mark_first_token(self, now: float) -> None:
+        """The first token was emitted at ``now``: write ``req/prefill``, which
+        runs from the first chunk taken through the install and the first
+        window to the point the device has confirmed."""
         if self.first_token_t is None:
             self.first_token_t = now
             self.phases_at_first = len(self.phases)
+            get_tracer().record(
+                "req/prefill", self.queue_end_t, now, req=self.req,
+                chunks=sum(1 for p in self.phases if p["phase"] == "prefill"),
+                prompt_tokens=self.prompt_len)
 
     def note_engine(self, engine: str, rid: int) -> None:
         self.engine = engine
@@ -369,6 +405,9 @@ class RequestTraceRegistry:
         now = time.perf_counter()
         trace.finish_t = now
         trace.status = status
+        if trace.first_token_t is not None:
+            get_tracer().record("req/decode", trace.first_token_t, now, req=trace.req,
+                                tokens=trace.tokens, status=status)
         with self._lock:
             self._active.pop(trace.tid, None)
             self.traces_completed += 1

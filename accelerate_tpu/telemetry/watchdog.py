@@ -123,6 +123,8 @@ class RecompileWatchdog:
         self.signatures[sig] = {"first_call_s": dt, "at": time.time()}
         self._count_gauge.set(len(self.signatures))
         self._time_counter.inc(dt)
+        # a recompile inside a traced window shows with its name and its time
+        get_tracer().record(f"compile/{self.name}", t0, t0 + dt, n=len(self.signatures))
         if self.over_budget() and not self._warned:
             self._warned = True
             shapes = "; ".join(
