@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from flax import struct
 
 from ..ops.attention import dot_product_attention
+from ..ops.view_attention import view_flash_applies, view_flash_attention
 
 
 def _constrain_sequence_parallel(x):
@@ -654,8 +655,21 @@ def cached_attention(q, k, v, q_positions, window=None, alibi=False,
     since the cache is written contiguously from 0, this is simultaneously the
     causal mask and the valid-entry mask (unwritten slots have ``j`` beyond
     every query position).  ``window`` adds the sliding-window band (Mistral):
-    ``j > q_positions[i] - window``.  Runs as a masked einsum: decode queries
-    are tiny (S=1) and prefill blocks fuse fine on the MXU; fp32 softmax.  GQA
+    ``j > q_positions[i] - window``.  Runs as a masked einsum with an fp32
+    softmax over all ``M`` columns: right for decode and verify windows, whose
+    queries are a few rows.  For a prefill chunk it is three passes over
+    ``[B,Hkv,rep,S,M]`` float32 scores through HBM, most of them over columns
+    that hold nothing yet: the one full-attention layer's ``[8,6,512,32768]``
+    took 1.24 s of a 12 s slice of the long-document cell (ledger, PR 37;
+    ``fusion.*_f32_8_6_512_``), 6 ms of a 23 ms chunk whose live keys needed
+    0.4.  So a chunk's worth of bfloat16 rows against a wide view of 128-wide
+    heads on a TPU goes to the Pallas flash kernel
+    (:func:`~accelerate_tpu.ops.view_attention.view_flash_attention`: only the
+    key blocks that can hold a visible key, scores in fast memory), chosen by
+    what this call can see (:func:`~accelerate_tpu.ops.view_attention
+    .view_flash_applies`) and by nothing else; ``tree_mask``, ``alibi``, float32,
+    64-wide heads, narrow views, short queries and every other platform keep the
+    einsum below as it was.  GQA
     groups fold into the query tensor (``[B,S,Hkv,rep,D]``) so the cache is
     contracted UNexpanded — a ``jnp.repeat`` of K/V would multiply the
     per-token HBM reads by the query/kv head ratio on the decode hot path.
@@ -678,6 +692,8 @@ def cached_attention(q, k, v, q_positions, window=None, alibi=False,
     precede its reads), and the band mask is taken over those positions;
     columns not yet written come out negative and are masked.
     """
+    if tree_mask is None and not alibi and view_flash_applies(q, k):
+        return view_flash_attention(q, k, v, q_positions, window=window, ring=ring)
     b, s, n_q, d = q.shape
     m = k.shape[2]
     n_kv = k.shape[1] // d

@@ -63,6 +63,7 @@ import numpy as np
 from ..logging import get_logger
 from ..models.generation import GenerationConfig
 from ..models.transformer import Transformer
+from ..ops.view_attention import KEY_BLOCK, view_flash_applies
 from ..telemetry import (
     CostTable,
     MetricsRegistry,
@@ -965,6 +966,17 @@ class ServingEngine:
             # summed over lane-steps (counted on the device, in the window)
             self.stats.update(kv_pages_taken=0, kv_pages_released_window=0,
                               kv_rows_live=0, kv_rows_live_window=0)
+        #: the widths of the views a chunk's ``cached_attention`` reads (the
+        #: ``max_len``-wide one first): none where the chunk reads pages in
+        #: place (the Pallas prefill kernel), latent rows or a recurrent state
+        self._chunk_views = ()
+        if not (self._stateful or self.prefill_kernel == "pallas" or cfg.latent_attention is not None):
+            self._chunk_views = (self.kv.tables.shape[1] * self.page_size,) + (
+                (self.kv.ring_pages * self.page_size,) if self._mixed else ())
+            # key blocks (``ops/view_attention.py`` ``KEY_BLOCK`` columns) of the
+            # ``max_len``-wide view that a dispatched chunk could see a key in,
+            # and that the view has: counted on the host, where ``base`` is known
+            self.stats.update(chunk_key_blocks_live=0, chunk_key_blocks_view=0)
         self.stats.engine = self
         self._counters = {
             k: self.metrics.counter(f"serve/{k}_total") for k in self.stats
@@ -1126,6 +1138,20 @@ class ServingEngine:
                 help="1 where the held experts' products run in the Pallas grouped "
                      "matmul, 0 where they are jax.lax.ragged_dot",
             ).set(float(self.moe_grouped_kernel))
+        #: whether a prefill chunk's attention over its gathered views runs in
+        #: the Pallas flash kernel (``ops/view_attention.py``): what
+        #: ``cached_attention`` will see when the chunk programs are traced
+        self.chunk_attention_kernel = self.tp_degree == 1 and cfg.positional != "alibi" and any(
+            view_flash_applies(
+                jax.ShapeDtypeStruct((1, b, cfg.num_heads, cfg.resolved_head_dim), cfg.dtype),
+                jax.ShapeDtypeStruct((1, cfg.num_kv_heads * cfg.resolved_head_dim, m),
+                                     cfg.dtype if self.quantized else self.kv.pages_k.dtype))
+            for b in self.buckets for m in self._chunk_views)
+        self.metrics.gauge(
+            "serve/chunk_attention_kernel",
+            help="1 where the prefill chunks' attention over the gathered views runs "
+                 "in the Pallas flash kernel, 0 where it is XLA's masked softmax",
+        ).set(float(self.chunk_attention_kernel))
         self._kv_quant_gauge = (
             self.metrics.gauge(
                 "serve/kv_quant_error",
@@ -1942,6 +1968,9 @@ class ServingEngine:
             raise RuntimeError("KV page pool exhausted mid-prefill")
         self.kv.lane_append_owned(s, ids)
         kv = self.kv
+        if self._chunk_views:
+            self._bump("chunk_key_blocks_live", -(-(start + bucket) // KEY_BLOCK))
+            self._bump("chunk_key_blocks_view", -(-self._chunk_views[0] // KEY_BLOCK))
         table = self._put(kv.tables[s])
         base = self._put(jnp.int32(start))
         if self._mixed:

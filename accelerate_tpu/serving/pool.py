@@ -94,6 +94,7 @@ serving tests via the jit cache counters.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -105,6 +106,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..models.generation import sample_tokens_batched
 from ..models.retention import StateCache
 from ..models.transformer import KVCache, MixedKVCache, PagedKVCache, Transformer
+from ..ops.view_attention import xla_form
 from ..parallel.mesh import mesh_axis_size
 from ..utils.jax_compat import jit_cache_size
 from .paging import NULL_PAGE
@@ -890,6 +892,9 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
     s = _unrouted(model, shardings)
     counted = _routed(model)
     flat = _flat_view(model)
+    # views sharded over key/value heads keep the XLA attention: the chunk's
+    # flash kernel has no partitioning rule (``ops/view_attention.py``)
+    form = xla_form if s is not None and s.tp_degree > 1 else contextlib.nullcontext
 
     if direct:
         def direct_prefill_chunk(params, tokens, pages_k, pages_v, k_scales,
@@ -900,7 +905,8 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
                 tables=table[None], index=base.reshape(1),
                 active=jnp.ones((1,), bool), quant_err=jnp.float32(0.0),
             )
-            _, cache = model.apply({"params": params}, tokens, cache=cache)
+            with form():
+                _, cache = model.apply({"params": params}, tokens, cache=cache)
             return (cache.pages_k, cache.pages_v, cache.k_scales,
                     cache.v_scales, cache.quant_err)
 
@@ -925,7 +931,8 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
             index=base,
         )
         rows = None if valid is None else jnp.arange(chunk_len)[None, :] < valid
-        _, cache, counts = _forward(model, params, tokens, cache, rows)
+        with form():
+            _, cache, counts = _forward(model, params, tokens, cache, rows)
         ids = jax.lax.dynamic_slice(table, (base // page_size,), (npg,))
 
         def write_back(pages, view):

@@ -1,0 +1,96 @@
+"""The chunk's view attention alone, on the chip: ``ops/view_attention.py`` beside
+``cached_attention``'s masked einsum at the long-document cell's shapes (48 query
+and 8 key/value heads of 128, a full view of 32,768 columns and a ring of 37 x
+128), at 2 k / 8 k / 24 k live keys and on the ring before and after it wraps.
+
+    python3 benchmarks/view_attention_microbench.py [out.jsonl] [--blocks 256,512,1024]
+
+One JSON line a measurement: milliseconds a call (host clock over ``CALLS`` calls
+of one jitted function, the last waited for), the FLOPs the live keys need
+(``4 x Hq x S x keys x D``: masked pairs inside the last blocks counted, dead
+blocks not) as a share of 197 TFLOP/s, and the largest difference from the
+einsum's output.  ``docs/kernels/view_attention.md`` quotes it.  Exits non-zero
+off a TPU: a time from anything else is no measurement.
+"""
+
+import json
+import sys
+import time
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from accelerate_tpu.models.transformer import cached_attention  # noqa: E402
+from accelerate_tpu.ops.view_attention import KEY_BLOCK, view_flash_attention, xla_form  # noqa: E402
+
+HQ, HKV, D, FULL, RING, WINDOW = 48, 8, 128, 32768, 37 * 128, 4096
+PEAK, CALLS = 197e12, 20
+BF16 = jnp.bfloat16
+
+
+def timed(fn, *args):
+    out = fn(*args)
+    jax.block_until_ready(out)
+    start = time.perf_counter()
+    for _ in range(CALLS):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - start) / CALLS * 1e3, out
+
+
+def main():
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        sys.exit(f"needs a TPU, found {device.platform}")
+    out_path = next((a for a in sys.argv[1:] if not a.startswith("--")), None)
+    blocks = [KEY_BLOCK]
+    if "--blocks" in sys.argv:
+        blocks = [int(b) for b in sys.argv[sys.argv.index("--blocks") + 1].split(",")]
+
+    def emit(**record):
+        record["device"] = device.device_kind
+        print(json.dumps(record), flush=True)
+        if out_path:
+            with open(out_path, "a") as fh:
+                fh.write(json.dumps(record) + "\n")
+
+    keys = jax.random.split(jax.random.PRNGKey(38), 3)
+    draw = lambda key, shape: jax.jit(lambda k: jax.random.normal(k, shape, jnp.float32).astype(BF16))(key)
+    cases = [(rows, FULL, base, None, False) for rows in (512, 128) for base in (2048 - rows, 8192 - rows, 24576 - rows)]
+    cases += [(rows, RING, base, WINDOW, True) for rows in (512, 128) for base in (1024, 16384)]
+    for rows, m, base, window, ring in cases:
+        q = draw(keys[0], (1, rows, HQ, D))
+        live = min(base + rows, m)
+        # a view as the gather leaves it: zeros where nothing was written
+        fill = lambda key: draw(key, (1, HKV * D, m)) * (jnp.arange(m) < live).astype(BF16)
+        k, v = fill(keys[1]), fill(keys[2])
+        positions = base + jnp.arange(rows, dtype=jnp.int32)[None]
+        seen = min(live, window + rows) if ring else live
+        flops = 4 * HQ * rows * seen * D
+        case = dict(rows=rows, view=m, base=base, ring=ring, live_keys=seen)
+
+        def einsum(q, k, v, positions):
+            with xla_form():
+                return cached_attention(q, k, v, positions, window=window, ring=ring)
+
+        ms, want = timed(jax.jit(einsum), q, k, v, positions)
+        emit(**case, form="xla", ms=ms, peak_pct=100 * flops / (ms * 1e-3) / PEAK)
+        for block in blocks:
+            kernel = jax.jit(lambda q, k, v, p, block=block: view_flash_attention(
+                q, k, v, p, window=window, ring=ring, block=block))
+            try:
+                ms, got = timed(kernel, q, k, v, positions)
+            except Exception as e:  # a block the compiler refuses is a finding, not a crash
+                emit(**case, form="kernel", block=block, error=repr(e)[:300])
+                continue
+            gap = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))))
+            emit(**case, form="kernel", block=block, ms=ms, peak_pct=100 * flops / (ms * 1e-3) / PEAK,
+                 max_abs_gap=gap, finite=bool(np.isfinite(np.asarray(got, np.float32)).all()))
+
+
+if __name__ == "__main__":
+    main()

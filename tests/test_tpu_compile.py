@@ -45,6 +45,7 @@ from accelerate_tpu.ops.paged_attention import (
     paged_flash_prefill,
 )
 from accelerate_tpu.ops.retention import retention_step_onepass
+from accelerate_tpu.ops.view_attention import view_flash_attention
 
 NUM_PAGES, PAGE, PAGES_PER_LANE, LANES = 512, 16, 64, 4
 HEADS = [(25, 25, 64), (12, 12, 64)]          # (q heads, kv heads, head dim)
@@ -723,3 +724,66 @@ def test_deepseek_programs_run_their_experts_in_the_kernel_and_copy_no_weights(o
     made = [ins.line for body in comps.values() for ins in body
             if ins.shape in held and ins.opcode not in _PASS_THROUGH]
     assert not made, made
+
+
+# ------------------------------------------- the chunk's attention over a view
+#: (rows, view, window, ring): the long-document cell's two buckets against its full view and its ring of 37
+#: pages (a masked tail: 4,736 is no multiple of a key block); a banded full view (``generate``'s cache of a
+#: window layer); a prefill of four row blocks; rows that are no whole row block
+VIEWS = [(512, 32768, None, False), (128, 32768, None, False), (512, 4736, 4096, True), (128, 4736, 4096, True),
+         (512, 32768, 4096, False), (2048, 8192, None, False), (200, 4096, None, False)]
+
+
+@pytest.mark.parametrize("rows,view,window,ring", VIEWS,
+                         ids=[f"{r}rows-{m}{'-ring' if ring else '-band' if w else ''}" for r, m, w, ring in VIEWS])
+def test_view_flash_attention_compiles_at_the_published_shapes(one_chip, rows, view, window, ring):
+    """``ops/view_attention.py`` at Trinity's 48 query and 8 key/value heads of
+    128: one kernel, the view handed over as it lies: nothing in the compiled
+    program outputs an array of the view's shape but the compiler's own
+    prefetch of a small view (the ring's 9.7 MB) into fast memory."""
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = _compiled_text(
+        lambda q, k, v, p: view_flash_attention(q, k, v, p, window=window, ring=ring, interpret=False),
+        spec((1, rows, 48, 128), jnp.bfloat16), spec((1, 1024, view), jnp.bfloat16),
+        spec((1, 1024, view), jnp.bfloat16), spec((1, rows), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "view_flash_attention" in text
+    comps, _ = _parse_hlo(text)
+    made = [ins.line for body in comps.values() for ins in body
+            if ins.shape == f"bf16[1,1024,{view}]" and ins.opcode not in _PASS_THROUGH | {"copy-start", "copy-done"}]
+    assert not made, made
+
+
+def test_trinity_chunk_attends_in_the_kernel_and_forms_no_scores_over_the_view(one_chip, monkeypatch):
+    """``bench/configs/trinity-large.json`` whole with the serve cell's pool,
+    its 512-chunk traced as a TPU traces it: five kernels (four window layers
+    on the ring, the full layer on the whole view), no float32 array over the
+    view's 32,768 or the ring's 4,736 columns left, and the temporaries no
+    larger than with the einsum (1.77 GB: sandbox compile, PR 35)."""
+    import json
+    from pathlib import Path
+
+    from accelerate_tpu.models.transformer import Transformer, TransformerConfig
+    from accelerate_tpu.ops import grouped_matmul as gm
+    from accelerate_tpu.ops import view_attention as va
+    from accelerate_tpu.serving import pool
+
+    monkeypatch.setattr(gm, "_platform_compiles", lambda: True)
+    monkeypatch.setattr(va, "_platform_compiles", lambda: True)
+    fields = json.loads((Path(__file__).resolve().parents[1] / "bench" / "configs"
+                         / "trinity-large.json").read_text())["transformer"]
+    fields["dtype"] = fields["param_dtype"] = jnp.bfloat16
+    model = Transformer(TransformerConfig(**fields))
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    lanes, page, table, ring = 8, 128, 32768 // 128, -(-(4096 + 512) // 128) + 1
+    full = [spec((1, lanes * table + 1, 8, page, 128), jnp.bfloat16)] * 2
+    rings = [spec((4, lanes * ring + 1, 8, page, 128), jnp.bfloat16)] * 2
+    i32 = lambda *s: spec(s, jnp.int32)
+    compiled = pool.make_mixed_prefill_chunk(model, 512, page).lower(
+        params, i32(1, 512), *full, *rings, i32(table), i32(ring), i32(), i32()).compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert len(set(re.findall(r"%(view_flash_attention[.\d]*) = ", text))) == 5
+    assert not re.search(r"f32\[[\d,]*,(32768|4736)\]", text)
+    assert memory.temp_size_in_bytes < 1.8e9, memory
