@@ -37,7 +37,17 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .transformer import KVCache, PagedKVCache, RMSNorm, TransformerConfig, _tag_proj, _write_rows
+from .transformer import (
+    KVCache,
+    PagedKVCache,
+    RMSNorm,
+    TransformerConfig,
+    _tag_proj,
+    _write_rows,
+    _yarn_m,
+    rope_amplitude,
+    rope_frequencies,
+)
 
 #: a cached call with at most this many new rows a lane runs absorbed.  The
 #: FLOP break-even is ``kv_rank * (nope + v) / (2 kv_rank - nope - v)`` rows
@@ -50,32 +60,6 @@ KEY_BLOCK = 1024
 
 def use_absorbed(cached: bool, new_rows: int) -> bool:
     return cached and new_rows <= ABSORB_MAX_ROWS
-
-
-def _yarn_m(factor: float, mscale: float) -> float:
-    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
-
-
-def rope_frequencies(dim: int, theta: float, yarn) -> jax.Array:
-    """``dim / 2`` rotary frequencies, YaRN-blended where ``yarn`` is given."""
-    j = jnp.arange(0, dim, 2, dtype=jnp.float32)
-    inv = 1.0 / (theta ** (j / dim))
-    if yarn is None:
-        return inv
-
-    def corr(beta):
-        return dim * math.log(yarn.original_max_position / (2 * math.pi * beta)) / (2 * math.log(theta))
-
-    low = max(math.floor(corr(yarn.beta_fast)), 0)
-    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
-    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / ((high - low) or 0.001), 0.0, 1.0)
-    return (inv / yarn.factor) * ramp + inv * (1.0 - ramp)
-
-
-def rope_amplitude(yarn) -> float:
-    if yarn is None:
-        return 1.0
-    return _yarn_m(yarn.factor, yarn.mscale) / _yarn_m(yarn.factor, yarn.mscale_all_dim)
 
 
 def softmax_scale(cfg: TransformerConfig) -> float:

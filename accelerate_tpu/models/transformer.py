@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -102,6 +103,49 @@ class YarnScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+
+
+def _yarn_m(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_frequencies(dim: int, theta: float, yarn: Optional[YarnScaling] = None) -> jax.Array:
+    """``dim / 2`` rotary frequencies ``theta^(-2j/dim)``, YaRN-blended where
+    ``yarn`` is given: the low and high correction dimensions floored and
+    ceiled, a linear ramp between them, ``inv / factor`` past it."""
+    j = jnp.arange(0, dim, 2, dtype=jnp.float32)
+    inv = 1.0 / (theta ** (j / dim))
+    if yarn is None:
+        return inv
+
+    def corr(beta):
+        return dim * math.log(yarn.original_max_position / (2 * math.pi * beta)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / ((high - low) or 0.001), 0.0, 1.0)
+    return (inv / yarn.factor) * ramp + inv * (1.0 - ramp)
+
+
+def rope_amplitude(yarn: Optional[YarnScaling]) -> float:
+    """What YaRN multiplies cos and sin by: ``m(factor, mscale) / m(factor,
+    mscale_all_dim)`` (``0.1 ln factor + 1`` at the defaults); 1 without it."""
+    if yarn is None:
+        return 1.0
+    return _yarn_m(yarn.factor, yarn.mscale) / _yarn_m(yarn.factor, yarn.mscale_all_dim)
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """The rotary encoding of one kind of layer: its ``theta`` and, where given,
+    its YaRN scaling (a :class:`YarnScaling` or its dict)."""
+
+    theta: float
+    yarn: Optional[YarnScaling] = None
+
+    def __post_init__(self):
+        if isinstance(self.yarn, dict):
+            object.__setattr__(self, "yarn", YarnScaling(**self.yarn))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -289,10 +333,13 @@ class TransformerConfig:
     # ``sliding_window`` then applies to the "window" layers only, and the
     # serving pool keeps a ring of pages for them and whole tables for the
     # "full" ones (serving/paging.py MixedKVPool).  ``rope_full_layers=False``
-    # leaves the "full" layers without any positional encoding.  None: every
-    # layer is what ``sliding_window`` and ``positional`` say.
+    # leaves the "full" layers without any positional encoding; ``full_rope``
+    # (a RopeSpec or its dict) gives them a rope of their own (theta, YaRN)
+    # while the "window" layers keep plain rope at ``rope_theta``.  None:
+    # every layer is what ``sliding_window`` and ``positional`` say.
     layer_types: Optional[Tuple[str, ...]] = None
     rope_full_layers: bool = True
+    full_rope: Optional[RopeSpec] = None
     # A sigmoid gate on the attention output, one value a query head and
     # channel, projected from the layer's normed input (``attn/gate_proj``).
     attention_gate: bool = False
@@ -342,6 +389,14 @@ class TransformerConfig:
             object.__setattr__(self, "latent_attention", LatentAttentionSpec(**self.latent_attention))
         if isinstance(self.experts, dict):
             object.__setattr__(self, "experts", ExpertSpec(**self.experts))
+        if isinstance(self.full_rope, dict):
+            object.__setattr__(self, "full_rope", RopeSpec(**self.full_rope))
+        if self.full_rope is not None and (self.layer_types is None or not self.rope_full_layers):
+            raise ValueError(
+                "full_rope is the rope of the 'full' layers of a stack of two kinds: it "
+                "needs layer_types, and rope_full_layers=False (no positions on them) "
+                "contradicts it"
+            )
         if self.retention is not None:
             from .retention import RetentionSpec
 
@@ -770,27 +825,30 @@ def _alibi_bias(n_heads: int, k_len: int) -> jax.Array:
     return (alibi_slopes(n_heads)[:, None, None] * j[None, None, :])[None]
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def _cos_sin(positions: jax.Array, d: int, theta: float, yarn=None):
+    """``cos, sin [B, S, 1, D/2]`` of the rotary angles at ``positions [B,
+    S]``; under YaRN its frequencies, both times its amplitude."""
+    freqs = rope_frequencies(d, theta, yarn)
+    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
+    # plain rope traces the operations it always did, in their order: the
+    # programs of every configuration without YaRN lower to the same text
+    amp = (lambda a: a) if yarn is None else (lambda a: a * rope_amplitude(yarn))
+    return amp(jnp.cos(angles))[:, :, None, :], amp(jnp.sin(angles))[:, :, None, :]
+
+
+def _rope(x: jax.Array, positions: jax.Array, theta: float, yarn=None) -> jax.Array:
     """Rotary embedding over the last dim of [B, S, H, D] — rotate-half
     convention (Llama/NeoX)."""
-    d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
+    cos, sin = _cos_sin(positions, x.shape[-1], theta, yarn)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 
 
-def _rope_interleaved(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def _rope_interleaved(x: jax.Array, positions: jax.Array, theta: float, yarn=None) -> jax.Array:
     """GPT-J's rotate-every-two pairing: dims (0,1), (2,3), ... form the
     rotation pairs (vs rotate-half's (i, i+D/2))."""
-    d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
+    cos, sin = _cos_sin(positions, x.shape[-1], theta, yarn)
     xf = x.astype(jnp.float32)
     x_even = xf[..., 0::2]
     x_odd = xf[..., 1::2]
@@ -801,14 +859,17 @@ def _rope_interleaved(x: jax.Array, positions: jax.Array, theta: float) -> jax.A
     return out.astype(x.dtype)
 
 
-def _apply_rope(x: jax.Array, positions: jax.Array, cfg: "TransformerConfig") -> jax.Array:
+def _apply_rope(x: jax.Array, positions: jax.Array, cfg: "TransformerConfig",
+                rope: Optional[RopeSpec] = None) -> jax.Array:
     """Config-selected rope: full or partial (first ``rope_dim`` dims),
-    rotate-half or interleaved."""
+    rotate-half or interleaved; at ``cfg.rope_theta``, or at the theta and
+    YaRN of ``rope`` (a kind of layer's own: ``cfg.full_rope``)."""
     fn = _rope_interleaved if cfg.rope_interleaved else _rope
+    theta, yarn = (cfg.rope_theta, None) if rope is None else (rope.theta, rope.yarn)
     rd = cfg.rope_dim
     if rd is None or rd >= x.shape[-1]:
-        return fn(x, positions, cfg.rope_theta)
-    rotated = fn(x[..., :rd], positions, cfg.rope_theta)
+        return fn(x, positions, theta, yarn)
+    rotated = fn(x[..., :rd], positions, theta, yarn)
     return jnp.concatenate([rotated, x[..., rd:]], axis=-1)
 
 
@@ -998,16 +1059,19 @@ class Attention(nn.Module):
         if cfg.qk_norm:
             q = RMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name="k_norm")(k)
-        # a "full" layer of two kinds sees every position, and carries no
-        # positional encoding where the configuration says so
-        window = None if self.kind == "full" else cfg.sliding_window
-        if cfg.positional == "rope" and (self.kind != "full" or cfg.rope_full_layers):
-            q = _apply_rope(q, positions, cfg)
-            k = _apply_rope(k, positions, cfg)
-
         # the device scope of a layer of two kinds (``attn/window``, ``attn/full``)
         kind_scope = lambda: (jax.named_scope(f"attn/{self.kind}") if self.kind
                               else contextlib.nullcontext())
+
+        # a "full" layer of two kinds sees every position, and carries no
+        # positional encoding where the configuration says so, or a rope of
+        # its own (``full_rope``)
+        window = None if self.kind == "full" else cfg.sliding_window
+        if cfg.positional == "rope" and (self.kind != "full" or cfg.rope_full_layers):
+            rope = cfg.full_rope if self.kind == "full" else None
+            with kind_scope():
+                q = _apply_rope(q, positions, cfg, rope)
+                k = _apply_rope(k, positions, cfg, rope)
 
         def project_out(out):
             """``out [B, S, Hq, D]`` through the output gate, where there is

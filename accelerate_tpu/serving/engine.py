@@ -119,6 +119,8 @@ _LATENCY_BUCKETS = tuple(1e-4 * 2.0**i for i in range(24))
 # ``window`` and ``live``, ``chunks``, ``chunk_tokens`` and ``emitted``.
 _STEP_CARRIED = ("decode_steps", "occupied_lane_steps", "prefill_chunks",
                  "prefill_tokens", "tokens_generated")
+# A routed engine's two more, as ``experts_hit`` and ``expert_slots``.
+_STEP_CARRIED_MOE = ("moe_experts_hit", "moe_expert_slots")
 
 # Process-wide replica ids ("e0", "e1", ...): every flight-recorder event and
 # request-trace phase an engine emits is tagged with its id so multi-replica
@@ -471,6 +473,12 @@ class ServingEngine:
         #: routed experts: decode windows and prefill chunks return the
         #: ``moe_*`` counters, fetched with the window's tokens
         self._routed = cfg.experts is not None
+        #: the held experts a decode step's expert layers could read
+        self._expert_slots_a_step = (
+            cfg.experts.num_held * (cfg.num_layers - cfg.experts.dense_layers) if self._routed else 0)
+        #: the counters ``serve/step`` closes with (``_STEP_CARRIED``, and a
+        #: routed engine's ``experts_hit`` / ``expert_slots``)
+        self._step_carried = _STEP_CARRIED + (_STEP_CARRIED_MOE if self._routed else ())
         #: a retention model: the pool is the lanes' recurrent state
         #: (:class:`StatePool`), its windows return the ``state_*`` counters,
         #: and every page-speaking step of the lane lifecycle has nothing to do
@@ -952,8 +960,11 @@ class ServingEngine:
         if self._routed:
             # token-expert choices made, those that fell on experts held
             # here, and (decode windows) held experts that got a row, summed
-            # over steps and layers: live lanes and valid prompt rows only
-            self.stats.update(moe_pairs_total=0, moe_pairs_here=0, moe_experts_hit=0)
+            # over steps and layers: live lanes and valid prompt rows only;
+            # and what that last one is out of, held experts x expert layers
+            # x the steps of each decode window drained (counted on the host)
+            self.stats.update(moe_pairs_total=0, moe_pairs_here=0, moe_experts_hit=0,
+                              moe_expert_slots=0)
         if self._stateful:
             # lane-steps whose state a decode window read and rewrote (lanes x
             # steps), those that emitted a token, and lanes zeroed at install
@@ -2538,6 +2549,7 @@ class ServingEngine:
             # experts hit is a decode-step quantity (a chunk of hundreds of
             # rows hits every expert): the window's own counter, parked first
             self._bump("moe_experts_hit", int(moe[0][2]))
+            self._bump("moe_expert_slots", self._expert_slots_a_step * hd.width)
         hd.moe_counts = []
         # overlap accounting: host work since dispatch ran under the device;
         # the blocking tail is what the pipeline failed to hide.  Under
@@ -3023,7 +3035,7 @@ class ServingEngine:
             raise self._poisoned
         try:
             stats = self.stats
-            before = [stats[k] for k in _STEP_CARRIED]
+            before = [stats[k] for k in self._step_carried]
             with self.tracer.span(
                 "serve/step", queue=self.scheduler.queue_depth
             ) as span:
@@ -3032,12 +3044,16 @@ class ServingEngine:
                 # what this step carried, from the counters its parts bumped:
                 # at most one window a step, so the lanes live at its dispatch
                 # are its lane-steps over its width
-                steps, lane_steps, chunks, chunk_tokens, emitted = (
-                    stats[k] - b for k, b in zip(_STEP_CARRIED, before))
+                steps, lane_steps, chunks, chunk_tokens, emitted, *moe = (
+                    stats[k] - b for k, b in zip(self._step_carried, before))
                 span["window"] = int(steps > 0)
                 span["live"] = lane_steps // steps if steps else 0
                 span["chunks"], span["chunk_tokens"] = chunks, chunk_tokens
                 span["emitted"] = emitted
+                if moe:
+                    # the held experts the windows it drained read, of those
+                    # their steps could have read
+                    span["experts_hit"], span["expert_slots"] = moe
         except Exception as exc:
             self._poisoned = exc
             self.recorder.record(
