@@ -669,6 +669,12 @@ class MixedKVCache(struct.PyTreeNode):
       least ``W`` later, which no query that still sees the old one can have
       written (:func:`cached_attention`, ``ring=True``).
 
+    Each of the four may instead be a tuple of one array a layer, ``[B, Hkv *
+    Dh, M]`` each (the decode window's views where the page copy kernel builds
+    them): a layer then writes and reads its own array whole, where a layer of
+    a stacked array is a static slice that the compiler copies out of the
+    carried array at every step.
+
     ``index`` is a :class:`KVCache`'s.  ``page`` (static) is the granule of a
     chunk's write: a chunk starts on a page boundary and is whole pages long,
     so it lands in the ring page by page and a page never straddles the ring's
@@ -685,7 +691,7 @@ class MixedKVCache(struct.PyTreeNode):
 
     @property
     def max_len(self) -> int:
-        return self.k.shape[3]
+        return (self.k[0] if isinstance(self.k, tuple) else self.k).shape[-1]
 
 
 def create_cache(config: "TransformerConfig", batch_size: int, max_len: Optional[int] = None,
@@ -1012,6 +1018,18 @@ def _write_ring(buf, new, index, layer, page: int):
     return buf
 
 
+def _write_kind(buf, new, index, at: int, page: Optional[int]):
+    """``new`` written into layer ``at`` of one kind's arrays of a
+    :class:`MixedKVCache`: the stacked array ``[L, B, H*D, M]``, or the tuple
+    of one array a layer, where only that layer's array is replaced.  ``page``:
+    a ring's (:func:`_write_ring`), ``None`` for a full layer's columns."""
+    write = (lambda b, layer: _write_columns(b, new, index, layer)) if page is None else (
+        lambda b, layer: _write_ring(b, new, index, layer, page))
+    if isinstance(buf, tuple):
+        return buf[:at] + (write(buf[at], None),) + buf[at + 1:]
+    return write(buf, at)
+
+
 class Attention(nn.Module):
     config: TransformerConfig
     # "window" / "full" in a stack of two kinds (``config.layer_types``; set
@@ -1086,17 +1104,15 @@ class Attention(nn.Module):
         if isinstance(cache, MixedKVCache):
             # this kind's arrays, the layer's place among its kind
             at_kind = cfg.layer_types[:layer].count(self.kind)
+            ring = self.kind == "window"
+            names = ("k_ring", "v_ring") if ring else ("k", "v")
             with kind_scope():
-                if self.kind == "window":
-                    k_all = _write_ring(cache.k_ring, k, cache.index, at_kind, cache.page)
-                    v_all = _write_ring(cache.v_ring, v, cache.index, at_kind, cache.page)
-                    cache = cache.replace(k_ring=k_all, v_ring=v_all)
-                else:
-                    k_all = _write_columns(cache.k, k, cache.index, at_kind)
-                    v_all = _write_columns(cache.v, v, cache.index, at_kind)
-                    cache = cache.replace(k=k_all, v=v_all)
+                k_all, v_all = (_write_kind(getattr(cache, name), new, cache.index, at_kind,
+                                            cache.page if ring else None)
+                                for name, new in zip(names, (k, v)))
+                cache = cache.replace(**dict(zip(names, (k_all, v_all))))
                 out = cached_attention(q, k_all[at_kind], v_all[at_kind], positions,
-                                       window=window, ring=self.kind == "window")
+                                       window=window, ring=ring)
             return project_out(out), cache
         # the stacked cache of every layer, addressed at ``layer``; or this
         # layer's own arrays in a tuple, addressed whole

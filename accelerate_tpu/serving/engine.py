@@ -64,6 +64,7 @@ from ..logging import get_logger
 from ..models.generation import GenerationConfig
 from ..models.transformer import Transformer
 from ..ops.view_attention import KEY_BLOCK, view_flash_applies
+from ..ops.view_gather import view_gather_applies
 from ..telemetry import (
     CostTable,
     MetricsRegistry,
@@ -78,7 +79,7 @@ from ..telemetry import (
 )
 from . import faults, transfer
 from .errors import AdmissionError
-from .paging import DraftContextWindow, MixedKVPool, PagedKVPool, StatePool
+from .paging import NULL_PAGE, DraftContextWindow, MixedKVPool, PagedKVPool, StatePool
 from .pool import (
     ServeShardings,
     audit_donation,
@@ -988,6 +989,14 @@ class ServingEngine:
             # ``max_len``-wide view that a dispatched chunk could see a key in,
             # and that the view has: counted on the host, where ``base`` is known
             self.stats.update(chunk_key_blocks_live=0, chunk_key_blocks_view=0)
+        #: whether the decode window gathers flat views of its pages (not where
+        #: it reads pages in place, latent rows or a recurrent state)
+        self._gathers_view = not (self._stateful or self._direct or cfg.latent_attention is not None)
+        if self._gathers_view:
+            # (lane, page slot) blocks the decode windows' flat views were
+            # filled with, over every such array, and those copied from a live
+            # page: counted on the host at dispatch, from the tables and indices
+            self.stats.update(view_slots_live=0, view_slots=0)
         self.stats.engine = self
         self._counters = {
             k: self.metrics.counter(f"serve/{k}_total") for k in self.stats
@@ -1163,6 +1172,17 @@ class ServingEngine:
             help="1 where the prefill chunks' attention over the gathered views runs "
                  "in the Pallas flash kernel, 0 where it is XLA's masked softmax",
         ).set(float(self.chunk_attention_kernel))
+        #: whether the decode window's flat views are built by the Pallas page
+        #: copy (``ops/view_gather.py``): what ``_gather_view`` will see when the
+        #: window is traced; never under ``tp > 1`` (``pool._kernel_form``)
+        self.view_gather_kernel = self._gathers_view and self.tp_degree == 1 and all(
+            view_gather_applies(pages) for pages in
+            ((self.kv.pages_k, self.kv.ring_k) if self._mixed else (self.kv.pages_k,)))
+        self.metrics.gauge(
+            "serve/view_gather_kernel",
+            help="1 where the decode window's gathered views are filled by the Pallas "
+                 "page copy, 0 where by a zero fill and one update a page",
+        ).set(float(self.view_gather_kernel))
         self._kv_quant_gauge = (
             self.metrics.gauge(
                 "serve/kv_quant_error",
@@ -2647,6 +2667,23 @@ class ServingEngine:
             n = max(int(counts[s]), 1)
             self._decode_tok_hist.observe(dur / n, n)
 
+    def _count_view_slots(self) -> None:
+        """The (lane, page slot) blocks the decode window about to be dispatched
+        fills its flat views with, K's and V's arrays each, and those copied
+        from a live page: a full table's slots as ``pool._live_tables`` masks
+        them with the lanes' indices, a ring's whole; the rest hold the null
+        page."""
+        kv = self.kv
+        live = (self._lane_len + self.window - 1) // self.page_size + 1
+        held = int(np.count_nonzero((np.arange(kv.tables.shape[1]) < live[:, None])
+                                    & (kv.tables != NULL_PAGE)))
+        slots = kv.tables.size
+        if self._mixed:
+            held += int(np.count_nonzero(kv.ring_tables != NULL_PAGE))
+            slots += kv.ring_tables.size
+        self._bump("view_slots_live", 2 * held)
+        self._bump("view_slots", 2 * slots)
+
     def _decode_cycle(self, n_occupied: int) -> Readback:
         """Dispatch one decode window and return its in-flight handle.  The
         tokens stay on device: the caller decides when to drain (immediately
@@ -2677,6 +2714,7 @@ class ServingEngine:
             consumed += [tables, ring_tables, index]
             args = (self.params, kv.pages_k, kv.pages_v, kv.ring_k, kv.ring_v, tables,
                     ring_tables, index, *lanes)
+            self._count_view_slots()
             if not self.cost_table.captured("serve/decode_window"):
                 self.cost_table.capture("serve/decode_window", self._decode, args)
             with self.tracer.span("serve/decode_window", occupied=n_occupied,
@@ -2711,6 +2749,8 @@ class ServingEngine:
             tables = self._put(kv.tables)
             index = self._put(self._lane_len)
             consumed += [tables, index]
+            if self._gathers_view:
+                self._count_view_slots()
             if not self.cost_table.captured("serve/decode_window"):
                 self.cost_table.capture(
                     "serve/decode_window", self._decode,

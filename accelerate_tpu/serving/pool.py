@@ -107,6 +107,7 @@ from ..models.generation import sample_tokens_batched
 from ..models.retention import StateCache
 from ..models.transformer import KVCache, MixedKVCache, PagedKVCache, Transformer
 from ..ops.view_attention import xla_form
+from ..ops.view_gather import gather_pages, view_gather_applies
 from ..parallel.mesh import mesh_axis_size
 from ..utils.jax_compat import jit_cache_size
 from .paging import NULL_PAGE
@@ -718,6 +719,15 @@ def make_state_decode_window(model: Transformer, window: int,
     )
 
 
+def _kernel_form(shardings: Optional[ServeShardings]):
+    """The context a program's gathered arm is traced in.  Under ``tp > 1`` its
+    pool and views are sharded over key/value heads and a ``pallas_call`` has no
+    partitioning rule, so the chunk's flash kernel (``ops/view_attention.py``)
+    and the view's page copy (``ops/view_gather.py``) give way to their XLA
+    forms: :func:`~accelerate_tpu.ops.view_attention.xla_form`."""
+    return xla_form if shardings is not None and shardings.tp_degree > 1 else contextlib.nullcontext
+
+
 def _flat_view(model: Transformer) -> bool:
     """Which gathered view the model's attention reads, from its
     configuration: the per-head block's ``[L, N, H * D, M]`` (rows flat,
@@ -731,15 +741,18 @@ def _gather_view(pages, tables, flat: bool):
     contiguous per-lane view: ``[L, N, H * D, P * page]`` if ``flat``, else
     ``[L, N, P * page, H, D]``.
 
-    The flat view is filled page by page: on the chip the pool is ``[.., H, D,
-    page]``, ``page`` minor, so a page is a block ``[L, H * D, page]`` of whole
-    tiles that goes into the view's columns ``p * page ..`` as it lies.  One
-    ``dynamic_update_slice`` a (lane, page slot), both offsets static and only
-    the page's id traced: the compiler's own gather collects the pages in
-    table order first and then passes the whole view into its layout (twice,
-    once to transpose and once to re-tile), an update at a traced column is
-    served element by element, and for a traced lane the pool is copied
-    page-major first."""
+    The flat view is filled a page block at a time: a page is a block ``[L, H
+    * D, page]`` of whole tiles that goes into the view's columns ``p * page
+    ..``.  Where the platform compiles it (:func:`~accelerate_tpu.ops
+    .view_gather.view_gather_applies`) one Pallas kernel writes every block
+    (:func:`~accelerate_tpu.ops.view_gather.gather_pages`); elsewhere the view
+    is zero-filled and each (lane, page slot) put in by one
+    ``dynamic_update_slice``, both offsets static and only the page's id traced.
+    Neither is the compiler's own gather, which collects the pages in table
+    order first and then passes the whole view into its layout (twice, once to
+    transpose and once to re-tile); an update at a traced column is served
+    element by element, and for a traced lane the pool is copied page-major
+    first."""
     L, _, H, page, D = pages.shape
     N, P = tables.shape
     if not flat:
@@ -747,15 +760,30 @@ def _gather_view(pages, tables, flat: bool):
                 .transpose(0, 1, 2, 4, 3, 5)
                 .reshape(L, N, P * page, H, D))
 
-    view = jnp.zeros((L, N, H * D, P * page), pages.dtype)
-    for n in range(N):
-        for p in range(P):
-            block = jax.lax.dynamic_slice_in_dim(pages, tables[n, p], 1, axis=1)
-            block = block.swapaxes(3, 4).reshape(L, 1, H * D, page)
-            view = jax.lax.dynamic_update_slice(view, block, (0, n, 0, p * page))
+    if view_gather_applies(pages):
+        view = gather_pages(pages, tables)
+    else:
+        view = jnp.zeros((L, N, H * D, P * page), pages.dtype)
+        for n in range(N):
+            for p in range(P):
+                block = jax.lax.dynamic_slice_in_dim(pages, tables[n, p], 1, axis=1)
+                block = block.swapaxes(3, 4).reshape(L, 1, H * D, page)
+                view = jax.lax.dynamic_update_slice(view, block, (0, n, 0, p * page))
     # as written: left alone the compiler fills the view lane-major (the order
     # it gives the first block's unit axis) and copies it for the model
     return with_layout_constraint(view, Layout(major_to_minor=(0, 1, 2, 3)))
+
+
+def _gather_layers(pages, tables):
+    """:func:`_gather_view`'s flat view as one array a layer, ``[N, H * D, P *
+    page]`` each, where the page copy kernel builds it (one call a layer);
+    elsewhere the stacked view as written.  The decode scan carries each
+    layer's view whole: a layer of a stacked view is a static slice, which the
+    compiler copies out of the carried array at every step (the 32-lane cell's
+    two full layers: four copies of 268 MB a step)."""
+    if not view_gather_applies(pages):
+        return _gather_view(pages, tables, True)
+    return tuple(gather_pages(pages, tables, layer=layer) for layer in range(pages.shape[0]))
 
 
 def _gather_columns(pages, tables):
@@ -767,10 +795,11 @@ def _gather_columns(pages, tables):
     cell's decode window 163 s here for the described chip and 245 s on it,
     against 22 s; a 512-chunk 23 s against 9).  The mixed PREFILL CHUNK takes
     this form: one lane's views, and it runs as fast either way (19.3 against
-    19.8 ms; my chip runs, PR 35).  The mixed DECODE WINDOW keeps the page-wide
-    updates: alone this gather fills 8 lanes of 256 one-layer pages in 2.4 ms
-    against 6.6, but the window that holds it runs 41.5 ms against 35.4 (the
-    gather's output passes through two more layouts before the scan takes it)."""
+    19.8 ms on one TPU v5e).  The mixed DECODE WINDOW takes
+    :func:`_gather_view` (the page copy kernel on the chip): alone this gather
+    fills 8 lanes of 256 one-layer pages in 2.4 ms against the updates' 6.6,
+    but the window that holds it runs 41.5 ms against 35.4 (the gather's output
+    passes through two more layouts before the scan takes it)."""
     L, _, H, page, D = pages.shape
     N, P = tables.shape
     return (pages[:, tables]                             # [L, N, P, H, page, D]
@@ -847,10 +876,15 @@ def _store_span_pages(pages, view, tables, start, width: int, active, flat: bool
 
 def _store_flat_page(pages, view, lane: int, column, page_id):
     """Columns ``column .. column + page - 1`` of lane ``lane`` of the flat
-    ``view [L, N, H * D, M]`` stored as page ``page_id`` of ``pages``, in
-    place: on the chip the two blocks are the same tiles in the same order."""
+    ``view [L, N, H * D, M]`` (or its tuple of one ``[N, H * D, M]`` a layer)
+    stored as page ``page_id`` of ``pages``, in place: on the chip the two
+    blocks are the same tiles in the same order."""
     L, _, H, page, D = pages.shape
-    block = jax.lax.dynamic_slice(view, (0, lane, 0, column), (L, 1, H * D, page))
+    if isinstance(view, tuple):              # one view a layer: :func:`_gather_layers`
+        block = jnp.stack([jax.lax.dynamic_slice(one, (lane, 0, column), (1, H * D, page))
+                           for one in view])
+    else:
+        block = jax.lax.dynamic_slice(view, (0, lane, 0, column), (L, 1, H * D, page))
     return jax.lax.dynamic_update_slice(
         pages, block.reshape(L, 1, H, D, page).swapaxes(3, 4), (0, page_id, 0, 0, 0))
 
@@ -892,9 +926,7 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
     s = _unrouted(model, shardings)
     counted = _routed(model)
     flat = _flat_view(model)
-    # views sharded over key/value heads keep the XLA attention: the chunk's
-    # flash kernel has no partitioning rule (``ops/view_attention.py``)
-    form = xla_form if s is not None and s.tp_degree > 1 else contextlib.nullcontext
+    form = _kernel_form(s)
 
     if direct:
         def direct_prefill_chunk(params, tokens, pages_k, pages_v, k_scales,
@@ -925,13 +957,13 @@ def make_paged_prefill_chunk(model: Transformer, chunk_len: int, page_size: int,
     def chunk(params, tokens, pages_k, pages_v, table, base, valid):
         live = (base + chunk_len - 1) // page_size + 1
         gt = _live_tables(table, live)
-        cache = KVCache(
-            k=_gather_view(pages_k, gt[None], flat),
-            v=_gather_view(pages_v, gt[None], flat),
-            index=base,
-        )
-        rows = None if valid is None else jnp.arange(chunk_len)[None, :] < valid
         with form():
+            cache = KVCache(
+                k=_gather_view(pages_k, gt[None], flat),
+                v=_gather_view(pages_v, gt[None], flat),
+                index=base,
+            )
+            rows = None if valid is None else jnp.arange(chunk_len)[None, :] < valid
             _, cache, counts = _forward(model, params, tokens, cache, rows)
         ids = jax.lax.dynamic_slice(table, (base // page_size,), (npg,))
 
@@ -1037,11 +1069,12 @@ def make_paged_decode_window(model: Transformer, window: int,
                             pad, rngs):
         page = pages_k.shape[3]
         gt = _live_tables(tables, (index + window - 1) // page + 1)
-        cache = KVCache(
-            k=_gather_view(pages_k, gt, flat),
-            v=_gather_view(pages_v, gt, flat),
-            index=index,
-        )
+        with _kernel_form(s)():
+            cache = KVCache(
+                k=_gather_view(pages_k, gt, flat),
+                v=_gather_view(pages_v, gt, flat),
+                index=index,
+            )
         cache, toks, tok, rngs, counts = _decode_scan(
             model, window, params, cache, tokens, active, eos, do_sample,
             temperature, top_k, top_p, pad, rngs,
@@ -1126,9 +1159,9 @@ def make_mixed_decode_window(model: Transformer, window: int):
         page = pages_k.shape[3]
         gt = _live_tables(tables, (index + window - 1) // page + 1)
         cache = MixedKVCache(
-            k=_gather_view(pages_k, gt, True), v=_gather_view(pages_v, gt, True),
-            k_ring=_gather_view(ring_k, ring_tables, True),
-            v_ring=_gather_view(ring_v, ring_tables, True),
+            k=_gather_layers(pages_k, gt), v=_gather_layers(pages_v, gt),
+            k_ring=_gather_layers(ring_k, ring_tables),
+            v_ring=_gather_layers(ring_v, ring_tables),
             index=index, page=page,
         )
         cache, toks, tok, rngs, counts = _decode_scan(
@@ -1198,11 +1231,12 @@ def make_paged_verify_window(model: Transformer, k: int, direct: bool = False,
                             pad, rngs):
         page = pages_k.shape[3]
         gt = _live_tables(tables, (index + kp1 - 1) // page + 1)
-        cache = KVCache(
-            k=_gather_view(pages_k, gt, flat),
-            v=_gather_view(pages_v, gt, flat),
-            index=index,
-        )
+        with _kernel_form(s)():
+            cache = KVCache(
+                k=_gather_view(pages_k, gt, flat),
+                v=_gather_view(pages_v, gt, flat),
+                index=index,
+            )
         cache, out, n_commit, new_pending, new_rngs = _verify_body(
             model, k, params, cache, tokens, active, eos, do_sample,
             temperature, top_k, top_p, pad, rngs,
@@ -1333,11 +1367,12 @@ def make_paged_tree_verify_window(model: Transformer, tree,
                                  top_k, top_p, pad, rngs):
         page = pages_k.shape[3]
         gt = _live_tables(tables, (index + s_nodes - 1) // page + 1)
-        cache = KVCache(
-            k=_gather_view(pages_k, gt, flat),
-            v=_gather_view(pages_v, gt, flat),
-            index=index,
-        )
+        with _kernel_form(s)():
+            cache = KVCache(
+                k=_gather_view(pages_k, gt, flat),
+                v=_gather_view(pages_v, gt, flat),
+                index=index,
+            )
         cache, out, n_commit, new_pending, new_rngs = _tree_verify_body(
             model, tree, params, cache, tokens, active, eos, do_sample,
             temperature, top_k, top_p, pad, rngs,
