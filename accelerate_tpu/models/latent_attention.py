@@ -26,7 +26,12 @@ with a cache and at most :data:`ABSORB_MAX_ROWS` new rows a lane (decode and
 verify windows: a few rows against a long context) the absorbed form; without
 a cache, and for prefill chunks, the decompressed form, in blocks of keys up
 to the last live one so that neither the decompressed keys nor the scores of
-the whole ``max_len`` are ever materialised.
+the whole ``max_len`` are ever materialised.  A cached chunk whose shapes the
+Pallas kernel takes (:func:`~accelerate_tpu.ops.latent_view_attention
+.latent_flash_applies`: bfloat16, 128 rows or more, on a TPU) runs the
+decompressed form there, with the decompression and the scores in fast memory
+(:func:`~accelerate_tpu.ops.latent_view_attention.latent_view_attention`);
+:func:`attend_decompressed` serves the rest.
 """
 
 from __future__ import annotations
@@ -37,6 +42,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.latent_view_attention import latent_flash_applies, latent_view_attention
 from .transformer import (
     KVCache,
     PagedKVCache,
@@ -205,9 +211,9 @@ class LatentAttention(nn.Module):
             q_nope = q[..., :la.nope_dim]
             q_pe = rope_pairs(q[..., la.nope_dim:], positions, cfg)
             k_pe = rope_pairs(kv_a[..., None, la.kv_rank:], positions, cfg)    # [B,S,1,rope]
-            w_ukv = _Kernel((la.kv_rank, h * (la.nope_dim + la.v_dim)), cfg.param_dtype,
-                            name="kv_b_proj")().astype(cfg.dtype)
-            w_ukv = w_ukv.reshape(la.kv_rank, h, la.nope_dim + la.v_dim)
+            kv_b = _Kernel((la.kv_rank, h * (la.nope_dim + la.v_dim)), cfg.param_dtype,
+                           name="kv_b_proj")().astype(cfg.dtype)
+            w_ukv = kv_b.reshape(la.kv_rank, h, la.nope_dim + la.v_dim)
             w_uk, w_uv = w_ukv[..., :la.nope_dim], w_ukv[..., la.nope_dim:]
         scale = softmax_scale(cfg)
         new_cache = None
@@ -228,8 +234,14 @@ class LatentAttention(nn.Module):
                 out = attend_absorbed(q_nope, q_pe, keys, key_pe, w_uk, w_uv, q_slots, scale)
         else:
             with jax.named_scope("mla/attend_prefill"):
-                out = attend_decompressed(q_nope, q_pe, keys, key_pe, w_uk, w_uv, q_slots, scale,
-                                          live_only=cache is not None)
+                if cache is not None and latent_flash_applies(q_nope, q_pe, keys, kv_b):
+                    # the views as the cache holds them: a stacked one's layer is
+                    # picked inside the kernel, so none is sliced out for it
+                    out = latent_view_attention(q_nope, q_pe, lat_buf[..., 0, :], pe_buf[..., 0, :], kv_b,
+                                                q_slots, scale, layer=layer if stacked else None)
+                else:
+                    out = attend_decompressed(q_nope, q_pe, keys, key_pe, w_uk, w_uv, q_slots, scale,
+                                              live_only=cache is not None)
         with jax.named_scope("mla/project"):
             out = _tag_proj(dense("o_proj", cfg.hidden_size)(out.reshape(b, s, h * la.v_dim)))
         return out if cache is None else (out, new_cache)

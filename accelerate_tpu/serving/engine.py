@@ -63,6 +63,7 @@ import numpy as np
 from ..logging import get_logger
 from ..models.generation import GenerationConfig
 from ..models.transformer import Transformer
+from ..ops.latent_view_attention import latent_flash_applies
 from ..ops.view_attention import KEY_BLOCK, view_flash_applies
 from ..ops.view_gather import view_gather_applies
 from ..telemetry import (
@@ -978,11 +979,11 @@ class ServingEngine:
             # summed over lane-steps (counted on the device, in the window)
             self.stats.update(kv_pages_taken=0, kv_pages_released_window=0,
                               kv_rows_live=0, kv_rows_live_window=0)
-        #: the widths of the views a chunk's ``cached_attention`` reads (the
+        #: the widths of the views a chunk's attention reads (the
         #: ``max_len``-wide one first): none where the chunk reads pages in
-        #: place (the Pallas prefill kernel), latent rows or a recurrent state
+        #: place (the Pallas prefill kernel) or a recurrent state
         self._chunk_views = ()
-        if not (self._stateful or self.prefill_kernel == "pallas" or cfg.latent_attention is not None):
+        if not (self._stateful or self.prefill_kernel == "pallas"):
             self._chunk_views = (self.kv.tables.shape[1] * self.page_size,) + (
                 (self.kv.ring_pages * self.page_size,) if self._mixed else ())
             # key blocks (``ops/view_attention.py`` ``KEY_BLOCK`` columns) of the
@@ -1159,14 +1160,11 @@ class ServingEngine:
                      "matmul, 0 where they are jax.lax.ragged_dot",
             ).set(float(self.moe_grouped_kernel))
         #: whether a prefill chunk's attention over its gathered views runs in
-        #: the Pallas flash kernel (``ops/view_attention.py``): what
-        #: ``cached_attention`` will see when the chunk programs are traced
+        #: a Pallas flash kernel (``ops/view_attention.py``, or
+        #: ``ops/latent_view_attention.py`` for latent rows): what the
+        #: attention will see when the chunk programs are traced
         self.chunk_attention_kernel = self.tp_degree == 1 and cfg.positional != "alibi" and any(
-            view_flash_applies(
-                jax.ShapeDtypeStruct((1, b, cfg.num_heads, cfg.resolved_head_dim), cfg.dtype),
-                jax.ShapeDtypeStruct((1, cfg.num_kv_heads * cfg.resolved_head_dim, m),
-                                     cfg.dtype if self.quantized else self.kv.pages_k.dtype))
-            for b in self.buckets for m in self._chunk_views)
+            self._chunk_kernel_applies(b, m) for b in self.buckets for m in self._chunk_views)
         self.metrics.gauge(
             "serve/chunk_attention_kernel",
             help="1 where the prefill chunks' attention over the gathered views runs "
@@ -1231,6 +1229,23 @@ class ServingEngine:
         # per-step deadline sweep off the hot path until a deadline exists
         self._service_ema = 0.0
         self._has_deadlines = False
+
+    def _chunk_kernel_applies(self, rows: int, m: int) -> bool:
+        """Whether a chunk of ``rows`` attends over an ``m``-wide view in a
+        flash kernel, by the rule its attention applies when traced: the latent
+        kernel's for latent rows, the view kernel's for the others."""
+        cfg, shape = self.config, jax.ShapeDtypeStruct
+        la = cfg.latent_attention
+        if la is not None:
+            h = cfg.num_heads
+            return latent_flash_applies(
+                shape((1, rows, h, la.nope_dim), cfg.dtype), shape((1, rows, h, la.rope_dim), cfg.dtype),
+                shape((1, m, la.kv_rank), self.kv.pages_k.dtype),
+                shape((la.kv_rank, h * (la.nope_dim + la.v_dim)), cfg.dtype))
+        return view_flash_applies(
+            shape((1, rows, cfg.num_heads, cfg.resolved_head_dim), cfg.dtype),
+            shape((1, cfg.num_kv_heads * cfg.resolved_head_dim, m),
+                  cfg.dtype if self.quantized else self.kv.pages_k.dtype))
 
     def _bump(self, key: str, n: int = 1) -> None:
         self.stats[key] += n

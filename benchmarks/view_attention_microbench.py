@@ -1,15 +1,19 @@
 """The chunk's view attention alone, on the chip: ``ops/view_attention.py`` beside
 ``cached_attention``'s masked einsum at the long-document cell's shapes (48 query
 and 8 key/value heads of 128, a full view of 32,768 columns and a ring of 37 x
-128), at 2 k / 8 k / 24 k live keys and on the ring before and after it wraps.
+128), at 2 k / 8 k / 24 k live keys and on the ring before and after it wraps;
+then ``ops/latent_view_attention.py`` beside ``attend_decompressed``'s blocked
+loop at DeepSeek-V2's widths (128 heads, ``kv_rank`` 512, nope 128, rope 64, v
+128, a view of 8,192) at one to eight live key blocks of 1,024.
 
-    python3 benchmarks/view_attention_microbench.py [out.jsonl] [--blocks 256,512,1024]
+    python3 benchmarks/view_attention_microbench.py [out.jsonl] [--blocks 256,512,1024] [--latent-only]
 
 One JSON line a measurement: milliseconds a call (host clock over ``CALLS`` calls
 of one jitted function, the last waited for), the FLOPs the live keys need
-(``4 x Hq x S x keys x D``: masked pairs inside the last blocks counted, dead
-blocks not) as a share of 197 TFLOP/s, and the largest difference from the
-einsum's output.  ``docs/kernels/view_attention.md`` quotes it.  Exits non-zero
+(``4 x Hq x S x keys x D``; the latent case adds the decompression, ``2 x H x
+keys x kv_rank x (nope + v)``, and scores over ``nope + rope``: masked pairs
+inside the last blocks counted, dead blocks not) as a share of 197 TFLOP/s, and
+the largest difference from the XLA form's output.  ``docs/kernels/view_attention.md`` quotes it.  Exits non-zero
 off a TPU: a time from anything else is no measurement.
 """
 
@@ -24,10 +28,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from accelerate_tpu.models.latent_attention import attend_decompressed  # noqa: E402
 from accelerate_tpu.models.transformer import cached_attention  # noqa: E402
+from accelerate_tpu.ops.latent_view_attention import latent_view_attention  # noqa: E402
 from accelerate_tpu.ops.view_attention import KEY_BLOCK, view_flash_attention, xla_form  # noqa: E402
 
 HQ, HKV, D, FULL, RING, WINDOW = 48, 8, 128, 32768, 37 * 128, 4096
+#: DeepSeek-V2's latent attention: heads, kv_rank, nope, rope, v, the view
+MLA_H, KV_RANK, NOPE, ROPE, V, MLA_VIEW = 128, 512, 128, 64, 128, 8192
 PEAK, CALLS = 197e12, 20
 BF16 = jnp.bfloat16
 
@@ -58,8 +66,14 @@ def main():
             with open(out_path, "a") as fh:
                 fh.write(json.dumps(record) + "\n")
 
-    keys = jax.random.split(jax.random.PRNGKey(38), 3)
     draw = lambda key, shape: jax.jit(lambda k: jax.random.normal(k, shape, jnp.float32).astype(BF16))(key)
+    if "--latent-only" not in sys.argv:
+        views(blocks, draw, emit)
+    latent(draw, emit)
+
+
+def views(blocks, draw, emit):
+    keys = jax.random.split(jax.random.PRNGKey(38), 3)
     cases = [(rows, FULL, base, None, False) for rows in (512, 128) for base in (2048 - rows, 8192 - rows, 24576 - rows)]
     cases += [(rows, RING, base, WINDOW, True) for rows in (512, 128) for base in (1024, 16384)]
     for rows, m, base, window, ring in cases:
@@ -89,6 +103,37 @@ def main():
                 continue
             gap = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))))
             emit(**case, form="kernel", block=block, ms=ms, peak_pct=100 * flops / (ms * 1e-3) / PEAK,
+                 max_abs_gap=gap, finite=bool(np.isfinite(np.asarray(got, np.float32)).all()))
+
+
+def latent(draw, emit):
+    """DeepSeek-V2's 512- and 128-chunks at one to eight live key blocks of its
+    8,192-wide view: the kernel against the XLA loop it replaces."""
+    keys = jax.random.split(jax.random.PRNGKey(44), 5)
+    w_ukv = draw(keys[4], (KV_RANK, MLA_H * (NOPE + V))) * KV_RANK ** -0.5
+    scale = (NOPE + ROPE) ** -0.5
+
+    def xla(q_nope, q_pe, lat, k_pe, w_ukv, positions):
+        w = w_ukv.reshape(KV_RANK, MLA_H, NOPE + V)
+        return attend_decompressed(q_nope, q_pe, lat, k_pe, w[..., :NOPE], w[..., NOPE:], positions, scale)
+
+    kernel = jax.jit(lambda *a: latent_view_attention(*a, scale))
+    for rows in (512, 128):
+        q_nope, q_pe = draw(keys[0], (1, rows, MLA_H, NOPE)), draw(keys[1], (1, rows, MLA_H, ROPE))
+        for live_blocks in (1, 2, 3, 5, 8):
+            base = live_blocks * KEY_BLOCK - rows
+            # a view as the gather leaves it: zeros where nothing was written
+            written = (jnp.arange(MLA_VIEW) < base + rows).astype(BF16)[None, :, None]
+            lat, k_pe = draw(keys[2], (1, MLA_VIEW, KV_RANK)) * written, draw(keys[3], (1, MLA_VIEW, ROPE)) * written
+            positions = base + jnp.arange(rows, dtype=jnp.int32)[None]
+            seen = live_blocks * KEY_BLOCK
+            flops = MLA_H * (2 * seen * KV_RANK * (NOPE + V) + 2 * rows * seen * (NOPE + ROPE + V))
+            case = dict(case="latent", rows=rows, view=MLA_VIEW, base=base, live_keys=seen)
+            ms, want = timed(jax.jit(xla), q_nope, q_pe, lat, k_pe, w_ukv, positions)
+            emit(**case, form="xla", ms=ms, peak_pct=100 * flops / (ms * 1e-3) / PEAK)
+            ms, got = timed(kernel, q_nope, q_pe, lat, k_pe, w_ukv, positions)
+            gap = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.reshape(got.shape).astype(jnp.float32))))
+            emit(**case, form="kernel", ms=ms, peak_pct=100 * flops / (ms * 1e-3) / PEAK,
                  max_abs_gap=gap, finite=bool(np.isfinite(np.asarray(got, np.float32)).all()))
 
 

@@ -39,6 +39,7 @@ from jax.sharding import SingleDeviceSharding
 
 from accelerate_tpu.ops.flash_attention import flash_attention
 from accelerate_tpu.ops.grouped_matmul import grouped_matmul
+from accelerate_tpu.ops.latent_view_attention import latent_view_attention
 from accelerate_tpu.ops.paged_attention import (
     KV_FORMATS,
     paged_attention,
@@ -769,11 +770,13 @@ def test_trinity_chunk_attends_in_the_kernel_and_forms_no_scores_over_the_view(o
 
     from accelerate_tpu.models.transformer import Transformer, TransformerConfig
     from accelerate_tpu.ops import grouped_matmul as gm
+    from accelerate_tpu.ops import latent_view_attention as lva
     from accelerate_tpu.ops import view_attention as va
     from accelerate_tpu.serving import pool
 
     monkeypatch.setattr(gm, "_platform_compiles", lambda: True)
     monkeypatch.setattr(va, "_platform_compiles", lambda: True)
+    monkeypatch.setattr(lva, "_platform_compiles", lambda: True)
     fields = json.loads((Path(__file__).resolve().parents[1] / "bench" / "configs"
                          / "trinity-large.json").read_text())["transformer"]
     fields["dtype"] = fields["param_dtype"] = jnp.bfloat16
@@ -790,8 +793,64 @@ def test_trinity_chunk_attends_in_the_kernel_and_forms_no_scores_over_the_view(o
         params, i32(1, 512), *full, *rings, i32(table), i32(ring), i32(), i32()).compile()
     memory, text = compiled.memory_analysis(), compiled.as_text()
     assert len(set(re.findall(r"%(view_flash_attention[.\d]*) = ", text))) == 5
+    assert "latent_view_attention" not in text
     assert not re.search(r"f32\[[\d,]*,(32768|4736)\]", text)
     assert memory.temp_size_in_bytes < 1.8e9, memory
+
+
+# ------------------------------------------- the chunk's latent attention over its view
+LATENT_ROWS = [512, 128, 200]
+
+
+@pytest.mark.parametrize("rows", LATENT_ROWS, ids=[f"{r}rows" for r in LATENT_ROWS])
+def test_latent_view_attention_compiles_at_the_published_shapes(one_chip, rows):
+    """``ops/latent_view_attention.py`` at DeepSeek-V2's 128 heads, ``kv_rank``
+    512, nope 128, rope 64 and v 128 over the cell's 8,192-wide view, for its
+    two buckets and rows that are no bucket: one kernel."""
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = _compiled_text(
+        lambda qn, qp, lat, pe, w, p: latent_view_attention(qn, qp, lat, pe, w, p, 0.1, interpret=False),
+        spec((1, rows, 128, 128)), spec((1, rows, 128, 64)), spec((1, 8192, 512)), spec((1, 8192, 64)),
+        spec((512, 128 * 256)), spec((1, rows), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "latent_view_attention" in text
+
+
+def test_deepseek_chunk_attends_in_the_kernel_and_copies_no_latent_view(one_chip, monkeypatch):
+    """``_deepseek_fields`` (published widths, two layers) with the serve cell's
+    pool, its 512-chunk traced as a TPU traces it: one kernel a layer; no
+    float32 ``[.., 128, 512, 1024]`` scores (the XLA loop's, a key block and
+    layer); the views handed over as the cache holds them, a layer picked
+    inside the kernel: nothing outside a fusion outputs a layer's latent or
+    rope-key view (the loop's ``squeeze`` of the stacked view) or the stacked
+    view but the in-place writes, bitcasts and the compiler's moves into fast
+    memory; and the temporaries under the XLA loop's (0.34 GB, compiled for the
+    same described chip)."""
+    from accelerate_tpu.models.transformer import Transformer, TransformerConfig
+    from accelerate_tpu.ops import latent_view_attention as lva
+    from accelerate_tpu.serving import pool
+
+    monkeypatch.setattr(lva, "_platform_compiles", lambda: True)
+    model = Transformer(TransformerConfig(**_deepseek_fields()))
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    L, lanes, per_lane, page = model.config.num_layers, 16, 64, 128
+    pages = [spec((L, lanes * per_lane + 1, 1, page, d), jnp.bfloat16) for d in (512, 64)]
+    i32 = lambda *s: spec(s, jnp.int32)
+    compiled = pool.make_paged_prefill_chunk(model, 512, page).lower(
+        params, i32(1, 512), *pages, i32(per_lane), i32(), i32()).compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert len(set(re.findall(r"%(latent_view_attention[.\d]*) = ", text))) == L
+    assert not re.search(r"f32\[[\d,]*128,512,1024\]", text)
+    views = {f"bf16[{stack}1,{per_lane * page},{unit}{d}]" for stack in ("", f"{L},") for unit in ("", "1,")
+             for d in (512, 64)}
+    comps, _ = _parse_hlo(text)
+    fused = {ins.called[0] for body in comps.values() for ins in body if ins.opcode == "fusion"}
+    made = [ins.line for name, body in comps.items() if name not in fused for ins in body
+            if ins.shape in views and ins.opcode not in _PASS_THROUGH | _WRITES | {"copy-start", "copy-done"}]
+    assert not made, made
+    assert memory.temp_size_in_bytes < 0.34e9, memory
 
 
 # --------------------------------------------- the decode window's views, built by the page copy
