@@ -28,7 +28,12 @@ import jax.numpy as jnp
 from flax import struct
 
 from ..ops.attention import dot_product_attention
-from ..ops.view_attention import view_flash_applies, view_flash_attention
+from ..ops.view_attention import (
+    view_decode_applies,
+    view_decode_attention,
+    view_flash_applies,
+    view_flash_attention,
+)
 
 
 def _constrain_sequence_parallel(x):
@@ -717,8 +722,8 @@ def cached_attention(q, k, v, q_positions, window=None, alibi=False,
     causal mask and the valid-entry mask (unwritten slots have ``j`` beyond
     every query position).  ``window`` adds the sliding-window band (Mistral):
     ``j > q_positions[i] - window``.  Runs as a masked einsum with an fp32
-    softmax over all ``M`` columns: right for decode and verify windows, whose
-    queries are a few rows.  For a prefill chunk it is three passes over
+    softmax over all ``M`` columns: right for verify windows, whose queries
+    are a few rows.  For a prefill chunk it is three passes over
     ``[B,Hkv,rep,S,M]`` float32 scores through HBM, most of them over columns
     that hold nothing yet: the one full-attention layer's ``[8,6,512,32768]``
     took 1.24 s of a 12 s slice of the long-document cell (ledger, PR 37;
@@ -730,7 +735,13 @@ def cached_attention(q, k, v, q_positions, window=None, alibi=False,
     what this call can see (:func:`~accelerate_tpu.ops.view_attention
     .view_flash_applies`) and by nothing else; ``tree_mask``, ``alibi``, float32,
     64-wide heads, narrow views, short queries and every other platform keep the
-    einsum below as it was.  GQA
+    einsum below as it was.  A decode step (``S == 1``) over such a view is
+    bound by the view's bytes, and the einsum reads every column of every
+    lane twice (Trinity's full layer: 5.7 ms of a 24.9 ms window on one TPU
+    v5e); it goes to the decode kernel
+    (:func:`~accelerate_tpu.ops.view_attention.view_decode_attention`: only
+    each lane's live key blocks), by the same kind of rule
+    (:func:`~accelerate_tpu.ops.view_attention.view_decode_applies`).  GQA
     groups fold into the query tensor (``[B,S,Hkv,rep,D]``) so the cache is
     contracted UNexpanded — a ``jnp.repeat`` of K/V would multiply the
     per-token HBM reads by the query/kv head ratio on the decode hot path.
@@ -753,8 +764,11 @@ def cached_attention(q, k, v, q_positions, window=None, alibi=False,
     precede its reads), and the band mask is taken over those positions;
     columns not yet written come out negative and are masked.
     """
-    if tree_mask is None and not alibi and view_flash_applies(q, k):
-        return view_flash_attention(q, k, v, q_positions, window=window, ring=ring)
+    if tree_mask is None and not alibi:
+        if view_flash_applies(q, k):
+            return view_flash_attention(q, k, v, q_positions, window=window, ring=ring)
+        if view_decode_applies(q, k):
+            return view_decode_attention(q, k, v, q_positions, window=window, ring=ring)
     b, s, n_q, d = q.shape
     m = k.shape[2]
     n_kv = k.shape[1] // d

@@ -64,7 +64,13 @@ from ..logging import get_logger
 from ..models.generation import GenerationConfig
 from ..models.transformer import Transformer
 from ..ops.latent_view_attention import latent_flash_applies
-from ..ops.view_attention import KEY_BLOCK, view_flash_applies
+from ..ops.view_attention import (
+    DECODE_BLOCK,
+    KEY_BLOCK,
+    live_key_blocks,
+    view_decode_applies,
+    view_flash_applies,
+)
 from ..ops.view_gather import view_gather_applies
 from ..telemetry import (
     CostTable,
@@ -984,8 +990,7 @@ class ServingEngine:
         #: place (the Pallas prefill kernel) or a recurrent state
         self._chunk_views = ()
         if not (self._stateful or self.prefill_kernel == "pallas"):
-            self._chunk_views = (self.kv.tables.shape[1] * self.page_size,) + (
-                (self.kv.ring_pages * self.page_size,) if self._mixed else ())
+            self._chunk_views = self._gathered_views()
             # key blocks (``ops/view_attention.py`` ``KEY_BLOCK`` columns) of the
             # ``max_len``-wide view that a dispatched chunk could see a key in,
             # and that the view has: counted on the host, where ``base`` is known
@@ -996,8 +1001,11 @@ class ServingEngine:
         if self._gathers_view:
             # (lane, page slot) blocks the decode windows' flat views were
             # filled with, over every such array, and those copied from a live
-            # page: counted on the host at dispatch, from the tables and indices
-            self.stats.update(view_slots_live=0, view_slots=0)
+            # page: counted on the host at dispatch, from the tables and indices;
+            # and the key blocks (``DECODE_BLOCK`` columns) of the ``max_len``-wide
+            # view that hold a key the window's steps see, and that the view has
+            self.stats.update(view_slots_live=0, view_slots=0,
+                              decode_key_blocks_live=0, decode_key_blocks_view=0)
         self.stats.engine = self
         self._counters = {
             k: self.metrics.counter(f"serve/{k}_total") for k in self.stats
@@ -1181,6 +1189,22 @@ class ServingEngine:
             help="1 where the decode window's gathered views are filled by the Pallas "
                  "page copy, 0 where by a zero fill and one update a page",
         ).set(float(self.view_gather_kernel))
+        #: whether a decode step's attention over the window's gathered views
+        #: runs in the Pallas decode kernel (``ops/view_attention.py``) for some
+        #: kind of view: what ``cached_attention`` will see when the window is
+        #: traced; never under ``tp > 1``
+        self.decode_attention_kernel = self._gathers_view and self.tp_degree == 1 and (
+            cfg.positional != "alibi") and any(
+            view_decode_applies(
+                jax.ShapeDtypeStruct((self.num_slots, 1, cfg.num_heads, cfg.resolved_head_dim), cfg.dtype),
+                jax.ShapeDtypeStruct((self.num_slots, cfg.num_kv_heads * cfg.resolved_head_dim, m),
+                                     self.kv.pages_k.dtype))
+            for m in self._gathered_views())
+        self.metrics.gauge(
+            "serve/decode_attention_kernel",
+            help="1 where a decode step's attention over the window's gathered views runs "
+                 "in the Pallas decode kernel, 0 where it is XLA's masked softmax",
+        ).set(float(self.decode_attention_kernel))
         self._kv_quant_gauge = (
             self.metrics.gauge(
                 "serve/kv_quant_error",
@@ -1229,6 +1253,12 @@ class ServingEngine:
         # per-step deadline sweep off the hot path until a deadline exists
         self._service_ema = 0.0
         self._has_deadlines = False
+
+    def _gathered_views(self) -> Tuple[int, ...]:
+        """The widths of the views a chunk or a decode window gathers: the
+        ``max_len``-wide one, and a two-rule pool's ring."""
+        return (self.kv.tables.shape[1] * self.page_size,) + (
+            (self.kv.ring_pages * self.page_size,) if self._mixed else ())
 
     def _chunk_kernel_applies(self, rows: int, m: int) -> bool:
         """Whether a chunk of ``rows`` attends over an ``m``-wide view in a
@@ -2687,7 +2717,9 @@ class ServingEngine:
         fills its flat views with, K's and V's arrays each, and those copied
         from a live page: a full table's slots as ``pool._live_tables`` masks
         them with the lanes' indices, a ring's whole; the rest hold the null
-        page."""
+        page.  And the key blocks of the ``max_len``-wide view its steps see
+        (:func:`~accelerate_tpu.ops.view_attention.live_key_blocks`), of all the
+        view's: what the decode kernel reads of it, whichever form runs."""
         kv = self.kv
         live = (self._lane_len + self.window - 1) // self.page_size + 1
         held = int(np.count_nonzero((np.arange(kv.tables.shape[1]) < live[:, None])
@@ -2698,6 +2730,12 @@ class ServingEngine:
             slots += kv.ring_tables.size
         self._bump("view_slots_live", 2 * held)
         self._bump("view_slots", 2 * slots)
+        # every lane's view up to the window's last position (a lane that is
+        # not decoding stays at its index: one block)
+        last = np.where(self._active, self._lane_len + self.window - 1, self._lane_len)
+        blocks = live_key_blocks(last, self._gathered_views()[0], DECODE_BLOCK, None, False, xp=np)
+        self._bump("decode_key_blocks_live", int(blocks.sum()))
+        self._bump("decode_key_blocks_view", blocks.size)
 
     def _decode_cycle(self, n_occupied: int) -> Readback:
         """Dispatch one decode window and return its in-flight handle.  The

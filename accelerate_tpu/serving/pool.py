@@ -722,9 +722,9 @@ def make_state_decode_window(model: Transformer, window: int,
 def _kernel_form(shardings: Optional[ServeShardings]):
     """The context a program's gathered arm is traced in.  Under ``tp > 1`` its
     pool and views are sharded over key/value heads and a ``pallas_call`` has no
-    partitioning rule, so the chunk's flash kernel (``ops/view_attention.py``)
-    and the view's page copy (``ops/view_gather.py``) give way to their XLA
-    forms: :func:`~accelerate_tpu.ops.view_attention.xla_form`."""
+    partitioning rule, so the chunk's flash kernel and the decode step's kernel
+    (``ops/view_attention.py``) and the view's page copy (``ops/view_gather.py``)
+    give way to their XLA forms: :func:`~accelerate_tpu.ops.view_attention.xla_form`."""
     return xla_form if shardings is not None and shardings.tp_degree > 1 else contextlib.nullcontext
 
 
@@ -1075,10 +1075,10 @@ def make_paged_decode_window(model: Transformer, window: int,
                 v=_gather_view(pages_v, gt, flat),
                 index=index,
             )
-        cache, toks, tok, rngs, counts = _decode_scan(
-            model, window, params, cache, tokens, active, eos, do_sample,
-            temperature, top_k, top_p, pad, rngs,
-        )
+            cache, toks, tok, rngs, counts = _decode_scan(
+                model, window, params, cache, tokens, active, eos, do_sample,
+                temperature, top_k, top_p, pad, rngs,
+            )
         pages_k = _store_span_pages(pages_k, cache.k, tables, index, window, active, flat)
         pages_v = _store_span_pages(pages_v, cache.v, tables, index, window, active, flat)
         return _with_counts(pages_k, pages_v, toks, tok, rngs, counts)

@@ -7,6 +7,16 @@ loop at DeepSeek-V2's widths (128 heads, ``kv_rank`` 512, nope 128, rope 64, v
 128, a view of 8,192) at one to eight live key blocks of 1,024.
 
     python3 benchmarks/view_attention_microbench.py [out.jsonl] [--blocks 256,512,1024] [--latent-only]
+    python3 benchmarks/view_attention_microbench.py [out.jsonl] --decode-only
+
+``--decode-only`` times a decode step instead (``view_decode_attention`` beside
+the masked einsum): Trinity's full view ``[8, 1024, 32768]`` and ring of 4,736,
+Mellum2's ``[32, 512, 8192]`` and ring of 1,664, and views of other widths at
+Mellum2's heads, each with the lanes at the cell's depths (a seeded draw of its
+prompt lengths and live share: ``decode_window_split.py``'s) and with every lane
+at the view's end; ``LOOP`` steps in one program, so the time is the device's
+and not the host's dispatch, and the share is of 819 GB/s for the bytes of the
+keys and values a query sees.
 
 One JSON line a measurement: milliseconds a call (host clock over ``CALLS`` calls
 of one jitted function, the last waited for), the FLOPs the live keys need
@@ -23,6 +33,7 @@ import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +42,12 @@ import numpy as np
 from accelerate_tpu.models.latent_attention import attend_decompressed  # noqa: E402
 from accelerate_tpu.models.transformer import cached_attention  # noqa: E402
 from accelerate_tpu.ops.latent_view_attention import latent_view_attention  # noqa: E402
-from accelerate_tpu.ops.view_attention import KEY_BLOCK, view_flash_attention, xla_form  # noqa: E402
+from accelerate_tpu.ops.view_attention import (  # noqa: E402
+    KEY_BLOCK,
+    view_decode_attention,
+    view_flash_attention,
+    xla_form,
+)
 
 HQ, HKV, D, FULL, RING, WINDOW = 48, 8, 128, 32768, 37 * 128, 4096
 #: DeepSeek-V2's latent attention: heads, kv_rank, nope, rope, v, the view
@@ -67,6 +83,8 @@ def main():
                 fh.write(json.dumps(record) + "\n")
 
     draw = lambda key, shape: jax.jit(lambda k: jax.random.normal(k, shape, jnp.float32).astype(BF16))(key)
+    if "--decode-only" in sys.argv:
+        return decode(draw, emit)
     if "--latent-only" not in sys.argv:
         views(blocks, draw, emit)
     latent(draw, emit)
@@ -135,6 +153,52 @@ def latent(draw, emit):
             gap = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.reshape(got.shape).astype(jnp.float32))))
             emit(**case, form="kernel", ms=ms, peak_pct=100 * flops / (ms * 1e-3) / PEAK,
                  max_abs_gap=gap, finite=bool(np.isfinite(np.asarray(got, np.float32)).all()))
+
+
+#: decode cases: name, lanes, query heads, key/value heads, view, window, ring, config for the lanes' depths
+DECODE = [("trinity-full", 8, 48, 8, 32768, None, False, "trinity-large"),
+          ("trinity-ring", 8, 48, 8, 37 * 128, 4096, True, "trinity-large"),
+          ("mellum2-full", 32, 32, 4, 8192, None, False, "mellum2-12b"),
+          ("mellum2-ring", 32, 32, 4, 13 * 128, 1024, True, "mellum2-12b"),
+          ("mellum2-heads-1024", 32, 32, 4, 1024, None, False, "mellum2-12b"),
+          ("mellum2-heads-2048", 32, 32, 4, 2048, None, False, "mellum2-12b"),
+          ("mellum2-heads-4096", 32, 32, 4, 4096, None, False, "mellum2-12b")]
+LOOP, HBM = 16, 819e9
+
+
+def decode(draw, emit):
+    """One decode step's attention, ``LOOP`` times in one program (the query
+    moved a little each time, so nothing is hoisted), the view the same."""
+    from decode_window_split import lane_positions
+
+    keys = jax.random.split(jax.random.PRNGKey(45), 3)
+
+    def looped(attend):
+        def run(q, k, v, positions):
+            def body(i, acc):
+                out = attend((q * (1 + i.astype(jnp.float32) * 1e-3)).astype(BF16), k, v, positions)
+                return acc + out.astype(jnp.float32)
+            return jax.lax.fori_loop(0, LOOP, body, jnp.zeros(q.shape, jnp.float32)) / LOOP
+        return jax.jit(run)
+
+    for name, lanes, hq, hkv, m, window, ring, config in DECODE:
+        k, v = draw(keys[1], (lanes, hkv * D, m)), draw(keys[2], (lanes, hkv * D, m))
+        q = draw(keys[0], (lanes, 1, hq, D))
+        drawn = np.minimum(lane_positions(config, 45), m - 1 if not ring else 10 ** 6)
+        for depth, last in (("cell", drawn), ("end", np.full(lanes, 3 * m + 17 if ring else m - 1))):
+            positions = jnp.asarray(last, jnp.int32)[:, None]
+            seen = np.minimum(last + 1, window) if window else last + 1
+            need = 2 * hkv * D * 2 * int(seen.sum())
+            case = dict(case=name, lanes=lanes, view=m, depth=depth, seen_keys=int(seen.sum()))
+            einsum = looped(lambda q, k, v, p: cached_attention(q, k, v, p, window=window, ring=ring))
+            with xla_form():
+                ms, want = timed(einsum, q, k, v, positions)
+            emit(**case, form="xla", ms=ms / LOOP, hbm_pct=100 * need / (ms / LOOP * 1e-3) / HBM)
+            kernel = looped(lambda q, k, v, p: view_decode_attention(q, k, v, p, window=window, ring=ring))
+            ms, got = timed(kernel, q, k, v, positions)
+            gap = float(jnp.max(jnp.abs(got - want)))
+            emit(**case, form="kernel", ms=ms / LOOP, hbm_pct=100 * need / (ms / LOOP * 1e-3) / HBM,
+                 max_abs_gap=gap, finite=bool(np.isfinite(np.asarray(got)).all()))
 
 
 if __name__ == "__main__":

@@ -875,7 +875,8 @@ class _Window(NamedTuple):
 @pytest.fixture(scope="module")
 def cell_window(one_chip):
     """``cell -> _Window``: the cell's decode window at full depth with its
-    pool, its views traced as a TPU traces them (the page copy; the experts'
+    pool, its views and their attention traced as a TPU traces them (the page
+    copy, the decode kernel where the view's shapes take it; the experts'
     products stay ``ragged-dot``), each compiled once a module."""
     import json
     from pathlib import Path
@@ -915,6 +916,7 @@ def cell_window(one_chip):
         pages = [spec((layers, num_pages, *rows), jnp.bfloat16) for layers, num_pages in arrays]
         with pytest.MonkeyPatch.context() as patch:
             patch.setattr("accelerate_tpu.ops.view_gather._platform_compiles", lambda: True)
+            patch.setattr("accelerate_tpu.ops.view_attention._platform_compiles", lambda: True)
             lowered = fn.lower(params, *pages, *tables, i32(lanes), i32(lanes), *lane_vectors)
         return _Window(lowered.compile(), tuple("bf16[" + ",".join(map(str, (*a, *rows))) + "]" for a in arrays),
                        views)
@@ -964,3 +966,59 @@ def test_the_cells_decode_windows_fill_their_views_in_the_page_copy(cell_window,
     memory = built.compiled.memory_analysis()
     assert memory.temp_size_in_bytes <= VIEW_CELLS[cell][-1] * 1e9, memory
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9, memory
+
+
+#: the mixed cells' decode windows: how many layers attend in the decode kernel (every kind of view
+#: at least ``view_attention._MIN_DECODE_VIEW`` wide), and the full view's width
+DECODE_KERNELS = {"trinity": (5, 32768), "mellum2": (8, 8192)}
+_NAMED = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+\S+\s+([\w\-]+)\(")
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_KERNELS))
+def test_the_cells_decode_windows_attend_in_the_decode_kernel(cell_window, cell):
+    """Trinity's and Mellum2's mixed windows at their published widths, compiled
+    for the chip: a decode step's attention over a view the rule takes is one
+    ``view_decode_attention`` call a layer (Trinity's four rings of 4,736 and its
+    full view; Mellum2's six rings of 1,664 and two full views);
+    no float32 array over the full view's columns (the einsum's scores) is
+    left; and each call is handed the K and V views as the layer's in-place
+    column writes leave them, no copy of a view round the kernel (a carried view
+    copied at every step cost the windows 13 ms before the page copy)."""
+    kernels, width = DECODE_KERNELS[cell]
+    text = cell_window(cell).compiled.as_text()
+    named = {m.group(1): (m.group(2), line) for line in text.splitlines() for m in [_NAMED.match(line)] if m}
+    calls = [line for op, line in named.values() if op == "custom-call" and "view_decode_attention" in line]
+    assert len({line.split(" = ")[0] for line in calls}) == kernels
+    assert not re.search(rf"f32\[[\d,]*,{width}\]", text)
+    comps, _ = _parse_hlo(text)
+    for line in calls:
+        *_, k_view, v_view = re.search(r"custom-call\(([^)]*)\)", line).group(1).replace("%", "").split(", ")
+        for view in (k_view, v_view):
+            opcode, source = named[view]
+            if opcode == "fusion":
+                called = re.search(r"calls=%?([\w.\-]+)", source).group(1)
+                opcode = next(i for i in comps[called] if i.is_root).opcode
+            assert opcode in _WRITES | _PASS_THROUGH, source[:300]
+
+
+#: heads -> (query heads, key/value heads): Trinity's and Mellum2's
+DECODE_HEADS = {"trinity": (48, 8), "mellum2": (32, 4)}
+
+
+@pytest.mark.parametrize("heads", sorted(DECODE_HEADS))
+def test_view_decode_attention_compiles_at_the_most_lanes_its_rule_takes(one_chip, heads):
+    """The decode kernel keeps every lane's query and output in fast memory:
+    at the most lanes ``view_decode_applies`` takes for these heads (a view of
+    1,024 columns, so the views fit the chip's memory) it compiles for the chip,
+    and one lane more is refused, so larger batches keep the einsum."""
+    from accelerate_tpu.ops import view_attention as va
+
+    n_q, n_kv = DECODE_HEADS[heads]
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    shapes = lambda lanes: (spec((lanes, 1, n_q, 128)), spec((lanes, n_kv * 128, 1024)))
+    most = max(lanes for lanes in range(1, 4096) if va.view_decode_applies(*shapes(lanes), interpret=True))
+    assert most >= 256 and not va.view_decode_applies(*shapes(most + 1), interpret=True)
+    q, k = shapes(most)
+    text = _compiled_text(lambda q, k, v, p: va.view_decode_attention(q, k, v, p, interpret=False),
+                          q, k, k, spec((most, 1), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "view_decode_attention" in text
